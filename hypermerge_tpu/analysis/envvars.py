@@ -75,13 +75,9 @@ REGISTRY: Tuple[EnvVar, ...] = (
            "round-robin scheduler."),
     EnvVar("HM_RR_LEAST_LOADED", "0", "Shortest-queue-first slab "
            "placement instead of strict round-robin."),
-    EnvVar("HM_ICI_PALLAS", "1", "Pallas async remote-copy ring for "
-           "collective gathers on real ICI (0 = lax.all_gather twin)."),
-    EnvVar("HM_COMPILE_CACHE", None, "Persistent XLA compile-cache "
-           "directory override (default ~/.cache/hypermerge_tpu/xla; "
-           "empty disables)."),
-    EnvVar("HM_COMPILE_CACHE_FORCE", "0", "Force-enable the persistent "
-           "XLA compile cache even on CPU."),
+    EnvVar("HM_ICI_PALLAS", "1", "Pallas async remote-copy gather "
+           "for collective gathers on real ICI (0 = lax.all_gather "
+           "twin)."),
     # -- storage --------------------------------------------------------
     EnvVar("HM_SLAB", "1", "Columnar sidecars in one mmap'd corpus slab "
            "file (0 = per-feed .cols2 files)."),

@@ -613,7 +613,17 @@ class _ShardRouter:
                 pid = slot["pid"] if slot is not None else None
                 alive = p is not None
             edits = 0
+            device = install_error = None
             if isinstance(p, dict):
+                # which device this worker computes on (None until it
+                # has compiled anything), and why its last serve
+                # install fell to the host path: on a one-chip host
+                # only one worker can hold the chip — the split must
+                # be visible, not a worker quietly serving from host
+                device = p.get("device")
+                install_error = (p.get("serve") or {}).get(
+                    "last_install_error"
+                )
                 for name, v in (p.get("counters") or {}).items():
                     if isinstance(v, (int, float)):
                         counters[name] = counters.get(name, 0) + v
@@ -640,6 +650,8 @@ class _ShardRouter:
                 "edits": edits,
                 "queue": queue,
                 "respawns": respawns,
+                "device": device,
+                "install_error": install_error,
             }
             counters[f"workers.{i}.edits"] = edits
             counters[f"workers.{i}.queue"] = queue

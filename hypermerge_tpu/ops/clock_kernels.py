@@ -14,24 +14,25 @@ INFINITY_SEQ) maps to INT32_INF on device.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INT32_INF = jnp.int32(2**31 - 1)
+from . import compile_cache
+
+INT32_INF = np.int32(2**31 - 1)  # host scalar: importing touches no device
 
 # cmp result codes — stable across host/device (crdt/clock.Ordering)
 EQ, GT, LT, CONCUR = 0, 1, 2, 3
 
 
-@jax.jit
+@compile_cache.jit
 def gte(a: jax.Array, b: jax.Array) -> jax.Array:
     """a, b: [..., actors] -> [...] bool. a dominates b elementwise."""
     return jnp.all(a >= b, axis=-1)
 
 
-@jax.jit
+@compile_cache.jit
 def cmp(a: jax.Array, b: jax.Array) -> jax.Array:
     """[..., actors] x [..., actors] -> [...] int32 code (EQ/GT/LT/CONCUR)."""
     a_gte = jnp.all(a >= b, axis=-1)
@@ -43,31 +44,31 @@ def cmp(a: jax.Array, b: jax.Array) -> jax.Array:
     ).astype(jnp.int32)
 
 
-@jax.jit
+@compile_cache.jit
 def union(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.maximum(a, b)
 
 
-@jax.jit
+@compile_cache.jit
 def intersection(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.minimum(a, b)
 
 
-@jax.jit
+@compile_cache.jit
 def union_reduce(clocks: jax.Array) -> jax.Array:
     """[n, actors] -> [actors]: union of many clocks in one reduction —
     the ClockStore.getMultiple + Clock.union fold as a single max-reduce."""
     return jnp.max(clocks, axis=0)
 
 
-@jax.jit
+@compile_cache.jit
 def satisfied(clock: jax.Array, minimum: jax.Array) -> jax.Array:
     """minimumClock render gate (reference src/DocBackend.ts:90-113):
     clock [..., actors] >= minimum [..., actors] -> [...] bool."""
     return jnp.all(clock >= minimum, axis=-1)
 
 
-@jax.jit
+@compile_cache.jit
 def cursor_window(doc_seqs: jax.Array, cursor_seqs: jax.Array) -> jax.Array:
     """Change-window computation of RepoBackend.syncChanges (reference
     src/RepoBackend.ts:513-522): per (doc, actor), how many new changes the
@@ -78,7 +79,7 @@ def cursor_window(doc_seqs: jax.Array, cursor_seqs: jax.Array) -> jax.Array:
     return jnp.maximum(jnp.minimum(cursor_seqs, INT32_INF) - doc_seqs, 0)
 
 
-@partial(jax.jit, static_argnames=("k",))
+@compile_cache.jit(static_argnames=("k",))
 def top_k_dominated(clocks: jax.Array, query: jax.Array, k: int):
     """Bulk query: indices of up to k docs whose clock is dominated by
     `query` — the device form of 'which docs are fully covered by this
@@ -93,8 +94,6 @@ def top_k_dominated(clocks: jax.Array, query: jax.Array, k: int):
 
 def pack_clocks(rows) -> jax.Array:
     """Host rows (crdt.clock.pack output) -> device array with int32 clamp."""
-    import numpy as np
-
     arr = np.asarray(rows, dtype=np.int64)
     arr = np.minimum(arr, int(INT32_INF))
     return jnp.asarray(arr.astype(np.int32))

@@ -29,7 +29,7 @@ The reference processes documents one at a time on one Node thread
   everything resident (clock union across every chip's slabs, the bulk
   summary gather) run as one `shard_map` collective program over the
   mesh instead of a host-side merge of per-device fetches. On real ICI
-  the gather rides a Pallas `make_async_remote_copy` ring
+  the gather is a Pallas `make_async_remote_copy` program
   (`remote_copy_capable`); host-platform CPU meshes lower the same
   program through `lax` collectives, so CPU CI pins the numerics.
 
@@ -46,9 +46,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import compile_cache
 from ..ops.columnar import ColumnarBatch
 from ..ops.crdt_kernels import MaterializeOut, batched_kernel
 from .. import telemetry
@@ -86,6 +87,9 @@ trace_counts: Dict[Tuple, int] = {}
 def _program(key: Tuple, build: Callable[[], Any]) -> Any:
     fn = _PROGRAMS.get(key)
     if fn is None:
+        # the one seam every mesh, serve and pack program is built
+        # through: place the compile cache before its first compile
+        compile_cache.ensure()
         fn = build()
         _PROGRAMS[key] = fn
     return fn
@@ -110,77 +114,91 @@ def clear_program_cache() -> None:
     trace_counts.clear()
 
 
+# a uint8 array tiles (32 rows, 128 lanes) on TPU: the gather's blocks
+# are padded to it so a block is a whole number of tiles
+_GATHER_TILE = (32, 128)
+
+
 def remote_copy_capable(mesh: Optional[Mesh] = None) -> bool:
     """True when the mesh's devices can run the Pallas
-    `make_async_remote_copy` ICI ring (real TPU chips with the pallas
-    TPU backend importable). Host-platform CPU meshes — the CI twin —
-    always lower the lax-collective variant instead. HM_ICI_PALLAS=0
-    forces the lax path on hardware too (A/B and escape hatch)."""
+    `make_async_remote_copy` ICI gather (real TPU chips). Host-platform
+    CPU meshes — the CI twin — always lower the lax-collective variant
+    instead. HM_ICI_PALLAS=0 forces the lax path on hardware too (A/B
+    and escape hatch)."""
     if os.environ.get("HM_ICI_PALLAS", "1") == "0":
         return False
-    try:
-        devs = (
-            list(mesh.devices.flat) if mesh is not None else jax.devices()
-        )
-        if not devs or devs[0].platform != "tpu":
-            return False
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-        return hasattr(pltpu, "make_async_remote_copy")
-    except Exception:
-        return False
+    devs = list(mesh.devices.flat) if mesh is not None else jax.devices()
+    return bool(devs) and devs[0].platform == "tpu"
 
 
-def _pallas_ring_gather(n_devices: int, rows: int, width: int, dtype):
-    """Pallas ring all-gather over the flattened mesh axis: each chip
-    DMAs its [rows, width] block to its right neighbor n-1 times
-    (`make_async_remote_copy`, double-buffered comm slots), assembling
-    the replicated [n*rows, width] output without touching the host.
+def _pallas_gather(n_devices: int, rows: int, width: int, dtype):
+    """Pallas all-gather over the "dp" mesh axis, HBM to HBM: after a
+    barrier (every peer has entered the kernel, so its output buffer
+    exists) each chip DMAs its [rows, width] block straight into its
+    slot of every peer's replicated [n*rows, width] output
+    (`make_async_remote_copy`) and into its own (`make_async_copy`) —
+    no VMEM staging, so the block size is bounded by HBM only. The
+    slot slices are DMA windows into a tiled buffer: `rows` and `width`
+    must be multiples of the uint8 tile (`_GATHER_TILE`; the caller
+    pads). Sends and receives are symmetric (n-1 equal-sized blocks
+    each way), so waiting on copy k settles one send and one receive.
     Built only when `remote_copy_capable` — the lax.all_gather twin is
-    the numerics reference on CPU CI. The ring runs over the "dp" mesh
-    axis: `_gather_program` selects this path only when sp == 1, so dp
-    IS the flattened device ring."""
+    the numerics reference on CPU CI. `_gather_program` selects this
+    path only when sp == 1, so dp IS the flattened device axis."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def kernel(local_ref, out_ref, comm_ref, send_sem, recv_sem):
+    mesh_id = pltpu.DeviceIdType.MESH
+
+    def kernel(local_ref, out_ref, own_sem, send_sem, recv_sem):
         my_id = jax.lax.axis_index("dp")
-        right = jax.lax.rem(my_id + 1, n_devices)
-        out_ref[pl.ds(my_id * rows, rows), :] = local_ref[:]
-        comm_ref[0] = local_ref[:]
-        for step in range(n_devices - 1):
-            src = (my_id - step - 1) % n_devices
-            send_slot = step % 2
-            recv_slot = (step + 1) % 2
+        peers = [
+            jax.lax.rem(my_id + k, n_devices)
+            for k in range(1, n_devices)
+        ]
+        barrier = pltpu.get_barrier_semaphore()
+        for peer in peers:
+            pltpu.semaphore_signal(
+                barrier, inc=1, device_id={"dp": peer},
+                device_id_type=mesh_id,
+            )
+        pltpu.semaphore_wait(barrier, n_devices - 1)
+        slot = out_ref.at[pl.ds(my_id * rows, rows)]
+        own = pltpu.make_async_copy(local_ref, slot, own_sem)
+        own.start()
+        sends = []
+        for k, peer in enumerate(peers):
             rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_ref.at[send_slot],
-                dst_ref=comm_ref.at[recv_slot],
-                send_sem=send_sem.at[send_slot],
-                recv_sem=recv_sem.at[recv_slot],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                src_ref=local_ref,
+                dst_ref=slot,
+                send_sem=send_sem.at[k],
+                recv_sem=recv_sem.at[k],
+                device_id={"dp": peer},
+                device_id_type=mesh_id,
             )
             rdma.start()
+            sends.append(rdma)
+        own.wait()
+        for rdma in sends:
             rdma.wait()
-            out_ref[pl.ds(src * rows, rows), :] = comm_ref[recv_slot]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
         scratch_shapes=[
-            pltpu.VMEM((2, rows, width), dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA((n_devices - 1,)),
+            pltpu.SemaphoreType.DMA((n_devices - 1,)),
         ],
     )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_devices * rows, width), dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0)
-        if hasattr(pltpu, "TPUCompilerParams")
-        else None,
+        compiler_params=pltpu.CompilerParams(
+            collective_id=0, has_side_effects=True
+        ),
     )
 
 
@@ -195,12 +213,8 @@ def shard_batch(batch: ColumnarBatch, mesh: Mesh):
     import numpy as np
 
     from ..ops import crdt_kernels as _ck
-    from ..ops.crdt_kernels import (
-        _enable_persistent_compile_cache,
-        host_args,
-    )
+    from ..ops.crdt_kernels import host_args
 
-    _enable_persistent_compile_cache()
     dp = mesh.shape["dp"]
     D = batch.n_docs
     D_pad = pad_to_multiple(max(D, dp), dp)
@@ -329,7 +343,7 @@ def _union_program(mesh: Mesh):
                 mesh=mesh,
                 in_specs=P("dp", "sp"),
                 out_specs=P("sp"),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -365,7 +379,7 @@ def _dominated_program(mesh: Mesh):
                 mesh=mesh,
                 in_specs=(P("dp", "sp"), P("sp")),
                 out_specs=P("dp"),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -411,7 +425,7 @@ def _local_union_program(mesh: Mesh, n_actors: int):
                 mesh=mesh,
                 in_specs=(P("dp"), P("dp")),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -455,7 +469,7 @@ def _step_program(mesh: Mesh, A: int, K: int, n_actors: int):
                     ),
                     P(),
                 ),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -475,21 +489,17 @@ def step(batch: ColumnarBatch, mesh: Mesh):
         return fn(*args)
 
 
-def _gather_program(mesh: Mesh, dtype, force_lax: bool = False):
+def _gather_program(mesh: Mesh, dtype):
     """[rows, W] sharded over the flattened mesh axis -> replicated
     [rows, W]: the bulk summary gather as one collective program. On
-    meshes whose chips pass `remote_copy_capable` the inner gather is a
-    Pallas `make_async_remote_copy` ring (sp == 1 ring topology);
+    meshes whose chips pass `remote_copy_capable` the inner gather is
+    the Pallas `make_async_remote_copy` program (sp == 1 topology);
     everywhere else (CPU CI, sp > 1) it is `lax.all_gather` — identical
-    numerics, different transport. A Pallas failure can surface at
-    TRACE time (caught inside, falls back per-build) or at COMPILE
-    time (outside any try here — the caller retries with
-    `force_lax=True`, which keys a separate cached program)."""
+    numerics, different transport. A Pallas failure is an error, never
+    a quiet switch to the lax twin."""
     n = mesh.devices.size
     use_pallas = (
-        not force_lax
-        and remote_copy_capable(mesh)
-        and mesh.shape["sp"] == 1
+        n > 1 and remote_copy_capable(mesh) and mesh.shape["sp"] == 1
     )
     key = ("gather", mesh, jnp.dtype(dtype).name, use_pallas)
 
@@ -499,16 +509,9 @@ def _gather_program(mesh: Mesh, dtype, force_lax: bool = False):
             return jax.lax.all_gather(g, "dp", axis=0, tiled=True)
 
         def pallas_gather(x):
-            try:
-                ring = _pallas_ring_gather(
-                    n, x.shape[0], x.shape[1], x.dtype
-                )
-                return ring(x)
-            except Exception:
-                # pallas TRACE failed for this shape/backend: the lax
-                # twin is always correct (compile-time failures are
-                # the caller's force_lax retry)
-                return lax_gather(x)
+            return _pallas_gather(
+                n, x.shape[0], x.shape[1], x.dtype
+            )(x)
 
         f = pallas_gather if use_pallas else lax_gather
         return jax.jit(
@@ -517,7 +520,7 @@ def _gather_program(mesh: Mesh, dtype, force_lax: bool = False):
                 mesh=mesh,
                 in_specs=P(("dp", "sp")),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -540,7 +543,7 @@ def _combine_partials_program(mesh: Mesh):
                 mesh=mesh,
                 in_specs=P(("dp", "sp")),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -721,7 +724,7 @@ class MeshBulkScheduler(SlabRoundRobin):
     - `gather_summaries()`: every chip's resident summary wires stack
       on-chip, assemble into one mesh-sharded [rows, W] array, and ONE
       collective gather program (`lax.all_gather`, or the Pallas
-      `make_async_remote_copy` ring on capable ICI) replicates them —
+      `make_async_remote_copy` program on capable ICI) replicates them —
       the host reads the whole load's summaries in ONE transfer, in
       dispatch order.
 
@@ -847,27 +850,25 @@ class MeshBulkScheduler(SlabRoundRobin):
                 sum(int(w.shape[0]) for _s, _n, w in per_chip.get(i, []))
                 for i in range(len(self.devices))
             ]
-            rows = max(max(rows_per_chip), 1)
+            rows = pad_to_multiple(
+                max(max(rows_per_chip), 1), _GATHER_TILE[0]
+            )
+            Wp = pad_to_multiple(W, _GATHER_TILE[1])
             stacks = []
             for i in range(len(self.devices)):
                 items = per_chip.get(i, [])
                 key = ("wire_stack", W, rows, len(items))
 
-                def build(items=items, rows=rows, W=W):
+                def build(items=items, rows=rows, W=W, Wp=Wp):
                     def f(*wires):
+                        used = sum(w.shape[0] for w in wires)
                         parts = list(wires) + [
-                            jnp.zeros(
-                                (
-                                    rows
-                                    - sum(
-                                        w.shape[0] for w in wires
-                                    ),
-                                    W,
-                                ),
-                                jnp.uint8,
-                            )
+                            jnp.zeros((rows - used, W), jnp.uint8)
                         ]
-                        return jnp.concatenate(parts, axis=0)
+                        return jnp.pad(
+                            jnp.concatenate(parts, axis=0),
+                            ((0, 0), (0, Wp - W)),
+                        )
 
                     return jax.jit(_traced(key, f))
 
@@ -877,33 +878,17 @@ class MeshBulkScheduler(SlabRoundRobin):
                 else:
                     stacks.append(
                         jax.device_put(
-                            jnp.zeros((rows, W), jnp.uint8),
+                            jnp.zeros((rows, Wp), jnp.uint8),
                             self.devices[i],
                         )
                     )
             sh = NamedSharding(self.mesh, P(("dp", "sp")))
             arr = jax.make_array_from_single_device_arrays(
-                (len(self.devices) * rows, W), sh, stacks
+                (len(self.devices) * rows, Wp), sh, stacks
             )
             gfn = _gather_program(self.mesh, jnp.uint8)
-            try:
-                with self.mesh:
-                    host = np.asarray(gfn(arr))
-            except Exception:
-                # a Pallas ring that traced but failed to COMPILE (or
-                # execute) for this shape: retry on the lax-collective
-                # twin, which is always correct. Never retry a lax
-                # failure — that is a real error.
-                if not (
-                    remote_copy_capable(self.mesh)
-                    and self.mesh.shape["sp"] == 1
-                ):
-                    raise
-                gfn = _gather_program(
-                    self.mesh, jnp.uint8, force_lax=True
-                )
-                with self.mesh:
-                    host = np.asarray(gfn(arr))
+            with self.mesh:
+                host = np.asarray(gfn(arr))[:, :W]
             _M_D2H.add(host.nbytes)
             for i in range(len(self.devices)):
                 base = i * rows

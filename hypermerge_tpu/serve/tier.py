@@ -29,9 +29,12 @@ second copy here).
 Degradation ladder (never an error to the reader): unresident or
 unrebuildable doc -> host path (serve.fallbacks); device OOM during
 install -> evict LRU + retry once (serve.evictions_pressure) -> host
-path; admission queue full -> host path. A repeated host-path read of
-a clock-unmoved doc hits the tier's host memo — zero wire parse on the
-warm fallback too.
+path; admission queue full -> host path; a batch flush that raises (a
+query kernel the device refuses) -> host path for every read still
+pending, counted in serve.flush_errors — the reader gets the host
+twin's value, and the counter says the device path is broken. A
+repeated host-path read of a clock-unmoved doc hits the tier's host
+memo — zero wire parse on the warm fallback too.
 """
 
 from __future__ import annotations
@@ -171,6 +174,10 @@ class ServeTier:
         self._host_memo: "OrderedDict[str, tuple]" = OrderedDict()
         self._host_memo_bytes = 0
         self._closed = False
+        # why the last failed install degraded to the host path (the
+        # Telemetry reply carries it: a worker that cannot reach its
+        # device says so instead of quietly serving from host)
+        self._last_install_error: Optional[str] = None
         reg = telemetry.REGISTRY
         inst = str(telemetry.next_instance())
         self._m: Dict[str, Any] = {
@@ -179,7 +186,7 @@ class ServeTier:
                 "reads", "hits", "installs", "invalidations",
                 "fallbacks", "evictions", "evictions_pressure",
                 "batches", "memo_hits", "host_memo_hits", "dispatches",
-                "overload_shed",
+                "overload_shed", "flush_errors",
             )
         }
         for k in ("resident_docs", "resident_bytes", "queue_depth"):
@@ -267,7 +274,10 @@ class ServeTier:
                 self._host_memo_bytes -= row[2]
 
     def residency_report(self) -> Dict[str, Any]:
-        return self._cache.report()
+        rep = self._cache.report()
+        if self._last_install_error is not None:
+            rep["last_install_error"] = self._last_install_error
+        return rep
 
     def flush_now(self, timeout: float = 5.0) -> bool:
         return self._batcher.flush_now(timeout)
@@ -286,16 +296,28 @@ class ServeTier:
     def _flush(self, reqs: List[ReadRequest]) -> None:
         """Resolve one admitted batch. Must never raise (a raised
         flush would re-queue the batch in the debouncer and double-
-        fire callbacks): every failure lane degrades per-request."""
+        fire callbacks): every failure lane degrades per-request. A
+        flush that raises is a broken device path, not a broken read:
+        it is counted (serve.flush_errors) and every read still
+        pending is answered by the host twin — never None, which is
+        the legitimate answer for a path that does not exist."""
         try:
             with telemetry.span("serve.batch", "serve", reads=len(reqs)):
                 self._m["batches"].add(1)
                 self._flush_inner(reqs)
-        except Exception as e:  # pragma: no cover - defensive
+        except Exception as e:
+            self._m["flush_errors"].add(1)
             log("serve", f"batch flush failed: {e!r}")
             for r in reqs:
-                if not r.done:
-                    self._finish_raw(r, None)
+                if r.done:
+                    continue
+                doc = self._back.docs.get(r.doc_id)
+                try:
+                    if doc is not None:
+                        self._fallback(r, doc)
+                except Exception as e2:  # the host twin failed too
+                    log("serve", f"host read {r.doc_id[:6]}: {e2!r}")
+                self._finish_raw(r, None)  # no-op once answered
         finally:
             self._m["queue_depth"].set(self._batcher.depth)
 
@@ -372,6 +394,7 @@ class ServeTier:
                     # of the cache on every read of the one broken
                     # doc — only genuine memory pressure earns a shed
                     log("serve", f"install {doc.id[:6]} failed: {e!r}")
+                    self._last_install_error = repr(e)[:500]
                     return None
                 # device memory pressure: shed LRU residents and give
                 # the install one more chance before degrading

@@ -22,8 +22,7 @@ from hypermerge_tpu.parallel.sharded import (
     step,
 )
 
-# mesh tests need the 8-device virtual CPU backend; under HM_TEST_TPU=1
-# (hardware validation runs) only one real chip is visible
+# mesh tests need the 8-device virtual CPU backend conftest.py sets up
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs >= 8 devices (virtual mesh)"
 )
@@ -337,3 +336,15 @@ def test_graft_dryrun_multichip(monkeypatch):
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")  # force device slabs
     g.dryrun_multichip(8)
     g.dryrun_multichip(4)
+
+
+def test_graft_multichip_refuses_devices_it_does_not_have():
+    """Asked for more devices than the backend shows, the hook raises:
+    it never rebuilds on virtual CPU devices and prints "ok" for a mesh
+    the chips did not run."""
+    sys.path.insert(0, "/root/repo")
+    import __graft_entry__ as g
+
+    with pytest.raises(RuntimeError, match="only 8 cpu device"):
+        g.measured_multichip(16)
+    assert jax.default_backend() == "cpu" and len(jax.devices()) == 8

@@ -490,3 +490,32 @@ def test_pipeline_stats_report_busy_and_critical_path(tmp_path, monkeypatch):
     assert stats["wall_critical_path"] >= 0.0
     assert "t_fetch" in stats and "t_fetch_busy" in stats
     repo.close()
+
+
+@pytest.mark.parametrize("mode", ["0", "1"], ids=["serial", "pipelined"])
+def test_bulk_stats_say_which_kernel_ran(tmp_path, monkeypatch, mode):
+    """last_bulk_stats names what ran each slab — the device program
+    (and on which platform) or the numpy twin below
+    HM_DEVICE_MIN_CELLS — in both HM_PIPELINE modes: a load that
+    quietly ran on the host must be visible in the stats."""
+    urls, _want = _make_corpus(tmp_path, n_docs=10)
+    ids = [validate_doc_url(u) for u in urls]
+    monkeypatch.setenv("HM_PIPELINE", mode)
+
+    monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")  # every slab: device
+    repo = Repo(path=str(tmp_path))
+    repo.back.load_documents_bulk(ids, slab=4)
+    repo.back.fetch_bulk_summaries()
+    st = dict(repo.back.last_bulk_stats)
+    repo.close()
+    assert (st["device_slabs"], st["host_slabs"]) == (3, 0)
+    assert st["platform"] == "cpu"
+
+    monkeypatch.setenv("HM_DEVICE_MIN_CELLS", str(2**30))  # none
+    repo = Repo(path=str(tmp_path))
+    repo.back.load_documents_bulk(ids, slab=4)
+    repo.back.fetch_bulk_summaries()
+    st = dict(repo.back.last_bulk_stats)
+    repo.close()
+    assert (st["device_slabs"], st["host_slabs"]) == (0, 3)
+    assert st["platform"] is None  # no slab reached a device

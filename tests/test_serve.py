@@ -409,3 +409,59 @@ def test_write_releases_resident_bytes(repo):
     repo.change(url, lambda d: d.__setitem__("n", 99))
     assert repo.back.serve._cache.resident_bytes < b0
     assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 99
+
+
+# ---------------------------------------------------------------------------
+# nothing on the device path fails silently
+
+
+def test_refused_serve_kernel_answers_host_value(repo, monkeypatch):
+    """A query kernel the device refuses (it raises inside the batch
+    flush) must not turn reads into None — the legitimate answer for a
+    path that does not exist. Every pending read gets the host twin's
+    value and serve.flush_errors says the device path is broken."""
+    from hypermerge_tpu.serve import kernels
+
+    url = _seed(repo)
+    doc = repo.back.docs[validate_doc_url(url)]
+
+    def refuse(*_a, **_k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    for name in ("map_lookup", "seq_order", "counts"):
+        monkeypatch.setattr(kernels, name, refuse)
+    e0 = serve_counter("flush_errors")
+    queries = [
+        {"kind": "lookup", "path": ["n"]},
+        {"kind": "text", "path": ["text"]},
+        {"kind": "index", "path": ["list"], "index": 1},
+        {"kind": "len", "path": ["list"]},
+        {"kind": "lookup", "path": ["nested", "deep", "v"]},
+    ]
+    for q in queries:
+        want = host_read(doc, q)["value"]
+        assert want is not None
+        assert repo.read(url, q) == want
+    assert serve_counter("flush_errors") >= e0 + len(queries)
+    # a path that does not exist still answers None — from the twin
+    assert repo.read(url, {"kind": "lookup", "path": ["nope"]}) is None
+
+
+def test_serve_tier_that_cannot_construct_fails_the_repo(monkeypatch):
+    """No silent host-only repo: a ServeTier constructor that raises
+    fails RepoBackend(...) — unless the operator chose HM_SERVE=0."""
+    from hypermerge_tpu import serve
+    from hypermerge_tpu.backend.repo_backend import RepoBackend
+
+    def broken(_backend):
+        raise RuntimeError("no usable jax backend")
+
+    monkeypatch.setattr(serve, "ServeTier", broken)
+    with pytest.raises(RuntimeError, match="no usable jax backend"):
+        RepoBackend(memory=True)
+    monkeypatch.setenv("HM_SERVE", "0")
+    back = RepoBackend(memory=True)
+    try:
+        assert back.serve is None
+    finally:
+        back.close()
