@@ -29,8 +29,14 @@ before the next starts.
              SIGKILL; then a "store" child recovers and reads every
              acknowledged edit back
 
-The last line of stdout is one JSON object. Any failed check or child
-makes the exit code non-zero and prints no `"ok": true`.
+Stdout is two JSON lines, printed only once every stage has passed: the
+report (counters, seconds, per-stage detail), then, as the LAST line,
+the verdict with exactly these keys:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Any failed check or child makes the exit code non-zero and prints
+neither.
 """
 
 from __future__ import annotations
@@ -1115,9 +1121,16 @@ def main() -> int:
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
-    result = {
+    verdict = {
         "ok": True,
-        "device": {k: device[k] for k in ("platform", "kind", "count")},
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    }
+    report = {
+        "ok": True,
         "platform": device["platform"],
         "device_kind": device["kind"],
         "n_devices": device["count"],
@@ -1148,10 +1161,13 @@ def main() -> int:
         "total_s": round(time.perf_counter() - t_start, 3),
     }
     if args.rehearse:
-        result["rehearsal"] = True
+        report["rehearsal"] = True
     if device["count"] > 1:
-        result["slabs_per_chip"] = first["bulk_stats"]["slabs_per_chip"]
-    print(json.dumps(result))
+        report["slabs_per_chip"] = first["bulk_stats"]["slabs_per_chip"]
+    # two lines, both only after every stage passed: the report, then
+    # the verdict, which is the LAST line and holds exactly these keys
+    print(json.dumps(report))
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

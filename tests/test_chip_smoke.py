@@ -3,7 +3,8 @@
 On the CPU the suite can only pin its contract: `--rehearse` drives
 the same stages (probe, native rebuild, corpus, store, cached store,
 hub daemon + SIGKILL, recovery) at 48 docs x 128 ops and prints the
-final JSON line; without `--rehearse` a machine with no TPU fails in
+report line and then the verdict, the last line, whose keys the driver
+holds to the letter; without `--rehearse` a machine with no TPU fails in
 seconds, before any set-up, and prints no result; and a directory that
 holds the script alone is refused.
 """
@@ -38,7 +39,14 @@ def _run(args, cwd=REPO, script=SMOKE, timeout=600):
 def test_rehearsal_runs_every_stage():
     p, _dt = _run(["--rehearse"])
     assert p.returncode == 0, p.stderr[-4000:]
-    out = json.loads(p.stdout.strip().splitlines()[-1])
+    lines = p.stdout.strip().splitlines()
+    assert len(lines) == 2
+    # the driver refuses a last line with any key more or less
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }
+    out = json.loads(lines[0])
     assert out["ok"] is True and out["rehearsal"] is True
     assert out["platform"] == "cpu"
     assert '"platform": "tpu"' not in p.stdout
