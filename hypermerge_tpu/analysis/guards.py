@@ -161,7 +161,7 @@ GUARDS: Tuple[GuardedClass, ...] = (
     GuardedClass(
         "RepoBackend(bulk)", "hypermerge_tpu.backend.repo_backend",
         "repo.bulk",
-        guarded=("_pending_memo", "_bulk_t0", "_fetch_ctx",
+        guarded=("_pending_memo", "_bulk_t0", "_bulk_open", "_fetch_ctx",
                  "_summary_memo_bytes"),
         atomic_read_ok=("_summary_memo",),
         unguarded=(

@@ -57,7 +57,6 @@ from __future__ import annotations
 import os
 import queue
 import threading
-import time
 from typing import Any, Callable, List, Optional, Tuple
 
 from ..analysis.lockdep import make_condition, make_lock
@@ -73,6 +72,35 @@ _M_BUSY = {
     stage: telemetry.counter(f"pipeline.{stage}_busy_s")
     for stage in ("io", "pack", "dispatch", "fetch")
 }
+
+
+class Stage:
+    """One timed stage of a bulk load, as a context manager yielding
+    its span. The clock is read once at each end (telemetry.timed) and
+    that one pair feeds all three books: the span (ring / profiler,
+    when live), the load's `last_bulk_stats` key (`stat(key, s)`) and
+    the process-wide `pipeline.<busy>_busy_s` counter."""
+
+    __slots__ = ("sp", "stat", "key", "busy")
+
+    def __init__(
+        self, name: str, stat: Optional[Callable[[str, float], None]] = None,
+        key: Optional[str] = None, busy: Optional[str] = None, **ids: Any,
+    ) -> None:
+        self.sp = telemetry.timed(name, "pipeline", **ids)
+        self.stat = stat
+        self.key = key
+        self.busy = busy
+
+    def __enter__(self) -> telemetry.SpanHandle:
+        return self.sp.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self.sp.__exit__(*exc)
+        if self.key is not None:
+            self.stat(self.key, self.sp.dur)
+        if self.busy is not None:
+            _M_BUSY[self.busy].add(self.sp.dur)
 
 
 class PipelineError(RuntimeError):
@@ -159,11 +187,18 @@ class SlabPipeline:
       pack(entries, seq)       -> ColumnarBatch (seq = slab index in
                                   doc order — the device-pack path
                                   uses it for per-chip placement)
-      dispatch(entries, batch) -> pending summary entry (runs on the
-                                  CALLER thread — device dispatch and
-                                  doc init stay single-threaded)
-      fetch(entry)             transfer + parse one slab's summary
+      dispatch(seq, entries, batch) -> pending summary entry (runs on
+                                  the CALLER thread — device dispatch
+                                  and doc init stay single-threaded)
+      fetch(seq, entry)        transfer + parse one slab's summary
                                   (mutates the entry in place)
+      stat(key, seconds)       adds a stage's seconds to the load's
+                                  stats (t_io, t_spec, t_pack)
+
+    io, spec and pack are timed here (`Stage`); dispatch and fetch time
+    themselves in the backend, which keeps per-chip books from the
+    same readings. Every span carries `open=open_id` and its `slab`;
+    each blocking queue / turn wait is a `pipeline.wait` span.
     """
 
     def __init__(
@@ -173,11 +208,13 @@ class SlabPipeline:
         prefetch: Callable[[List[Any]], None],
         classify: Callable[[Any], Tuple[str, Any]],
         pack: Callable[[List[Any], int], Any],
-        dispatch: Callable[[List[Any], Any], Any],
-        fetch: Callable[[Any], None],
+        dispatch: Callable[[int, List[Any], Any], Any],
+        fetch: Callable[[int, Any], None],
+        stat: Callable[[str, float], None],
         slab: int,
         fetch_workers: int = 1,
         pack_workers: int = 1,
+        open_id: int = 0,
     ) -> None:
         self.docs = docs
         self.prefetch = prefetch
@@ -185,6 +222,8 @@ class SlabPipeline:
         self.pack = pack
         self.dispatch = dispatch
         self.fetch = fetch
+        self.stat = stat
+        self.open_id = open_id
         self.slab = max(1, int(slab))
         self.fetch_workers = max(1, int(fetch_workers))
         self.pack_workers = max(1, int(pack_workers))
@@ -199,6 +238,11 @@ class SlabPipeline:
             id(self.pack_q): telemetry.gauge("pipeline.q_pack"),
             id(self.disp_q): telemetry.gauge("pipeline.q_dispatch"),
             id(self.fetch_q): telemetry.gauge("pipeline.q_fetch"),
+        }
+        self._q_names = {
+            id(self.pack_q): "pack",
+            id(self.disp_q): "dispatch",
+            id(self.fetch_q): "fetch",
         }
         self.abort = threading.Event()
         self.error: Optional[BaseException] = None
@@ -224,27 +268,47 @@ class SlabPipeline:
     # -- queue plumbing (abort-aware: a failed stage must never leave a
     # sibling blocked forever on a full/empty bounded queue) ----------
 
+    # Each blocking wait is a `pipeline.wait` span naming its queue and
+    # side. A put carries the slab that waited (work held back by a
+    # full queue or by its turn); a get is a worker starved of work and
+    # carries none, so a slab's waits never overlap its own busy spans.
+
+    def _wait(self, q_name: str, side: str):
+        return telemetry.begin(
+            "pipeline.wait", "pipeline", open=self.open_id, q=q_name,
+            side=side,
+        )
+
     def _put(self, q: "queue.Queue", item: Any) -> None:
-        while True:
-            if self.abort.is_set():
-                raise _Abort()
-            try:
-                q.put(item, timeout=_POLL_S)
-                self._q_gauges[id(q)].set(q.qsize())
-                return
-            except queue.Full:
-                continue
+        """Queue items are (slab seq, ...) tuples, or _DONE."""
+        sp = self._wait(self._q_names[id(q)], "put")
+        try:
+            while True:
+                if self.abort.is_set():
+                    raise _Abort()
+                try:
+                    q.put(item, timeout=_POLL_S)
+                    self._q_gauges[id(q)].set(q.qsize())
+                    return
+                except queue.Full:
+                    continue
+        finally:
+            sp.end(**({} if item is _DONE else {"slab": item[0]}))
 
     def _get(self, q: "queue.Queue") -> Any:
-        while True:
-            if self.abort.is_set():
-                raise _Abort()
-            try:
-                item = q.get(timeout=_POLL_S)
-                self._q_gauges[id(q)].set(q.qsize())
-                return item
-            except queue.Empty:
-                continue
+        sp = self._wait(self._q_names[id(q)], "get")
+        try:
+            while True:
+                if self.abort.is_set():
+                    raise _Abort()
+                try:
+                    item = q.get(timeout=_POLL_S)
+                    self._q_gauges[id(q)].set(q.qsize())
+                    return item
+                except queue.Empty:
+                    continue
+        finally:
+            sp.end()
 
     def _fail(self, stage: str, exc: BaseException) -> None:
         with self._err_lock:
@@ -258,7 +322,9 @@ class SlabPipeline:
     def _io_loop(self) -> None:
         """Read-ahead + spec: emits slab-sized entry groups in doc
         order — exactly the chunks the serial loader would form, so
-        pipeline and serial materialize bit-identical slabs."""
+        pipeline and serial materialize bit-identical slabs. The io and
+        spec spans carry the doc chunk's index as `slab` (the emitted
+        slab's seq unless memo hits or fallbacks thinned the stream)."""
         try:
             buf: List[Any] = []
             seq = 0
@@ -266,9 +332,16 @@ class SlabPipeline:
                 if self.abort.is_set():
                     raise _Abort()
                 chunk = self.docs[base : base + self.slab]
-                t0 = time.perf_counter()
-                with telemetry.span("pipeline.io", "pipeline"):
+                ids = {"open": self.open_id, "slab": base // self.slab}
+                with Stage(
+                    "pipeline.io", self.stat, "t_io", "io",
+                    parent="pipeline.bulk_load", **ids,
+                ):
                     self.prefetch(chunk)
+                with Stage(
+                    "pipeline.spec", self.stat, "t_spec", "io",
+                    parent="pipeline.bulk_load", docs=len(chunk), **ids,
+                ):
                     for doc in chunk:
                         kind, payload = self.classify(doc)
                         if kind == "entry":
@@ -277,7 +350,6 @@ class SlabPipeline:
                             self.memo_hits.append(payload)
                         else:
                             self.fallbacks.append(payload)
-                _M_BUSY["io"].add(time.perf_counter() - t0)
                 # the put blocks on a full queue: that's backpressure
                 # WAIT, not io busy — keep it outside the busy window
                 while len(buf) >= self.slab:
@@ -300,11 +372,15 @@ class SlabPipeline:
     def _await_pack_turn(self, seq: int) -> None:
         """Block until slab `seq` may emit into disp_q (ordered merge
         of the pack pool's out-of-order completions). Abort-aware."""
-        with self._pack_cv:
-            while self._pack_turn != seq:
-                if self.abort.is_set():
-                    raise _Abort()
-                self._pack_cv.wait(_POLL_S)
+        sp = self._wait("turn", "put")
+        try:
+            with self._pack_cv:
+                while self._pack_turn != seq:
+                    if self.abort.is_set():
+                        raise _Abort()
+                    self._pack_cv.wait(_POLL_S)
+        finally:
+            sp.end(slab=seq)
 
     def _bump_pack_turn(self) -> None:
         with self._pack_cv:
@@ -345,19 +421,19 @@ class SlabPipeline:
                     self._put(self.disp_q, _DONE)
                     return
                 seq, entries = item
-                t0 = time.perf_counter()
-                with telemetry.span("pipeline.pack", "pipeline"):
+                with Stage(
+                    "pipeline.pack", self.stat, "t_pack", "pack",
+                    open=self.open_id, slab=seq, parent="pipeline.spec",
+                ) as sp:
                     packed = self.pack(entries, seq)
-                t1 = time.perf_counter()
-                self.pack_busy[widx] += t1 - t0
+                self.pack_busy[widx] += sp.dur
                 if self.pack_t0[widx] is None:
-                    self.pack_t0[widx] = t0
-                self.pack_t1[widx] = t1
-                _M_BUSY["pack"].add(t1 - t0)
+                    self.pack_t0[widx] = sp.t0
+                self.pack_t1[widx] = sp.t0 + sp.dur
                 _M_SLABS.add(1)
                 # ordered emit: the turn-wait is backpressure, not busy
                 self._await_pack_turn(seq)
-                self._put(self.disp_q, (entries, packed))
+                self._put(self.disp_q, (seq, entries, packed))
                 self._bump_pack_turn()
         except _Abort:
             pass
@@ -373,10 +449,7 @@ class SlabPipeline:
                     # overlaps across chips) see it and drain too
                     self._put(self.fetch_q, _DONE)
                     return
-                t0 = time.perf_counter()
-                with telemetry.span("pipeline.fetch", "pipeline"):
-                    self.fetch(item)
-                _M_BUSY["fetch"].add(time.perf_counter() - t0)
+                self.fetch(*item)
         except _Abort:
             pass
         except BaseException as e:
@@ -422,12 +495,10 @@ class SlabPipeline:
                 item = self._get(self.disp_q)
                 if item is _DONE:
                     break
-                entries, batch = item
-                t0 = time.perf_counter()
-                with telemetry.span("pipeline.dispatch", "pipeline"):
-                    pending = self.dispatch(entries, batch)
-                _M_BUSY["dispatch"].add(time.perf_counter() - t0)
-                self._put(self.fetch_q, pending)
+                seq, entries, batch = item
+                self._put(
+                    self.fetch_q, (seq, self.dispatch(seq, entries, batch))
+                )
             self._put(self.fetch_q, _DONE)
         except _Abort:
             pass

@@ -51,6 +51,7 @@ from ..files.file_store import FileStore
 from .actor import Actor
 from .doc_backend import DocBackend
 from .metadata import Metadata
+from .pipeline import Stage
 
 # device->host summary-wire transfer bytes (same series sharded.py's
 # collective gather feeds; handle cached — one per-slab bump)
@@ -252,6 +253,7 @@ class RepoBackend:
         self._stats_lock = make_lock("repo.stats")
         self._fetch_ctx = None
         self._bulk_t0: Optional[float] = None
+        self._bulk_open = 0  # request id of the latest load's spans
         self._rr_cached = False  # round-robin scheduler, built lazily
         self._rr_value = None
         # per-doc summary memo: doc_id -> last fetched summary row + the
@@ -761,23 +763,20 @@ class RepoBackend:
 
         `pad_docs`/`pad_rows` override the slab's jit bucket (benchmarks
         prime a [4096, N] executable with a small load)."""
-        with telemetry.span(
-            "pipeline.bulk_load", "pipeline", docs=len(doc_ids)
-        ):
-            return self._load_documents_bulk(
-                doc_ids, slab, pad_docs, pad_rows
-            )
-
-    def _load_documents_bulk(
-        self, doc_ids: List[str], slab: Optional[int],
-        pad_docs: Optional[int], pad_rows: Optional[int],
-    ) -> None:
+        # the open's request id: the facade's (Repo.open_many is the
+        # root span) or, called directly, the next of the sequence
+        open_id = telemetry.open_id()
         if slab is None:
             slab = int(os.environ.get("HM_BULK_SLAB", "4096"))
-        with self._bulk_mutex:  # concurrent open_many calls serialize
-            self._load_documents_bulk_locked(
-                doc_ids, slab, pad_docs, pad_rows
-            )
+        with telemetry.span(
+            "pipeline.bulk_load", "pipeline", open=open_id,
+            docs=len(doc_ids),
+        ):
+            with self._bulk_mutex:  # concurrent open_many calls serialize
+                self._bulk_open = open_id
+                self._load_documents_bulk_locked(
+                    doc_ids, slab, pad_docs, pad_rows
+                )
 
     def _load_documents_bulk_locked(
         self, doc_ids, slab, pad_docs, pad_rows
@@ -806,31 +805,33 @@ class RepoBackend:
         pipelined = pipeline_enabled()
 
         # -- phase 1: register docs + one bulk cursor upsert/select -----
-        t0 = now()
         new_docs: List[DocBackend] = []
         already_ready: List[str] = []  # open docs: frontend may re-read
-        with self._lock:
-            for doc_id in doc_ids:
-                existing = self.docs.get(doc_id)
-                if existing is not None:
-                    if existing._announced:
-                        already_ready.append(doc_id)
-                    continue
-                doc = DocBackend(
-                    doc_id, self._doc_notify, None, live=self.live
+        with Stage("pipeline.register", docs=len(doc_ids)) as register:
+            with self._lock:
+                for doc_id in doc_ids:
+                    existing = self.docs.get(doc_id)
+                    if existing is not None:
+                        if existing._announced:
+                            already_ready.append(doc_id)
+                        continue
+                    doc = DocBackend(
+                        doc_id, self._doc_notify, None, live=self.live
+                    )
+                    self.docs[doc_id] = doc
+                    new_docs.append(doc)
+            # docs closed with store rows still in the debouncer must
+            # not bulk-reload from the stale rows (same guard as
+            # open/destroy)
+            self._settle_store_rows({d.id for d in new_docs})
+            with self.db.bulk():
+                self.cursors.add_actors(
+                    self.id,
+                    [(d.id, root_actor_id(d.id)) for d in new_docs],
                 )
-                self.docs[doc_id] = doc
-                new_docs.append(doc)
-        # docs closed with store rows still in the debouncer must not
-        # bulk-reload from the stale rows (same guard as open/destroy)
-        self._settle_store_rows({d.id for d in new_docs})
-        with self.db.bulk():
-            self.cursors.add_actors(
-                self.id, [(d.id, root_actor_id(d.id)) for d in new_docs]
+            cursor_map = self.cursors.get_multiple(
+                self.id, [d.id for d in new_docs]
             )
-        cursor_map = self.cursors.get_multiple(
-            self.id, [d.id for d in new_docs]
-        )
         # stage breakdown (seconds; VERDICT r5 item 1). Serial mode:
         # each stage's wall time (they run back-to-back, so they sum to
         # the wall clock). Pipeline mode: each stage's BUSY time — the
@@ -854,7 +855,7 @@ class RepoBackend:
                 "host_slabs": 0,
                 "platform": None,
                 "pack_workers": 0,  # serial twin: pack inline, no pool
-                "t_sql": round(now() - t0, 3),
+                "t_sql": round(register.dur, 3),
                 "t_io": 0.0,
                 "t_spec": 0.0,
                 "t_pack": 0.0,
@@ -890,10 +891,12 @@ class RepoBackend:
                     ready_ids, clock_rows,
                 )
                 self._pending_memo.append((doc.id, m))
-            t0 = now()
-            with self.db.bulk():
-                self.clocks.update_many(self.id, clock_rows)
-            self._stat_add("t_sql", now() - t0)
+            with Stage(
+                "pipeline.clock_rows", self._stat_add, "t_sql",
+                docs=len(clock_rows),
+            ):
+                with self.db.bulk():
+                    self.clocks.update_many(self.id, clock_rows)
             for doc in fallback_docs:
                 self._load_document(doc)
             if fallback_docs:
@@ -923,7 +926,8 @@ class RepoBackend:
                     pass  # the load's own error is the one to raise
             raise
         finally:
-            self._end_bulk_actors()
+            with telemetry.span("pipeline.actors_flush", "pipeline"):
+                self._end_bulk_actors()
         if pipelined:
             # busy aliases: explicit names for consumers (bench JSON)
             # that want both views without knowing the mode
@@ -942,16 +946,32 @@ class RepoBackend:
             )
         ready_ids.extend(already_ready)
         if ready_ids:
-            self.to_frontend.push(msgs.bulk_ready_msg(ready_ids))
+            with telemetry.span(
+                "pipeline.notify", "pipeline", docs=len(ready_ids)
+            ):
+                self.to_frontend.push(msgs.bulk_ready_msg(ready_ids))
 
-    def _stat_add(self, key: str, dt: float) -> None:
-        """Accumulate a stage timing into last_bulk_stats (pipeline
-        stage threads add concurrently). Microsecond precision: the
-        pipeline adds per-doc slivers (tens of µs from classify), and
-        rounding each addition to ms would floor a whole stage to 0."""
+    def _stat_add(self, key: str, dt: float, stats=None) -> None:
+        """Accumulate a stage's seconds into last_bulk_stats (pipeline
+        stage threads add concurrently), or into the `stats` dict a
+        stage captured when its load began. Microsecond precision:
+        rounding each addition to ms would floor a short stage to 0."""
         with self._stats_lock:
-            s = self.last_bulk_stats
+            s = self.last_bulk_stats if stats is None else stats
             s[key] = round(s.get(key, 0.0) + dt, 6)
+
+    def _open_feeds(self, docs, cursor_map) -> None:
+        """The io stage of `docs`: open every cursor actor's feed, then
+        load the actors' column sidecars (both twins call this)."""
+        needed = self._collect_cursor_actors(docs, cursor_map)
+        with telemetry.span(
+            "storage.feeds.open", "storage", feeds=len(needed)
+        ):
+            actors = [self._get_or_create_actor(a) for a in needed]
+        with telemetry.span(
+            "storage.columns.load", "storage", feeds=len(actors)
+        ):
+            self._prefetch_columns(actors)
 
     def _collect_cursor_actors(self, docs, cursor_map) -> List[str]:
         needed: List[str] = []
@@ -971,31 +991,29 @@ class RepoBackend:
         """The correctness twin (HM_PIPELINE=0): every stage finishes
         for ALL docs before the next begins — wall clock = sum(stages).
         Returns (memo_hits, fallback_docs)."""
-        now = time.perf_counter
-
         # -- phase 2: open every cursor actor, per-feed work deferred ---
-        t0 = now()
-        needed = self._collect_cursor_actors(new_docs, cursor_map)
-        actors = [self._get_or_create_actor(a) for a in needed]
-        self._prefetch_columns(actors)
-        self._stat_add("t_io", now() - t0)
+        with Stage("pipeline.io", self._stat_add, "t_io"):
+            self._open_feeds(new_docs, cursor_map)
 
         # -- phase 3: per-doc feed specs --------------------------------
-        t0 = now()
         entries = []  # (doc, spec, clock, n_changes, actor_ids)
         contiguous: Dict[str, bool] = {}
         fallback_docs: List[DocBackend] = []
-        for doc in new_docs:
-            spec, clock, n_changes, actor_ids, ok = self._doc_feed_spec(
-                doc.id, contiguous, cursor_map[doc.id]
-            )
-            if not ok:
-                fallback_docs.append(doc)
-                continue
-            if n_changes == 0:
-                self._gate_unknown_empty(doc)
-            entries.append((doc, spec, clock, n_changes, actor_ids))
-        self._stat_add("t_spec", now() - t0)
+        with Stage(
+            "pipeline.spec", self._stat_add, "t_spec", docs=len(new_docs)
+        ):
+            for doc in new_docs:
+                spec, clock, n_changes, actor_ids, ok = (
+                    self._doc_feed_spec(
+                        doc.id, contiguous, cursor_map[doc.id]
+                    )
+                )
+                if not ok:
+                    fallback_docs.append(doc)
+                    continue
+                if n_changes == 0:
+                    self._gate_unknown_empty(doc)
+                entries.append((doc, spec, clock, n_changes, actor_ids))
 
         # -- phase 3.5: clean docs (summary memo holds a row fetched
         # at this exact clock) skip pack/dispatch/transfer --------------
@@ -1035,35 +1053,29 @@ class RepoBackend:
             pack_worker_count,
         )
 
-        now = time.perf_counter
         contiguous: Dict[str, bool] = {}
+        open_id = self._bulk_open
+
+        # the stages time themselves (pipeline.Stage: one clock pair
+        # per stage feeds span, stat and counter); these closures only
+        # do the work
 
         def prefetch(doc_chunk):
-            t0 = now()
-            needed = self._collect_cursor_actors(doc_chunk, cursor_map)
-            actors = [self._get_or_create_actor(a) for a in needed]
-            self._prefetch_columns(actors)
-            self._stat_add("t_io", now() - t0)
+            self._open_feeds(doc_chunk, cursor_map)
 
         def classify(doc):
-            t0 = now()
-            try:
-                spec, clock, n_changes, actor_ids, ok = (
-                    self._doc_feed_spec(
-                        doc.id, contiguous, cursor_map[doc.id]
-                    )
-                )
-                if not ok:
-                    return ("fallback", doc)
-                if n_changes == 0:
-                    self._gate_unknown_empty(doc)
-                e = (doc, spec, clock, n_changes, actor_ids)
-                m = self._summary_memo.get(doc.id)
-                if m is not None and m["clock"] == clock:
-                    return ("memo", (e, m))
-                return ("entry", e)
-            finally:
-                self._stat_add("t_spec", now() - t0)
+            spec, clock, n_changes, actor_ids, ok = self._doc_feed_spec(
+                doc.id, contiguous, cursor_map[doc.id]
+            )
+            if not ok:
+                return ("fallback", doc)
+            if n_changes == 0:
+                self._gate_unknown_empty(doc)
+            e = (doc, spec, clock, n_changes, actor_ids)
+            m = self._summary_memo.get(doc.id)
+            if m is not None and m["clock"] == clock:
+                return ("memo", (e, m))
+            return ("entry", e)
 
         def pack(chunk, seq):
             # rr / rr_cursor0 bind below, before the pipeline runs.
@@ -1071,8 +1083,7 @@ class RepoBackend:
             # on the chip strict round-robin will dispatch slab `seq`
             # to, so the packed columns never cross chips; host packs
             # ignore it. Runs on a pack-pool worker (HM_PACK_WORKERS).
-            t0 = now()
-            batch = pack_docs_columns(
+            return pack_docs_columns(
                 [e[1] for e in chunk],
                 n_docs=pad_docs or round_up_pow2(len(chunk)),
                 n_rows=pad_rows,
@@ -1082,12 +1093,10 @@ class RepoBackend:
                     else None
                 ),
             )
-            self._stat_add("t_pack", now() - t0)
-            return batch
 
-        def dispatch(chunk, batch):
+        def dispatch(seq, chunk, batch):
             return self._dispatch_slab(
-                chunk, batch, DecodedBatch, decode_patch,
+                seq, chunk, batch, DecodedBatch, decode_patch,
                 ready_ids, clock_rows,
             )
 
@@ -1106,11 +1115,14 @@ class RepoBackend:
         # seq), so pack workers can place device packs ahead of dispatch
         rr_cursor0 = rr.cursor() if rr is not None else 0
 
-        def fetch(entry):
-            t0 = now()
+        def fetch(seq, entry):
             wire = entry[3]
-            self._fetch_slab(entry)
-            dt = now() - t0
+            with Stage(
+                "pipeline.fetch", busy="fetch", open=open_id, slab=seq,
+                parent="pipeline.dispatch",
+            ) as sp:
+                self._fetch_slab(entry)
+            dt = sp.dur
             chip = None
             if rr is not None and hasattr(wire, "devices"):
                 try:
@@ -1145,9 +1157,11 @@ class RepoBackend:
             pack=pack,
             dispatch=dispatch,
             fetch=fetch,
+            stat=lambda key, dt: self._stat_add(key, dt, stats),
             slab=slab,
             fetch_workers=workers,
             pack_workers=pack_worker_count(),
+            open_id=open_id,
         )
         ctx = FetchContext()
         try:
@@ -1158,8 +1172,8 @@ class RepoBackend:
                 self._rr_value.release()
         with self._stats_lock:
             # pool shape + per-worker busy lanes: sum(busy) can exceed
-            # the wall once packs overlap — profile_cold draws one lane
-            # per worker and bench computes speedup = sum(busy)/wall
+            # the wall once packs overlap — a trace draws one lane per
+            # worker and bench computes speedup = sum(busy)/wall
             stats["pack_workers"] = pipe.pack_workers
             stats["t_pack_busy_per_worker"] = [
                 round(b, 6) for b in pipe.pack_busy
@@ -1272,31 +1286,60 @@ class RepoBackend:
         # thread whose native hm_pack_prefix call drops the GIL, so it
         # overlaps the next slab's sidecar IO and the previous slab's
         # device work instead.
-        for base in range(0, len(entries), slab):
+        for seq, base in enumerate(range(0, len(entries), slab)):
             chunk = entries[base : base + slab]
             # bucket the doc axis (pow2) so every slab of a bulk load —
             # and every later bulk load — reuses one compiled executable
-            t0 = time.perf_counter()
-            batch = pack_docs_columns(
-                [e[1] for e in chunk],
-                n_docs=pad_docs or round_up_pow2(len(chunk)),
-                n_rows=pad_rows,
-            )
-            self._stat_add("t_pack", time.perf_counter() - t0)
+            with Stage(
+                "pipeline.pack", self._stat_add, "t_pack", slab=seq
+            ):
+                batch = pack_docs_columns(
+                    [e[1] for e in chunk],
+                    n_docs=pad_docs or round_up_pow2(len(chunk)),
+                    n_rows=pad_rows,
+                )
             self._dispatch_slab(
-                chunk, batch, DecodedBatch, decode_patch,
+                seq, chunk, batch, DecodedBatch, decode_patch,
                 ready_ids, clock_rows,
             )
 
+    # the seconds of a dispatch's child spans (recorded where the work
+    # happens: ops/crdt_kernels.py, parallel/sharded.py) -> stats keys
+    _DISPATCH_KIDS = (
+        ("pipeline.narrow", "t_narrow"),
+        ("pipeline.upload", "t_upload"),
+        ("pipeline.enqueue", "t_dispatch"),
+    )
+
     def _dispatch_slab(
-        self, chunk, batch, DecodedBatch, decode_patch,
+        self, seq, chunk, batch, DecodedBatch, decode_patch,
         ready_ids, clock_rows,
     ):
         """One packed slab -> async device dispatch + deferred doc init.
         Returns the pending-summary entry (a mutable list: the pipeline
         fetch worker replaces its wire slot with parsed host arrays).
         Shared by the serial twin and the streaming pipeline, which
-        only differ in WHEN stages run, never in what they compute."""
+        only differ in WHEN stages run, never in what they compute.
+
+        The whole of it is the `pipeline.dispatch` stage (it runs on
+        the dispatching thread and holds back the next slab): host-arg
+        narrowing, upload and the jitted call are its child spans, and
+        their ends feed t_narrow / t_upload / t_dispatch."""
+        # (on the loading thread, inside `pipeline.bulk_load`: the
+        # open's id comes down from that span)
+        with Stage("pipeline.dispatch", busy="dispatch", slab=seq) as sp:
+            entry = self._dispatch_slab_staged(
+                chunk, batch, DecodedBatch, decode_patch,
+                ready_ids, clock_rows,
+            )
+        for kid, key in self._DISPATCH_KIDS:
+            self._stat_add(key, sp.kids.get(kid, 0.0))
+        return entry
+
+    def _dispatch_slab_staged(
+        self, chunk, batch, DecodedBatch, decode_patch,
+        ready_ids, clock_rows,
+    ):
         from ..ops.crdt_kernels import run_batch_full
         from ..ops.host_kernel import run_batch_host
 
@@ -1311,12 +1354,11 @@ class RepoBackend:
         slab_clocks = [e[2] for e in chunk] + [{}] * (
             batch.n_docs - len(chunk)
         )
-        t0 = time.perf_counter()
         lean = False
         if batch.n_docs * batch.n_rows < min_cells:
-            out = run_batch_host(batch)
+            with telemetry.timed("pipeline.enqueue", "pipeline", host=1):
+                out = run_batch_host(batch)
             summary = None
-            self._stat_add("t_dispatch", time.perf_counter() - t0)
             with self._stats_lock:
                 stats["host_slabs"] += 1
         else:
@@ -1357,16 +1399,6 @@ class RepoBackend:
                     )
             else:
                 out, summary = run_batch_full(batch, lean=lean)
-            from ..ops import crdt_kernels as _ck
-
-            slab_narrow = _ck.last_args_timings.get("narrow", 0.0)
-            slab_upload = _ck.last_args_timings.get("upload", 0.0)
-            self._stat_add("t_narrow", slab_narrow)
-            self._stat_add("t_upload", slab_upload)
-            self._stat_add(
-                "t_dispatch",
-                time.perf_counter() - t0 - slab_narrow - slab_upload,
-            )
             if os.environ.get("HM_ASYNC_SUMMARY_COPY", "1") != "0":
                 # start the device->host copy of the ONE fused wire
                 # buffer now so the barrier (fetch_bulk_summaries)
@@ -1379,14 +1411,17 @@ class RepoBackend:
         dec = DecodedBatch(batch, out, host_clocks=slab_clocks)
         entry = [[e[0].id for e in chunk], batch, dec, summary, lean]
         self._pending_summaries.append(entry)
-        for j, (doc, _spec, clock, n_changes, actor_ids) in enumerate(
-            chunk
+        with telemetry.span(
+            "pipeline.init_docs", "pipeline", docs=len(chunk)
         ):
-            self._init_bulk_doc(
-                doc, clock, n_changes, actor_ids,
-                lambda dec=dec, j=j: decode_patch(dec.doc_view(j), 0),
-                ready_ids, clock_rows,
-            )
+            for j, (doc, _spec, clock, n_changes, actor_ids) in enumerate(
+                chunk
+            ):
+                self._init_bulk_doc(
+                    doc, clock, n_changes, actor_ids,
+                    lambda dec=dec, j=j: decode_patch(dec.doc_view(j), 0),
+                    ready_ids, clock_rows,
+                )
         return entry
 
     def _slab_rr(self):
@@ -1449,7 +1484,6 @@ class RepoBackend:
         otherwise swap the pending lists out from under each other —
         the load's stale-join path still covers barrier-less loads."""
         from ..ops.materialize import BulkSummaries
-
         with self._bulk_mutex:
             pending = self._pending_summaries
             memo_pending = self._pending_memo
@@ -1462,17 +1496,18 @@ class RepoBackend:
             # fetch failure below nor a later (empty) barrier call can
             # restamp the critical path with idle wall time
             self._bulk_t0 = None
-            t0 = time.perf_counter()
-            if fetch_ctx is not None:
-                fetch_ctx.join()  # raises PipelineError on fetch failure
-            out = BulkSummaries(
-                pending, memo_slabs=self._memo_slabs(memo_pending)
-            )
-            self._memoize_summaries(out, pending, memo_pending)
+            with Stage(
+                "pipeline.barrier", open=self._bulk_open,
+                parent="repo.open_many", slabs=len(pending),
+            ) as barrier:
+                if fetch_ctx is not None:
+                    fetch_ctx.join()  # PipelineError on fetch failure
+                out = BulkSummaries(
+                    pending, memo_slabs=self._memo_slabs(memo_pending)
+                )
+                self._memoize_summaries(out, pending, memo_pending)
         with self._stats_lock:
-            self.last_bulk_stats["t_fetch"] = round(
-                time.perf_counter() - t0, 3
-            )
+            self.last_bulk_stats["t_fetch"] = round(barrier.dur, 3)
             if wall_t0 is not None:
                 self.last_bulk_stats["wall_critical_path"] = round(
                     time.perf_counter() - wall_t0, 3

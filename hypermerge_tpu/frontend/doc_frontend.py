@@ -12,6 +12,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
+from .. import telemetry
 from ..analysis.lockdep import make_rlock
 from ..crdt.frontend_state import FrontendDoc
 from ..crdt.patch import Patch
@@ -120,22 +121,32 @@ class DocFrontend:
         self._run_change(fn, message)
 
     def _run_change(self, fn: Callable, message: str) -> None:
-        with self._lock:
-            if self._inflight is not None:
-                # an echo is outstanding: the committed state this fn
-                # would read is stale — run it when the echo lands
-                self._change_queue.append((fn, message))
-                return
-            with bench("front:change"):
-                request, preview = self.front.change(
-                    fn, self.actor_id, self.seq, message
-                )
-            if request is None:
-                return
-            self.seq += 1
-            self._inflight = request.seq
-        self._fan_out(preview)  # «change preview»
-        self._repo.send_request(self.doc_id, request)
+        # `frontend.change` is the whole local change as the caller
+        # waits on it (in-process the request is applied before
+        # send_request returns); its child is intent resolution: the
+        # change fn run over a scratch mirror of the doc, O(doc length)
+        with telemetry.span("frontend.change", "frontend"):
+            with self._lock:
+                if self._inflight is not None:
+                    # an echo is outstanding: the committed state this
+                    # fn would read is stale — run it when the echo lands
+                    self._change_queue.append((fn, message))
+                    return
+                with bench("front:change"), telemetry.span(
+                    "frontend.change.resolve", "frontend"
+                ) as sp:
+                    request, preview = self.front.change(
+                        fn, self.actor_id, self.seq, message
+                    )
+                    sp.note(
+                        ops=len(request.intents) if request else 0
+                    )
+                if request is None:
+                    return
+                self.seq += 1
+                self._inflight = request.seq
+            self._fan_out(preview)  # «change preview»
+            self._repo.send_request(self.doc_id, request)
 
     def send_doc_message(self, contents: Any) -> None:
         self._repo.send_doc_message(self.doc_id, contents)

@@ -11,7 +11,7 @@ import threading
 from typing import Any, Callable, Dict, Optional
 
 from ..analysis.lockdep import make_rlock
-from .. import msgs
+from .. import msgs, telemetry
 from ..crdt import clock as clockmod
 from ..crdt.change import ChangeRequest
 from ..crdt.frontend_state import FrontendDoc
@@ -71,15 +71,18 @@ class RepoFrontend:
         doc cold start stays one XLA dispatch chain with zero eager
         per-doc decodes. Contrast the reference's per-doc open loop
         (src/RepoFrontend.ts:155-159 + src/RepoBackend.ts:238-257)."""
-        doc_ids = [validate_doc_url(u) for u in urls]
         handles = []
-        with self._lock:
-            for doc_id in doc_ids:
-                df = self.docs.get(doc_id)
-                if df is None:
-                    df = DocFrontend(self, doc_id)
-                    self.docs[doc_id] = df
-                handles.append(df.handle())
+        with telemetry.span(
+            "frontend.open_many.handles", "frontend", docs=len(urls)
+        ):
+            doc_ids = [validate_doc_url(u) for u in urls]
+            with self._lock:
+                for doc_id in doc_ids:
+                    df = self.docs.get(doc_id)
+                    if df is None:
+                        df = DocFrontend(self, doc_id)
+                        self.docs[doc_id] = df
+                    handles.append(df.handle())
         self.to_backend.push(msgs.open_bulk_msg(doc_ids))
         return handles
 

@@ -17,16 +17,24 @@ loop, SURVEY.md §3.3), as one fused XLA program over the columnar encoding
 
 Everything is `vmap`ed over the leading doc axis and jit-cached per
 (N, P, A, K) bucket. The doc axis is the `dp` sharding axis (parallel/).
+
+Each numbered section, and the summary wire, sits in a `jax.named_scope`
+(`PHASES`): metadata only — the scope reaches each HLO instruction's
+`op_name`, not the program or its cache key — so a device trace's
+seconds can be split by phase (`phase_of_ops`).
 """
 
 from __future__ import annotations
 
+import functools
 import math
+import re
 from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..crdt.change import Action
 from . import compile_cache
 from .columnar import (
@@ -41,6 +49,12 @@ _DEL = int(Action.DEL)
 _INC = int(Action.INC)
 _MAKE_LIST = int(Action.MAKE_LIST)
 _MAKE_TEXT = int(Action.MAKE_TEXT)
+
+# the named scopes of the slab program, in program order
+PHASES = (
+    "supersession", "counters", "lww", "elem_values", "rga_order",
+    "clock", "wire",
+)
 
 
 class MaterializeOut(NamedTuple):
@@ -79,131 +93,141 @@ def _doc_kernel(
     is_ins = (insert == 1) & valid
 
     # -- 1. supersession ------------------------------------------------
-    tgt = jnp.where(ptgt >= 0, ptgt, N)
-    dead = jnp.zeros(N + 1, dtype=bool).at[tgt].set(True)[:N]
-    visible = (is_make | is_set) & ~dead
+    with jax.named_scope("supersession"):
+        tgt = jnp.where(ptgt >= 0, ptgt, N)
+        dead = jnp.zeros(N + 1, dtype=bool).at[tgt].set(True)[:N]
+        visible = (is_make | is_set) & ~dead
 
     # -- 2. counter increments -----------------------------------------
-    is_inc = (action == _INC) & valid
-    inc_tgt = jnp.clip(ref, 0, N - 1)
-    inc_ok = is_inc & (ref >= 0) & ~dead[inc_tgt]
-    inc_total = (
-        jnp.zeros(N + 1, dtype=jnp.int32)
-        .at[jnp.where(inc_ok, inc_tgt, N)]
-        .add(jnp.where(inc_ok, value, 0))[:N]
-    )
+    with jax.named_scope("counters"):
+        is_inc = (action == _INC) & valid
+        inc_tgt = jnp.clip(ref, 0, N - 1)
+        inc_ok = is_inc & (ref >= 0) & ~dead[inc_tgt]
+        inc_total = (
+            jnp.zeros(N + 1, dtype=jnp.int32)
+            .at[jnp.where(inc_ok, inc_tgt, N)]
+            .add(jnp.where(inc_ok, value, 0))[:N]
+        )
 
     # -- 3. LWW map winners --------------------------------------------
-    # group id over (obj, key); 0 = not a map-located value op
-    in_map = visible & (key >= 0)
-    gid = jnp.where(in_map, (obj + 1) * (K + 1) + (key + 1), 0)
-    order = jnp.lexsort((slot, ctr, gid))
-    g_sorted = gid[order]
-    run_end = jnp.concatenate(
-        [g_sorted[1:] != g_sorted[:-1], jnp.ones((1,), dtype=bool)]
-    )
-    winner_sorted = run_end & (g_sorted > 0)
-    map_winner = jnp.zeros(N, dtype=bool).at[order].set(winner_sorted)
+    with jax.named_scope("lww"):
+        # group id over (obj, key); 0 = not a map-located value op
+        in_map = visible & (key >= 0)
+        gid = jnp.where(in_map, (obj + 1) * (K + 1) + (key + 1), 0)
+        order = jnp.lexsort((slot, ctr, gid))
+        g_sorted = gid[order]
+        run_end = jnp.concatenate(
+            [g_sorted[1:] != g_sorted[:-1], jnp.ones((1,), dtype=bool)]
+        )
+        winner_sorted = run_end & (g_sorted > 0)
+        map_winner = jnp.zeros(N, dtype=bool).at[order].set(winner_sorted)
 
     # -- 4. element values: winner per element -------------------------
-    # OpId composite; +1 so 0 means "no visible value"
-    comp = ctr * jnp.int32(A) + slot + 1
-    is_elem_update = visible & ~is_ins & (key < 0) & (ref >= 0)
-    own_value = visible & is_ins
-    contrib = is_elem_update | own_value
-    elem_of = jnp.where(is_elem_update, ref, jnp.where(own_value, idx, N))
-    best = (
-        jnp.zeros(N + 1, dtype=jnp.int32)
-        .at[elem_of]
-        .max(jnp.where(contrib, comp, 0))[:N]
-    )
-    elem_live = is_ins & (best > 0)
-    elem_winner = contrib & (
-        comp == best[jnp.clip(elem_of, 0, N - 1)]
-    )
+    with jax.named_scope("elem_values"):
+        # OpId composite; +1 so 0 means "no visible value"
+        comp = ctr * jnp.int32(A) + slot + 1
+        is_elem_update = visible & ~is_ins & (key < 0) & (ref >= 0)
+        own_value = visible & is_ins
+        contrib = is_elem_update | own_value
+        elem_of = jnp.where(is_elem_update, ref, jnp.where(own_value, idx, N))
+        best = (
+            jnp.zeros(N + 1, dtype=jnp.int32)
+            .at[elem_of]
+            .max(jnp.where(contrib, comp, 0))[:N]
+        )
+        elem_live = is_ins & (best > 0)
+        elem_winner = contrib & (
+            comp == best[jnp.clip(elem_of, 0, N - 1)]
+        )
 
     # -- 5. RGA forest order -------------------------------------------
-    is_seq_container = ((action == _MAKE_LIST) | (action == _MAKE_TEXT)) & valid
-    in_forest = is_ins | is_seq_container
-    # parent: INS -> predecessor elem (HEAD -> the container row);
-    # non-inserted containers are tree roots (-1)
-    parent = jnp.where(
-        is_ins, jnp.where(ref == -2, obj, ref), jnp.int32(-1)
-    )
-    # sibling sort: group by parent (asc), OpId descending within group
-    pa = jnp.where(in_forest, parent + 1, N + 1)
-    inv = jnp.int32(2**30) - comp
-    order2 = jnp.lexsort((inv, pa))
-    pa_s = pa[order2]
-    run_start = jnp.concatenate(
-        [jnp.ones((1,), dtype=bool), pa_s[1:] != pa_s[:-1]]
-    )
-    fc_table = (
-        jnp.full(N + 2, -1, dtype=jnp.int32)
-        .at[jnp.where(run_start, pa_s, N + 1)]
-        .set(jnp.where(run_start, order2, -1).astype(jnp.int32))
-    )
-    first_child = fc_table[idx + 1]  # children of node i have pa == i+1
-    nxt_in_sort = jnp.concatenate([order2[1:], jnp.full((1,), -1, jnp.int32)])
-    same_parent = jnp.concatenate(
-        [pa_s[1:] == pa_s[:-1], jnp.zeros((1,), dtype=bool)]
-    )
-    nsib = (
-        jnp.full(N, -1, dtype=jnp.int32)
-        .at[order2]
-        .set(jnp.where(same_parent, nxt_in_sort, -1).astype(jnp.int32))
-    )
-
-    # climb-to-sibling fixpoint via pointer doubling (terminal = N);
-    # int16 payload when it fits — gathers move half the bytes
-    has_sib = nsib != -1
-    jump = jnp.where(
-        has_sib, idx, jnp.where(parent >= 0, parent, N)
-    ).astype(jnp.int32)
-    jump = jnp.where(in_forest, jump, N)
-    jump_ext = jnp.concatenate([jump, jnp.array([N], jnp.int32)])
-    if N < 2**15:
-        j16 = jump_ext.astype(jnp.int16)
-        for _ in range(_ceil_log2(N) + 1):
-            j16 = j16[j16.astype(jnp.int32)]
-        jump_ext = j16.astype(jnp.int32)
-    else:
-        for _ in range(_ceil_log2(N) + 1):
-            jump_ext = jump_ext[jump_ext]
-    fix = jump_ext[:N]
-    nsib_ext = jnp.concatenate([nsib, jnp.array([-1], jnp.int32)])
-    succ = jnp.where(first_child != -1, first_child, nsib_ext[fix])
-    succ = jnp.where(in_forest, succ, -1)
-    nxt = jnp.where(succ == -1, N, succ).astype(jnp.int32)
-
-    # Wyllie list-ranking: rank = #nodes from here to end of chain
-    rank = jnp.where(in_forest, 1, 0).astype(jnp.int32)
-    if N < 2**15:
-        # pack (rank, nxt) into one int32 lane: rank <= chain length <= N
-        # < 2^15 and nxt <= N, so `nxt | rank<<16` fits — one gather per
-        # round instead of two (the gathers, not the VPU work, bound
-        # these loops on TPU)
-        p = jnp.concatenate([nxt, jnp.array([N], jnp.int32)]) | (
-            jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)]) << 16
+    with jax.named_scope("rga_order"):
+        is_seq_container = (
+            (action == _MAKE_LIST) | (action == _MAKE_TEXT)
+        ) & valid
+        in_forest = is_ins | is_seq_container
+        # parent: INS -> predecessor elem (HEAD -> the container row);
+        # non-inserted containers are tree roots (-1)
+        parent = jnp.where(
+            is_ins, jnp.where(ref == -2, obj, ref), jnp.int32(-1)
         )
-        for _ in range(_ceil_log2(N) + 1):
-            q = p[p & 0xFFFF]
-            p = (q & 0xFFFF) | ((p >> 16) + (q >> 16)) << 16
-        rank = (p >> 16)[:N]
-    else:
-        rank_ext = jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)])
-        nxt_ext = jnp.concatenate([nxt, jnp.array([N], jnp.int32)])
-        for _ in range(_ceil_log2(N) + 1):
-            rank_ext = rank_ext + rank_ext[nxt_ext]
-            nxt_ext = nxt_ext[nxt_ext]
-        rank = rank_ext[:N]
+        # sibling sort: group by parent (asc), OpId descending within group
+        pa = jnp.where(in_forest, parent + 1, N + 1)
+        inv = jnp.int32(2**30) - comp
+        order2 = jnp.lexsort((inv, pa))
+        pa_s = pa[order2]
+        run_start = jnp.concatenate(
+            [jnp.ones((1,), dtype=bool), pa_s[1:] != pa_s[:-1]]
+        )
+        fc_table = (
+            jnp.full(N + 2, -1, dtype=jnp.int32)
+            .at[jnp.where(run_start, pa_s, N + 1)]
+            .set(jnp.where(run_start, order2, -1).astype(jnp.int32))
+        )
+        first_child = fc_table[idx + 1]  # children of node i have pa == i+1
+        nxt_in_sort = jnp.concatenate(
+            [order2[1:], jnp.full((1,), -1, jnp.int32)]
+        )
+        same_parent = jnp.concatenate(
+            [pa_s[1:] == pa_s[:-1], jnp.zeros((1,), dtype=bool)]
+        )
+        nsib = (
+            jnp.full(N, -1, dtype=jnp.int32)
+            .at[order2]
+            .set(jnp.where(same_parent, nxt_in_sort, -1).astype(jnp.int32))
+        )
+
+        # climb-to-sibling fixpoint via pointer doubling (terminal = N);
+        # int16 payload when it fits — gathers move half the bytes
+        has_sib = nsib != -1
+        jump = jnp.where(
+            has_sib, idx, jnp.where(parent >= 0, parent, N)
+        ).astype(jnp.int32)
+        jump = jnp.where(in_forest, jump, N)
+        jump_ext = jnp.concatenate([jump, jnp.array([N], jnp.int32)])
+        if N < 2**15:
+            j16 = jump_ext.astype(jnp.int16)
+            for _ in range(_ceil_log2(N) + 1):
+                j16 = j16[j16.astype(jnp.int32)]
+            jump_ext = j16.astype(jnp.int32)
+        else:
+            for _ in range(_ceil_log2(N) + 1):
+                jump_ext = jump_ext[jump_ext]
+        fix = jump_ext[:N]
+        nsib_ext = jnp.concatenate([nsib, jnp.array([-1], jnp.int32)])
+        succ = jnp.where(first_child != -1, first_child, nsib_ext[fix])
+        succ = jnp.where(in_forest, succ, -1)
+        nxt = jnp.where(succ == -1, N, succ).astype(jnp.int32)
+
+        # Wyllie list-ranking: rank = #nodes from here to end of chain
+        rank = jnp.where(in_forest, 1, 0).astype(jnp.int32)
+        if N < 2**15:
+            # pack (rank, nxt) into one int32 lane: rank <= chain length <= N
+            # < 2^15 and nxt <= N, so `nxt | rank<<16` fits — one gather per
+            # round instead of two (the gathers, not the VPU work, bound
+            # these loops on TPU)
+            p = jnp.concatenate([nxt, jnp.array([N], jnp.int32)]) | (
+                jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)]) << 16
+            )
+            for _ in range(_ceil_log2(N) + 1):
+                q = p[p & 0xFFFF]
+                p = (q & 0xFFFF) | ((p >> 16) + (q >> 16)) << 16
+            rank = (p >> 16)[:N]
+        else:
+            rank_ext = jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)])
+            nxt_ext = jnp.concatenate([nxt, jnp.array([N], jnp.int32)])
+            for _ in range(_ceil_log2(N) + 1):
+                rank_ext = rank_ext + rank_ext[nxt_ext]
+                nxt_ext = nxt_ext[nxt_ext]
+            rank = rank_ext[:N]
 
     # -- 6. clock (local slots; [A_loc], decoded via doc_actors) -------
-    clock = (
-        jnp.zeros(A, dtype=jnp.int32)
-        .at[jnp.where(valid, slot, 0)]
-        .max(jnp.where(valid, seq, 0))
-    )
+    with jax.named_scope("clock"):
+        clock = (
+            jnp.zeros(A, dtype=jnp.int32)
+            .at[jnp.where(valid, slot, 0)]
+            .max(jnp.where(valid, seq, 0))
+        )
 
     return MaterializeOut(
         dead=dead,
@@ -341,6 +365,13 @@ def _le_bytes(x: jax.Array, nbytes: int) -> jax.Array:
 
 
 def _summarize_wire(
+    out: MaterializeOut, N: int, A: int, lean: bool
+) -> jax.Array:
+    with jax.named_scope("wire"):
+        return _summarize_wire_scoped(out, N, A, lean)
+
+
+def _summarize_wire_scoped(
     out: MaterializeOut, N: int, A: int, lean: bool
 ) -> jax.Array:
     spec = summary_wire_spec(N, A, lean)
@@ -647,33 +678,25 @@ def host_args(batch: ColumnarBatch, lean: bool = False):
     return args, A, K
 
 
-# stage timings of the most recent _device_args call — the bulk loader
-# folds these into last_bulk_stats for the bench's stage breakdown
-last_args_timings: Dict[str, float] = {}
-
-
 def _device_args(batch: ColumnarBatch, lean: bool = False, device=None):
     """(device args, A_loc, K) for the jitted kernels. `lean` skips the
     seq/value builds and uploads (their slots are None). `device` pins
     the upload to a specific device (the slab round-robin scheduler);
-    None uses the default placement."""
-    import time
-
-    t0 = time.perf_counter()
-    np_args, A, K = host_args(batch, lean=lean)
-    t1 = time.perf_counter()
-    if device is None:
-        args = tuple(
-            None if a is None else jnp.asarray(a) for a in np_args
-        )
-    else:
-        args = tuple(
-            None if a is None else jax.device_put(a, device)
-            for a in np_args
-        )
-    t2 = time.perf_counter()
-    last_args_timings["narrow"] = t1 - t0
-    last_args_timings["upload"] = t2 - t1
+    None uses the default placement. Narrowing and upload are spans; a
+    bulk load's dispatch stage reads their seconds from its own span's
+    `kids` (t_narrow / t_upload)."""
+    with telemetry.timed("pipeline.narrow", "pipeline"):
+        np_args, A, K = host_args(batch, lean=lean)
+    with telemetry.timed("pipeline.upload", "pipeline"):
+        if device is None:
+            args = tuple(
+                None if a is None else jnp.asarray(a) for a in np_args
+            )
+        else:
+            args = tuple(
+                None if a is None else jax.device_put(a, device)
+                for a in np_args
+            )
     return args, A, K
 
 
@@ -701,13 +724,134 @@ def run_batch_full(
     `device` pins args (and therefore execution) to one device — the
     slab round-robin scheduler's per-chip dispatch."""
     args, A, K = _device_args(batch, lean=lean, device=device)
-    if lean:
-        (flags, slot, ctr, _seq, obj, key, ref, _value, psrc, ptgt,
-         da) = args
-        return materialize_full_lean_device(
-            flags, slot, ctr, obj, key, ref, psrc, ptgt, da, A=A, K=K
+    fn, args = _full_entry(args, lean)
+    _dispatched[(batch.n_docs, batch.n_rows, bool(lean))] = (
+        fn,
+        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args),
+        {"A": A, "K": K},
+    )
+    with telemetry.timed("pipeline.enqueue", "pipeline"):
+        return fn(*args, A=A, K=K)
+
+
+def _full_entry(args, lean: bool):
+    """(jitted entry, its positional args) of a full-kernel dispatch:
+    the lean entry takes no seq and value wires."""
+    if not lean:
+        return materialize_full_device, args
+    flags, slot, ctr, _seq, obj, key, ref, _value, psrc, ptgt, da = args
+    return materialize_full_lean_device, (
+        flags, slot, ctr, obj, key, ref, psrc, ptgt, da
+    )
+
+
+# -- device seconds by phase --------------------------------------------
+# A device trace names an operation by its HLO instruction (`%fusion.27`)
+# and carries no scope, so the map from instruction to `PHASES` comes
+# from the program: the optimized HLO of the same executable (a hit of
+# the compile caches, so the names are those of the trace) keeps each
+# instruction's `op_name`, and a fusion's scope is that of the
+# instructions fused into it.
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?(%?[\w.\-]+)\s+=")
+_HLO_CALLS = re.compile(r"\b(?:calls|to_apply)=(%?[\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_PHASE_IN_OP_NAME = re.compile(
+    r"(?<![\w.])(" + "|".join(PHASES) + r")(?![\w.])"
+)
+
+
+def phases_of_hlo(hlo_text: str) -> Dict[str, str]:
+    """{instruction of the entry computation: phase} from optimized HLO
+    text. The phases of an instruction are those its own `op_name`
+    names plus, for a fusion (or any caller), those of the instructions
+    inside the computation it calls: one gives that phase, two or more
+    "mixed", none "unscoped"."""
+    inside: Dict[str, set] = {}  # computation -> phases its lines name
+    callees: Dict[str, set] = {}  # computation -> computations it calls
+    entry: Dict[str, tuple] = {}  # entry instruction -> (own, callees)
+    comp, in_entry = None, False
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp, in_entry = m.group(1), line.startswith("ENTRY")
+            inside[comp], callees[comp] = set(), set()
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or comp is None:
+            continue
+        named = _HLO_OP_NAME.search(line)
+        own = (
+            set(_PHASE_IN_OP_NAME.findall(named.group(1))) if named
+            else set()
         )
-    return materialize_full_device(*args, A=A, K=K)
+        called = set(_HLO_CALLS.findall(line))
+        inside[comp] |= own
+        callees[comp] |= called
+        if in_entry:
+            entry[m.group(1)] = (own, called)
+
+    def phases_in(name: str, seen: frozenset) -> set:
+        if name in seen or name not in inside:
+            return set()
+        out = set(inside[name])
+        for c in callees[name]:
+            out |= phases_in(c, seen | {name})
+        return out
+
+    out: Dict[str, str] = {}
+    for name, (own, called) in entry.items():
+        seen = set(own)
+        for c in called:
+            seen |= phases_in(c, frozenset())
+        out[name] = (
+            "unscoped" if not seen
+            else next(iter(seen)) if len(seen) == 1 else "mixed"
+        )
+    return out
+
+
+def phase_of_ops(n_docs: int, n_rows: int, lean: bool) -> Dict[str, str]:
+    """{HLO instruction: phase | "mixed" | "unscoped"} of the slab
+    program this process dispatched for a [n_docs, n_rows] slab, or {}
+    if it dispatched none of that shape.
+
+    Compiles the same function for the same argument shapes (those
+    `run_batch_full` remembered) and reads the optimized HLO: the same
+    program as the one that ran, so the same instruction names. Not
+    the very executable, though: the persistent cache's key leaves
+    metadata out, so the executable that ran may date from before its
+    scopes were named. So this compile goes through a function object
+    and a jit of its own (JAX's in-process caches would hand back the
+    executable that ran) with the metadata in the persistent cache's
+    key: cached too, but never stale."""
+    sig = _dispatched.get((n_docs, n_rows, bool(lean)))
+    if sig is None:
+        return {}
+    fn, avals, statics = sig
+
+    @functools.wraps(fn.__wrapped__)
+    def same_program(*args, **kwargs):
+        return fn.__wrapped__(*args, **kwargs)
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        compiled = (
+            jax.jit(same_program, static_argnames=tuple(statics))
+            .lower(*avals, **statics)
+            .compile()
+        )
+    finally:
+        jax.config.update(flag, before)
+    return phases_of_hlo(compiled.as_text())
+
+
+# (n_docs, n_rows, lean) -> (jitted entry, arg shapes, statics) of the
+# full-kernel programs this process dispatched: what phase_of_ops lowers
+_dispatched: Dict[tuple, tuple] = {}
 
 
 def _check_ranges(batch: ColumnarBatch, A: int, K: int) -> None:

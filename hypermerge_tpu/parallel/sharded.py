@@ -208,20 +208,18 @@ def shard_batch(batch: ColumnarBatch, mesh: Mesh):
     Returns (args, A_loc, K, D_pad) — the same narrow wire args (and the
     same A_loc/K bucketing) as the single-device path, so both compile to
     the same per-shard program; only the sharding differs."""
-    import time
-
     import numpy as np
 
-    from ..ops import crdt_kernels as _ck
     from ..ops.crdt_kernels import host_args
 
     dp = mesh.shape["dp"]
     D = batch.n_docs
     D_pad = pad_to_multiple(max(D, dp), dp)
     sh = doc_sharding(mesh)
-    t0 = time.perf_counter()
-    np_args, A, K = host_args(batch)
-    t1 = time.perf_counter()
+    # the same child spans as crdt_kernels._device_args: a bulk load's
+    # dispatch stage reads t_narrow / t_upload from them
+    with telemetry.timed("pipeline.narrow", "pipeline"):
+        np_args, A, K = host_args(batch)
 
     def put(arr, pad_value):
         if D_pad != arr.shape[0]:
@@ -231,9 +229,8 @@ def shard_batch(batch: ColumnarBatch, mesh: Mesh):
             arr = np.concatenate([arr, pad], axis=0)
         return jax.device_put(arr, sh)
 
-    args = tuple(put(a, pv) for a, pv in zip(np_args, _PAD_VALUES))
-    _ck.last_args_timings["narrow"] = t1 - t0
-    _ck.last_args_timings["upload"] = time.perf_counter() - t1
+    with telemetry.timed("pipeline.upload", "pipeline"):
+        args = tuple(put(a, pv) for a, pv in zip(np_args, _PAD_VALUES))
     return args, A, K, D_pad
 
 
@@ -308,7 +305,8 @@ def sharded_full(batch: ColumnarBatch, mesh: Mesh, lean: bool = False):
     args, A, K, _ = shard_batch(batch, mesh)
     jfn = _full_program(mesh, A, K, batch.n_rows, lean)
     _M_DISPATCHES.add(1)
-    with mesh, telemetry.span("mesh.sharded_full", "mesh"):
+    with mesh, telemetry.timed("pipeline.enqueue", "pipeline"), \
+            telemetry.span("mesh.sharded_full", "mesh"):
         return jfn(*args)
 
 
@@ -662,16 +660,13 @@ class SlabRoundRobin:
         The kernel entry is run_batch_full with a pinned device — the
         same code path as the single-device twin, so the two cannot
         diverge."""
-        import time
-
         from ..ops.crdt_kernels import run_batch_full
 
         i = self._pick_device()
         q = self._inflight[i]
         while len(q) >= self.depth:
             q.pop(0).block_until_ready()
-        t0 = time.perf_counter()
-        with telemetry.span("mesh.dispatch", "mesh"):
+        with telemetry.timed("mesh.dispatch", "mesh", chip=i) as sp:
             out, summary = run_batch_full(
                 batch, lean=lean, device=self.devices[i]
             )
@@ -681,7 +676,7 @@ class SlabRoundRobin:
             + batch.psrc.nbytes
             + batch.ptgt.nbytes
         )
-        self.t_dispatch_chip[i] += time.perf_counter() - t0
+        self.t_dispatch_chip[i] += sp.dur
         self.slabs_per_chip[i] += 1
         self.last_device = i
         q.append(summary)

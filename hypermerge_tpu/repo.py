@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
+from . import telemetry
 from .backend.repo_backend import RepoBackend
 from .frontend.handle import Handle
 from .frontend.repo_frontend import RepoFrontend
@@ -21,10 +22,11 @@ class Repo:
     def __init__(
         self, path: Optional[str] = None, memory: bool = False
     ) -> None:
-        self.front = RepoFrontend()
-        self.back = RepoBackend(path=path, memory=memory)
-        self.front.subscribe(self.back.receive)
-        self.back.subscribe(self.front.receive)
+        with telemetry.span("repo.init", "repo"):
+            self.front = RepoFrontend()
+            self.back = RepoBackend(path=path, memory=memory)
+            self.front.subscribe(self.back.receive)
+            self.back.subscribe(self.front.receive)
 
     # -- identity -------------------------------------------------------
 
@@ -43,8 +45,15 @@ class Repo:
     def open_many(self, urls) -> list:
         """Batched cold open: one backend bulk load (device slabs for
         large counts), handles whose snapshots decode lazily on first
-        read. THE way to bring a big repo up (BASELINE config 4)."""
-        return self.front.open_many(urls)
+        read. THE way to bring a big repo up (BASELINE config 4).
+
+        The root span of the open: every span of the load carries its
+        `open` id (telemetry.open_id), on whichever thread it runs."""
+        with telemetry.span(
+            "repo.open_many", "repo", open=telemetry.open_id(),
+            docs=len(urls),
+        ):
+            return self.front.open_many(urls)
 
     def doc(self, url: str, cb: Optional[Callable] = None) -> Any:
         return self.front.doc(url, cb)
@@ -121,4 +130,5 @@ class Repo:
         self.back.start_file_server(path)
 
     def close(self) -> None:
-        self.back.close()
+        with telemetry.span("repo.close", "repo"):
+            self.back.close()

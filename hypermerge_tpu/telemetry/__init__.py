@@ -7,10 +7,13 @@ round 13). Every subsystem registers into the same two instruments:
   counters, gauges, fixed-bucket histograms — lock-cheap via
   per-thread shards, merged on read, exportable as a Prometheus text
   snapshot (``prometheus_text``) or a plain dict (``snapshot``);
-- a bounded **span ring** (``trace``): begin/end spans with tags,
-  off by default (``span()`` is a no-op singleton), activated by
-  ``HM_TRACE=<path>`` (Chrome trace JSON written at exit, loadable in
-  Perfetto) or ``enable_tracing()``.
+- one **span seam** (``trace``): begin/end spans with tags and the ids
+  of their request (``open``, ``slab``, ``parent``), off by default
+  (``span()`` is a no-op singleton). A span is live when either sink
+  is: the bounded ring, activated by ``HM_TRACE=<path>`` (Chrome trace
+  JSON written at exit, loadable in Perfetto) or ``enable_tracing()``,
+  or a running ``jax.profiler`` session, where the span becomes a
+  ``TraceAnnotation`` beside the device ops on the device's clock.
 
 Naming convention: ``<subsystem>.<metric>`` with subsystems
 ``live`` (apply engine), ``pipeline`` (bulk cold open), ``mesh``
@@ -51,11 +54,16 @@ from .trace import (
     event_count,
     events as trace_events,
     flush as flush_trace,
+    install_gc_hook,
     instant,
+    open_id,
     reset as reset_trace,
     span,
+    timed,
     trace_path,
 )
+
+install_gc_hook()  # host.gc spans + host.gc_full / host.gc_full_s
 
 # module-level conveniences bound to the process registry
 counter = REGISTRY.counter
@@ -104,7 +112,7 @@ __all__ = [
     "DEFAULT_TIME_BUCKETS_S", "counter", "gauge", "histogram",
     "snapshot", "next_instance", "prometheus_text",
     "chrome_trace_events", "write_chrome_trace", "span", "begin",
-    "instant", "NOOP", "SpanHandle", "enable_tracing",
+    "timed", "open_id", "instant", "NOOP", "SpanHandle", "enable_tracing",
     "disable_tracing", "tracing_enabled", "trace_events",
     "event_count", "flush_trace", "reset_trace", "trace_path",
     "query_payload", "snapshot_repo",

@@ -1,14 +1,32 @@
-"""Bounded structured-trace ring buffer of spans.
+"""The one seam through which the program records a span.
 
-Spans are begin/end windows with tags, recorded into a fixed-capacity
-ring (HM_TRACE_RING events, default 65536) — a long-running daemon
-keeps the LAST N events, never unbounded memory. Export renders
-Chrome trace-event JSON (load the file in Perfetto / chrome://tracing)
-via telemetry.export.
+A span is live when either of two sinks is on, and goes to both:
 
-Off by default and cheap when off: ``span()`` checks one module flag
-and returns a shared no-op singleton — no object allocation, no
-timestamp read. Enable with:
+- the **ring**: begin/end windows with tags in a fixed-capacity ring
+  (HM_TRACE_RING events, default 65536) — a long-running daemon keeps
+  the LAST N events, never unbounded memory. Export renders Chrome
+  trace-event JSON (Perfetto / chrome://tracing) via telemetry.export.
+  For operators without a chip trace.
+- the **profiler**: while a ``jax.profiler`` session runs
+  (``TraceAnnotation.is_enabled()``), the span is also entered as a
+  ``jax.profiler.TraceAnnotation(name, **ids)``, so it lands in the
+  ``.xplane.pb`` beside the device ops, on the device's clock. This
+  module never imports jax: it looks the class up only once ``jax``
+  is already in ``sys.modules``.
+
+Cause and request: a span entered with ``with`` becomes the context of
+its thread, and spans begun under it inherit its ``open`` (the request
+id of one cold open, ``open_id()``) and ``slab`` ids; same-thread
+nesting is the parent. A span whose cause ran on another thread says
+so itself (``parent=<name>``). A span entered with ``with`` also
+collects the seconds of the spans that ran under it on its thread, by
+name (``kids``), so a stage can feed a stat from its child spans' own
+clock readings.
+
+With both sinks off ``span()`` checks two flags and returns a shared
+no-op singleton — no object allocation, no timestamp read. ``timed()``
+is for a stage whose seconds also feed a stat: it reads the clock once
+at each end whether or not a sink is on. Enable the ring with:
 
 - ``HM_TRACE=<path>`` in the environment (read at import): tracing on
   for the process lifetime, the trace file written at exit (atexit)
@@ -24,11 +42,15 @@ oldest slot; ``events()`` reorders by sequence.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from .registry import REGISTRY
 
 # event tuples: (seq implicit via slot, ph, name, cat, ts_us, dur_us,
 # tid, args) — converted to Chrome dicts at export time (export.py)
@@ -119,42 +141,110 @@ def _note_thread() -> int:
     return tid
 
 
+# ids a span hands down to the spans begun under it on its thread
+_ID_KEYS = ("open", "slab")
+_CTX = threading.local()  # .top: innermost span entered with `with`
+_OPEN_SEQ = itertools.count(1)
+_TA = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _profiling() -> bool:
+    """Is a jax.profiler session recording? A static check on the
+    annotation class; False while nothing has imported jax."""
+    global _TA
+    ta = _TA
+    if ta is None:
+        jax = sys.modules.get("jax")
+        # mid-import `jax` has no `profiler` yet: look again next time
+        ta = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if ta is None:
+            return False
+        _TA = ta
+    return ta.is_enabled()
+
+
+def open_id() -> int:
+    """The request id of the cold open this thread works on (from the
+    enclosing span that carries ``open=``), else the next of the
+    per-process sequence."""
+    top = getattr(_CTX, "top", None)
+    if top is not None and top.args and "open" in top.args:
+        return top.args["open"]
+    return next(_OPEN_SEQ)
+
+
 class SpanHandle:
-    """An open span: ``end()`` records it. Use via ``span()`` as a
-    context manager, or ``begin()``/``end()`` across seams where the
-    window opens and closes on different code paths."""
+    """An open span: ``end()`` records it and returns its seconds. Use
+    via ``span()`` / ``timed()`` as a context manager, or ``begin()`` /
+    ``end()`` across seams where the window opens and closes on
+    different code paths. After the end ``dur`` holds the seconds and
+    ``kids`` the seconds, by name, of the spans that ran under it on
+    its thread (those entered with ``with``)."""
 
-    __slots__ = ("name", "cat", "t0", "args")
+    __slots__ = ("name", "cat", "args", "t0", "dur", "kids", "_ring",
+                 "_ann", "_up")
 
-    def __init__(self, name: str, cat: str, args: Optional[Dict]):
+    def __init__(self, name: str, cat: str, args: Dict, ring: bool,
+                 prof: bool):
+        top = getattr(_CTX, "top", None)
+        if top is not None and top.args:
+            for k in _ID_KEYS:
+                if k in top.args and k not in args:
+                    args[k] = top.args[k]
         self.name = name
         self.cat = cat
-        self.args = args
+        self.args = args or None
+        self.dur = 0.0
+        self.kids: Dict[str, float] = {}
+        self._ring = ring
+        self._up: Optional["SpanHandle"] = None
+        # the annotation's clock starts where it is made
+        self._ann = _TA(name, **args) if prof else None
         self.t0 = time.perf_counter()
 
-    def end(self, **more: Any) -> None:
-        if not _T.on:
-            return
-        t1 = time.perf_counter()
-        args = self.args
-        if more:
-            args = {**(args or {}), **more}
-        _T.ring.add((
-            "X",
-            self.name,
-            self.cat,
-            (self.t0 - _T.t0) * 1e6,
-            (t1 - self.t0) * 1e6,
-            _note_thread(),
-            args,
-        ))
+    def note(self, **more: Any) -> None:
+        """Tags known only once the work is done (`docs=`, `ops=`)."""
+        self.args = {**(self.args or {}), **more}
+        if self._ann is not None:
+            self._ann.set_metadata(**more)
 
-    # context-manager protocol (what span() hands out when enabled)
+    def end(self, **more: Any) -> float:
+        t1 = time.perf_counter()
+        self.dur = t1 - self.t0
+        if more:
+            self.note(**more)
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        if self._ring and _T.on:
+            args = self.args
+            _T.ring.add((
+                "X",
+                self.name,
+                self.cat,
+                (self.t0 - _T.t0) * 1e6,
+                self.dur * 1e6,
+                _note_thread(),
+                args,
+            ))
+        return self.dur
+
+    # context-manager protocol: the span is its thread's context
     def __enter__(self) -> "SpanHandle":
+        self._up = getattr(_CTX, "top", None)
+        _CTX.top = self
         return self
 
     def __exit__(self, *exc) -> None:
-        self.end()
+        up = _CTX.top = self._up
+        self._up = None
+        dur = self.end()
+        if up is not None:  # hand up own seconds and the descendants'
+            kids = up.kids
+            kids[self.name] = kids.get(self.name, 0.0) + dur
+            for name, secs in self.kids.items():
+                kids[name] = kids.get(name, 0.0) + secs
 
 
 class _NoopSpan:
@@ -168,31 +258,41 @@ class _NoopSpan:
     def __exit__(self, *exc) -> None:
         pass
 
-    def end(self, **more: Any) -> None:
+    def note(self, **more: Any) -> None:
         pass
+
+    def end(self, **more: Any) -> float:
+        return 0.0
 
 
 NOOP = _NoopSpan()
 
 
 def span(name: str, cat: str = "", **args: Any):
-    """A context manager timing one section into the ring. Disabled
-    tracing returns the shared no-op singleton."""
-    if not _T.on:
+    """A context manager timing one section into the live sinks. With
+    ring and profiler both off it is the shared no-op singleton."""
+    ring, prof = _T.on, _profiling()
+    if not (ring or prof):
         return NOOP
-    return SpanHandle(name, cat, args or None)
+    return SpanHandle(name, cat, args, ring, prof)
 
 
-def begin(name: str, cat: str = "", **args: Any):
-    """Open a span to be closed by ``handle.end()`` later (possibly on
-    another code path). Disabled tracing returns the no-op handle."""
-    if not _T.on:
-        return NOOP
-    return SpanHandle(name, cat, args or None)
+# Open a span to be closed by ``handle.end()`` later (possibly on
+# another code path); it does not become its thread's context.
+begin = span
+
+
+def timed(name: str, cat: str = "", **args: Any) -> SpanHandle:
+    """A span whose seconds the caller needs too (a stage that feeds a
+    stat or a counter): always a real handle, the clock read once at
+    each end, recorded only into the sinks that are on."""
+    return SpanHandle(name, cat, args, _T.on, _profiling())
 
 
 def instant(name: str, cat: str = "", **args: Any) -> None:
     """A point event (demotions, resync closures, faults)."""
+    if _profiling():
+        _TA(name, **args).__exit__(None, None, None)
     if not _T.on:
         return
     _T.ring.add((
@@ -204,6 +304,41 @@ def instant(name: str, cat: str = "", **args: Any) -> None:
         _note_thread(),
         args or None,
     ))
+
+
+# -- the interpreter's full collections ---------------------------------
+# A generation-2 collection stops every thread for as long as it walks
+# the heap (0.2-0.8 s with a 10k-doc store open): a stall no stage owns.
+# One hook times them as `host.gc` spans and counts them; generations 0
+# and 1 (thousands a second, microseconds each) return at once.
+
+_GC_SPAN: List[Optional[SpanHandle]] = [None]
+# monotone totals kept as gauges: the hook can run inside any
+# allocation, under any lock, so it takes none (Gauge.set is one
+# assignment; a Counter's first add on a thread locks)
+_GC_FULL = REGISTRY.gauge("host.gc_full")
+_GC_FULL_S = REGISTRY.gauge("host.gc_full_s")
+
+
+def _gc_hook(phase: str, info: Dict[str, int]) -> None:
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _GC_SPAN[0] = timed("host.gc", "host", gen=2)
+        return
+    sp, _GC_SPAN[0] = _GC_SPAN[0], None
+    if sp is None:
+        return
+    dt = sp.end(collected=info.get("collected", 0))
+    # collections never overlap, so read-then-set loses nothing
+    _GC_FULL.set(_GC_FULL.value() + 1)
+    _GC_FULL_S.set(_GC_FULL_S.value() + dt)
+
+
+def install_gc_hook() -> None:
+    """Idempotent; the telemetry package calls it once at import."""
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
 
 
 def events() -> List[EventT]:

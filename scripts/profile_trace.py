@@ -1,14 +1,16 @@
-"""Replay an HM_TRACE file into the busy-vs-wall stage timeline.
+"""Replay a trace of the program's spans into the busy-vs-wall stage
+timeline.
 
-Takes the Chrome trace-event JSON a run wrote under HM_TRACE=<path>
-(hypermerge_tpu/telemetry/trace.py) and prints the same per-stage
-concurrency table scripts/profile_cold.py renders from bulk stats —
-busy seconds per span name vs the overlapped wall clock, so a trace
-from ANY run (bench, daemon, test) answers "where did the time go"
-without re-running it under a profiler.
+Takes either file the span seam (hypermerge_tpu/telemetry/trace.py)
+feeds: the Chrome trace-event JSON a run wrote under HM_TRACE=<path>,
+or the `.xplane.pb` of a `jax.profiler` session that ran while the
+program did (there the spans sit on the device's clock, beside the
+device ops). Prints busy seconds per span name vs the overlapped wall
+clock, so a trace from ANY run (bench, daemon, test) answers "where
+did the time go" without re-running it under a profiler.
 
 Usage:
-    python scripts/profile_trace.py /tmp/t.json [--by name|cat]
+    python scripts/profile_trace.py /tmp/t.json [--by name|cat|slab]
         [--top N] [--threads]
 
 --by cat groups by subsystem (live/pipeline/net/storage/mesh/serve)
@@ -17,12 +19,18 @@ serving tier's spans show up as `serve.read` (per-request latency,
 admission to completion) and `serve.batch` (one coalesced kernel
 flush) — their count ratio IS the read-batching factor.
 
+--by slab follows each cold open in the trace (the span tree of
+benchmark/readers/span_tree.py: `open` / `slab` ids and nesting): the
+head of the open stage by stage, each slab's walk io -> spec -> pack ->
+dispatch -> fetch across its four threads with the seconds the work
+waited between stages, and, from an `.xplane.pb` with a device in it,
+each idle gap of device 0 over 50 ms by the span that covers it.
+
 Under HM_PACK_WORKERS>1 the pack plane fans out: each pool worker
 emits its own `pipeline.pack` spans from an `hm-pipe-pack-<i>` thread,
 so `--threads` draws one busy lane per pack worker (their sum past the
 `pipeline.pack` row's share of the wall is the pool's realized
-speedup; scripts/profile_cold.py prints the same lanes from bulk
-stats). Device packs (HM_DEVICE_PACK=1) run inside those same spans —
+speedup). Device packs (HM_DEVICE_PACK=1) run inside those same spans —
 whether the kernel or the host packed is in the metrics registry, not
 the trace: `pack.device_packs` counts kernel-packed slabs and
 `pack.device_fallbacks` counts silent host fallbacks.
@@ -38,15 +46,95 @@ the timeline.
 
 import argparse
 import json
+import os
 import sys
 from collections import defaultdict
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 
 def load_events(path):
+    """Chrome trace-event dicts, and the busy intervals of device 0
+    (seconds; empty for a ring file). An `.xplane.pb` is read through
+    the benchmark's span-tree reader (needs jax)."""
+    if path.endswith(".pb"):
+        from benchmark.readers import span_tree
+
+        spans, busy = span_tree.load(path)
+        lines = {}
+        return [
+            {
+                "ph": "X", "name": s.name, "cat": s.name.split(".")[0],
+                "ts": s.t0 * 1e6, "dur": s.dur * 1e6,
+                "tid": lines.setdefault(s.line, len(lines) + 1),
+                "args": s.args,
+            }
+            for s in spans
+        ], busy
     with open(path) as fh:
         doc = json.load(fh)
     events = doc["traceEvents"] if isinstance(doc, dict) else doc
-    return [e for e in events if isinstance(e, dict)]
+    return [e for e in events if isinstance(e, dict)], []
+
+
+def slab_view(events, busy, out=sys.stdout) -> bool:
+    """Every cold open in the trace, followed by request and slab."""
+    from benchmark.readers import span_tree
+
+    spans = span_tree.from_chrome(events)
+    opens = sorted({s.args["open"] for s in spans
+                    if s.name == span_tree.ROOT and "open" in s.args})
+    for open_id in opens:
+        _open_view(span_tree.Tree(spans, open_id), busy, span_tree, out)
+    return bool(opens)
+
+
+def _open_view(tree, busy, span_tree, out) -> None:
+    root = tree.root
+    w = out.write
+    w(f"open {tree.open}: {root.dur:.3f}s in repo.open_many, "
+      f"{tree.end - root.t0:.3f}s to its last span, "
+      f"{len(tree.members)} spans\n")
+    w("head (spans of the open that carry no slab, by start):\n")
+    for s in tree.members:
+        if s.slab is None and s.name != span_tree.WAIT:
+            w(f"  {s.t0 - root.t0:9.3f}s {'  ' * s.depth}{s.name:<28}"
+              f" {s.dur:8.3f}s  self {tree.self_s(s):.3f}s\n")
+    for k in tree.slabs():
+        w(f"slab {k}: waited {tree.chain_wait(k):.3f}s between stages\n")
+        last = None
+        for s in tree.chain(k):
+            gap = "" if last is None else f"  (+{s.t0 - last:.3f}s)"
+            w(f"  {s.t0 - root.t0:9.3f}s {s.name:<20} {s.dur:8.3f}s"
+              f"  thread {s.line}{gap}\n")
+            for kid in tree.members:
+                if (kid.parent is s and kid.line == s.line
+                        and kid.name != span_tree.WAIT):
+                    w(f"  {kid.t0 - root.t0:9.3f}s   {kid.name:<18} "
+                      f"{kid.dur:8.3f}s\n")
+            last = s.t1
+    waits = defaultdict(float)
+    for s in tree.members:
+        if s.name == span_tree.WAIT:
+            waits[(s.args.get("q"), s.args.get("side"))] += s.dur
+    if waits:
+        w("pipeline.wait, thread-seconds by queue and side: " + ", ".join(
+            f"{q}/{side} {v:.3f}" for (q, side), v in sorted(waits.items())
+        ) + "\n")
+    gc = tree.named(("host.gc",))
+    if gc:
+        w(f"host.gc: {len(gc)} full collections, "
+          f"{sum(s.dur for s in gc):.3f}s, at "
+          + ", ".join(f"{s.t0 - root.t0:.2f}s" for s in gc) + "\n")
+    if busy:
+        table = tree.idle_table(busy)
+        idle = sum(r[1] for r in table)
+        w(f"device 0 idle {idle:.3f}s of {tree.end - root.t0:.3f}s; "
+          "gaps over 50 ms by covering span:\n")
+        for at, secs, name, slab in table:
+            if secs >= 0.05:
+                tag = "" if slab is None else f"{{slab={slab}}}"
+                w(f"  {at:9.3f}s {secs:8.3f}s  {name}{tag}\n")
 
 
 def timeline(events, by="name"):
@@ -84,8 +172,12 @@ def thread_busy(events, tid_names):
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("trace", help="Chrome trace JSON (HM_TRACE output)")
-    ap.add_argument("--by", choices=("name", "cat"), default="name")
+    ap.add_argument(
+        "trace", help="Chrome trace JSON (HM_TRACE output) or .xplane.pb"
+    )
+    ap.add_argument(
+        "--by", choices=("name", "cat", "slab"), default="name"
+    )
     ap.add_argument("--top", type=int, default=24)
     ap.add_argument(
         "--threads", action="store_true",
@@ -93,7 +185,12 @@ def main() -> None:
     )
     args = ap.parse_args()
 
-    events = load_events(args.trace)
+    events, busy = load_events(args.trace)
+    if args.by == "slab":
+        if not slab_view(events, busy):
+            print("no repo.open_many span in trace", file=sys.stderr)
+            sys.exit(1)
+        return
     rows, wall, _t0 = timeline(events, by=args.by)
     if not rows:
         print("no complete spans in trace", file=sys.stderr)

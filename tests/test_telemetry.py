@@ -242,6 +242,11 @@ def test_chrome_trace_golden(tracer, tmp_path):
     with telemetry.span("live.tick", cat="live", docs=3):
         pass
     telemetry.instant("net.resync", cat="net", ms=5)
+    # cause and request (ISSUE 24): the ids ride as the event's args
+    with telemetry.span("pipeline.io", "pipeline", open=7, slab=2,
+                        parent="pipeline.bulk_load"):
+        with telemetry.span("storage.feeds.open", "storage", feeds=4):
+            pass
     path = str(tmp_path / "t.json")
     telemetry.flush_trace(path)
     doc = json.load(open(path))
@@ -249,12 +254,191 @@ def test_chrome_trace_golden(tracer, tmp_path):
     evs = doc["traceEvents"]
     meta = [e for e in evs if e["ph"] == "M"]
     assert {m["name"] for m in meta} >= {"process_name", "thread_name"}
-    (x,) = [e for e in evs if e["ph"] == "X"]
+    x, feeds, io = [e for e in evs if e["ph"] == "X"]
     assert x["name"] == "live.tick" and x["cat"] == "live"
     assert x["args"] == {"docs": 3}
     assert {"ts", "dur", "pid", "tid"} <= set(x)
     (i,) = [e for e in evs if e["ph"] == "i"]
     assert i["name"] == "net.resync" and i["s"] == "t"
+    assert io["name"] == "pipeline.io" and io["args"] == {
+        "open": 7, "slab": 2, "parent": "pipeline.bulk_load"}
+    # the child inherits the request and slab ids, not the parent tag
+    assert feeds["name"] == "storage.feeds.open" and feeds["args"] == {
+        "feeds": 4, "open": 7, "slab": 2}
+    assert io["ts"] <= feeds["ts"] and io["tid"] == feeds["tid"]
+
+
+def test_ids_are_inherited_per_thread(tracer):
+    """`open` / `slab` come down from the span a thread is in (entered
+    with `with`); another thread starts with none, and `open_id()` is
+    the enclosing open's id or the next of the sequence."""
+    got = {}
+
+    def worker():
+        got["other"] = telemetry.open_id()
+        with telemetry.span("t.other", open=got["other"]):
+            with telemetry.span("t.other.child"):
+                pass
+
+    with telemetry.span("t.root", open=telemetry.open_id(), docs=5) as root:
+        assert telemetry.open_id() == root.args["open"]
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+        with telemetry.span("t.slab", slab=1):
+            sp = telemetry.begin("t.leaf", docs=2)  # begin() inherits too
+            sp.end(n=3)
+    assert telemetry.open_id() not in (root.args["open"], got["other"])
+    by = {e[1]: e[6] for e in telemetry.trace_events()}
+    oid = root.args["open"]
+    assert by["t.root"] == {"open": oid, "docs": 5}
+    assert by["t.slab"] == {"slab": 1, "open": oid}
+    assert by["t.leaf"] == {"docs": 2, "open": oid, "slab": 1, "n": 3}
+    assert by["t.other"] == {"open": got["other"]} and got["other"] != oid
+    assert by["t.other.child"] == {"open": got["other"]}
+
+
+def test_timed_reads_the_clock_with_tracing_off():
+    """A stage that feeds a stat gets its seconds whether or not a sink
+    is on, and the seconds of the spans under it by name (`kids`): one
+    clock pair a stage."""
+    was_on = ttrace.enabled()
+    ttrace.disable()
+    try:
+        n0 = telemetry.event_count()
+        with telemetry.timed("t.stage", "pipeline") as sp:
+            with telemetry.timed("t.a"):
+                with telemetry.timed("t.b"):
+                    time.sleep(0.002)
+            with telemetry.timed("t.a"):
+                pass
+            with telemetry.span("t.noop") as noop:  # off: not a handle
+                noop.note(docs=1)
+        assert noop is telemetry.NOOP
+        assert telemetry.event_count() == n0  # nothing recorded
+        assert set(sp.kids) == {"t.a", "t.b"}  # descendants, by name
+        assert sp.dur >= sp.kids["t.a"] >= sp.kids["t.b"] >= 0.002
+    finally:
+        if was_on:
+            ttrace.enable()
+
+
+def test_import_telemetry_imports_no_jax():
+    """The seam looks the profiler up only once jax is loaded: the
+    package itself stays dependency-free, and with ring and profiler
+    both off `span()` is the shared NOOP."""
+    code = (
+        "import sys\n"
+        "import hypermerge_tpu.telemetry as t\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n"
+        "assert t.span('a.b') is t.NOOP and t.begin('a.c') is t.NOOP\n"
+        "assert t.trace.begin is t.trace.span\n"
+        "import gc\n"
+        "assert t.trace._gc_hook in gc.callbacks\n"
+        "print('ok')\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "HM_TRACE"}
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True,
+        text=True, timeout=60,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_span_reaches_a_running_profiler_session(tmp_path):
+    """Ring off, profiler on: the span is live and lands in the
+    `.xplane.pb` with its ids as the event's stats; after the session
+    `span()` is the NOOP again."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    was_on = ttrace.enabled()
+    ttrace.disable()
+    try:
+        assert telemetry.span("t.before") is telemetry.NOOP
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            n0 = telemetry.event_count()
+            with telemetry.span("pipeline.pack", "pipeline", open=4,
+                                slab=1) as sp:
+                sp.note(docs=9)
+            telemetry.instant("live.demote", cat="live", k="v")
+            assert sp is not telemetry.NOOP
+            assert telemetry.event_count() == n0  # the ring stays off
+        finally:
+            jax.profiler.stop_trace()
+        assert telemetry.span("t.after") is telemetry.NOOP
+    finally:
+        if was_on:
+            ttrace.enable()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found = {
+        e.name: dict(e.stats)
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name in ("pipeline.pack", "live.demote")
+    }
+    assert found == {
+        "pipeline.pack": {"open": 4, "slab": 1, "docs": 9},
+        "live.demote": {"k": "v"},
+    }
+
+
+def test_full_collections_are_spans_and_counts(tracer):
+    """One gc hook: a generation-2 collection is a `host.gc` span and
+    bumps host.gc_full / host.gc_full_s; younger generations are not
+    timed."""
+    import gc
+
+    before = telemetry.snapshot()
+    gc.collect(0)
+    gc.collect(1)
+    assert not [e for e in telemetry.trace_events() if e[1] == "host.gc"]
+    with telemetry.span("pipeline.io", "pipeline", open=5, slab=0):
+        gc.collect()
+    after = telemetry.snapshot()
+    assert after["host.gc_full"] == before.get("host.gc_full", 0) + 1
+    assert after["host.gc_full_s"] > before.get("host.gc_full_s", 0.0)
+    (ev,) = [e for e in telemetry.trace_events() if e[1] == "host.gc"]
+    assert ev[2] == "host" and ev[6]["gen"] == 2 and "collected" in ev[6]
+    assert ev[6]["open"] == 5 and ev[6]["slab"] == 0  # inside the open
+    assert gc.callbacks.count(ttrace._gc_hook) == 1
+    telemetry.install_gc_hook()  # idempotent
+    assert gc.callbacks.count(ttrace._gc_hook) == 1
+
+
+def test_change_span_carries_its_op_count(tracer):
+    """`frontend.change` > `frontend.change.resolve{ops}`: the local
+    change as the caller waits on it, and the run of the change fn
+    over the doc with the intents it recorded."""
+    from hypermerge_tpu.repo import Repo
+
+    repo = Repo(memory=True)
+    try:
+        url = repo.create({"items": []})
+        ttrace.reset()
+
+        def paste(d):
+            for i in range(5):
+                d["items"].append(i)
+
+        repo.change(url, paste)
+        repo.change(url, lambda d: None)  # no mutation: no request
+    finally:
+        repo.close()
+    evs = telemetry.trace_events()
+    resolve = [e for e in evs if e[1] == "frontend.change.resolve"]
+    change = [e for e in evs if e[1] == "frontend.change"]
+    assert [e[6]["ops"] for e in resolve] == [5, 0]
+    assert len(change) == 2 and all(e[2] == "frontend" for e in change)
+    for outer, inner in zip(change, resolve):  # the child nests in it
+        assert outer[5] == inner[5]
+        assert outer[3] <= inner[3]
+        assert inner[3] + inner[4] <= outer[3] + outer[4] + 1.0
 
 
 def test_prometheus_golden():

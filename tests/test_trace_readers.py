@@ -1,0 +1,456 @@
+"""The span tree of a cold open, as the benchmark's readers see it
+(ISSUE 24): counts and structure only, no clock asserted.
+
+- a rehearsal-size cold open under `jax.profiler.start_trace` puts the
+  program's own spans into the `.xplane.pb`; `readers/span_tree.py`
+  rebuilds one tree per open from `open` / `slab` / nesting;
+- hand-made spans with known gaps pin the idle attribution, the
+  self-time and the chain-wait arithmetic;
+- `readers/trace_scope_time.py` on a hand-made trace and hand-made HLO
+  books a fusion of two scopes to `mixed`, and the real slab program's
+  instructions carry every phase of `crdt_kernels.PHASES`.
+"""
+
+import glob
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.readers import span_tree, trace_scope_time  # noqa: E402
+from benchmark.readers.span_tree import Span, Tree  # noqa: E402
+
+from hypermerge_tpu.ops import crdt_kernels  # noqa: E402
+
+N_DOCS, N_OPS, SLAB = 40, 64, 16  # slabs of 16, 16, 8 docs
+STAGES = ("pipeline.io", "pipeline.spec", "pipeline.pack",
+          "pipeline.dispatch", "pipeline.fetch")
+
+
+@pytest.fixture(scope="module")
+def traced_open(tmp_path_factory):
+    """Two cold opens of one small corpus in one profiler session, every
+    slab on the device path. Returns (spans, stats of the last open)."""
+    import jax
+
+    from hypermerge_tpu.ops.corpus import make_corpus
+    from hypermerge_tpu.repo import Repo
+
+    tmp = tmp_path_factory.mktemp("traced")
+    env = {"HM_DEVICE_MIN_CELLS": "0", "HM_BULK_SLAB": str(SLAB),
+           "HM_PIPELINE": "1"}
+    old = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        urls = make_corpus(str(tmp / "repo"), N_DOCS, N_OPS)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp / "trace"), profiler_options=opts)
+        try:
+            for _ in range(2):
+                repo = Repo(path=str(tmp / "repo"))
+                repo.open_many(urls)
+                repo.back.fetch_bulk_summaries()
+                stats = dict(repo.back.last_bulk_stats)
+                repo.close()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        for k, v in old.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+    (path,) = glob.glob(
+        str(tmp / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    spans, busy = span_tree.load(path)
+    assert busy == []  # no TPU plane in a CPU trace
+    return spans, stats
+
+
+def test_one_root_per_open(traced_open):
+    spans, _ = traced_open
+    roots = [s for s in spans if s.name == span_tree.ROOT]
+    assert len(roots) == 2
+    ids = [s.args["open"] for s in roots]
+    assert len(set(ids)) == 2  # a per-process sequence: the request id
+    for oid in ids:
+        tree = Tree(spans, open_id=oid)
+        assert tree.root.args["open"] == oid
+        assert [s for s in tree.members if s.parent is None] == [tree.root]
+    # with no id asked for, the tree is the latest open's
+    assert Tree(spans).open == max(ids)
+
+
+def test_every_span_of_the_open_carries_its_id(traced_open):
+    spans, _ = traced_open
+    outside = {"repo.init", "repo.close", "host.gc"}
+    for s in spans:
+        if s.name.split(".")[0] in ("pipeline", "frontend"):
+            assert "open" in s.args, s
+        elif s.name.startswith("storage.") and "feeds" in s.args:
+            assert "open" in s.args and "slab" in s.args, s
+        elif s.name not in outside and s.name.startswith("repo."):
+            assert "open" in s.args, s
+    tree = Tree(spans)
+    names = {s.name for s in tree.members}
+    assert names >= {
+        "repo.open_many", "frontend.open_many.handles",
+        "pipeline.bulk_load", "pipeline.register", "pipeline.io",
+        "storage.feeds.open", "storage.columns.load", "pipeline.spec",
+        "pipeline.pack", "pipeline.wait", "pipeline.dispatch",
+        "pipeline.narrow", "pipeline.upload", "pipeline.enqueue",
+        "pipeline.init_docs", "pipeline.fetch", "pipeline.clock_rows",
+        "pipeline.barrier",
+    }
+    assert {"repo.init", "repo.close"} <= {s.name for s in spans}
+    # stage and slab granularity: tens of spans an open, none per doc
+    assert len(tree.members) < 40 * len(tree.slabs())
+
+
+@pytest.mark.parametrize("slab", [0, 1, 2])
+def test_slab_chain_in_causal_order(traced_open, slab):
+    spans, _ = traced_open
+    tree = Tree(spans)
+    assert tree.slabs() == [0, 1, 2]
+    chain = tree.chain(slab)
+    assert tuple(s.name for s in chain) == STAGES
+    for a, b in zip(chain, chain[1:]):
+        assert a.t1 <= b.t0, (a, b)  # each stage ends before the next
+    io, spec, pack, dispatch, fetch = chain
+    # the walk crosses threads: io thread, pack pool, caller, fetch
+    assert io.line == spec.line
+    assert len({io.line, pack.line, dispatch.line, fetch.line}) == 4
+    assert dispatch.line == tree.root.line
+    # cause: nesting on the caller's thread, `parent=` across threads
+    assert pack.parent is spec and fetch.parent is dispatch
+    assert dispatch.parent.name == "pipeline.bulk_load"
+    # (with several devices, as under this suite's 8-device CPU mesh,
+    # the round-robin scheduler's `mesh.dispatch` sits between)
+    under = [s.name for s in tree.members
+             if s.line == dispatch.line and s.name.startswith("pipeline.")
+             and dispatch.t0 <= s.t0 and s.t1 <= dispatch.t1
+             and s is not dispatch]
+    assert under == ["pipeline.narrow", "pipeline.upload",
+                     "pipeline.enqueue", "pipeline.init_docs"]
+    docs = [s for s in tree.members if s.name == "pipeline.init_docs"
+            and s.slab == slab]
+    assert [s.args["docs"] for s in docs] == [(16, 16, 8)[slab]]
+    assert tree.chain_wait(slab) >= 0.0
+
+
+def test_wait_never_overlaps_its_own_slabs_busy_span(traced_open):
+    spans, _ = traced_open
+    tree = Tree(spans)
+    waits = [s for s in tree.members if s.name == "pipeline.wait"]
+    assert {s.args["q"] for s in waits} == {
+        "pack", "turn", "dispatch", "fetch"}
+    assert {s.args["side"] for s in waits} == {"put", "get"}
+    with_slab = [s for s in waits if s.slab is not None]
+    assert with_slab and all(s.args["side"] == "put" for s in with_slab)
+    for w in with_slab:
+        for b in tree.chain(w.slab):
+            assert w.t1 <= b.t0 or b.t1 <= w.t0, (w, b)
+
+
+def test_span_metrics_read_from_the_tree(traced_open, monkeypatch):
+    """Every span_tree metric file of the cold-open cell finds
+    something to read in a traced open (the device ones need a TPU
+    plane and read None here)."""
+    import json
+
+    spans, _ = traced_open
+    monkeypatch.setattr(span_tree, "tree_of", lambda obs: (Tree(spans), []))
+    read = {}
+    for f in glob.glob(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                    "*.json")):
+        spec = json.load(open(f))
+        if spec["reader"] == "span_tree":
+            read[spec["name"]] = span_tree.read(spec["params"], {})
+    device = {"device.head_idle_s.open", "device.idle_attributed_pct.open"}
+    assert set(read) == device | {
+        "facade.self_s.open", "loader.register_s",
+        "loader.first_dispatch_s", "loader.queue_wait_s",
+        "loader.io_feeds_s", "loader.io_columns_s", "loader.upload_s",
+        "loader.doc_init_s", "host.gc_s.open",
+    }
+    for name, value in read.items():
+        if name in device:
+            assert value is None
+        elif name != "host.gc_s.open":  # a tiny open may collect nothing
+            assert value is not None and value >= 0.0, name
+
+
+def test_a_trace_without_program_spans_reads_none(monkeypatch):
+    """Laid over the program before PR 24 the readers find nothing and
+    do not raise."""
+    monkeypatch.setattr(span_tree, "newest_trace", lambda: None)
+    assert span_tree.read({"measure": "first_dispatch"}, {}) is None
+    assert trace_scope_time.read(
+        {"match": ["x"], "scope": "wire", "shapes": "bulk_slabs"},
+        {"bulk_slabs": [[16, 64]]}) is None
+    with pytest.raises(LookupError):
+        Tree([Span("bench.loader.open_many", 0.0, 1.0, 0, {})])
+
+
+def test_dispatch_stats_come_from_its_child_spans(traced_open):
+    """t_narrow / t_upload / t_dispatch are the seconds of the dispatch
+    stage's child spans (one clock pair a stage), with tracing on or
+    off; every key the loader's stats had is still there."""
+    _, stats = traced_open
+    for key in ("t_sql", "t_io", "t_spec", "t_pack", "t_narrow",
+                "t_upload", "t_dispatch", "t_fetch_busy", "t_fetch",
+                "t_pack_wall", "wall_critical_path", "t_io_busy",
+                "t_dispatch_busy"):
+        assert key in stats, key
+    for key in ("t_io", "t_pack", "t_narrow", "t_upload", "t_dispatch",
+                "t_fetch_busy"):
+        assert stats[key] > 0.0, key
+    assert stats["device_slabs"] == 3 and stats["host_slabs"] == 0
+
+
+# -- hand-made spans: the arithmetic ------------------------------------
+
+
+def _handmade():
+    """One open on a 10 s clock. Caller thread `c`, io thread `i`, pack
+    thread `p`, fetch thread `f`; device busy 4-5 s and 7-8 s."""
+    o = {"open": 9}
+    s0, s1 = dict(o, slab=0), dict(o, slab=1)
+    spans = [
+        Span("repo.init", -0.5, -0.1, "c", {}),
+        Span("repo.open_many", 0.0, 9.0, "c", dict(o)),
+        Span("frontend.open_many.handles", 0.1, 0.4, "c", dict(o)),
+        Span("pipeline.bulk_load", 0.5, 9.0, "c", dict(o)),
+        Span("pipeline.register", 0.5, 1.0, "c", dict(o)),
+        Span("pipeline.io", 1.0, 2.0, "i", dict(s0, parent="pipeline.bulk_load")),
+        Span("storage.feeds.open", 1.0, 1.25, "i", dict(s0)),
+        Span("storage.columns.load", 1.25, 2.0, "i", dict(s0)),
+        Span("pipeline.io", 2.0, 3.0, "i", dict(s1, parent="pipeline.bulk_load")),
+        Span("pipeline.pack", 2.5, 3.5, "p", dict(s0, parent="pipeline.io")),
+        Span("pipeline.wait", 1.0, 3.5, "c", dict(o, q="dispatch", side="get")),
+        Span("pipeline.dispatch", 3.5, 4.0, "c", dict(s0)),
+        Span("pipeline.enqueue", 3.75, 4.0, "c", dict(s0)),
+        Span("pipeline.pack", 5.0, 6.0, "p", dict(s1, parent="pipeline.io")),
+        Span("pipeline.fetch", 4.0, 5.5, "f", dict(s0, parent="pipeline.dispatch")),
+        Span("pipeline.dispatch", 6.5, 7.0, "c", dict(s1)),
+        Span("pipeline.fetch", 7.0, 8.5, "f", dict(s1, parent="pipeline.dispatch")),
+        Span("pipeline.barrier", 9.0, 9.5, "c", dict(o, parent="repo.open_many")),
+        Span("host.gc", 0.6, 0.85, "c", dict(o, gen=2)),
+        Span("host.gc", 5.25, 5.5, "x", {"gen": 2}),  # a thread with no context
+        Span("host.gc", 20.0, 21.0, "x", {"gen": 2}),  # after the open
+    ]
+    return Tree(spans), [(4.0, 5.0), (7.0, 8.0)]
+
+
+def test_handmade_tree_parents_and_depth():
+    tree, _ = _handmade()
+    by = {(s.name, s.slab): s for s in tree.members if s.name != "host.gc"}
+    assert by[("pipeline.register", None)].parent.name == "pipeline.bulk_load"
+    assert by[("storage.columns.load", 0)].parent is by[("pipeline.io", 0)]
+    assert by[("pipeline.io", 1)].parent.name == "pipeline.bulk_load"
+    # `parent=` picks the namesake of the same slab
+    assert by[("pipeline.pack", 1)].parent is by[("pipeline.io", 1)]
+    assert by[("pipeline.fetch", 0)].parent is by[("pipeline.dispatch", 0)]
+    assert by[("pipeline.barrier", None)].parent is tree.root
+    assert by[("pipeline.enqueue", 0)].depth == 3
+    assert tree.end == 9.5
+
+
+def test_handmade_self_time():
+    tree, _ = _handmade()
+    by = {(s.name, s.slab): s for s in tree.members if s.name != "host.gc"}
+    # root 9.0 s, minus handles 0.3 and bulk_load 8.5 on its thread
+    assert tree.self_s(tree.root) == pytest.approx(0.2)
+    # register 0.5 s, a 0.25 s collection inside it
+    assert tree.self_s(by[("pipeline.register", None)]) == pytest.approx(0.25)
+    assert tree.self_s(by[("pipeline.io", 0)]) == pytest.approx(0.0)
+    init = tree.before_root("repo.init")
+    assert init is not None and tree.self_s(init) == pytest.approx(0.4)
+    # the facade metric: root self + handles + repo.init before the root
+    facade = sum(tree.self_s(s) for s in tree.named(
+        ("repo.open_many", "frontend.open_many.handles"))) + tree.self_s(init)
+    assert facade == pytest.approx(0.2 + 0.3 + 0.4)
+
+
+def test_handmade_totals_and_chain_wait():
+    tree, _ = _handmade()
+    assert tree.total(("pipeline.io",)) == pytest.approx(2.0)
+    assert tree.total(("pipeline.io",), slab=1) == pytest.approx(1.0)
+    # host.gc: the one inside the open by id, the one inside its extent
+    # by time, not the one after it
+    assert tree.total(("host.gc",)) == pytest.approx(0.5)
+    assert tree.first_dispatch() == pytest.approx(4.0)
+    # slab 0: io 1-2, pack 2.5-3.5, dispatch 3.5-4, fetch 4-5.5:
+    # 4.5 s end to end, 4.0 s busy
+    assert tree.chain_wait(0) == pytest.approx(0.5)
+    # slab 1: io 2-3, pack 5-6, dispatch 6.5-7, fetch 7-8.5
+    assert tree.chain_wait(1) == pytest.approx(2.0 + 0.5)
+
+
+def test_handmade_idle_attribution():
+    tree, busy = _handmade()
+    assert tree.idle_gaps(busy) == [(0.0, 4.0), (5.0, 7.0), (8.0, 9.5)]
+    assert tree.head_idle(busy) == pytest.approx(4.0)
+    table = tree.idle_table(busy)
+    assert sum(r[1] for r in table) == pytest.approx(7.5)
+    got = [(round(at, 2), round(secs, 2), name, slab)
+           for at, secs, name, slab in table]
+    assert got == [
+        (0.0, 0.1, "repo.open_many (self)", None),
+        (0.1, 0.3, "frontend.open_many.handles", None),
+        (0.4, 0.1, "repo.open_many (self)", None),
+        (0.5, 0.1, "pipeline.register", None),
+        (0.6, 0.25, "host.gc", None),  # the innermost span
+        (0.85, 0.15, "pipeline.register", None),
+        # work beats the caller's wait; the lowest slab beats slab 1's io
+        (1.0, 0.25, "storage.feeds.open", 0),
+        (1.25, 0.75, "storage.columns.load", 0),
+        (2.0, 0.5, "pipeline.io", 1),
+        (2.5, 1.0, "pipeline.pack", 0),
+        (3.5, 0.25, "pipeline.dispatch", 0),
+        (3.75, 0.25, "pipeline.enqueue", 0),
+        # work that feeds the device beats slab 0's fetch behind it
+        (5.0, 1.0, "pipeline.pack", 1),
+        (6.0, 0.5, "pipeline.bulk_load", None),
+        (6.5, 0.5, "pipeline.dispatch", 1),
+        (8.0, 0.5, "pipeline.fetch", 1),
+        (8.5, 0.5, "pipeline.bulk_load", None),
+        (9.0, 0.5, "pipeline.barrier", None),
+    ]
+    named = sum(r[1] for r in table if not r[2].startswith("repo.open_many"))
+    assert 100.0 * named / 7.5 == pytest.approx(100.0 * 7.3 / 7.5)
+
+
+def test_ring_file_gives_the_same_tree():
+    """scripts/profile_trace.py --by slab reads a ring (Chrome) file
+    through the same tree."""
+    tree, _ = _handmade()
+    events = [
+        {"ph": "X", "name": s.name, "ts": s.t0 * 1e6,
+         "dur": s.dur * 1e6, "tid": s.line, "args": s.args}
+        for s in tree.all
+    ] + [{"ph": "M", "name": "thread_name", "tid": "c", "args": {}},
+         {"ph": "X", "name": "bench.loader.open_many", "ts": 0, "dur": 5}]
+    again = Tree(span_tree.from_chrome(events))
+    assert [(s.name, s.slab, s.depth) for s in again.members] == [
+        (s.name, s.slab, s.depth) for s in tree.members]
+    assert again.chain_wait(1) == pytest.approx(2.5)
+
+
+# -- kernel seconds by scope ---------------------------------------------
+
+_HLO = """\
+HloModule jit_materialize_full_lean_device, is_scheduled=true
+
+%fused_computation.1 (p0: s32[8]) -> s32[8] {
+  %p0 = s32[8]{0} parameter(0)
+  ROOT %g = s32[8]{0} gather(%p0), metadata={op_name="jit(f)/vmap(rga_order)/gather"}
+}
+
+%fused_computation.2 (p0: s32[8]) -> s32[8] {
+  %p0 = s32[8]{0} parameter(0)
+  %a = s32[8]{0} add(%p0, %p0), metadata={op_name="jit(f)/vmap(lww)/add"}
+  ROOT %b = s32[8]{0} add(%a, %a), metadata={op_name="jit(f)/vmap(elem_values)/add"}
+}
+
+%fused_computation.3 (p0: s32[8]) -> s32[8] {
+  %p0 = s32[8]{0} parameter(0)
+  ROOT %c = s32[8]{0} convert(%p0), metadata={op_name="jit(f)/convert_element_type"}
+}
+
+ENTRY %main.9 (x: s32[8]) -> s32[8] {
+  %x = s32[8]{0} parameter(0)
+  %fusion.1 = s32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/vmap(rga_order)/gather"}
+  %fusion.2 = s32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(f)/vmap(lww)/add"}
+  %fusion.3 = s32[8]{0} fusion(%fusion.2), kind=kLoop, calls=%fused_computation.3
+  %sort.4 = s32[8]{0} sort(%fusion.3), dimensions={0}, to_apply=%cmp, metadata={op_name="jit(f)/wire/sort"}
+  ROOT %copy.5 = s32[8]{0} copy(%sort.4), metadata={op_name="jit(f)/vmap(clock)/a;jit(f)/vmap(wire)/b"}
+}
+"""
+
+
+def test_phases_of_handmade_hlo():
+    got = crdt_kernels.phases_of_hlo(_HLO)
+    assert got == {
+        "%x": "unscoped",
+        "%fusion.1": "rga_order",
+        "%fusion.2": "mixed",  # lww + elem_values fused together
+        "%fusion.3": "unscoped",
+        "%sort.4": "wire",
+        "%copy.5": "mixed",  # one instruction merged from two scopes
+    }
+
+
+def test_scope_seconds_books_a_two_scope_fusion_to_mixed():
+    phases = crdt_kernels.phases_of_hlo(_HLO)
+    modules = [("jit_materialize_full_lean_device(77)", 100.0, 50.0),
+               ("jit_other(3)", 200.0, 10.0),
+               ("jit_materialize_full_lean_device(78)", 300.0, 20.0)]
+    ops = [
+        ("%fusion.1 = s32[8]{0} fusion(s32[8]{0} %x), kind=kLoop", 100.0, 10.0),
+        ("%fusion.2 = s32[8]{0} fusion(s32[8]{0} %fusion.1)", 110.0, 20.0),
+        ("%sort.4 = s32[8]{0} sort(s32[8]{0} %fusion.3)", 130.0, 5.0),
+        ("%unknown.9 = s32[8]{0} add(s32[8]{0} %q)", 135.0, 5.0),
+        ("%fusion.1 = s32[8]{0} fusion(s32[8]{0} %y)", 205.0, 5.0),  # other
+        ("%fusion.1 = s32[8]{0} fusion(s32[8]{0} %x), kind=kLoop", 300.0, 20.0),
+    ]
+    asked = []
+
+    def phase_of_ops(n_docs, n_rows, lean):
+        asked.append((n_docs, n_rows, lean))
+        return phases
+
+    got = trace_scope_time.scope_seconds(
+        modules, ops, [(16, 64), (8, 64)], ["materialize_full"], phase_of_ops)
+    assert asked == [(16, 64, True), (8, 64, True)]  # k-th program, k-th slab
+    assert got == {"rga_order": 30.0, "mixed": 20.0, "wire": 5.0,
+                   "unscoped": 5.0, "uncovered": 10.0}
+    # nothing dropped: the scopes add up to the matched programs' time
+    assert sum(got.values()) == 50.0 + 20.0
+    # a shape the program never dispatched: nothing to read
+    assert trace_scope_time.scope_seconds(
+        modules, ops, [(16, 64)], ["materialize_full"],
+        lambda *a: {}) is None
+
+
+def test_real_program_carries_every_phase(traced_open):
+    """The slab program the traced open dispatched, compiled again for
+    its shape: every phase of PHASES owns some instruction, and the
+    scopes changed nothing a cache key sees (test_summary_wire and the
+    cold-start tests pin the results)."""
+    got = crdt_kernels.phase_of_ops(SLAB, N_OPS, True)
+    assert got, sorted(crdt_kernels._dispatched)
+    assert set(got.values()) >= set(crdt_kernels.PHASES) - {"counters"}
+    assert set(got.values()) <= set(crdt_kernels.PHASES) | {
+        "mixed", "unscoped"}
+    assert crdt_kernels.phase_of_ops(SLAB, N_OPS * 64, True) == {}
+
+
+def test_profile_trace_by_slab(traced_open, tmp_path, capsys):
+    """scripts/profile_trace.py --by slab: the same lanes, by request
+    and slab, from a ring file."""
+    import importlib.util
+
+    spans, _ = traced_open
+    spec = importlib.util.spec_from_file_location(
+        "profile_trace", os.path.join(ROOT, "scripts", "profile_trace.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    events = [
+        {"ph": "X", "name": s.name, "ts": s.t0 * 1e6, "dur": s.dur * 1e6,
+         "tid": s.line, "args": s.args} for s in spans
+    ]
+    assert mod.slab_view(events, [])
+    out = capsys.readouterr().out
+    for k in range(3):
+        assert f"slab {k}: waited" in out
+    assert "pipeline.register" in out and "pipeline.wait, thread-seconds" in out
+    assert not mod.slab_view(
+        [e for e in events if e["name"] != "repo.open_many"], [])
