@@ -57,8 +57,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
     EnvVar("HM_DEVICE_PACK", "0", "Run the fast-path pack as a jitted "
            "device kernel (ops/pack_kernels.py); falls back native -> "
            "numpy, bit-identical."),
-    EnvVar("HM_LOAD_THREADS", "8", "Parallel sidecar prefetch threads "
-           "for bulk document loads."),
     EnvVar("HM_FAST_OPEN", "1", "Serve single-doc opens from the "
            "columnar sidecar when possible (0 = full feed replay)."),
     EnvVar("HM_SUMMARY_MEMO_MB", "256", "Byte-bounded LRU of per-doc "

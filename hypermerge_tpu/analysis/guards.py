@@ -541,7 +541,7 @@ REQUIRES: Dict[Tuple[str, str], str] = {
     ("RepoBackend", "_memoize_summaries"): "repo.bulk",
     ("ResidencyCache", "_note_evicted"): "serve.cache",
     ("FeedColumnCache", "_ensure_loaded"): "store.colcache",
-    ("FeedColumnCache", "_apply_tables"): "store.colcache",
+    ("FeedColumnCache", "_set_loaded"): "store.colcache",
     ("FeedColumnCache", "_intern"): "store.colcache",
     ("FeedColumnCache", "_take_pending"): "store.colcache",
     ("FeedColumnCache", "_total_rows"): "store.colcache",

@@ -70,6 +70,10 @@ class Actor:
         return self.feed.writable
 
     @property
+    def colcache(self) -> FeedColumnCache:
+        return self._colcache
+
+    @property
     def changes(self) -> List[Any]:
         """Slot list sized to the feed's block log, re-checked on EVERY
         read, not just first touch: append_verified fires its listener

@@ -14,11 +14,17 @@ sum(stages), with at most `HM_PIPELINE_DEPTH` (default 2) slabs of
 host staging alive per seam — double buffering, not an unbounded
 backlog.
 
-    io/spec thread:   slab read-ahead (storage/slab.py mmap slices +
-                      colcache decode; file reads drop the GIL) and
-                      per-doc feed specs, emitted as slab-sized entry
-                      groups — composition IDENTICAL to the serial
-                      loader's chunks, so summaries are bit-identical.
+    io/spec thread:   slab read-ahead: feed opens, then the chunk's
+                      column sidecars in one pass over the corpus
+                      slab's mapping (colcache.load_slab_images: views,
+                      no copy; feeds it cannot take load one by one).
+                      All of it is Python under the GIL but the feed
+                      head lookups, so it runs on this one thread: a
+                      pool only took the GIL from the pack worker.
+                      Then per-doc feed specs, emitted as slab-sized
+                      entry groups — composition IDENTICAL to the
+                      serial loader's chunks, so summaries are
+                      bit-identical.
     pack pool:        pack_docs_columns on HM_PACK_WORKERS threads —
                       the native hm_pack_prefix call is bound through
                       ctypes.CDLL and therefore RELEASES the GIL
@@ -485,11 +491,15 @@ class SlabPipeline:
             for i in range(self.fetch_workers)
         ]
         ctx.threads = fetch_ts
-        io_t.start()
+        # consumers first, the producer last: every worker is alive
+        # before the io thread can finish, so no two of one load's
+        # threads ever share an OS thread id (a trace draws one lane
+        # per id; a small load's io stage can be done in milliseconds)
         for t in pack_ts:
             t.start()
         for t in fetch_ts:
             t.start()
+        io_t.start()
         try:
             while True:
                 item = self._get(self.disp_q)

@@ -19,7 +19,7 @@ import math
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..analysis.lockdep import make_lock, make_rlock, maybe_install_racedep
 from .. import msgs
@@ -28,6 +28,7 @@ from ..crdt.change import Change, ChangeRequest
 from ..crdt.opset import OpSet
 from ..storage.colcache import (
     file_column_storage_fn,
+    load_slab_images,
     memory_column_storage_fn,
 )
 from ..storage.feed import (
@@ -56,6 +57,10 @@ from .pipeline import Stage
 # device->host summary-wire transfer bytes (same series sharded.py's
 # collective gather feeds; handle cached — one per-slab bump)
 _M_D2H = telemetry.counter("mesh.d2h_bytes")
+# column sidecars by loader: slab-granular (one pass over cols.slab) or
+# feed by feed (_prefetch_columns)
+_M_COLS_BULK = telemetry.counter("loader.cols_bulk_feeds")
+_M_COLS_SINGLE = telemetry.counter("loader.cols_single_feeds")
 
 
 # actor id -> discovery id is a pure hash of an immutable key: memoize
@@ -757,8 +762,8 @@ class RepoBackend:
 
         Host-side work is batched, not per-doc: one cursor upsert + one
         SELECT for all docs, one feed-registry executemany, one clock
-        executemany, parallel sidecar loads, and per-actor syncs deferred
-        to a single pass at the end. Device dispatches are async — the
+        executemany, sidecar loads a slab at a time, and per-actor syncs
+        deferred to a single pass at the end. Device dispatches are async — the
         materialization barrier is `fetch_bulk_summaries`.
 
         `pad_docs`/`pad_rows` override the slab's jit bucket (benchmarks
@@ -855,6 +860,11 @@ class RepoBackend:
                 "host_slabs": 0,
                 "platform": None,
                 "pack_workers": 0,  # serial twin: pack inline, no pool
+                # column sidecars loaded slab-granular / feed by feed
+                # (_prefetch_columns); the share lands after the load
+                "cols_bulk_feeds": 0,
+                "cols_single_feeds": 0,
+                "cols_bulk_pct": 0.0,
                 "t_sql": round(register.dur, 3),
                 "t_io": 0.0,
                 "t_spec": 0.0,
@@ -881,6 +891,11 @@ class RepoBackend:
                 pad_docs, pad_rows,
             )
             stats = self.last_bulk_stats
+            cols = stats["cols_bulk_feeds"] + stats["cols_single_feeds"]
+            if cols:
+                stats["cols_bulk_pct"] = round(
+                    100.0 * stats["cols_bulk_feeds"] / cols, 3
+                )
             stats["memo"] = len(memo_hits)
             stats["fallback"] = len(fallback_docs)
             stats["fast"] = len(new_docs) - len(fallback_docs)
@@ -970,8 +985,15 @@ class RepoBackend:
             actors = [self._get_or_create_actor(a) for a in needed]
         with telemetry.span(
             "storage.columns.load", "storage", feeds=len(actors)
-        ):
-            self._prefetch_columns(actors)
+        ) as sp:
+            bulk, single = self._prefetch_columns(actors)
+            sp.note(bulk=bulk)
+        _M_COLS_BULK.add(bulk)
+        _M_COLS_SINGLE.add(single)
+        with self._stats_lock:
+            stats = self.last_bulk_stats
+            stats["cols_bulk_feeds"] += bulk
+            stats["cols_single_feeds"] += single
 
     def _collect_cursor_actors(self, docs, cursor_map) -> List[str]:
         needed: List[str] = []
@@ -1236,25 +1258,49 @@ class RepoBackend:
             if actor is not None:
                 self._sync_changes(actor)
 
-    def _prefetch_columns(self, actors: List[Actor]) -> None:
-        """Load every actor's columnar sidecar in parallel — the bulk of
-        cold-start IO; file reads drop the GIL so threads overlap it."""
-        from concurrent.futures import ThreadPoolExecutor
-
-        if self._col_slab is not None:
-            # hint the corpus slab's extents into the page cache first:
-            # the decode loop below then slices warm pages (and, under
-            # the pipeline, the NEXT chunk's hint overlaps this chunk's
+    def _prefetch_columns(self, actors: List[Actor]) -> Tuple[int, int]:
+        """Load the column sidecars of a chunk's actors. Feeds whose
+        sidecar is one complete v3 image in the corpus slab, level with
+        the feed head, load slab-granular: one pass over the chunk's
+        extents (colcache.load_slab_images; views of the mapping, no
+        copy, no per-feed parse). Every other feed (a v2 tail, a legacy
+        or memory sidecar, HM_SLAB=0, a sidecar ahead of or behind its
+        feed) loads through Actor.columns(), feed by feed, on this
+        thread: both are mmap slices and Python, which no thread pool
+        speeds up (it only took the GIL from the pack worker). Returns
+        (feeds loaded slab-granular, feeds loaded one by one); feeds
+        whose cache was loaded already count in neither."""
+        cold = [a for a in actors if not a.colcache.loaded]
+        bulk: set = set()
+        slab = self._col_slab
+        if slab is not None:
+            # hint the chunk's extents into the page cache first (under
+            # the pipeline the NEXT chunk's hint overlaps this chunk's
             # pack)
-            self._col_slab.prefetch([a.id for a in actors])
-        big = [a for a in actors if a.feed.colcache is not None]
-        if len(big) < 2:
-            for a in actors:
-                a.columns()
-            return
-        workers = min(16, int(os.environ.get("HM_LOAD_THREADS", "8")))
-        with ThreadPoolExecutor(max_workers=workers) as pool:
-            list(pool.map(lambda a: a.columns(), actors))
+            slab.prefetch([a.id for a in actors])
+            cands = [a for a in cold if a.colcache.slab is slab]
+            if cands:
+                # the staleness rule, batched: each feed's head (file
+                # metadata lookups, the one part that leaves the GIL)
+                with telemetry.span(
+                    "storage.columns.heads", "storage", feeds=len(cands)
+                ):
+                    heads = [a.seq_head for a in cands]
+                with telemetry.span(
+                    "storage.columns.bulk", "storage", feeds=len(cands)
+                ):
+                    done = load_slab_images(
+                        slab, [a.colcache for a in cands], heads
+                    )
+                bulk = {a.id for a, d in zip(cands, done) if d}
+        rest = [a for a in actors if a.id not in bulk]
+        if rest:
+            with telemetry.span(
+                "storage.columns.single", "storage", feeds=len(rest)
+            ):
+                for a in rest:
+                    a.columns()  # loads, or catches a loaded one up
+        return len(bulk), len(cold) - len(bulk)
 
     def _mesh(self):
         """The device mesh the bulk loader shards over, when >1 device is

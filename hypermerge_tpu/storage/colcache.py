@@ -425,6 +425,11 @@ _V2_HDR = struct.Struct("<IIIB")
 _V3_MAGIC = b"HMc3"
 _V3_HDR = struct.Struct("<IIII")  # n_rows, n_changes, n_preds, tables_len
 _V3_DTYPES = (np.int8, np.int16, np.int32, np.uint8)
+_V3_ITEMSIZE = np.asarray(
+    [np.dtype(d).itemsize for d in _V3_DTYPES], np.int64
+)
+_NO_ROWS = np.zeros((0, ROW_FIELDS), np.int32)
+_NO_ROWS.flags.writeable = False
 
 
 def _narrow_plane(col: np.ndarray) -> np.ndarray:
@@ -569,6 +574,17 @@ def parse_v3_checkpoint(raw: bytes):
     return planes, preds, row_ends, flags, tables, pos, plane_meta
 
 
+def _v3_commits(row_ends, flags, n_tail: int) -> np.ndarray:
+    """Commit rows [row_end, 0, 0, flag] of a checkpoint's changes
+    (only columns 0 and 3 feed FeedColumns), with `n_tail` zero rows
+    after them for the v2 records that follow the checkpoint."""
+    n = len(row_ends)
+    commits = np.zeros((n + n_tail, COMMIT_FIELDS), np.int32)
+    commits[:n, 0] = row_ends
+    commits[:n, 3] = flags
+    return commits
+
+
 def pack_v2_record(
     rows: np.ndarray, preds: np.ndarray, table_lines: List[str], flag: int
 ) -> bytes:
@@ -653,11 +669,7 @@ class FileColumnStorageV2:
         planes, preds_ck, row_ends, flags, tables_ck, off, meta = ck
         t_rows, t_preds, t_tables, t_commits = self._load_v2(raw, off)
         n_base_rows = int(row_ends[-1]) if len(row_ends) else 0
-        commits = np.zeros(
-            (len(row_ends) + len(t_commits), COMMIT_FIELDS), np.int32
-        )
-        commits[: len(row_ends), 0] = row_ends
-        commits[: len(row_ends), 3] = flags
+        commits = _v3_commits(row_ends, flags, len(t_commits))
         if len(t_commits):
             commits[len(row_ends) :] = t_commits
             commits[len(row_ends) :, 0] += n_base_rows
@@ -899,20 +911,60 @@ def file_column_storage_fn(root: str):
 
 
 class _Interner:
+    """Ordered set of table items. `share()` hands out twins over the
+    SAME list and dict (feeds of one bulk load that carry identical
+    tables, load_slab_images); a twin copies them before its first
+    write, so a shared list is never mutated by anyone."""
+
+    __slots__ = ("items", "_index", "_shared")
+
     def __init__(self) -> None:
         self.items: List[Any] = []
         self._index: Dict[Any, int] = {}
+        self._shared = False
+
+    def share(self) -> "_Interner":
+        twin = _Interner.__new__(_Interner)
+        twin.items = self.items
+        twin._index = self._index
+        twin._shared = self._shared = True
+        return twin
 
     def add(self, item: Any) -> int:
         idx = self._index.get(item)
         if idx is None:
+            if self._shared:
+                self.items = list(self.items)
+                self._index = dict(self._index)
+                self._shared = False
             idx = len(self.items)
             self.items.append(item)
             self._index[item] = idx
         return idx
 
+    def snapshot(self) -> List[Any]:
+        """The items as a list nobody will mutate (a FeedColumns
+        table): the shared list itself, else a copy."""
+        return self.items if self._shared else list(self.items)
+
     def __contains__(self, item: Any) -> bool:
         return item in self._index
+
+
+_TABLE_KINDS = "aksfb"  # actors, keys, strings, floats, bigints
+
+
+def _parse_tables(lines: List[str]) -> Dict[str, _Interner]:
+    """Interner per table kind from the sidecar's JSONL table lines."""
+    kinds = {t: _Interner() for t in _TABLE_KINDS}
+    if lines:
+        # one C-level parse for the whole file beats a json.loads per line
+        # (bulk cold opens read tens of thousands of these)
+        for rec in json.loads("[" + ",".join(lines) + "]"):
+            t = rec["t"]
+            v = rec["v"]
+            kinds[t].add(int(v) if t == "b" else v)
+    return kinds
 
 
 class FeedColumnCache:
@@ -931,40 +983,61 @@ class FeedColumnCache:
         self._lock = make_rlock("store.colcache")
         self.writer = writer
         self._loaded = False  # storage read is deferred: a bulk cold
-        # start creates thousands of caches serially but loads them in
-        # parallel (RepoBackend._prefetch_columns)
+        # start creates thousands of caches and loads them a chunk at a
+        # time (RepoBackend._prefetch_columns, load_slab_images)
+
+    @property
+    def loaded(self) -> bool:
+        with self._lock:
+            return self._loaded
+
+    @property
+    def slab(self):
+        """The corpus slab that serves this sidecar, else None."""
+        return getattr(self._storage, "_slab", None)
 
     def _ensure_loaded(self) -> None:
         if self._loaded:
             return
-        self._loaded = True
-        self._actors = _Interner()
-        self._keys = _Interner()
-        self._strings = _Interner()
-        self._floats = _Interner()
-        self._bigints = _Interner()
-        self._pending_tables = []
-        self._base_planes: Optional[Dict[str, np.ndarray]] = None
-        self._base_meta = None
+        planes = meta = None
         n_tail = 0
         lv3 = getattr(self._storage, "load_v3", None)
         if lv3 is not None:
-            (
-                self._base_planes, rows, preds, tables, commits, n_tail,
-                self._base_meta,
-            ) = lv3()
+            planes, rows, preds, tables, commits, n_tail, meta = lv3()
         else:
             rows, preds, tables, commits = self._storage.load()
-        self._apply_tables(tables)
+        self._set_loaded(
+            _parse_tables(tables), planes, meta, rows, preds, commits
+        )
+        # long v2 tails re-pay the per-record parse on every cold load:
+        # fold them into the checkpoint now (atomic rewrite)
+        if n_tail >= int(os.environ.get("HM_CKPT_TAIL", "64")):
+            try:
+                self.compact()
+            except OSError:  # read-only media: served from memory fine
+                pass
+
+    def _set_loaded(
+        self, kinds: Dict[str, _Interner], planes, meta, rows, preds,
+        commits,
+    ) -> None:
+        """Become the loaded cache of what storage held: the one place
+        both loaders end in (per feed above, slab-granular in
+        load_slab_images)."""
+        self._loaded = True
+        self._actors = kinds["a"]
+        self._keys = kinds["k"]
+        self._strings = kinds["s"]
+        self._floats = kinds["f"]
+        self._bigints = kinds["b"]
+        self._pending_tables = []
         if self.writer not in self._actors:
             # fresh cache: actor 0 is the writer (the table line flushes
             # with the first commit)
             self._intern("a", self._actors, self.writer)
-        self._base_rows = (
-            len(self._base_planes["action"])
-            if self._base_planes is not None
-            else 0
-        )
+        self._base_planes: Optional[Dict[str, np.ndarray]] = planes
+        self._base_meta = meta
+        self._base_rows = len(planes["action"]) if planes is not None else 0
         self._row_chunks: List[np.ndarray] = [rows] if len(rows) else []
         self._pred_chunks: List[np.ndarray] = [preds] if len(preds) else []
         self._n_rows_total = self._base_rows + len(rows)
@@ -974,32 +1047,27 @@ class FeedColumnCache:
         ).reshape(-1, COMMIT_FIELDS)
         self._commits_new: List[Tuple[int, int, int, int]] = []
         self._cached: Optional[FeedColumns] = None
-        # long v2 tails re-pay the per-record parse on every cold load:
-        # fold them into the checkpoint now (atomic rewrite)
-        if n_tail >= int(os.environ.get("HM_CKPT_TAIL", "64")):
-            try:
-                self.compact()
-            except OSError:  # read-only media: served from memory fine
-                pass
+
+    def install_image(
+        self, kinds, planes, meta, preds, row_ends, flags
+    ) -> bool:
+        """The slab-granular loader's hand-over (load_slab_images): one
+        complete v3 image, parsed outside, becomes this cache's loaded
+        state and its FeedColumns is built, exactly as `_ensure_loaded`
+        + `columns()` would leave them. False, and nothing touched,
+        when the cache loaded itself meanwhile (an append raced the
+        bulk load)."""
+        with self._lock:
+            if self._loaded:
+                return False
+            self._set_loaded(
+                kinds, planes, meta, _NO_ROWS, preds,
+                _v3_commits(row_ends, flags, 0),
+            )
+            self.columns()
+            return True
 
     # -- table interning ----------------------------------------------
-
-    def _apply_tables(self, lines: List[str]) -> None:
-        if not lines:
-            return
-        kinds = {
-            "a": self._actors,
-            "k": self._keys,
-            "s": self._strings,
-            "f": self._floats,
-            "b": self._bigints,
-        }
-        # one C-level parse for the whole file beats a json.loads per line
-        # (bulk cold opens read tens of thousands of these)
-        for rec in json.loads("[" + ",".join(lines) + "]"):
-            t = rec["t"]
-            v = rec["v"]
-            kinds[t].add(int(v) if t == "b" else v)
 
     def _intern(self, kind: str, interner: _Interner, v: Any) -> int:
         if v in interner:
@@ -1232,11 +1300,11 @@ class FeedColumnCache:
             self._cached = FeedColumns(
                 rows=rows,
                 preds=preds,
-                actors=list(self._actors.items),
-                keys=list(self._keys.items),
-                strings=list(self._strings.items),
-                floats=list(self._floats.items),
-                bigints=list(self._bigints.items),
+                actors=self._actors.snapshot(),
+                keys=self._keys.snapshot(),
+                strings=self._strings.snapshot(),
+                floats=self._floats.snapshot(),
+                bigints=self._bigints.snapshot(),
                 n_changes=n,
                 ok_prefix_len=ok_prefix,
                 row_ends=row_ends,
@@ -1293,3 +1361,127 @@ class FeedColumnCache:
 
     def close(self) -> None:
         self._storage.close()
+
+
+# ---------------------------------------------------------------------------
+# the slab-granular loader
+
+_ACTOR_LINE = b'{"t":"a","v":"'
+
+
+def _image_tables(blob: bytes, memo: Dict[bytes, Dict[str, _Interner]]):
+    """Interners of one image's tables blob. The lines that name the
+    feed's own actors lead the blob and differ feed by feed; what
+    follows them (keys, strings, floats, bigints) is parsed once per
+    DISTINCT byte string of a load (`memo`) and shared among the feeds
+    that carry it, copied on the first write. Feeds that share nothing
+    just parse everything: the split only moves work, each kind's items
+    keep their order either way."""
+    cut = 0
+    while blob.startswith(_ACTOR_LINE, cut):
+        nl = blob.find(b"\n", cut)
+        if nl < 0:
+            break
+        cut = nl + 1
+    kinds = _parse_tables(blob[:cut].decode("utf-8").splitlines())
+    rest = blob[cut:]
+    proto = memo.get(rest)
+    if proto is None:
+        proto = memo[rest] = _parse_tables(
+            rest.decode("utf-8").splitlines()
+        )
+    for actor in proto["a"].items:
+        kinds["a"].add(actor)
+    for t in _TABLE_KINDS[1:]:
+        kinds[t] = proto[t].share()
+    return kinds
+
+
+def load_slab_images(slab, caches, heads) -> List[bool]:
+    """The column sidecars of a whole doc chunk in ONE pass over the
+    corpus slab: for every cache of `caches` (slab-backed, see
+    SlabColumnStorage) whose sidecar is exactly one complete v3 image
+    segment holding `heads[i]` changes, become loaded (install_image)
+    without a bytes copy or a per-feed parse. The extents come from the
+    slab under its lock once; headers and plane dtype codes of all
+    images are read from the mapping with vectorised numpy into
+    [F, planes] offset / dtype tables; planes, row_ends, flags and
+    preds are views of the mapping, which they keep alive
+    (CorpusSlab._drop_mapping).
+
+    Returns, per cache, whether it was installed. The rest — a v2 tail
+    (record segments, or records inside the image), a torn or short
+    image, a foreign block, a sidecar ahead of or behind its feed head,
+    tables that do not parse, a cache that loaded meanwhile — is left
+    untouched for the per-feed loader, which heals or raises as it
+    always did."""
+    done = [False] * len(caches)
+    whole, offs, lens = slab.image_extents(
+        [cc._storage._name for cc in caches]
+    )
+    if whole is None:
+        return done
+    fixed = len(_V3_MAGIC) + _V3_HDR.size
+    lens = np.asarray(lens, np.int64)
+    cand = np.nonzero(lens >= fixed)[0]
+    if not len(cand):
+        return done
+    at = np.asarray(offs, np.int64)[cand]  # image starts in the mapping
+    ln = lens[cand]
+    head = whole[at[:, None] + np.arange(fixed)]
+    ok = (head[:, :4] == np.frombuffer(_V3_MAGIC, np.uint8)).all(axis=1)
+    n_rows, n_changes, n_preds, t_len = (
+        np.ascontiguousarray(head[:, 4:]).view("<u4").astype(np.int64).T
+    )
+    n_planes = len(PLANE_NAMES)
+    plane_offs = np.empty((len(cand), n_planes), np.int64)
+    plane_dts = np.empty((len(cand), n_planes), np.uint8)
+    pos = np.full(len(cand), fixed, np.int64)
+    for pi in range(n_planes):
+        ok &= pos < ln  # the dtype code lies inside the image
+        code = whole[at + np.where(ok, pos, 0)]
+        ok &= code < len(_V3_DTYPES)
+        plane_dts[:, pi] = code
+        plane_offs[:, pi] = pos + 1
+        # (a bad code is masked into range: its feed is not ok anyway)
+        pos = pos + 1 + n_rows * _V3_ITEMSIZE[code & 3]
+    # what follows the planes (layout: pack_v3_checkpoint), as absolute
+    # positions in the mapping: row_ends, flags, preds, tables, the end
+    marks = np.empty((len(cand), 5), np.int64)
+    marks[:, 0] = at + pos
+    marks[:, 1] = marks[:, 0] + 8 * n_changes
+    marks[:, 2] = marks[:, 1] + n_changes
+    marks[:, 3] = marks[:, 2] + 4 * PRED_FIELDS * n_preds
+    marks[:, 4] = marks[:, 3] + t_len
+    # complete, and nothing after it: every plane and table lies inside
+    # the image (all lengths are >= 0) and no v2 record trails it
+    ok &= marks[:, 4] == at + ln
+    ok &= n_changes == np.asarray(heads, np.int64)[cand]
+
+    base_addr = whole.__array_interface__["data"][0]
+    dtypes = [np.dtype(d) for d in _V3_DTYPES]
+    memo: Dict[bytes, Dict[str, _Interner]] = {}
+    good = np.nonzero(ok)[0]
+    for j, i, a, nr, (ea, fa, pa, ta, end), offs_j, dts_j in zip(
+        good.tolist(), cand[good].tolist(), at[good].tolist(),
+        n_rows[good].tolist(), marks[good].tolist(),
+        plane_offs[good].tolist(), plane_dts[good].tolist(),
+    ):
+        try:
+            kinds = _image_tables(whole[ta:end].tobytes(), memo)
+        except (ValueError, KeyError, TypeError):
+            continue  # the per-feed loader raises it where it always did
+        planes = {}
+        for name, off, code in zip(PLANE_NAMES, offs_j, dts_j):
+            dt = dtypes[code]
+            lo = a + off
+            planes[name] = whole[lo : lo + nr * dt.itemsize].view(dt)
+        done[i] = caches[i].install_image(
+            kinds,
+            planes,
+            (base_addr + a, plane_offs[j], plane_dts[j], whole),
+            whole[pa:ta].view(np.int32).reshape(-1, PRED_FIELDS),
+            whole[ea:fa].view(np.int64),
+            whole[fa:pa],
+        )
+    return done

@@ -161,12 +161,17 @@ class FileFeedStorage:
         """Trust the .len sidecar iff its end offset equals the log's
         actual size."""
         try:
-            with open(self._len_path(), "rb") as fh:
-                raw = fh.read(self._LEN.size)
+            # raw descriptors: a bulk open asks this of every feed, and
+            # a buffered file object adds an fstat and an ioctl to each
+            fd = os.open(self._len_path(), os.O_RDONLY)
+            try:
+                raw = os.read(fd, self._LEN.size)
+            finally:
+                os.close(fd)
             if len(raw) != self._LEN.size:
                 return False
             count, end = self._LEN.unpack(raw)
-            if os.path.getsize(self.path) != end:
+            if os.stat(self.path).st_size != end:
                 return False  # torn append or external edit: rescan
             self._count = count
             self._end = end

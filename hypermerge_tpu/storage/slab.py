@@ -46,6 +46,8 @@ import struct
 import threading
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from ..analysis.lockdep import make_rlock
 from ..utils.debug import log
 from .faults import io_fsync, io_open, io_remove, io_replace
@@ -80,7 +82,10 @@ class CorpusSlab:
         self._live_bytes = 0  # header+payload bytes of live segments
         self._fh: Optional[io.BufferedRandom] = None
         self._mm: Optional[mmap.mmap] = None
-        self._mm_size = 0
+        # the mapping as one uint8 array: every zero-copy view a bulk
+        # column load hands out (image_extents) is a slice of it and
+        # keeps the mapping alive through it
+        self._mm_bytes: Optional[np.ndarray] = None
         self._idx_fh = None
         self._closed = False
         # crash-recovery accounting from the last _ensure_loaded: how
@@ -250,8 +255,17 @@ class CorpusSlab:
             return None
         with open(self.path, "rb") as fh:
             self._mm = mmap.mmap(fh.fileno(), 0, access=mmap.ACCESS_READ)
-        self._mm_size = size
         return self._mm
+
+    def _drop_mapping(self) -> None:
+        """Forget the mapping (caller holds the lock): the next read
+        remaps. Dropped, never closed: column views of a bulk load
+        (image_extents) may still point into it, and closing would turn
+        the plane pointers the native pack derives from them into reads
+        of unmapped memory. It unmaps with its last view — at once when
+        there is none."""
+        self._mm = None
+        self._mm_bytes = None
 
     def prefetch(self, names) -> None:
         """Read-ahead hint for the streaming pipeline's io stage: ask
@@ -296,6 +310,31 @@ class CorpusSlab:
                 return mm[off : off + ln]
             return b"".join(mm[off : off + ln] for _k, off, ln in segs)
 
+    def image_extents(self, names):
+        """(mapping, offsets, lengths) for a bulk column load
+        (colcache.load_slab_images), under the lock ONCE for the whole
+        chunk: `mapping` is the slab's mmap as a read-only uint8 array
+        and feed i's sidecar is mapping[offsets[i]:][:lengths[i]] when
+        it is exactly one live image segment; any other feed (record
+        segments after its image, tombstoned, unknown) gets length -1
+        and loads feed by feed through image_bytes. No bytes are
+        copied. (None, ...) when there is no slab file yet."""
+        offs = [0] * len(names)
+        lens = [-1] * len(names)
+        with self._lock:
+            self._ensure_loaded()
+            mm = self._mapped()
+            if mm is None:
+                return None, offs, lens
+            if self._mm_bytes is None:
+                self._mm_bytes = np.frombuffer(mm, np.uint8)
+            feeds = self._feeds
+            for i, name in enumerate(names):
+                segs = feeds.get(name)
+                if segs and len(segs) == 1 and segs[0][0] == KIND_IMAGE:
+                    _k, offs[i], lens[i] = segs[0]
+            return self._mm_bytes, offs, lens
+
     # -- writes ---------------------------------------------------------
 
     def _writable(self):
@@ -333,10 +372,7 @@ class CorpusSlab:
                 raise
             off = self._end + len(head)
             self._apply(kind, name, off, len(payload))
-            if self._mm is not None:
-                self._mm.close()  # stale mapping: remap on next read
-                self._mm = None
-                self._mm_size = 0
+            self._drop_mapping()  # stale: remap on next read
             self._append_idx(kind, name, off, len(payload))
 
     def _append_idx(self, kind, name, off, ln) -> None:
@@ -427,10 +463,7 @@ class CorpusSlab:
             return True
 
     def _close_files(self) -> None:
-        if self._mm is not None:
-            self._mm.close()
-            self._mm = None
-            self._mm_size = 0
+        self._drop_mapping()
         if self._fh is not None:
             self._fh.close()
             self._fh = None

@@ -519,3 +519,43 @@ def test_bulk_stats_say_which_kernel_ran(tmp_path, monkeypatch, mode):
     repo.close()
     assert (st["device_slabs"], st["host_slabs"]) == (0, 3)
     assert st["platform"] is None  # no slab reached a device
+
+
+@pytest.mark.parametrize("mode", ["0", "1"], ids=["serial", "pipelined"])
+def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, mode):
+    """last_bulk_stats counts, in both twins, the column sidecars that
+    loaded slab-granular (one v3 image a feed, colcache.load_slab_images)
+    and those that loaded feed by feed: a checkpointed corpus is all
+    bulk, a store the live path wrote (v2 records only) all single, and
+    a re-open of docs whose caches are loaded counts neither."""
+    from hypermerge_tpu.ops.corpus import make_corpus
+
+    monkeypatch.setenv("HM_PIPELINE", mode)
+
+    def cols(st):
+        return (
+            st["cols_bulk_feeds"], st["cols_single_feeds"],
+            st["cols_bulk_pct"],
+        )
+
+    urls = make_corpus(str(tmp_path / "ckpt"), 10, 64)
+    ids = [validate_doc_url(u) for u in urls]
+    repo = Repo(path=str(tmp_path / "ckpt"))
+    repo.back.load_documents_bulk(ids, slab=4)
+    repo.back.fetch_bulk_summaries()
+    st = dict(repo.back.last_bulk_stats)
+    assert st["pipeline"] == int(mode)
+    assert cols(st) == (10, 0, 100.0)
+    for d in ids[:3]:
+        repo.back.docs.pop(d)  # forget three docs; their actors stay
+    repo.back.load_documents_bulk(ids, slab=4)
+    assert cols(repo.back.last_bulk_stats) == (0, 0, 0.0)
+    repo.close()
+
+    urls, _want = _make_corpus(tmp_path / "live", n_docs=6)
+    ids = [validate_doc_url(u) for u in urls]
+    repo = Repo(path=str(tmp_path / "live"))
+    repo.back.load_documents_bulk(ids, slab=4)
+    repo.back.fetch_bulk_summaries()
+    assert cols(repo.back.last_bulk_stats) == (0, 6, 0.0)
+    repo.close()

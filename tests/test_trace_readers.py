@@ -187,6 +187,33 @@ def test_span_metrics_read_from_the_tree(traced_open, monkeypatch):
             assert value is not None and value >= 0.0, name
 
 
+def test_cols_bulk_pct_reads_through_bulk_stats(traced_open):
+    """`loader.cols_bulk_pct` (ISSUE 25) is data only: its metric file
+    names the `bulk_stats` reader, which takes the median of the opens'
+    `last_bulk_stats["cols_bulk_pct"]` from a recorded `obs`, and finds
+    nothing (None, no raise) in the stats of a program that lacks it."""
+    import json
+
+    from benchmark.readers import bulk_stats
+
+    _spans, stats = traced_open
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "loader.cols_bulk_pct.json")) as fh:
+        spec = json.load(fh)
+    assert spec["reader"] == "bulk_stats"
+    assert (stats["cols_bulk_feeds"], stats["cols_single_feeds"]) == (
+        N_DOCS, 0)
+    obs = {"bulk_stats": [stats, dict(stats, cols_bulk_pct=50.0), stats]}
+    assert bulk_stats.read(spec["params"], obs) == 100.0
+    older = {k: v for k, v in stats.items() if not k.startswith("cols_")}
+    assert bulk_stats.read(spec["params"], {"bulk_stats": [older]}) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == spec["name"]]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == spec[key], key
+
+
 def test_a_trace_without_program_spans_reads_none(monkeypatch):
     """Laid over the program before PR 24 the readers find nothing and
     do not raise."""
