@@ -865,6 +865,14 @@ class RepoBackend:
                 "cols_bulk_feeds": 0,
                 "cols_single_feeds": 0,
                 "cols_bulk_pct": 0.0,
+                # feeds the open read; docs whose slab took the general
+                # (multi-writer) pack and their share; the widest actor
+                # and pred buckets among the slabs' programs
+                "feeds": 0,
+                "pack_general_docs": 0,
+                "pack_general_pct": 0.0,
+                "a_loc_max": 0,
+                "pred_max": 0,
                 "t_sql": round(register.dur, 3),
                 "t_io": 0.0,
                 "t_spec": 0.0,
@@ -895,6 +903,10 @@ class RepoBackend:
             if cols:
                 stats["cols_bulk_pct"] = round(
                     100.0 * stats["cols_bulk_feeds"] / cols, 3
+                )
+            if new_docs:
+                stats["pack_general_pct"] = round(
+                    100.0 * stats["pack_general_docs"] / len(new_docs), 3
                 )
             stats["memo"] = len(memo_hits)
             stats["fallback"] = len(fallback_docs)
@@ -992,6 +1004,7 @@ class RepoBackend:
         _M_COLS_SINGLE.add(single)
         with self._stats_lock:
             stats = self.last_bulk_stats
+            stats["feeds"] += len(needed)
             stats["cols_bulk_feeds"] += bulk
             stats["cols_single_feeds"] += single
 
@@ -1386,7 +1399,7 @@ class RepoBackend:
         self, chunk, batch, DecodedBatch, decode_patch,
         ready_ids, clock_rows,
     ):
-        from ..ops.crdt_kernels import run_batch_full
+        from ..ops.crdt_kernels import actor_bucket, run_batch_full
         from ..ops.host_kernel import run_batch_host
 
         # small loads aren't worth a device dispatch (let alone a fresh
@@ -1400,6 +1413,12 @@ class RepoBackend:
         slab_clocks = [e[2] for e in chunk] + [{}] * (
             batch.n_docs - len(chunk)
         )
+        a_loc = actor_bucket(batch)
+        with self._stats_lock:
+            if batch.packed_by == "general":
+                stats["pack_general_docs"] += len(chunk)
+            stats["a_loc_max"] = max(stats["a_loc_max"], a_loc)
+            stats["pred_max"] = max(stats["pred_max"], batch.psrc.shape[1])
         lean = False
         if batch.n_docs * batch.n_rows < min_cells:
             with telemetry.timed("pipeline.enqueue", "pipeline", host=1):

@@ -39,6 +39,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..crdt.change import HEAD, ROOT, Action, Change, OpId
 
 PAD = int(Action.PAD)
@@ -109,6 +110,8 @@ class ColumnarBatch:
     op_actor_ids: List[List[str]] = field(default_factory=list)
     doc_actors: Optional[np.ndarray] = None  # [D, A_loc] int32, -1 pad
     slot: Optional[np.ndarray] = None  # [D, N] int16 local actor slots
+    # which pack_docs_columns path made the batch: "prefix" | "general"
+    packed_by: str = ""
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -551,9 +554,22 @@ def _native_pack_prefix(
     }
 
 
-def _try_pack_prefix_single(
+def _prefix_single_slab(doc_specs) -> bool:
+    """The gate of the prefix path, per slab and all or nothing: every
+    doc is one single-writer feed read from its start (one doc of two
+    writers sends its whole slab through the general path)."""
+    for spec in doc_specs:
+        if len(spec) != 1:
+            return False
+        fc, s, _e = spec[0]
+        if s != 0 or not _prefix_single_ok(fc):
+            return False
+    return True
+
+
+def _pack_prefix_single(
     doc_specs, n_rows, n_pred, n_docs, device=None
-) -> Optional[ColumnarBatch]:
+) -> ColumnarBatch:
     """Fast pack for the dominant cold-open shape: one single-writer feed
     per doc, whole-prefix windows. Rows are already in causal order (ctr
     ascending) and every reference resolves within the prefix (causal
@@ -571,13 +587,6 @@ def _try_pack_prefix_single(
     fallback when the native layer is absent, HM_NATIVE_PACK=0, or a
     feed is not plane-backed). `device` is the mesh scheduler's
     placement hint for the device twin; host twins ignore it."""
-    for spec in doc_specs:
-        if len(spec) != 1:
-            return None
-        fc, s, _e = spec[0]
-        if s != 0 or not _prefix_single_ok(fc):
-            return None
-
     D = len(doc_specs)
     Dp = max(n_docs, D) if n_docs is not None else D
 
@@ -847,6 +856,18 @@ def _try_pack_prefix_single(
     return batch
 
 
+# docs packed by each path, over the process's life (tools/top.py)
+_M_PACK_PREFIX = telemetry.counter("pipeline.pack_prefix_docs")
+_M_PACK_GENERAL = telemetry.counter("pipeline.pack_general_docs")
+
+
+def _stage(name: str):
+    """A stage of the general pack: a span below `pipeline.pack.general`
+    (the stages follow one another and never nest, so their seconds add
+    up to the general span's)."""
+    return telemetry.span("pipeline.pack." + name, "pipeline")
+
+
 def pack_docs_columns(
     doc_specs: Sequence[Sequence[Tuple[Any, int, float]]],
     n_rows: Optional[int] = None,
@@ -873,11 +894,32 @@ def pack_docs_columns(
     mesh scheduler will dispatch this slab to. Host packs — and the
     general path, which never runs on device — ignore it.
     """
-    fast = _try_pack_prefix_single(
-        doc_specs, n_rows, n_pred, n_docs, device
-    )
-    if fast is not None:
-        return fast
+    n = len(doc_specs)
+    if _prefix_single_slab(doc_specs):
+        _M_PACK_PREFIX.add(n)
+        with telemetry.span("pipeline.pack.prefix", "pipeline", docs=n):
+            batch = _pack_prefix_single(
+                doc_specs, n_rows, n_pred, n_docs, device
+            )
+        batch.packed_by = "prefix"
+        return batch
+    _M_PACK_GENERAL.add(n)
+    with telemetry.span("pipeline.pack.general", "pipeline", docs=n) as sp:
+        batch = _pack_general(doc_specs, n_rows, n_pred, n_docs, sp)
+    batch.packed_by = "general"
+    return batch
+
+
+def _pack_general(
+    doc_specs, n_rows, n_pred, n_docs, span
+) -> ColumnarBatch:
+    """The general pack: any number of feeds a doc, any windows. Rows
+    are gathered feed by feed, references and pred targets resolved by
+    a composite (doc, counter, actor) key over one M-sized argsort, ops
+    whose container or referenced element is outside the window dropped
+    to a fixpoint, and the rows laid out in causal order by a second
+    argsort. `span` (pipeline.pack.general) takes the slab's feeds and
+    rows as tags once they are known."""
     from ..storage.colcache import (
         OBJ_ROOT,
         REF_HEAD,
@@ -891,175 +933,179 @@ def pack_docs_columns(
     Dp = max(n_docs, D) if n_docs is not None else D
 
     # -- global tables + per-feed LUTs ---------------------------------
-    fcs: List[Any] = []
-    fc_of: Dict[int, int] = {}
-    for spec in doc_specs:
-        for fc, _s, _e in spec:
-            if id(fc) not in fc_of:
-                fc_of[id(fc)] = len(fcs)
-                fcs.append(fc)
+    with _stage("tables"):
+        fcs: List[Any] = []
+        fc_of: Dict[int, int] = {}
+        for spec in doc_specs:
+            for fc, _s, _e in spec:
+                if id(fc) not in fc_of:
+                    fc_of[id(fc)] = len(fcs)
+                    fcs.append(fc)
 
-    actor_int = _Interner()
-    key_int = _Interner()
-    str_int = _Interner()
-    float_int = _Interner()
-    big_int = _Interner()
-    luts = {"a": [], "k": [], "s": [], "f": [], "b": []}
-    for fc in fcs:
-        luts["a"].append(
-            np.asarray([actor_int(x) for x in fc.actors], np.int64)
-        )
-        luts["k"].append(
-            np.asarray([key_int(x) for x in fc.keys], np.int64)
-        )
-        luts["s"].append(
-            np.asarray([str_int(x) for x in fc.strings], np.int64)
-        )
-        luts["f"].append(
-            np.asarray([float_int(x) for x in fc.floats], np.int64)
-        )
-        luts["b"].append(
-            np.asarray([big_int(x) for x in fc.bigints], np.int64)
-        )
+        actor_int = _Interner()
+        key_int = _Interner()
+        str_int = _Interner()
+        float_int = _Interner()
+        big_int = _Interner()
+        luts = {"a": [], "k": [], "s": [], "f": [], "b": []}
+        for fc in fcs:
+            luts["a"].append(
+                np.asarray([actor_int(x) for x in fc.actors], np.int64)
+            )
+            luts["k"].append(
+                np.asarray([key_int(x) for x in fc.keys], np.int64)
+            )
+            luts["s"].append(
+                np.asarray([str_int(x) for x in fc.strings], np.int64)
+            )
+            luts["f"].append(
+                np.asarray([float_int(x) for x in fc.floats], np.int64)
+            )
+            luts["b"].append(
+                np.asarray([big_int(x) for x in fc.bigints], np.int64)
+            )
 
-    # actor index order must equal actor string sort order (device
-    # tie-break parity — same remap as pack_docs)
-    sorted_actors = sorted(actor_int.items)
-    rank_of = {name: i for i, name in enumerate(sorted_actors)}
-    arank = np.asarray(
-        [rank_of[a] for a in actor_int.items], np.int64
-    )
-    luts["a"] = [
-        arank[l] if len(l) else l for l in luts["a"]
-    ]
-
-    def _flat_lut(kind: str) -> Tuple[np.ndarray, np.ndarray]:
-        offs = np.zeros(len(fcs) + 1, np.int64)
-        for i, l in enumerate(luts[kind]):
-            offs[i + 1] = offs[i] + len(l)
-        flat = (
-            np.concatenate(luts[kind])
-            if any(len(l) for l in luts[kind])
-            else np.zeros(1, np.int64)
+        # actor index order must equal actor string sort order (device
+        # tie-break parity — same remap as pack_docs)
+        sorted_actors = sorted(actor_int.items)
+        rank_of = {name: i for i, name in enumerate(sorted_actors)}
+        arank = np.asarray(
+            [rank_of[a] for a in actor_int.items], np.int64
         )
-        return flat, offs
+        luts["a"] = [
+            arank[l] if len(l) else l for l in luts["a"]
+        ]
 
-    alut, aoffs = _flat_lut("a")
-    klut, koffs = _flat_lut("k")
-    slut, soffs = _flat_lut("s")
-    flut, foffs = _flat_lut("f")
-    blut, boffs = _flat_lut("b")
+        def _flat_lut(kind: str) -> Tuple[np.ndarray, np.ndarray]:
+            offs = np.zeros(len(fcs) + 1, np.int64)
+            for i, l in enumerate(luts[kind]):
+                offs[i + 1] = offs[i] + len(l)
+            flat = (
+                np.concatenate(luts[kind])
+                if any(len(l) for l in luts[kind])
+                else np.zeros(1, np.int64)
+            )
+            return flat, offs
+
+        alut, aoffs = _flat_lut("a")
+        klut, koffs = _flat_lut("k")
+        slut, soffs = _flat_lut("s")
+        flut, foffs = _flat_lut("f")
+        blut, boffs = _flat_lut("b")
 
     # -- gather window slices ------------------------------------------
-    row_slices: List[np.ndarray] = []
-    w_doc: List[int] = []
-    w_fc: List[int] = []
-    w_cnt: List[int] = []
-    pred_slices: List[np.ndarray] = []
-    p_doc: List[int] = []
-    p_fc: List[int] = []
-    p_cnt: List[int] = []
-    p_base: List[int] = []
-    flat_base = 0
-    for d, spec in enumerate(doc_specs):
-        seen = set()
-        for fc, s, e in spec:
-            fci = fc_of[id(fc)]
-            if fci in seen:
-                continue  # same feed listed twice: one window only
-            seen.add(fci)
-            lo, hi = fc.window(int(s), e)
-            if hi <= lo:
-                continue
-            row_slices.append(fc.ensure_rows()[lo:hi])
-            w_doc.append(d)
-            w_fc.append(fci)
-            w_cnt.append(hi - lo)
-            psrc_col = fc.preds[:, 0]
-            plo = int(np.searchsorted(psrc_col, lo, side="left"))
-            phi = int(np.searchsorted(psrc_col, hi, side="left"))
-            if phi > plo:
-                pred_slices.append(fc.preds[plo:phi])
-                p_doc.append(d)
-                p_fc.append(fci)
-                p_cnt.append(phi - plo)
-                p_base.append(flat_base - lo)
-            flat_base += hi - lo
+    with _stage("gather"):
+        row_slices: List[np.ndarray] = []
+        w_doc: List[int] = []
+        w_fc: List[int] = []
+        w_cnt: List[int] = []
+        pred_slices: List[np.ndarray] = []
+        p_doc: List[int] = []
+        p_fc: List[int] = []
+        p_cnt: List[int] = []
+        p_base: List[int] = []
+        flat_base = 0
+        for d, spec in enumerate(doc_specs):
+            seen = set()
+            for fc, s, e in spec:
+                fci = fc_of[id(fc)]
+                if fci in seen:
+                    continue  # same feed listed twice: one window only
+                seen.add(fci)
+                lo, hi = fc.window(int(s), e)
+                if hi <= lo:
+                    continue
+                row_slices.append(fc.ensure_rows()[lo:hi])
+                w_doc.append(d)
+                w_fc.append(fci)
+                w_cnt.append(hi - lo)
+                psrc_col = fc.preds[:, 0]
+                plo = int(np.searchsorted(psrc_col, lo, side="left"))
+                phi = int(np.searchsorted(psrc_col, hi, side="left"))
+                if phi > plo:
+                    pred_slices.append(fc.preds[plo:phi])
+                    p_doc.append(d)
+                    p_fc.append(fci)
+                    p_cnt.append(phi - plo)
+                    p_base.append(flat_base - lo)
+                flat_base += hi - lo
 
-    M = flat_base
-    A = max(1, len(sorted_actors))
-    if M == 0:
-        N = n_rows if n_rows is not None else 1
-        P = n_pred if n_pred is not None else 1
-        return _empty_batch(
-            Dp, N, P, sorted_actors, key_int, str_int, float_int, big_int
+        M = flat_base
+        A = max(1, len(sorted_actors))
+        if M == 0:
+            N = n_rows if n_rows is not None else 1
+            P = n_pred if n_pred is not None else 1
+            return _empty_batch(
+                Dp, N, P, sorted_actors, key_int, str_int, float_int, big_int
+            )
+
+        w_cnt_a = np.asarray(w_cnt, np.int64)
+        w_doc_a = np.asarray(w_doc, np.int64)
+        w_fc_a = np.asarray(w_fc, np.int64)
+        R = np.concatenate(row_slices, axis=0)
+        doc_col = np.repeat(w_doc_a, w_cnt_a)
+        aoff_col = np.repeat(aoffs[w_fc_a], w_cnt_a)
+
+        action = R[:, 0].astype(np.int64)
+        ctr = R[:, 1].astype(np.int64)
+        seqc = R[:, 2].astype(np.int64)
+        start_op = R[:, 3].astype(np.int64)
+        obj_ctr = R[:, 4].astype(np.int64)
+        obj_a_l = R[:, 5].astype(np.int64)
+        key_l = R[:, 6].astype(np.int64)
+        ref_ctr = R[:, 7].astype(np.int64)
+        ref_a_l = R[:, 8].astype(np.int64)
+        insert = R[:, 9].astype(np.int64)
+        vkind = R[:, 10].astype(np.int64)
+        value_l = R[:, 11].astype(np.int64)
+        dt = R[:, 12].astype(np.int64)
+
+        # writer (op actor) = feed-local actor 0
+        writer_g = np.asarray(
+            [int(luts["a"][fci][0]) for fci in range(len(fcs))], np.int64
         )
+        actor_g = np.repeat(writer_g[w_fc_a], w_cnt_a)
 
-    w_cnt_a = np.asarray(w_cnt, np.int64)
-    w_doc_a = np.asarray(w_doc, np.int64)
-    w_fc_a = np.asarray(w_fc, np.int64)
-    R = np.concatenate(row_slices, axis=0)
-    doc_col = np.repeat(w_doc_a, w_cnt_a)
-    aoff_col = np.repeat(aoffs[w_fc_a], w_cnt_a)
+        def _lut_where(cond, lut, idx, alt):
+            # np.where evaluates both branches: rows where cond is False
+            # carry a sentinel local index (e.g. -1), and a feed whose table
+            # is empty but sits at the end of the flat LUT would index one
+            # past the end — clamp before gathering, select after.
+            safe = np.minimum(np.maximum(idx, 0), len(lut) - 1)
+            return np.where(cond, lut[safe], alt)
 
-    action = R[:, 0].astype(np.int64)
-    ctr = R[:, 1].astype(np.int64)
-    seqc = R[:, 2].astype(np.int64)
-    start_op = R[:, 3].astype(np.int64)
-    obj_ctr = R[:, 4].astype(np.int64)
-    obj_a_l = R[:, 5].astype(np.int64)
-    key_l = R[:, 6].astype(np.int64)
-    ref_ctr = R[:, 7].astype(np.int64)
-    ref_a_l = R[:, 8].astype(np.int64)
-    insert = R[:, 9].astype(np.int64)
-    vkind = R[:, 10].astype(np.int64)
-    value_l = R[:, 11].astype(np.int64)
-    dt = R[:, 12].astype(np.int64)
-
-    # writer (op actor) = feed-local actor 0
-    writer_g = np.asarray(
-        [int(luts["a"][fci][0]) for fci in range(len(fcs))], np.int64
-    )
-    actor_g = np.repeat(writer_g[w_fc_a], w_cnt_a)
-
-    def _lut_where(cond, lut, idx, alt):
-        # np.where evaluates both branches: rows where cond is False
-        # carry a sentinel local index (e.g. -1), and a feed whose table
-        # is empty but sits at the end of the flat LUT would index one
-        # past the end — clamp before gathering, select after.
-        safe = np.minimum(np.maximum(idx, 0), len(lut) - 1)
-        return np.where(cond, lut[safe], alt)
-
-    obj_a_g = _lut_where(obj_a_l >= 0, alut, aoff_col + obj_a_l, obj_a_l)
-    ref_a_g = _lut_where(ref_a_l >= 0, alut, aoff_col + ref_a_l, ref_a_l)
-    key_g = _lut_where(
-        key_l >= 0, klut, np.repeat(koffs[w_fc_a], w_cnt_a) + key_l, -1
-    )
-    value_g = value_l.copy()
-    for code, lut, offs in (
-        (VK_STR, slut, soffs),
-        (VK_FLOAT, flut, foffs),
-        (VK_BIGINT, blut, boffs),
-    ):
-        m = vkind == code
-        if m.any():
-            off_col = np.repeat(offs[w_fc_a], w_cnt_a)
-            value_g[m] = lut[off_col[m] + value_l[m]]
-
-    # preds (flat, pre-sort indices for src)
-    if pred_slices:
-        p_cnt_a = np.asarray(p_cnt, np.int64)
-        p_fc_a = np.asarray(p_fc, np.int64)
-        PR = np.concatenate(pred_slices, axis=0)
-        pr_src = PR[:, 0].astype(np.int64) + np.repeat(
-            np.asarray(p_base, np.int64), p_cnt_a
+        obj_a_g = _lut_where(obj_a_l >= 0, alut, aoff_col + obj_a_l, obj_a_l)
+        ref_a_g = _lut_where(ref_a_l >= 0, alut, aoff_col + ref_a_l, ref_a_l)
+        key_g = _lut_where(
+            key_l >= 0, klut, np.repeat(koffs[w_fc_a], w_cnt_a) + key_l, -1
         )
-        pr_tgt_ctr = PR[:, 1].astype(np.int64)
-        pr_aoff = np.repeat(aoffs[p_fc_a], p_cnt_a)
-        pr_tgt_a = alut[pr_aoff + PR[:, 2].astype(np.int64)]
-        pr_doc = np.repeat(np.asarray(p_doc, np.int64), p_cnt_a)
-    else:
-        pr_src = pr_tgt_ctr = pr_tgt_a = pr_doc = np.zeros(0, np.int64)
+        value_g = value_l.copy()
+        for code, lut, offs in (
+            (VK_STR, slut, soffs),
+            (VK_FLOAT, flut, foffs),
+            (VK_BIGINT, blut, boffs),
+        ):
+            m = vkind == code
+            if m.any():
+                off_col = np.repeat(offs[w_fc_a], w_cnt_a)
+                value_g[m] = lut[off_col[m] + value_l[m]]
+
+        # preds (flat, pre-sort indices for src)
+        if pred_slices:
+            p_cnt_a = np.asarray(p_cnt, np.int64)
+            p_fc_a = np.asarray(p_fc, np.int64)
+            PR = np.concatenate(pred_slices, axis=0)
+            pr_src = PR[:, 0].astype(np.int64) + np.repeat(
+                np.asarray(p_base, np.int64), p_cnt_a
+            )
+            pr_tgt_ctr = PR[:, 1].astype(np.int64)
+            pr_aoff = np.repeat(aoffs[p_fc_a], p_cnt_a)
+            pr_tgt_a = alut[pr_aoff + PR[:, 2].astype(np.int64)]
+            pr_doc = np.repeat(np.asarray(p_doc, np.int64), p_cnt_a)
+        else:
+            pr_src = pr_tgt_ctr = pr_tgt_a = pr_doc = np.zeros(0, np.int64)
+
+    span.note(feeds=len(fcs), rows=M)
 
     # -- composite key bit budget --------------------------------------
     ab = max(1, int(A - 1).bit_length())
@@ -1088,149 +1134,166 @@ def pack_docs_columns(
         hit = rk_sorted[pos_c] == q
         return order_rk[pos_c], hit
 
-    # validity fixpoint: an op drops if its container or referenced
-    # element is absent from the packed window (matches _pack_one's
-    # incremental row_of misses, including the cascade)
-    rk = _rowkey(doc_col, ctr, actor_g)
-    order_rk = np.argsort(rk)
-    rk_sorted = rk[order_rk]
-    obj_tgt, obj_hit = _resolve(rk_sorted, order_rk, doc_col, obj_ctr, obj_a_g)
-    ref_tgt, ref_hit = _resolve(rk_sorted, order_rk, doc_col, ref_ctr, ref_a_g)
-    valid = np.ones(M, bool)
-    while True:
-        bad = (
-            (need_obj & (~obj_hit | ~valid[obj_tgt]))
-            | (need_ref & (~ref_hit | ~valid[ref_tgt]))
-        ) & valid
-        if not bad.any():
-            break
-        valid[bad] = False
-
-    if not valid.all():
-        keep = valid
-        (
-            action, ctr, seqc, start_op, obj_ctr, obj_a_g, key_g,
-            ref_ctr, ref_a_g, insert, vkind, value_g, dt, actor_g,
-            doc_col, need_obj, need_ref,
-        ) = (
-            x[keep]
-            for x in (
-                action, ctr, seqc, start_op, obj_ctr, obj_a_g, key_g,
-                ref_ctr, ref_a_g, insert, vkind, value_g, dt, actor_g,
-                doc_col, need_obj, need_ref,
-            )
-        )
-        # remap pred srcs through the compaction
-        new_idx = np.cumsum(valid) - 1
-        if len(pr_src):
-            pk = valid[pr_src]
-            pr_src = new_idx[pr_src[pk]]
-            pr_tgt_ctr = pr_tgt_ctr[pk]
-            pr_tgt_a = pr_tgt_a[pk]
-            pr_doc = pr_doc[pk]
-        M = len(action)
-        if M == 0:
-            N = n_rows if n_rows is not None else 1
-            P = n_pred if n_pred is not None else 1
-            return _empty_batch(
-                Dp, N, P, sorted_actors, key_int, str_int, float_int,
-                big_int,
-            )
+    with _stage("sort"):
         rk = _rowkey(doc_col, ctr, actor_g)
         order_rk = np.argsort(rk)
         rk_sorted = rk[order_rk]
+    # validity fixpoint: an op drops if its container or referenced
+    # element is absent from the packed window (matches _pack_one's
+    # incremental row_of misses, including the cascade)
+    with _stage("resolve"):
         obj_tgt, obj_hit = _resolve(
             rk_sorted, order_rk, doc_col, obj_ctr, obj_a_g
         )
         ref_tgt, ref_hit = _resolve(
             rk_sorted, order_rk, doc_col, ref_ctr, ref_a_g
         )
+        valid = np.ones(M, bool)
+        while True:
+            bad = (
+                (need_obj & (~obj_hit | ~valid[obj_tgt]))
+                | (need_ref & (~ref_hit | ~valid[ref_tgt]))
+            ) & valid
+            if not bad.any():
+                break
+            valid[bad] = False
+
+    if not valid.all():
+        with _stage("resolve"):
+            keep = valid
+            (
+                action, ctr, seqc, start_op, obj_ctr, obj_a_g, key_g,
+                ref_ctr, ref_a_g, insert, vkind, value_g, dt, actor_g,
+                doc_col, need_obj, need_ref,
+            ) = (
+                x[keep]
+                for x in (
+                    action, ctr, seqc, start_op, obj_ctr, obj_a_g, key_g,
+                    ref_ctr, ref_a_g, insert, vkind, value_g, dt, actor_g,
+                    doc_col, need_obj, need_ref,
+                )
+            )
+            # remap pred srcs through the compaction
+            new_idx = np.cumsum(valid) - 1
+            if len(pr_src):
+                pk = valid[pr_src]
+                pr_src = new_idx[pr_src[pk]]
+                pr_tgt_ctr = pr_tgt_ctr[pk]
+                pr_tgt_a = pr_tgt_a[pk]
+                pr_doc = pr_doc[pk]
+            M = len(action)
+            if M == 0:
+                N = n_rows if n_rows is not None else 1
+                P = n_pred if n_pred is not None else 1
+                return _empty_batch(
+                    Dp, N, P, sorted_actors, key_int, str_int, float_int,
+                    big_int,
+                )
+        with _stage("sort"):
+            rk = _rowkey(doc_col, ctr, actor_g)
+            order_rk = np.argsort(rk)
+            rk_sorted = rk[order_rk]
+        with _stage("resolve"):
+            obj_tgt, obj_hit = _resolve(
+                rk_sorted, order_rk, doc_col, obj_ctr, obj_a_g
+            )
+            ref_tgt, ref_hit = _resolve(
+                rk_sorted, order_rk, doc_col, ref_ctr, ref_a_g
+            )
 
     # -- causal order + within-doc positions ---------------------------
-    sort_key = _rowkey(doc_col, start_op, actor_g)
-    perm = np.argsort(sort_key, kind="stable")
-    inv = np.empty(M, np.int64)
-    inv[perm] = np.arange(M, dtype=np.int64)
-    doc_counts = np.bincount(doc_col, minlength=Dp).astype(np.int64)
-    doc_starts = np.zeros(Dp + 1, np.int64)
-    np.cumsum(doc_counts, out=doc_starts[1:])
-    pos = inv - doc_starts[doc_col]
+    with _stage("sort"):
+        sort_key = _rowkey(doc_col, start_op, actor_g)
+        perm = np.argsort(sort_key, kind="stable")
+        inv = np.empty(M, np.int64)
+        inv[perm] = np.arange(M, dtype=np.int64)
+        doc_counts = np.bincount(doc_col, minlength=Dp).astype(np.int64)
+        doc_starts = np.zeros(Dp + 1, np.int64)
+        np.cumsum(doc_counts, out=doc_starts[1:])
+        pos = inv - doc_starts[doc_col]
 
-    obj_row = np.where(need_obj, pos[obj_tgt], OBJ_ROOT)
-    ref_row = np.where(
-        need_ref,
-        pos[ref_tgt],
-        np.where(ref_a_l_compact(ref_a_g) == REF_HEAD, REF_HEAD, REF_NONE),
-    )
-
-    # -- pred edges -> per-doc rows ------------------------------------
-    if len(pr_src):
-        tgt_row, tgt_hit = _resolve(
-            rk_sorted, order_rk, pr_doc, pr_tgt_ctr, pr_tgt_a
+    with _stage("resolve"):
+        obj_row = np.where(need_obj, pos[obj_tgt], OBJ_ROOT)
+        ref_row = np.where(
+            need_ref,
+            pos[ref_tgt],
+            np.where(
+                ref_a_l_compact(ref_a_g) == REF_HEAD, REF_HEAD, REF_NONE
+            ),
         )
-        pk = tgt_hit
-        pr_doc = pr_doc[pk]
-        p_src_row = pos[pr_src[pk]]
-        p_tgt_row = pos[tgt_row[pk]]
-        pred_counts = np.bincount(pr_doc, minlength=Dp).astype(np.int64)
-        pred_starts = np.zeros(Dp + 1, np.int64)
-        np.cumsum(pred_counts, out=pred_starts[1:])
-        # pr_doc is nondecreasing (windows gathered doc-by-doc; the
-        # validity compaction preserves order)
-        p_pos = np.arange(len(pr_doc), dtype=np.int64) - pred_starts[pr_doc]
-    else:
-        pred_counts = np.zeros(Dp, np.int64)
-        p_src_row = p_tgt_row = p_pos = pr_doc = np.zeros(0, np.int64)
+
+        # -- pred edges -> per-doc rows --------------------------------
+        if len(pr_src):
+            tgt_row, tgt_hit = _resolve(
+                rk_sorted, order_rk, pr_doc, pr_tgt_ctr, pr_tgt_a
+            )
+            pk = tgt_hit
+            pr_doc = pr_doc[pk]
+            p_src_row = pos[pr_src[pk]]
+            p_tgt_row = pos[tgt_row[pk]]
+            pred_counts = np.bincount(pr_doc, minlength=Dp).astype(np.int64)
+            pred_starts = np.zeros(Dp + 1, np.int64)
+            np.cumsum(pred_counts, out=pred_starts[1:])
+            # pr_doc is nondecreasing (windows gathered doc-by-doc; the
+            # validity compaction preserves order)
+            p_pos = (
+                np.arange(len(pr_doc), dtype=np.int64) - pred_starts[pr_doc]
+            )
+        else:
+            pred_counts = np.zeros(Dp, np.int64)
+            p_src_row = p_tgt_row = p_pos = pr_doc = np.zeros(0, np.int64)
 
     # -- scatter into padded [D, N] ------------------------------------
-    max_ops = int(doc_counts.max(initial=0))
-    max_preds = int(pred_counts.max(initial=0))
-    N = n_rows if n_rows is not None else _round_up(max(max_ops, 1))
-    P = n_pred if n_pred is not None else _round_up(max(max_preds, 1))
-    if max_ops > N or max_preds > P:
-        raise ValueError(
-            f"doc exceeds bucket: ops {max_ops}>{N} or preds {max_preds}>{P}"
+    with _stage("emit"):
+        max_ops = int(doc_counts.max(initial=0))
+        max_preds = int(pred_counts.max(initial=0))
+        N = n_rows if n_rows is not None else _round_up(max(max_ops, 1))
+        P = n_pred if n_pred is not None else _round_up(max(max_preds, 1))
+        if max_ops > N or max_preds > P:
+            raise ValueError(
+                f"doc exceeds bucket: ops {max_ops}>{N} or "
+                f"preds {max_preds}>{P}"
+            )
+
+        flat_idx = doc_col * N + pos
+        cols: Dict[str, np.ndarray] = {}
+        defaults = {
+            "action": PAD, "obj": -1, "key": -1, "ref": -3,
+        }
+        sources = {
+            "action": action, "actor": actor_g, "ctr": ctr, "seq": seqc,
+            "obj": obj_row, "key": key_g, "ref": ref_row, "insert": insert,
+            "vkind": vkind, "value": value_g, "dt": dt,
+        }
+        for name in COLUMNS:
+            flat = np.full(Dp * N, defaults.get(name, 0), np.int32)
+            flat[flat_idx] = sources[name].astype(np.int32)
+            cols[name] = flat.reshape(Dp, N)
+        psrc = np.full(Dp * P, -1, np.int32)
+        ptgt = np.full(Dp * P, -1, np.int32)
+        if len(p_src_row):
+            pidx = pr_doc * P + p_pos
+            psrc[pidx] = p_src_row.astype(np.int32)
+            ptgt[pidx] = p_tgt_row.astype(np.int32)
+
+        # per-doc local actor map (ascending == string sort order: actor_g
+        # indexes sorted_actors)
+        doc_actors = doc_actor_map_from_pairs(
+            np.unique(doc_col * np.int64(A) + actor_g), A, Dp
         )
 
-    flat_idx = doc_col * N + pos
-    cols: Dict[str, np.ndarray] = {}
-    defaults = {
-        "action": PAD, "obj": -1, "key": -1, "ref": -3,
-    }
-    sources = {
-        "action": action, "actor": actor_g, "ctr": ctr, "seq": seqc,
-        "obj": obj_row, "key": key_g, "ref": ref_row, "insert": insert,
-        "vkind": vkind, "value": value_g, "dt": dt,
-    }
-    for name in COLUMNS:
-        flat = np.full(Dp * N, defaults.get(name, 0), np.int32)
-        flat[flat_idx] = sources[name].astype(np.int32)
-        cols[name] = flat.reshape(Dp, N)
-    psrc = np.full(Dp * P, -1, np.int32)
-    ptgt = np.full(Dp * P, -1, np.int32)
-    if len(p_src_row):
-        pidx = pr_doc * P + p_pos
-        psrc[pidx] = p_src_row.astype(np.int32)
-        ptgt[pidx] = p_tgt_row.astype(np.int32)
-
-    # per-doc local actor map (ascending == string sort order: actor_g
-    # indexes sorted_actors)
-    doc_actors = doc_actor_map_from_pairs(
-        np.unique(doc_col * np.int64(A) + actor_g), A, Dp
-    )
-
-    return ColumnarBatch(
-        cols=cols,
-        psrc=psrc.reshape(Dp, P),
-        ptgt=ptgt.reshape(Dp, P),
-        n_ops=doc_counts.astype(np.int32),
-        actors=list(sorted_actors),
-        keys=list(key_int.items),
-        strings=list(str_int.items),
-        floats=list(float_int.items),
-        bigints=list(big_int.items),
-        doc_actors=doc_actors,
-    )
+        return ColumnarBatch(
+            cols=cols,
+            psrc=psrc.reshape(Dp, P),
+            ptgt=ptgt.reshape(Dp, P),
+            n_ops=doc_counts.astype(np.int32),
+            actors=list(sorted_actors),
+            keys=list(key_int.items),
+            strings=list(str_int.items),
+            floats=list(float_int.items),
+            bigints=list(big_int.items),
+            doc_actors=doc_actors,
+        )
 
 
 def ref_a_l_compact(ref_a_g: np.ndarray) -> np.ndarray:

@@ -578,6 +578,12 @@ def ensure_doc_actors(batch: ColumnarBatch):
     return batch.doc_actors
 
 
+def actor_bucket(batch: ColumnarBatch) -> int:
+    """A_loc: the pow2 bucket (floor 4) of the most actors any doc of
+    the batch has."""
+    return max(4, round_up_pow2(ensure_doc_actors(batch).shape[1]))
+
+
 def bucket_doc_actors(batch: ColumnarBatch):
     """(doc_actors padded to the A_loc bucket, A_loc, K): the pow2 bucket
     shape (A_loc >= 4, K >= 16) shared by the single-device and sharded
@@ -586,7 +592,7 @@ def bucket_doc_actors(batch: ColumnarBatch):
     import numpy as np
 
     da = ensure_doc_actors(batch)
-    A = max(4, round_up_pow2(da.shape[1]))
+    A = actor_bucket(batch)
     if da.shape[1] < A:
         da = np.concatenate(
             [da, np.full((da.shape[0], A - da.shape[1]), -1, np.int32)],
@@ -725,12 +731,14 @@ def run_batch_full(
     slab round-robin scheduler's per-chip dispatch."""
     args, A, K = _device_args(batch, lean=lean, device=device)
     fn, args = _full_entry(args, lean)
-    _dispatched[(batch.n_docs, batch.n_rows, bool(lean))] = (
-        fn,
-        tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args),
-        {"A": A, "K": K},
-    )
-    with telemetry.timed("pipeline.enqueue", "pipeline"):
+    avals = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
+    statics = {"A": A, "K": K}
+    _dispatched.setdefault(
+        (batch.n_docs, batch.n_rows, bool(lean)), {}
+    )[(fn.__name__, avals, A, K)] = (fn, avals, statics)
+    with telemetry.timed(
+        "pipeline.enqueue", "pipeline", A=A, K=K, P=batch.psrc.shape[1]
+    ):
         return fn(*args, A=A, K=K)
 
 
@@ -814,8 +822,15 @@ def phases_of_hlo(hlo_text: str) -> Dict[str, str]:
 
 def phase_of_ops(n_docs: int, n_rows: int, lean: bool) -> Dict[str, str]:
     """{HLO instruction: phase | "mixed" | "unscoped"} of the slab
-    program this process dispatched for a [n_docs, n_rows] slab, or {}
+    programs this process dispatched for a [n_docs, n_rows] slab, or {}
     if it dispatched none of that shape.
+
+    Two slabs of one [n_docs, n_rows] are different programs when they
+    differ in the actor bucket `A`, the key bucket `K`, the pred bucket
+    or a wire dtype. Each is lowered; an instruction name that all of
+    them give one phase keeps it, and one they disagree on is "mixed"
+    (the caller knows a slab by its shape alone, and must not book a
+    program's seconds under another program's phase).
 
     Compiles the same function for the same argument shapes (those
     `run_batch_full` remembered) and reads the optimized HLO: the same
@@ -826,11 +841,15 @@ def phase_of_ops(n_docs: int, n_rows: int, lean: bool) -> Dict[str, str]:
     and a jit of its own (JAX's in-process caches would hand back the
     executable that ran) with the metadata in the persistent cache's
     key: cached too, but never stale."""
-    sig = _dispatched.get((n_docs, n_rows, bool(lean)))
-    if sig is None:
-        return {}
-    fn, avals, statics = sig
+    out: Dict[str, str] = {}
+    programs = _dispatched.get((n_docs, n_rows, bool(lean)), {})
+    for fn, avals, statics in programs.values():
+        for name, phase in _phases_of_program(fn, avals, statics).items():
+            out[name] = phase if out.get(name, phase) == phase else "mixed"
+    return out
 
+
+def _phases_of_program(fn, avals, statics) -> Dict[str, str]:
     @functools.wraps(fn.__wrapped__)
     def same_program(*args, **kwargs):
         return fn.__wrapped__(*args, **kwargs)
@@ -849,9 +868,10 @@ def phase_of_ops(n_docs: int, n_rows: int, lean: bool) -> Dict[str, str]:
     return phases_of_hlo(compiled.as_text())
 
 
-# (n_docs, n_rows, lean) -> (jitted entry, arg shapes, statics) of the
-# full-kernel programs this process dispatched: what phase_of_ops lowers
-_dispatched: Dict[tuple, tuple] = {}
+# (n_docs, n_rows, lean) -> {full static signature: (jitted entry, arg
+# shapes, statics)} of the full-kernel programs this process
+# dispatched: what phase_of_ops lowers
+_dispatched: Dict[tuple, Dict[tuple, tuple]] = {}
 
 
 def _check_ranges(batch: ColumnarBatch, A: int, K: int) -> None:

@@ -29,7 +29,7 @@ clamps to the real one — out-of-range lanes are discarded by the same
 where() masks, so the clamp bound never reaches the output.
 
 Anything the kernel can't serve — no jax, no device, a tracing failure
-— returns {} and the caller (ops/columnar._try_pack_prefix_single)
+— returns {} and the caller (ops/columnar._pack_prefix_single)
 falls through native -> numpy, so HM_DEVICE_PACK=1 on a host-only box
 degrades to exactly today's path; fallbacks are a counter, never an
 error (telemetry pack.device_fallbacks).
