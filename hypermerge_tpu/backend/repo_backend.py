@@ -61,6 +61,10 @@ _M_D2H = telemetry.counter("mesh.d2h_bytes")
 # feed by feed (_prefetch_columns)
 _M_COLS_BULK = telemetry.counter("loader.cols_bulk_feeds")
 _M_COLS_SINGLE = telemetry.counter("loader.cols_single_feeds")
+# feed heads of a bulk open by who answered: the store's head snapshot
+# (storage/feed.py HeadSnapshot) or a probe of the feed's files
+_M_HEADS_SNAP = telemetry.counter("loader.heads_snapshot_feeds")
+_M_HEADS_PROBED = telemetry.counter("loader.heads_probed_feeds")
 
 
 # actor id -> discovery id is a pure hash of an immutable key: memoize
@@ -105,7 +109,7 @@ class RepoBackend:
             memory_sig_storage_fn,
         )
 
-        from ..storage.durability import DurabilityManager
+        from ..storage.durability import DurabilityManager, fsync_dir
 
         # durability tiers (HM_FSYNC, storage/durability.py): feed
         # appends either fsync inline (tier 2), group-fsync on this
@@ -133,6 +137,11 @@ class RepoBackend:
             # session crashed -> run whole-repo recovery below.
             self._dirty_marker = os.path.join(path, "repo.dirty")
             was_dirty = os.path.exists(self._dirty_marker)
+            if was_dirty:
+                # a crashed session's head snapshot vouches for
+                # nothing, whether recovery runs below or HM_RECOVER=0
+                # skips it: the session starts with none
+                storage_fn.heads.discard()
         # corpus slab handle (storage/slab.py) when file-backed: the
         # backend owns its lifecycle (compaction on close)
         self._col_slab = getattr(cache_fn, "slab", None)
@@ -165,6 +174,9 @@ class RepoBackend:
             self.recovery_report = recover_repo(self)
         elif was_dirty:
             recovery_skipped = True
+        # (a session that never established that the logs are whole
+        # vouches for no feed head at its close either)
+        self._recovery_skipped = recovery_skipped
         # shared group-commit journal (storage/wal.py): created AFTER
         # recovery consumed the crashed session's journal. With
         # recovery explicitly skipped (HM_RECOVER=0 — tools/scrub.py
@@ -218,7 +230,7 @@ class RepoBackend:
                         self.durability.wal.session.encode("utf-8")
                     )
                 io_fsync(fh)
-            self._fsync_dir(path)
+            fsync_dir(path)
         if os.environ.get("HM_CLOCK_MIRROR", "1") != "0":
             # device-resident ClockStore query twin (ops/clock_mirror.py):
             # writes buffer host-side, so this costs nothing until the
@@ -367,19 +379,6 @@ class RepoBackend:
                 # ever dropped
                 wal.ack_pacer = self.overload.ack_extra_s
             self.overload.start()
-
-    @staticmethod
-    def _fsync_dir(path: str) -> None:
-        """Durably record a directory entry (marker create). Advisory:
-        platforms without O_DIRECTORY fsync just skip it."""
-        try:
-            fd = os.open(path, os.O_RDONLY)
-            try:
-                os.fsync(fd)
-            finally:
-                os.close(fd)
-        except OSError:
-            pass
 
     def _invalidate_recovery_stamp(self) -> None:
         """First feed write of a journal-less HM_RECOVER=0 session
@@ -865,6 +864,11 @@ class RepoBackend:
                 "cols_bulk_feeds": 0,
                 "cols_single_feeds": 0,
                 "cols_bulk_pct": 0.0,
+                # cold feeds whose head the store's snapshot answered /
+                # whose files were probed, and the snapshot's share
+                "heads_snapshot_feeds": 0,
+                "heads_probed_feeds": 0,
+                "heads_snapshot_pct": 0.0,
                 # feeds the open read; docs whose slab took the general
                 # (multi-writer) pack and their share; the widest actor
                 # and pred buckets among the slabs' programs
@@ -903,6 +907,11 @@ class RepoBackend:
             if cols:
                 stats["cols_bulk_pct"] = round(
                     100.0 * stats["cols_bulk_feeds"] / cols, 3
+                )
+            asked = stats["heads_snapshot_feeds"] + stats["heads_probed_feeds"]
+            if asked:
+                stats["heads_snapshot_pct"] = round(
+                    100.0 * stats["heads_snapshot_feeds"] / asked, 3
                 )
             if new_docs:
                 stats["pack_general_pct"] = round(
@@ -1272,40 +1281,59 @@ class RepoBackend:
                 self._sync_changes(actor)
 
     def _prefetch_columns(self, actors: List[Actor]) -> Tuple[int, int]:
-        """Load the column sidecars of a chunk's actors. Feeds whose
-        sidecar is one complete v3 image in the corpus slab, level with
-        the feed head, load slab-granular: one pass over the chunk's
-        extents (colcache.load_slab_images; views of the mapping, no
-        copy, no per-feed parse). Every other feed (a v2 tail, a legacy
-        or memory sidecar, HM_SLAB=0, a sidecar ahead of or behind its
-        feed) loads through Actor.columns(), feed by feed, on this
-        thread: both are mmap slices and Python, which no thread pool
-        speeds up (it only took the GIL from the pack worker). Returns
-        (feeds loaded slab-granular, feeds loaded one by one); feeds
-        whose cache was loaded already count in neither."""
+        """Load the column sidecars of a chunk's actors. First the head
+        of every cold actor's feed, in one batch (FeedStore.
+        resolve_heads): answered by the store's head snapshot where a
+        clean close sealed one, probed feed by feed (`.len` + `stat`)
+        where not; after it `Actor.seq_head` is a list length. Feeds
+        whose sidecar is one complete v3 image in the corpus slab,
+        level with the feed head, load slab-granular: one pass over the
+        chunk's extents (colcache.load_slab_images; views of the
+        mapping, no copy, no per-feed parse). Every other feed (a v2
+        tail, a legacy or memory sidecar, HM_SLAB=0, a sidecar ahead of
+        or behind its feed) loads through Actor.columns(), feed by
+        feed, on this thread: both are mmap slices and Python, which no
+        thread pool speeds up (it only took the GIL from the pack
+        worker). Returns (feeds loaded slab-granular, feeds loaded one
+        by one); feeds whose cache was loaded already count in neither.
+        Who answered the heads goes to last_bulk_stats
+        (heads_snapshot_feeds / heads_probed_feeds) and the counters of
+        the same names."""
         cold = [a for a in actors if not a.colcache.loaded]
         bulk: set = set()
         slab = self._col_slab
+        cands: List[Actor] = []
         if slab is not None:
             # hint the chunk's extents into the page cache first (under
             # the pipeline the NEXT chunk's hint overlaps this chunk's
             # pack)
             slab.prefetch([a.id for a in actors])
             cands = [a for a in cold if a.colcache.slab is slab]
-            if cands:
-                # the staleness rule, batched: each feed's head (file
-                # metadata lookups, the one part that leaves the GIL)
-                with telemetry.span(
-                    "storage.columns.heads", "storage", feeds=len(cands)
-                ):
-                    heads = [a.seq_head for a in cands]
-                with telemetry.span(
-                    "storage.columns.bulk", "storage", feeds=len(cands)
-                ):
-                    done = load_slab_images(
-                        slab, [a.colcache for a in cands], heads
-                    )
-                bulk = {a.id for a, d in zip(cands, done) if d}
+        with telemetry.span(
+            "storage.columns.heads", "storage", feeds=len(cold)
+        ) as sp:
+            snap, probed = self.feeds.resolve_heads([a.feed for a in cold])
+            sp.note(probed=probed)
+            # the staleness rule, batched: a sidecar installs only if
+            # it holds exactly its feed head's count of changes
+            heads = [a.seq_head for a in cands]
+        _M_HEADS_SNAP.add(snap)
+        _M_HEADS_PROBED.add(probed)
+        with self._stats_lock:
+            stats = self.last_bulk_stats
+            for key, n in (
+                ("heads_snapshot_feeds", snap),
+                ("heads_probed_feeds", probed),
+            ):
+                stats[key] = stats.get(key, 0) + n
+        if cands:
+            with telemetry.span(
+                "storage.columns.bulk", "storage", feeds=len(cands)
+            ):
+                done = load_slab_images(
+                    slab, [a.colcache for a in cands], heads
+                )
+            bulk = {a.id for a, d in zip(cands, done) if d}
         rest = [a for a in actors if a.id not in bulk]
         if rest:
             with telemetry.span(
@@ -2586,7 +2614,15 @@ class RepoBackend:
             and os.path.exists(self._dirty_marker)
         ):
             # clean close: every flusher drained, every store closed —
-            # the next open skips crash recovery
+            # the next open skips crash recovery. Every log and .len is
+            # flushed (synced, as the tier says), so the feed heads
+            # this session changed or learned are sealed now, BEFORE
+            # the marker goes: a snapshot is only ever read beside a
+            # clean close (storage/feed.py HeadSnapshot). Where a stale
+            # one could not even be removed, the marker stays. A session
+            # that skipped recovery (HM_RECOVER=0) started with none and
+            # seals none.
             from ..storage.faults import io_remove
 
-            io_remove(self._dirty_marker)
+            if self._recovery_skipped or self.feeds.heads.seal():
+                io_remove(self._dirty_marker)

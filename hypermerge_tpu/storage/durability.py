@@ -37,6 +37,18 @@ Ordering invariants (the recoverable direction):
     already on the platter. (Tier 0 relies on recovery-on-open
     clamping clock rows back to feed reality instead.)
 
+  - the head snapshot (`feeds/heads.snap`, storage/feed.py
+    HeadSnapshot) is sealed by a clean close in this order: every log
+    and `.len` flushed (and fsynced, at tiers 1/2) -> the snapshot
+    written (tmp + rename; fsynced with its directory at tiers 1/2)
+    -> `repo.dirty` removed. So a snapshot is read only beside a clean
+    close of the logs it describes: a crash at any instant leaves the
+    marker, and a dirty open discards the snapshot before any doc
+    opens. (Tier 0 after a POWER CUT that follows a clean close is as
+    before outside what the tier promises: nothing was fsynced, so
+    logs, `.len`, snapshot and sqlite rows may each be older than the
+    others.)
+
 Sidecars (columnar slab, signature records) stay flush-only at every
 tier: they are derived data — blocks are the source of truth and every
 sidecar format detects-and-rebuilds on mismatch.
@@ -66,6 +78,20 @@ def fsync_tier() -> int:
         return int(os.environ.get("HM_FSYNC", "0"))
     except ValueError:
         return 0
+
+
+def fsync_dir(path: str) -> None:
+    """Durably record a directory entry (the crash marker's create, the
+    head snapshot's rename). Advisory: platforms without O_DIRECTORY
+    fsync just skip it."""
+    try:
+        fd = os.open(path, os.O_RDONLY)
+        try:
+            os.fsync(fd)
+        finally:
+            os.close(fd)
+    except OSError:
+        pass
 
 
 def _flush_window_s() -> float:

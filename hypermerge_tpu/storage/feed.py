@@ -20,15 +20,18 @@ from __future__ import annotations
 import os
 import struct
 import threading
-from typing import Callable, Dict, List, Optional
+import zlib
+from typing import Callable, Dict, List, Optional, Tuple
+
+import numpy as np
 
 from ..analysis.lockdep import make_rlock
 from ..utils import keys as keymod
 from ..utils.debug import log
 from ..utils.ids import DiscoveryId, get_or_create
 from ..utils.queue import Queue
-from .durability import fsync_tier
-from .faults import harness_gen, io_fsync, io_open, io_remove
+from .durability import fsync_dir, fsync_tier
+from .faults import harness_gen, io_fsync, io_open, io_remove, io_replace
 
 
 class MemoryFeedStorage:
@@ -68,6 +71,21 @@ class FileFeedStorage:
     full scan. The per-block offset index is built lazily on first
     `get`.
 
+    A bulk cold open does not ask `.len` at all where the store's head
+    snapshot (HeadSnapshot below) vouches for the feed: the storage is
+    primed with the count and end the last clean close sealed, and no
+    `stat` of the log follows. What that gives up, at a bulk open
+    only: a log whose LENGTH was changed by something other than this
+    program while the store was closed is no longer noticed there (the
+    `stat` noticed that and nothing else; a same-length edit passed it
+    too). Content is the signature chain's business
+    (storage/integrity.py, Feed.audit, tools/scrub.py), and the first
+    read of such a log still rescans it (`_ensure_scan`). The
+    single-feed paths (Repo.open, open_if_present, replication) keep
+    `.len` + `stat`. Every mutator of the log's length tells the
+    snapshot (`_tell_heads`), so a told feed is never answered from a
+    sealed entry.
+
     Durability (storage/durability.py HM_FSYNC): tier 2 fsyncs the log
     inside `append` BEFORE the `.len` sidecar describes it; tier 1
     marks this storage dirty with the repo's DurabilityManager, whose
@@ -77,9 +95,10 @@ class FileFeedStorage:
     _HDR = struct.Struct("<I")
     _LEN = struct.Struct("<QQ")  # block count, end offset
 
-    def __init__(self, path: str, durability=None) -> None:
+    def __init__(self, path: str, durability=None, heads=None) -> None:
         self.path = path
         self._durability = durability
+        self._heads = heads  # the feeds root's HeadSnapshot, or None
         self._offsets: List[int] = []
         self._sizes: List[int] = []
         self._end = 0
@@ -144,6 +163,13 @@ class FileFeedStorage:
 
     def _len_path(self) -> str:
         return self.path + ".len"
+
+    def _tell_heads(self) -> None:
+        # the log's length changed (or is about to): the sealed head of
+        # this feed is void for the session and its next snapshot entry
+        # is what this storage knows at close. No I/O, no lock.
+        if self._heads is not None:
+            self._heads.tell(self)
 
     def _write_len(self) -> None:
         # REQUIRES store.feed_io (analysis/guards.py)
@@ -218,6 +244,7 @@ class FileFeedStorage:
     def _append_io_locked(self, data: bytes) -> None:
         # REQUIRES store.feed_io (analysis/guards.py)
         self._ensure_scan()
+        self._tell_heads()  # before the write: a torn one counts too
         tier = fsync_tier()
         # exception safety under mid-write ENOSPC/EIO: the in-memory
         # _offsets/_end/_count only advance AFTER every log byte landed
@@ -322,6 +349,7 @@ class FileFeedStorage:
                     with io_open(self.path, "r+b") as fh:
                         fh.truncate(self._end)
             if write:
+                self._tell_heads()
                 try:
                     self._write_len()
                 except OSError:
@@ -343,6 +371,7 @@ class FileFeedStorage:
             del self._offsets[count:]
             del self._sizes[count:]
             self._count = count
+            self._tell_heads()
             self._drop_write_handles()
             with io_open(self.path, "r+b") as fh:
                 fh.truncate(self._end)
@@ -374,6 +403,7 @@ class FileFeedStorage:
         """Remove the block log (and its .len index) from disk."""
         with self._io:
             self._drop_write_handles()
+            self._tell_heads()
             for p in (self.path, self._len_path()):
                 if os.path.exists(p):
                     io_remove(p)
@@ -395,12 +425,188 @@ def memory_storage_fn(_name: str) -> MemoryFeedStorage:
     return MemoryFeedStorage()
 
 
+class HeadSnapshot:
+    """`<feeds root>/heads.snap`: the head (block count, log end: the
+    two numbers `.len` holds) of every feed this store can vouch for,
+    sealed by a clean close so that a bulk cold open answers "how long
+    is each of these ten thousand logs" from one read instead of an
+    `open` + `read` + `close` of `<feed>.len` and a `stat` of the log
+    per feed. The clean-shutdown checkpoint of a log store: per-feed
+    `.len` stays the per-feed truth (and the write path's only
+    record); this file is valid iff it parses whole AND the session did
+    not start dirty (RepoBackend discards it beside `repo.dirty`,
+    before any doc opens; a session that then skips recovery,
+    HM_RECOVER=0, seals none at its close either). No age, no option.
+
+    Layout: header `(magic, version, entry count, crc32 of the
+    entries)`, then fixed 64-byte entries `(name as the feed's file is
+    named, ASCII, NUL-padded to 48; u64 count; u64 end)`. Read lazily,
+    once, through one numpy table into a name -> (count, end) map.
+
+    During a session every mutator of a log's length tells this object
+    (`FileFeedStorage._tell_heads`), and a bulk open's probes of feeds
+    the snapshot lacks join it (`resolve`): a told or probed feed's
+    next entry is what its storage knows at close (or none, for an
+    empty or destroyed feed). `seal` writes only if that changes an
+    entry: a session that reads a store whose snapshot is complete
+    writes nothing. tell() is one dict store under the GIL: no I/O and
+    no lock on the append path."""
+
+    NAME = "heads.snap"
+    _MAGIC = b"HMHEAD"
+    _VERSION = 1
+    _HDR = struct.Struct("<6sHQI4x")  # magic, version, count, crc32
+    _ENTRY = np.dtype([("name", "S48"), ("count", "<u8"), ("end", "<u8")])
+
+    def __init__(self, root: str) -> None:
+        self.path = os.path.join(root, self.NAME)
+        # "found" (sealed entries trusted), "absent", or "discarded"
+        # (dirty session, or a file that does not parse whole); None
+        # until the file is first looked at
+        self.state: Optional[str] = None
+        # name -> (block count, log end), as the file sealed them
+        self._sealed: Dict[str, Tuple[int, int]] = {}
+        # name -> the storage that knows better than the sealed entry
+        self._fresh: Dict[str, "FileFeedStorage"] = {}
+
+    def _load(self) -> None:
+        if self.state is not None:
+            return
+        try:
+            with open(self.path, "rb") as fh:
+                raw = fh.read()
+        except OSError:
+            self.state = "absent"
+            return
+        self.state = "discarded"
+        if len(raw) < self._HDR.size:
+            return
+        magic, version, n, crc = self._HDR.unpack_from(raw, 0)
+        body = memoryview(raw)[self._HDR.size:]
+        if (
+            magic != self._MAGIC
+            or version != self._VERSION
+            or len(body) != n * self._ENTRY.itemsize
+            or zlib.crc32(body) & 0xFFFFFFFF != crc
+        ):
+            return
+        table = np.frombuffer(body, self._ENTRY)
+        names = [b.decode("ascii", "replace") for b in table["name"].tolist()]
+        self._sealed = dict(
+            zip(names, zip(table["count"].tolist(), table["end"].tolist()))
+        )
+        self.state = "found"
+
+    def status(self) -> str:
+        """What this session made of the file: "found", "discarded" or
+        "absent" (tools/scrub.py reports it)."""
+        self._load()
+        return self.state
+
+    def sealed(self) -> Dict[str, Tuple[int, int]]:
+        """name -> (block count, log end) of every entry the session
+        started with (none unless `status()` is "found")."""
+        self._load()
+        return dict(self._sealed)
+
+    def discard(self) -> None:
+        """The session starts dirty (crash marker, or a journal replay
+        appended to a log): no sealed entry is trusted, and the file
+        goes now, so that no later session can read it either."""
+        self._sealed = {}
+        try:
+            io_remove(self.path)
+            self.state = "discarded"
+        except FileNotFoundError:
+            self.state = "absent"
+
+    def tell(self, storage: "FileFeedStorage") -> None:
+        self._fresh[os.path.basename(storage.path)] = storage
+
+    def resolve(self, storage: "FileFeedStorage") -> bool:
+        """The batched question, one feed of it (the caller holds the
+        feed's lock): a storage that does not know its count yet is
+        primed from the sealed entry (left exactly as
+        `_try_count_shortcut` + `_ensure_count` leave it) and True is
+        returned; a feed the snapshot cannot vouch for is probed as
+        ever, and its answer joins the next snapshot."""
+        self._load()
+        name = os.path.basename(storage.path)
+        entry = self._sealed.get(name)
+        if entry is None or name in self._fresh:
+            len(storage)  # .len + stat, or the scan
+            self._fresh[name] = storage
+            return False
+        storage._count, storage._end = entry
+        storage._init_checked = True
+        return True
+
+    def seal(self) -> bool:
+        """Called by a clean close, after every log and `.len` was
+        flushed (and synced, as the tier says) and BEFORE the crash
+        marker goes: write the snapshot if this session changed an
+        entry (tmp + atomic rename through the fault seam; fsynced at
+        HM_FSYNC>=1, so that it is durable before the marker's removal
+        can be). A failed write leaves no snapshot, never a stale one.
+        False only where a stale file could not be removed either: the
+        caller then keeps the crash marker, and the next open
+        discards."""
+        fresh, self._fresh = self._fresh, {}
+        if not fresh:
+            return True
+        self._load()
+        entries = dict(self._sealed)
+        for name, st in fresh.items():
+            if st._count and name.isascii() and len(name) <= 48:
+                entries[name] = (st._count, st._end)
+            else:  # empty, destroyed, or not a feed's name
+                entries.pop(name, None)
+        if entries == self._sealed:
+            return True
+        table = np.zeros(len(entries), self._ENTRY)
+        table["name"] = [n.encode("ascii") for n in entries]
+        table["count"] = [c for c, _e in entries.values()]
+        table["end"] = [e for _c, e in entries.values()]
+        body = table.tobytes()
+        tmp = self.path + ".tmp"
+        try:
+            with io_open(tmp, "wb") as fh:
+                fh.write(
+                    self._HDR.pack(
+                        self._MAGIC, self._VERSION, len(entries),
+                        zlib.crc32(body) & 0xFFFFFFFF,
+                    )
+                )
+                fh.write(body)
+                fh.flush()
+                if fsync_tier() >= 1:
+                    io_fsync(fh)
+            io_replace(tmp, self.path)
+            if fsync_tier() >= 1:
+                fsync_dir(os.path.dirname(self.path))
+        except OSError as e:
+            log("storage:heads", f"snapshot not sealed {self.path}: {e}")
+            for p in (tmp, self.path):
+                try:
+                    io_remove(p)
+                except FileNotFoundError:
+                    pass
+                except OSError:
+                    return False
+        return True
+
+
 def file_storage_fn(root: str, durability=None) -> StorageFn:
+    heads = HeadSnapshot(root)
+
     def fn(name: str) -> FileFeedStorage:
         return FileFeedStorage(
-            os.path.join(root, name[:2], name), durability=durability
+            os.path.join(root, name[:2], name),
+            durability=durability,
+            heads=heads,
         )
 
+    fn.heads = heads  # FeedStore.heads: the loader asks the store
     return fn
 
 
@@ -706,6 +912,10 @@ class FeedStore:
         self._storage_fn = storage_fn
         self._cache_fn = cache_fn
         self._sig_fn = sig_fn or memory_sig_storage_fn
+        # the feeds root's head snapshot (file-backed stores only)
+        self.heads: Optional[HeadSnapshot] = getattr(
+            storage_fn, "heads", None
+        )
         self._feeds: Dict[str, Feed] = {}
         self._by_discovery: Dict[str, str] = {}
         self._discovery_pending: List[Feed] = []  # ids computed lazily
@@ -762,6 +972,31 @@ class FeedStore:
             if not has_blocks:
                 return None
         return self._open(public_key, None)
+
+    def resolve_heads(self, feeds: List[Feed]) -> Tuple[int, int]:
+        """A bulk open's question, batched: make every feed of `feeds`
+        know its length. A feed the head snapshot vouches for is primed
+        from it (no file is touched); every other one is probed as
+        `Feed.length` always did (`.len` + `stat`, else the scan).
+        Returns (feeds the snapshot answered, feeds probed); a feed
+        that knew its length already, or whose storage keeps no files,
+        counts in neither."""
+        heads = self.heads
+        snap = probed = 0
+        if heads is None:
+            return snap, probed
+        for feed in feeds:
+            with feed._lock:
+                st = feed._storage
+                if not isinstance(st, FileFeedStorage):
+                    continue
+                if st._count is not None:
+                    continue
+                if heads.resolve(st):
+                    snap += 1
+                else:
+                    probed += 1
+        return snap, probed
 
     def _drain_discovery_pending(self) -> None:
         # caller holds the lock

@@ -573,6 +573,11 @@ def recover(back, repair: bool = True) -> Dict:
                 storage.close()
     _M_REPLAYED.add(report["replayed"])
     report["replayed_feeds"] = sorted(replayed_feeds)
+    heads = getattr(back.feeds, "heads", None)
+    if replayed_feeds and heads is not None:
+        # a replay that appended to a log: the session holds no head
+        # snapshot (storage/feed.py HeadSnapshot), as after a crash
+        heads.discard()
     if replay_durable:
         try:
             io_remove(path)  # consumed: a fresh session writes its own

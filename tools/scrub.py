@@ -68,6 +68,10 @@ def main() -> None:
     back = RepoBackend(path=args.repo)
     try:
         report = recover_repo(back, repair=not args.dry_run)
+        # feeds/heads.snap as this session met it: "found" (sealed by a
+        # clean close, trusted), "discarded" (the store was dirty, or
+        # the file does not parse whole) or "absent"
+        report["head_snapshot"] = back.feeds.heads.status()
         if args.audit:
             audits = {}
             for name in sorted(
@@ -100,6 +104,7 @@ def main() -> None:
                 f"clamped "
                 f"({report['t_recover_ms']}ms)"
             )
+            print(f"  head snapshot: {report['head_snapshot']}")
             wal = report.get("wal") or {}
             if wal.get("present"):
                 replayed = wal.get(
