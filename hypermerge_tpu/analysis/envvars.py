@@ -43,34 +43,18 @@ REGISTRY: Tuple[EnvVar, ...] = (
     # -- bulk cold open / pipeline -------------------------------------
     EnvVar("HM_BULK_SLAB", "4096", "Docs per bulk-load slab (the "
            "streaming pipeline's unit of IO/pack/dispatch)."),
-    EnvVar("HM_PIPELINE", None, "Force the streaming pipeline on (1) or "
-           "off (0); unset = auto (on when the native pack drops the "
-           "GIL)."),
-    EnvVar("HM_PIPELINE_DEPTH", "2", "Bounded depth of each pipeline "
-           "stage queue."),
-    EnvVar("HM_FETCH_WORKERS", "4", "Summary-fetch workers (sized to "
-           "device count by the bulk loader)."),
     EnvVar("HM_PACK_WORKERS", "0", "Pack-pool threads for the bulk "
            "pipeline (slab-granular, order-preserving); 0 = auto: "
            "min(4, cores) when the native pack is concurrency-safe, "
            "else 1."),
-    EnvVar("HM_DEVICE_PACK", "0", "Run the fast-path pack as a jitted "
-           "device kernel (ops/pack_kernels.py); falls back native -> "
-           "numpy, bit-identical."),
     EnvVar("HM_FAST_OPEN", "1", "Serve single-doc opens from the "
            "columnar sidecar when possible (0 = full feed replay)."),
     EnvVar("HM_SUMMARY_MEMO_MB", "256", "Byte-bounded LRU of per-doc "
            "summary rows; clean docs skip pack+dispatch+fetch "
            "(0 = disabled)."),
-    EnvVar("HM_ASYNC_SUMMARY_COPY", "1", "Overlap the device->host "
-           "summary copy with the next slab's dispatch."),
     # -- mesh / multi-chip ---------------------------------------------
     EnvVar("HM_MESH", "1", "Multi-device mesh programs (0 = single "
            "device)."),
-    EnvVar("HM_SLAB_RR", "1", "Round-robin whole slabs across devices "
-           "(0 = sharded_full lockstep)."),
-    EnvVar("HM_RR_DEPTH", "2", "Per-device in-flight slab bound of the "
-           "round-robin scheduler."),
     EnvVar("HM_RR_LEAST_LOADED", "0", "Shortest-queue-first slab "
            "placement instead of strict round-robin."),
     EnvVar("HM_ICI_PALLAS", "1", "Pallas async remote-copy gather "

@@ -146,7 +146,7 @@ GUARDS: Tuple[GuardedClass, ...] = (
             "to_frontend", "recovery_report", "_dirty_marker",
             "_col_slab", "_query_handlers", "_gossip",
             "_syncs", "_cache_syncs", "_stores", "_store_debounce",
-            "_gossip_fresh", "live", "serve",
+            "_gossip_fresh", "live", "serve", "loader",
         ),
         unguarded=("network", "file_store", "_file_server", "_closed",
                    "_actor_keys"),
@@ -159,26 +159,22 @@ GUARDS: Tuple[GuardedClass, ...] = (
             "GIL-atomic dict ops, sqlite is the durable truth).",
     ),
     GuardedClass(
-        "RepoBackend(bulk)", "hypermerge_tpu.backend.repo_backend",
+        "BulkLoader", "hypermerge_tpu.backend.bulk_loader",
         "repo.bulk",
         guarded=("_pending_memo", "_bulk_t0", "_bulk_open", "_fetch_ctx",
                  "_summary_memo_bytes"),
         atomic_read_ok=("_summary_memo",),
-        unguarded=(
-            "_pending_summaries", "_rr_cached", "_rr_value",
-            "_mesh_cached", "_mesh_value",
-        ),
+        unguarded=("_pending_summaries",),
         doc="Bulk-load accumulators: one load at a time under "
-            "repo.bulk (the barrier, fetch_bulk_summaries, takes it "
+            "repo.bulk (the barrier, fetch_summaries, takes it "
             "too). `_summary_memo` is read lock-free by pipeline "
             "classify and serve installs (GIL-atomic dict.get); "
-            "`_pending_summaries` is appended by pipeline stage "
-            "threads (GIL-atomic) and swapped whole under repo.bulk "
-            "after the stage barrier joined them; the scheduler/mesh "
-            "caches build once, idempotently, on first use.",
+            "`_pending_summaries` is appended by the dispatching "
+            "thread (GIL-atomic) and swapped whole under repo.bulk "
+            "after the stage barrier joined the workers.",
     ),
     GuardedClass(
-        "RepoBackend(stats)", "hypermerge_tpu.backend.repo_backend",
+        "BulkLoader(stats)", "hypermerge_tpu.backend.bulk_loader",
         "repo.stats",
         atomic_read_ok=("last_bulk_stats",),
         doc="Stage timings accumulate from pipeline worker threads "
@@ -535,10 +531,10 @@ REQUIRES: Dict[Tuple[str, str], str] = {
     ("FileFeedStorage", "_drop_write_handles"): "store.feed_io",
     ("FileFeedStorage", "_write_len"): "store.feed_io",
     ("DocBackend", "_minimum_satisfied"): "doc",
-    ("RepoBackend", "_load_documents_bulk_locked"): "repo.bulk",
-    ("RepoBackend", "_load_slabs_serial"): "repo.bulk",
-    ("RepoBackend", "_load_slabs_pipelined"): "repo.bulk",
-    ("RepoBackend", "_memoize_summaries"): "repo.bulk",
+    ("BulkLoader", "_load_locked"): "repo.bulk",
+    ("BulkLoader", "_load_slabs"): "repo.bulk",
+    ("BulkLoader", "_memoize_summaries"): "repo.bulk",
+    ("BulkLoader", "_settle_fetch"): "repo.bulk",
     ("ResidencyCache", "_note_evicted"): "serve.cache",
     ("FeedColumnCache", "_ensure_loaded"): "store.colcache",
     ("FeedColumnCache", "_set_loaded"): "store.colcache",

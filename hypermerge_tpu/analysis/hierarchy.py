@@ -77,7 +77,7 @@ LOCK_CLASSES: Tuple[LockClass, ...] = (
     # -- ranked core (the documented hierarchy) -------------------------
     LockClass(
         "repo.bulk", 5,
-        "RepoBackend._bulk_mutex — serializes whole bulk loads; held "
+        "BulkLoader._mutex — serializes whole bulk loads; held "
         "across ready-notifies that may take a doc's emission domain, "
         "so it is the outermost lock in the process.",
     ),
@@ -131,7 +131,7 @@ LOCK_CLASSES: Tuple[LockClass, ...] = (
     ),
     LockClass(
         "repo.stats", 40,
-        "RepoBackend._stats_lock — bulk-load stage timing "
+        "BulkLoader._stats_lock — bulk-load stage timing "
         "accumulators (pipeline worker threads).",
     ),
     LockClass(

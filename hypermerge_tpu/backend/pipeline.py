@@ -1,18 +1,16 @@
 """Streaming slab pipeline — overlap IO → pack → dispatch → fetch.
 
-The serial bulk loader pays a cold open as the SUM of its per-slab
-stage costs: sidecar IO, spec, pack, upload/dispatch, and the summary
-fetch each finish completely before the next begins (BENCH_r05: 9.45s
-= 0.37 sql + 1.99 io + 0.21 spec + 2.96 pack + ~0.1 wire + 2.68 fetch
-+ 1.14 other). But the stages are independent per slab: slab N+1's
-sidecar reads and native pack need nothing from slab N beyond host
-buffers, and slab N's device work needs nothing from the host at all.
-This module is the classic software-pipelining / double-buffering move
-from accelerator input pipelines: four stages connected by small
-BOUNDED queues so the cold open costs ~max(stage) instead of
-sum(stages), with at most `HM_PIPELINE_DEPTH` (default 2) slabs of
-host staging alive per seam — double buffering, not an unbounded
-backlog.
+Run one stage after another and a cold open costs the SUM of its
+per-slab stage costs: sidecar IO, spec, pack, upload/dispatch, and the
+summary fetch (BENCH_r05: 9.45s = 0.37 sql + 1.99 io + 0.21 spec +
+2.96 pack + ~0.1 wire + 2.68 fetch + 1.14 other). But the stages are
+independent per slab: slab N+1's sidecar reads and pack need nothing
+from slab N beyond host buffers, and slab N's device work needs nothing
+from the host at all. This module is the classic software-pipelining /
+double-buffering move from accelerator input pipelines: four stages
+connected by small BOUNDED queues so the cold open costs ~max(stage)
+instead of sum(stages), with at most `QUEUE_DEPTH` slabs of host
+staging alive per seam — double buffering, not an unbounded backlog.
 
     io/spec thread:   slab read-ahead: feed opens, then the chunk's
                       column sidecars in one pass over the corpus
@@ -22,9 +20,7 @@ backlog.
                       head lookups, so it runs on this one thread: a
                       pool only took the GIL from the pack worker.
                       Then per-doc feed specs, emitted as slab-sized
-                      entry groups — composition IDENTICAL to the
-                      serial loader's chunks, so summaries are
-                      bit-identical.
+                      entry groups in doc order.
     pack pool:        pack_docs_columns on HM_PACK_WORKERS threads —
                       the native hm_pack_prefix call is bound through
                       ctypes.CDLL and therefore RELEASES the GIL
@@ -33,20 +29,21 @@ backlog.
                       Sharding is slab-granular and the emit into the
                       dispatch queue is SEQUENCED (a turn counter under
                       the pipeline.pack_pool condition), so slab order
-                      and bytes stay identical to the single-worker
-                      and serial twins no matter which worker finishes
-                      first. Per-worker busy seconds are kept apart
+                      and bytes stay identical to a single worker's
+                      no matter which worker finishes first. Per-worker
+                      busy seconds are kept apart
                       (pack_busy[w]) so busy-vs-wall accounting stays
                       honest — the SUM of pack busy can exceed the
                       load's wall once packs genuinely overlap.
     caller thread:    async device upload + dispatch (round-robin
                       across visible devices via parallel/sharded.py
-                      SlabRoundRobin, mesh-sharded, or single-device)
-                      plus deferred doc init; never blocks on results.
+                      SlabRoundRobin, or the one device) plus deferred
+                      doc init; never blocks on results.
     fetch workers:    summary wire transfer + host parse for slab N
                       overlapped with slab N+1's pack; with >1 device
-                      one worker per chip (bounded, HM_FETCH_WORKERS)
-                      so fetches overlap ACROSS chips too. The
+                      one worker per chip (bounded, bulk_loader.
+                      FETCH_WORKERS) so fetches overlap ACROSS chips
+                      too. The
                       materialization barrier (fetch_bulk_summaries)
                       joins them and finds host arrays.
 
@@ -54,8 +51,7 @@ Failure contract: any stage raising aborts the whole pipeline — every
 queue drains, every worker joins (bounded), device refs drop, and the
 caller sees one PipelineError carrying the original exception. A fetch
 failure after the load returned surfaces at the barrier via
-FetchContext.join. The serial path stays available behind
-HM_PIPELINE=0 as the correctness twin.
+FetchContext.join.
 """
 
 from __future__ import annotations
@@ -120,28 +116,8 @@ class _Abort(Exception):
 _DONE = object()
 _POLL_S = 0.05
 _JOIN_S = 120.0
-
-
-def pipeline_enabled() -> bool:
-    """Pipeline gate. Explicit HM_PIPELINE=0/1 always wins; the unset
-    default enables the pipeline only when the native GIL-dropping
-    pack is actually loadable (HM_NATIVE_PACK not disabled). With the
-    pure-numpy pack fallback, the pack worker holds the GIL for long
-    stretches and starves the dispatch feeder on a small host — the
-    r5 measurement that kept packing serial — so that configuration
-    stays on the serial twin unless forced."""
-    v = os.environ.get("HM_PIPELINE")
-    if v is not None:
-        return v != "0"
-    if os.environ.get("HM_NATIVE_PACK", "1") == "0":
-        return False
-    from .. import native
-
-    return native.pack_drops_gil()
-
-
-def queue_depth() -> int:
-    return max(1, int(os.environ.get("HM_PIPELINE_DEPTH", "2")))
+# bounded depth of each stage queue (the fetch seam holds twice it)
+QUEUE_DEPTH = 2
 
 
 def pack_worker_count() -> int:
@@ -165,7 +141,7 @@ class FetchContext:
     """Handle on the async fetch stage (one or more workers — with >1
     device the fetch overlaps ACROSS chips: each worker can be pulling
     a different chip's wire concurrently). The barrier
-    (RepoBackend.fetch_bulk_summaries) joins it before decoding; a
+    (BulkLoader.fetch_summaries) joins it before decoding; a
     fetch error recorded during the overlap window re-raises there."""
 
     def __init__(self) -> None:
@@ -185,14 +161,12 @@ class FetchContext:
 
 class SlabPipeline:
     """One bulk load's stage executor. All callables are supplied by
-    RepoBackend (which owns locks, stats, and device handles):
+    the BulkLoader (which owns locks, stats, and device handles):
 
       prefetch(doc_chunk)      read-ahead actors + sidecar columns
       classify(doc)            -> ("entry", e) | ("memo", (e, m))
                                   | ("fallback", doc)
-      pack(entries, seq)       -> ColumnarBatch (seq = slab index in
-                                  doc order — the device-pack path
-                                  uses it for per-chip placement)
+      pack(entries)            -> ColumnarBatch
       dispatch(seq, entries, batch) -> pending summary entry (runs on
                                   the CALLER thread — device dispatch
                                   and doc init stay single-threaded)
@@ -202,7 +176,7 @@ class SlabPipeline:
                                   stats (t_io, t_spec, t_pack)
 
     io, spec and pack are timed here (`Stage`); dispatch and fetch time
-    themselves in the backend, which keeps per-chip books from the
+    themselves in the loader, which keeps per-chip books from the
     same readings. Every span carries `open=open_id` and its `slab`;
     each blocking queue / turn wait is a `pipeline.wait` span.
     """
@@ -213,7 +187,7 @@ class SlabPipeline:
         *,
         prefetch: Callable[[List[Any]], None],
         classify: Callable[[Any], Tuple[str, Any]],
-        pack: Callable[[List[Any], int], Any],
+        pack: Callable[[List[Any]], Any],
         dispatch: Callable[[int, List[Any], Any], Any],
         fetch: Callable[[int, Any], None],
         stat: Callable[[str, float], None],
@@ -233,7 +207,7 @@ class SlabPipeline:
         self.slab = max(1, int(slab))
         self.fetch_workers = max(1, int(fetch_workers))
         self.pack_workers = max(1, int(pack_workers))
-        depth = queue_depth()
+        depth = QUEUE_DEPTH
         self.pack_q: "queue.Queue" = queue.Queue(maxsize=depth)
         self.disp_q: "queue.Queue" = queue.Queue(maxsize=depth)
         self.fetch_q: "queue.Queue" = queue.Queue(maxsize=2 * depth)
@@ -260,7 +234,7 @@ class SlabPipeline:
         # slabs are packed CONCURRENTLY but emitted into disp_q in slab
         # order: a worker holding packed slab `seq` waits its turn on
         # the pack_pool condition, so downstream (dispatch, fetch, doc
-        # init) sees the exact slab stream the serial twin produces.
+        # init) sees the slab stream one pack thread would produce.
         self._pack_cv = make_condition("pipeline.pack_pool")
         self._pack_turn = 0         # next slab seq allowed to emit
         self._pack_eof_claimed = False  # one worker forwards _DONE
@@ -327,8 +301,7 @@ class SlabPipeline:
 
     def _io_loop(self) -> None:
         """Read-ahead + spec: emits slab-sized entry groups in doc
-        order — exactly the chunks the serial loader would form, so
-        pipeline and serial materialize bit-identical slabs. The io and
+        order. The io and
         spec spans carry the doc chunk's index as `slab` (the emitted
         slab's seq unless memo hits or fallbacks thinned the stream)."""
         try:
@@ -431,7 +404,7 @@ class SlabPipeline:
                     "pipeline.pack", self.stat, "t_pack", "pack",
                     open=self.open_id, slab=seq, parent="pipeline.spec",
                 ) as sp:
-                    packed = self.pack(entries, seq)
+                    packed = self.pack(entries)
                 self.pack_busy[widx] += sp.dur
                 if self.pack_t0[widx] is None:
                     self.pack_t0[widx] = sp.t0

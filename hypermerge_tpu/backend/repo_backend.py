@@ -6,29 +6,27 @@ frontend is JSON dicts (msgs.py), so the frontend can live on another
 thread/process, and the batched XLA path can slot in behind the same seam
 (SURVEY.md §7.1).
 
-Bulk cold-start: `load_documents_bulk` packs many docs' feeds into one
-columnar batch and materializes them in a single device dispatch
-(ops/materialize.py) — the reference's per-doc loadDocument loop
-(src/RepoBackend.ts:238-257) becomes one XLA program.
+Bulk cold-start: `load_documents_bulk` hands many docs to the bulk
+loader (backend/bulk_loader.py), which packs their feeds into columnar
+slabs and materializes each in one device dispatch — the reference's
+per-doc loadDocument loop (src/RepoBackend.ts:238-257) becomes one XLA
+program a slab.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 import os
-import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
-from ..analysis.lockdep import make_lock, make_rlock, maybe_install_racedep
+from ..analysis.lockdep import make_rlock, maybe_install_racedep
 from .. import msgs
 from ..crdt import clock as clockmod
 from ..crdt.change import Change, ChangeRequest
 from ..crdt.opset import OpSet
 from ..storage.colcache import (
     file_column_storage_fn,
-    load_slab_images,
     memory_column_storage_fn,
 )
 from ..storage.feed import (
@@ -50,21 +48,9 @@ from .. import telemetry
 from ..utils.queue import Queue
 from ..files.file_store import FileStore
 from .actor import Actor
+from .bulk_loader import BulkLoader
 from .doc_backend import DocBackend
 from .metadata import Metadata
-from .pipeline import Stage
-
-# device->host summary-wire transfer bytes (same series sharded.py's
-# collective gather feeds; handle cached — one per-slab bump)
-_M_D2H = telemetry.counter("mesh.d2h_bytes")
-# column sidecars by loader: slab-granular (one pass over cols.slab) or
-# feed by feed (_prefetch_columns)
-_M_COLS_BULK = telemetry.counter("loader.cols_bulk_feeds")
-_M_COLS_SINGLE = telemetry.counter("loader.cols_single_feeds")
-# feed heads of a bulk open by who answered: the store's head snapshot
-# (storage/feed.py HeadSnapshot) or a probe of the feed's files
-_M_HEADS_SNAP = telemetry.counter("loader.heads_snapshot_feeds")
-_M_HEADS_PROBED = telemetry.counter("loader.heads_probed_feeds")
 
 
 # actor id -> discovery id is a pure hash of an immutable key: memoize
@@ -255,37 +241,12 @@ class RepoBackend:
         self.file_store: Optional[FileStore] = None
         self._file_server = None
         self._closed = False
-        # bulk-load state: deferred per-actor work (one executemany / one
-        # resync instead of per-feed sqlite + sync queries), and the
-        # device summary refs the materialization barrier fetches
+        # deferred per-actor work of a bulk load in flight (one
+        # executemany / one resync instead of per-feed sqlite + sync
+        # queries); None outside one
         self._bulk_deferred_syncs: Optional[set] = None
         self._bulk_feed_rows: Optional[List] = None
-        self._bulk_mutex = make_lock("repo.bulk")  # serializes bulk loads:
-        # the deferral accumulators above are per-load state
-        self._pending_summaries: List = []
-        self._pending_memo: List = []
-        # streaming-pipeline state: stage threads add stage timings
-        # concurrently, and the async fetch worker of the most recent
-        # load is joined by the materialization barrier
-        self._stats_lock = make_lock("repo.stats")
-        self._fetch_ctx = None
-        self._bulk_t0: Optional[float] = None
-        self._bulk_open = 0  # request id of the latest load's spans
-        self._rr_cached = False  # round-robin scheduler, built lazily
-        self._rr_value = None
-        # per-doc summary memo: doc_id -> last fetched summary row + the
-        # clock it was fetched at. A later bulk load of a doc whose
-        # clock has not moved (the same clock rows the device-resident
-        # ClockStore mirror tracks) is CLEAN: it skips pack, dispatch,
-        # and the summary transfer entirely — only dirty docs ride the
-        # wire. Bounded LRU by BYTES (HM_SUMMARY_MEMO_MB, 0 disables) —
-        # entries scale with the doc's row bucket, so an entry-count cap
-        # would let large buckets pin gigabytes.
-        from collections import OrderedDict
-
-        self._summary_memo: "OrderedDict[str, Dict]" = OrderedDict()
-        self._summary_memo_bytes = 0
-        self.last_bulk_stats: Dict[str, int] = {}
+        self.loader = BulkLoader(self)
         # cursor/clock gossip is a latest-state broadcast: debounce it
         # so a burst of local changes to one doc costs one frame
         from ..utils.debounce import Debouncer
@@ -746,515 +707,26 @@ class RepoBackend:
         return True
 
     def load_documents_bulk(
-        self, doc_ids: List[str], slab: Optional[int] = None,
-        pad_docs: Optional[int] = None, pad_rows: Optional[int] = None,
+        self, doc_ids: List[str], slab: Optional[int] = None
     ) -> None:
-        """Cold-start many docs with zero per-op host work (the north
-        star, BASELINE config 4): each doc's feed windows come from the
-        columnar sidecars (storage/colcache.py), pack vectorized
-        (ops/columnar.py pack_docs_columns), and materialize in slab-sized
-        device dispatches. Docs come up ready with host-verified clocks
-        and lazily-decoded snapshot patches; the host OpSet reconstructs
-        only when a doc takes its first incremental change
-        (DocBackend.init_deferred). Contrast the reference's per-doc
-        loadDocument replay loop (src/RepoBackend.ts:238-257).
+        """Cold-start many docs in slab-sized device dispatches
+        (backend/bulk_loader.py BulkLoader.load); the materialization
+        barrier is `fetch_bulk_summaries`."""
+        self.loader.load(doc_ids, slab)
 
-        Host-side work is batched, not per-doc: one cursor upsert + one
-        SELECT for all docs, one feed-registry executemany, one clock
-        executemany, sidecar loads a slab at a time, and per-actor syncs
-        deferred to a single pass at the end. Device dispatches are async — the
-        materialization barrier is `fetch_bulk_summaries`.
+    def fetch_bulk_summaries(self) -> "BulkSummaries":
+        """The materialization barrier of the preceding bulk load
+        (BulkLoader.fetch_summaries)."""
+        return self.loader.fetch_summaries()
 
-        `pad_docs`/`pad_rows` override the slab's jit bucket (benchmarks
-        prime a [4096, N] executable with a small load)."""
-        # the open's request id: the facade's (Repo.open_many is the
-        # root span) or, called directly, the next of the sequence
-        open_id = telemetry.open_id()
-        if slab is None:
-            slab = int(os.environ.get("HM_BULK_SLAB", "4096"))
-        with telemetry.span(
-            "pipeline.bulk_load", "pipeline", open=open_id,
-            docs=len(doc_ids),
-        ):
-            with self._bulk_mutex:  # concurrent open_many calls serialize
-                self._bulk_open = open_id
-                self._load_documents_bulk_locked(
-                    doc_ids, slab, pad_docs, pad_rows
-                )
+    @property
+    def last_bulk_stats(self) -> Dict[str, Any]:
+        """The latest bulk load's counts and stage seconds."""
+        return self.loader.last_bulk_stats
 
-    def _load_documents_bulk_locked(
-        self, doc_ids, slab, pad_docs, pad_rows
-    ) -> None:
-        from ..ops.columnar import pack_docs_columns
-        from ..ops.materialize import DecodedBatch, decode_patch
-        from .pipeline import pipeline_enabled
-
-        # summaries are for the latest load: drop refs nobody fetched so
-        # repeated open_many calls can't pin old slabs' host+device memory
-        self._pending_summaries = []
-        self._pending_memo = []
-        stale = self._fetch_ctx
-        self._fetch_ctx = None
-        if stale is not None:
-            # nobody ran the barrier for the previous load: settle its
-            # fetch worker before dispatching a new pipeline (and don't
-            # let a fetch error vanish with the discarded context)
-            try:
-                stale.join()
-            except Exception as e:
-                log("repo:backend", f"unfetched bulk load's fetch: {e}")
-
-        now = time.perf_counter
-        self._bulk_t0 = now()
-        pipelined = pipeline_enabled()
-
-        # -- phase 1: register docs + one bulk cursor upsert/select -----
-        new_docs: List[DocBackend] = []
-        already_ready: List[str] = []  # open docs: frontend may re-read
-        with Stage("pipeline.register", docs=len(doc_ids)) as register:
-            with self._lock:
-                for doc_id in doc_ids:
-                    existing = self.docs.get(doc_id)
-                    if existing is not None:
-                        if existing._announced:
-                            already_ready.append(doc_id)
-                        continue
-                    doc = DocBackend(
-                        doc_id, self._doc_notify, None, live=self.live
-                    )
-                    self.docs[doc_id] = doc
-                    new_docs.append(doc)
-            # docs closed with store rows still in the debouncer must
-            # not bulk-reload from the stale rows (same guard as
-            # open/destroy)
-            self._settle_store_rows({d.id for d in new_docs})
-            with self.db.bulk():
-                self.cursors.add_actors(
-                    self.id,
-                    [(d.id, root_actor_id(d.id)) for d in new_docs],
-                )
-            cursor_map = self.cursors.get_multiple(
-                self.id, [d.id for d in new_docs]
-            )
-        # stage breakdown (seconds; VERDICT r5 item 1). Serial mode:
-        # each stage's wall time (they run back-to-back, so they sum to
-        # the wall clock). Pipeline mode: each stage's BUSY time — the
-        # stages overlap, so the wall clock is `wall_critical_path`,
-        # ~max(stage) rather than sum(stages). t_fetch lands when the
-        # materialization barrier runs.
-        # rebinding the stats dict holds repo.stats (guard manifest,
-        # analysis/guards.py): stage threads _stat_add concurrently
-        # once the load streams, and bench/tools read the dict after
-        with self._stats_lock:
-            self.last_bulk_stats = {
-                "docs": len(new_docs),
-                "fast": 0,
-                "memo": 0,
-                "fallback": 0,
-                "pipeline": 1 if pipelined else 0,
-                # which kernel ran each slab: the device program or
-                # the numpy twin below HM_DEVICE_MIN_CELLS — and on
-                # which platform the device slabs ran (None: none did)
-                "device_slabs": 0,
-                "host_slabs": 0,
-                "platform": None,
-                "pack_workers": 0,  # serial twin: pack inline, no pool
-                # column sidecars loaded slab-granular / feed by feed
-                # (_prefetch_columns); the share lands after the load
-                "cols_bulk_feeds": 0,
-                "cols_single_feeds": 0,
-                "cols_bulk_pct": 0.0,
-                # cold feeds whose head the store's snapshot answered /
-                # whose files were probed, and the snapshot's share
-                "heads_snapshot_feeds": 0,
-                "heads_probed_feeds": 0,
-                "heads_snapshot_pct": 0.0,
-                # feeds the open read; docs whose slab took the general
-                # (multi-writer) pack and their share; the widest actor
-                # and pred buckets among the slabs' programs
-                "feeds": 0,
-                "pack_general_docs": 0,
-                "pack_general_pct": 0.0,
-                "a_loc_max": 0,
-                "pred_max": 0,
-                "t_sql": round(register.dur, 3),
-                "t_io": 0.0,
-                "t_spec": 0.0,
-                "t_pack": 0.0,
-                "t_narrow": 0.0,
-                "t_upload": 0.0,
-                "t_dispatch": 0.0,
-            }
-
-        ready_ids: List[str] = []
-        clock_rows: Dict[str, Dict[str, int]] = {}
-        self._begin_bulk_actors()
-        try:
-            # -- phases 2-4: io -> spec -> pack -> dispatch, streamed
-            # per slab (pipeline) or strictly staged (serial twin) -----
-            load = (
-                self._load_slabs_pipelined
-                if pipelined
-                else self._load_slabs_serial
-            )
-            memo_hits, fallback_docs = load(
-                new_docs, cursor_map, slab, pack_docs_columns,
-                DecodedBatch, decode_patch, ready_ids, clock_rows,
-                pad_docs, pad_rows,
-            )
-            stats = self.last_bulk_stats
-            cols = stats["cols_bulk_feeds"] + stats["cols_single_feeds"]
-            if cols:
-                stats["cols_bulk_pct"] = round(
-                    100.0 * stats["cols_bulk_feeds"] / cols, 3
-                )
-            asked = stats["heads_snapshot_feeds"] + stats["heads_probed_feeds"]
-            if asked:
-                stats["heads_snapshot_pct"] = round(
-                    100.0 * stats["heads_snapshot_feeds"] / asked, 3
-                )
-            if new_docs:
-                stats["pack_general_pct"] = round(
-                    100.0 * stats["pack_general_docs"] / len(new_docs), 3
-                )
-            stats["memo"] = len(memo_hits)
-            stats["fallback"] = len(fallback_docs)
-            stats["fast"] = len(new_docs) - len(fallback_docs)
-            for (doc, spec, clock, n_changes, actor_ids), m in memo_hits:
-                self._init_bulk_doc(
-                    doc, clock, n_changes, actor_ids,
-                    self._doc_snapshot_fn(spec, clock),
-                    ready_ids, clock_rows,
-                )
-                self._pending_memo.append((doc.id, m))
-            with Stage(
-                "pipeline.clock_rows", self._stat_add, "t_sql",
-                docs=len(clock_rows),
-            ):
-                with self.db.bulk():
-                    self.clocks.update_many(self.id, clock_rows)
-            for doc in fallback_docs:
-                self._load_document(doc)
-            if fallback_docs:
-                log(
-                    "repo:backend",
-                    f"bulk load: {len(fallback_docs)}/{len(new_docs)} "
-                    "docs fell back to per-op host replay "
-                    "(non-contiguous feed seqs)",
-                )
-        except Exception:
-            # a failed load must not pin device refs, leave the fetch
-            # worker running unjoined, or hand the barrier a
-            # half-fetched pending list. (A failure AFTER pipe.run —
-            # clock write, fallback replay — still has a live fetch
-            # worker; join it so no hm-pipe thread outlives the load
-            # and any fetch error isn't silently dropped with it.)
-            ctx = self._fetch_ctx
-            self._pending_summaries = []
-            self._pending_memo = []
-            self._fetch_ctx = None
-            self._bulk_t0 = None  # a later barrier must not stamp
-            # wall_critical_path with this dead load's idle time
-            if ctx is not None:
-                try:
-                    ctx.join()
-                except Exception:
-                    pass  # the load's own error is the one to raise
-            raise
-        finally:
-            with telemetry.span("pipeline.actors_flush", "pipeline"):
-                self._end_bulk_actors()
-        if pipelined:
-            # busy aliases: explicit names for consumers (bench JSON)
-            # that want both views without knowing the mode
-            with self._stats_lock:
-                for k in (
-                    "t_io", "t_spec", "t_pack", "t_narrow", "t_upload",
-                    "t_dispatch",
-                ):
-                    self.last_bulk_stats[k + "_busy"] = (
-                        self.last_bulk_stats.get(k, 0.0)
-                    )
-        # provisional: the barrier extends this through the fetch
-        with self._stats_lock:
-            self.last_bulk_stats["wall_critical_path"] = round(
-                now() - self._bulk_t0, 3
-            )
-        ready_ids.extend(already_ready)
-        if ready_ids:
-            with telemetry.span(
-                "pipeline.notify", "pipeline", docs=len(ready_ids)
-            ):
-                self.to_frontend.push(msgs.bulk_ready_msg(ready_ids))
-
-    def _stat_add(self, key: str, dt: float, stats=None) -> None:
-        """Accumulate a stage's seconds into last_bulk_stats (pipeline
-        stage threads add concurrently), or into the `stats` dict a
-        stage captured when its load began. Microsecond precision:
-        rounding each addition to ms would floor a short stage to 0."""
-        with self._stats_lock:
-            s = self.last_bulk_stats if stats is None else stats
-            s[key] = round(s.get(key, 0.0) + dt, 6)
-
-    def _open_feeds(self, docs, cursor_map) -> None:
-        """The io stage of `docs`: open every cursor actor's feed, then
-        load the actors' column sidecars (both twins call this)."""
-        needed = self._collect_cursor_actors(docs, cursor_map)
-        with telemetry.span(
-            "storage.feeds.open", "storage", feeds=len(needed)
-        ):
-            actors = [self._get_or_create_actor(a) for a in needed]
-        with telemetry.span(
-            "storage.columns.load", "storage", feeds=len(actors)
-        ) as sp:
-            bulk, single = self._prefetch_columns(actors)
-            sp.note(bulk=bulk)
-        _M_COLS_BULK.add(bulk)
-        _M_COLS_SINGLE.add(single)
-        with self._stats_lock:
-            stats = self.last_bulk_stats
-            stats["feeds"] += len(needed)
-            stats["cols_bulk_feeds"] += bulk
-            stats["cols_single_feeds"] += single
-
-    def _collect_cursor_actors(self, docs, cursor_map) -> List[str]:
-        needed: List[str] = []
-        seen: set = set()
-        for d in docs:
-            for actor_id in cursor_map[d.id]:
-                if actor_id not in seen:
-                    seen.add(actor_id)
-                    needed.append(actor_id)
-        return needed
-
-    def _load_slabs_serial(
-        self, new_docs, cursor_map, slab, pack_docs_columns,
-        DecodedBatch, decode_patch, ready_ids, clock_rows,
-        pad_docs, pad_rows,
-    ):
-        """The correctness twin (HM_PIPELINE=0): every stage finishes
-        for ALL docs before the next begins — wall clock = sum(stages).
-        Returns (memo_hits, fallback_docs)."""
-        # -- phase 2: open every cursor actor, per-feed work deferred ---
-        with Stage("pipeline.io", self._stat_add, "t_io"):
-            self._open_feeds(new_docs, cursor_map)
-
-        # -- phase 3: per-doc feed specs --------------------------------
-        entries = []  # (doc, spec, clock, n_changes, actor_ids)
-        contiguous: Dict[str, bool] = {}
-        fallback_docs: List[DocBackend] = []
-        with Stage(
-            "pipeline.spec", self._stat_add, "t_spec", docs=len(new_docs)
-        ):
-            for doc in new_docs:
-                spec, clock, n_changes, actor_ids, ok = (
-                    self._doc_feed_spec(
-                        doc.id, contiguous, cursor_map[doc.id]
-                    )
-                )
-                if not ok:
-                    fallback_docs.append(doc)
-                    continue
-                if n_changes == 0:
-                    self._gate_unknown_empty(doc)
-                entries.append((doc, spec, clock, n_changes, actor_ids))
-
-        # -- phase 3.5: clean docs (summary memo holds a row fetched
-        # at this exact clock) skip pack/dispatch/transfer --------------
-        memo_hits = []
-        if self._summary_memo:
-            fresh = []
-            for e in entries:
-                m = self._summary_memo.get(e[0].id)
-                if m is not None and m["clock"] == e[2]:
-                    memo_hits.append((e, m))
-                else:
-                    fresh.append(e)
-            entries = fresh
-
-        # -- phase 4: slab dispatches -----------------------------------
-        self._load_slabs(
-            entries, slab, pack_docs_columns, DecodedBatch,
-            decode_patch, ready_ids, clock_rows, pad_docs, pad_rows,
-        )
-        return memo_hits, fallback_docs
-
-    def _load_slabs_pipelined(
-        self, new_docs, cursor_map, slab, pack_docs_columns,
-        DecodedBatch, decode_patch, ready_ids, clock_rows,
-        pad_docs, pad_rows,
-    ):
-        """Streamed phases 2-4: slab N+1's sidecar IO and native pack
-        proceed while slab N is on-device and slab N-1's summary is in
-        flight to host (backend/pipeline.py). Entry-group composition
-        matches the serial twin exactly (slab-sized chunks of the
-        post-memo-filter entry stream, in doc order), so both paths
-        produce bit-identical summaries."""
-        from ..ops.columnar import round_up_pow2
-        from .pipeline import (
-            FetchContext,
-            SlabPipeline,
-            pack_worker_count,
-        )
-
-        contiguous: Dict[str, bool] = {}
-        open_id = self._bulk_open
-
-        # the stages time themselves (pipeline.Stage: one clock pair
-        # per stage feeds span, stat and counter); these closures only
-        # do the work
-
-        def prefetch(doc_chunk):
-            self._open_feeds(doc_chunk, cursor_map)
-
-        def classify(doc):
-            spec, clock, n_changes, actor_ids, ok = self._doc_feed_spec(
-                doc.id, contiguous, cursor_map[doc.id]
-            )
-            if not ok:
-                return ("fallback", doc)
-            if n_changes == 0:
-                self._gate_unknown_empty(doc)
-            e = (doc, spec, clock, n_changes, actor_ids)
-            m = self._summary_memo.get(doc.id)
-            if m is not None and m["clock"] == clock:
-                return ("memo", (e, m))
-            return ("entry", e)
-
-        def pack(chunk, seq):
-            # rr / rr_cursor0 bind below, before the pipeline runs.
-            # The device hint places a device pack (HM_DEVICE_PACK=1)
-            # on the chip strict round-robin will dispatch slab `seq`
-            # to, so the packed columns never cross chips; host packs
-            # ignore it. Runs on a pack-pool worker (HM_PACK_WORKERS).
-            return pack_docs_columns(
-                [e[1] for e in chunk],
-                n_docs=pad_docs or round_up_pow2(len(chunk)),
-                n_rows=pad_rows,
-                device=(
-                    rr.pack_device_for(seq, rr_cursor0)
-                    if rr is not None
-                    else None
-                ),
-            )
-
-        def dispatch(seq, chunk, batch):
-            return self._dispatch_slab(
-                seq, chunk, batch, DecodedBatch, decode_patch,
-                ready_ids, clock_rows,
-            )
-
-        stats = self.last_bulk_stats  # captured: the fetch worker can
-        # outlive this load; its timings belong to THIS load's stats
-
-        # mesh-aware accounting: the scheduler (built here, before any
-        # dispatch, so the fetch stage can size itself) accumulates
-        # per-chip dispatch busy time across loads — snapshot now, diff
-        # after the run, so the stats carry THIS load's per-chip times
-        rr = self._slab_rr()
-        disp0 = list(rr.t_dispatch_chip) if rr is not None else None
-        slabs0 = list(rr.slabs_per_chip) if rr is not None else None
-        # round-robin cursor snapshot: with strict round-robin the chip
-        # for slab seq is fully determined by (cursor at load start +
-        # seq), so pack workers can place device packs ahead of dispatch
-        rr_cursor0 = rr.cursor() if rr is not None else 0
-
-        def fetch(seq, entry):
-            wire = entry[3]
-            with Stage(
-                "pipeline.fetch", busy="fetch", open=open_id, slab=seq,
-                parent="pipeline.dispatch",
-            ) as sp:
-                self._fetch_slab(entry)
-            dt = sp.dur
-            chip = None
-            if rr is not None and hasattr(wire, "devices"):
-                try:
-                    chip = rr.device_index(next(iter(wire.devices())))
-                except Exception:  # non-jax wire / foreign device
-                    chip = None
-            with self._stats_lock:
-                stats["t_fetch_busy"] = round(
-                    stats.get("t_fetch_busy", 0.0) + dt, 6
-                )
-                if chip is not None:
-                    per = stats.setdefault(
-                        "t_fetch_chips", [0.0] * len(rr.devices)
-                    )
-                    per[chip] = round(per[chip] + dt, 6)
-
-        # fetch overlaps across chips: one worker per device (bounded —
-        # each worker is host-side parse + one transfer at a time)
-        workers = 1
-        if rr is not None:
-            workers = max(
-                1,
-                min(
-                    len(rr.devices),
-                    int(os.environ.get("HM_FETCH_WORKERS", "4")),
-                ),
-            )
-        pipe = SlabPipeline(
-            new_docs,
-            prefetch=prefetch,
-            classify=classify,
-            pack=pack,
-            dispatch=dispatch,
-            fetch=fetch,
-            stat=lambda key, dt: self._stat_add(key, dt, stats),
-            slab=slab,
-            fetch_workers=workers,
-            pack_workers=pack_worker_count(),
-            open_id=open_id,
-        )
-        ctx = FetchContext()
-        try:
-            memo_hits, fallbacks = pipe.run(ctx)
-        finally:
-            if self._rr_value is not None:
-                # dispatching done: drop backpressure refs
-                self._rr_value.release()
-        with self._stats_lock:
-            # pool shape + per-worker busy lanes: sum(busy) can exceed
-            # the wall once packs overlap — a trace draws one lane per
-            # worker and bench computes speedup = sum(busy)/wall
-            stats["pack_workers"] = pipe.pack_workers
-            stats["t_pack_busy_per_worker"] = [
-                round(b, 6) for b in pipe.pack_busy
-            ]
-            stats["t_pack_wall"] = round(pipe.pack_wall(), 6)
-        if rr is not None:
-            with self._stats_lock:
-                stats["t_dispatch_chips"] = [
-                    round(b - a, 6)
-                    for a, b in zip(disp0, rr.t_dispatch_chip)
-                ]
-                stats["slabs_per_chip"] = [
-                    b - a for a, b in zip(slabs0, rr.slabs_per_chip)
-                ]
-        self._fetch_ctx = ctx
-        return memo_hits, fallbacks
-
-    def _fetch_slab(self, entry) -> None:
-        """Transfer + parse one slab's summary wire (the fetch stage:
-        runs on the pipeline's fetch worker so the barrier finds host
-        arrays already decoded; idempotent for host-kernel slabs).
-
-        This runs even for loads whose caller never hits the barrier
-        (the frontend OpenBulk path) — deliberately: the parse swaps
-        the pinned DEVICE wire buffer for a compact host dict, so a
-        barrier-less cold open releases its device memory as the
-        worker drains instead of pinning every slab's wire until the
-        next load, and a late barrier is nearly free."""
-        from ..ops.materialize import fetch_summary
-
-        _ids, batch, _dec, wire, lean = entry
-        if wire is None or isinstance(wire, dict):
-            return
-        nbytes = getattr(wire, "nbytes", 0)
-        entry[3] = fetch_summary(wire, batch, lean)
-        if nbytes:
-            _M_D2H.add(nbytes)
+    def summary_memo_row(self, doc_id: str) -> Optional[Dict]:
+        """The bulk loader's summary-memo row for a doc, or None."""
+        return self.loader.summary_memo_row(doc_id)
 
     def _begin_bulk_actors(self) -> None:
         """Defer per-feed sqlite writes and actor syncs for the duration
@@ -1279,440 +751,6 @@ class RepoBackend:
             actor = self.actors.get(actor_id)
             if actor is not None:
                 self._sync_changes(actor)
-
-    def _prefetch_columns(self, actors: List[Actor]) -> Tuple[int, int]:
-        """Load the column sidecars of a chunk's actors. First the head
-        of every cold actor's feed, in one batch (FeedStore.
-        resolve_heads): answered by the store's head snapshot where a
-        clean close sealed one, probed feed by feed (`.len` + `stat`)
-        where not; after it `Actor.seq_head` is a list length. Feeds
-        whose sidecar is one complete v3 image in the corpus slab,
-        level with the feed head, load slab-granular: one pass over the
-        chunk's extents (colcache.load_slab_images; views of the
-        mapping, no copy, no per-feed parse). Every other feed (a v2
-        tail, a legacy or memory sidecar, HM_SLAB=0, a sidecar ahead of
-        or behind its feed) loads through Actor.columns(), feed by
-        feed, on this thread: both are mmap slices and Python, which no
-        thread pool speeds up (it only took the GIL from the pack
-        worker). Returns (feeds loaded slab-granular, feeds loaded one
-        by one); feeds whose cache was loaded already count in neither.
-        Who answered the heads goes to last_bulk_stats
-        (heads_snapshot_feeds / heads_probed_feeds) and the counters of
-        the same names."""
-        cold = [a for a in actors if not a.colcache.loaded]
-        bulk: set = set()
-        slab = self._col_slab
-        cands: List[Actor] = []
-        if slab is not None:
-            # hint the chunk's extents into the page cache first (under
-            # the pipeline the NEXT chunk's hint overlaps this chunk's
-            # pack)
-            slab.prefetch([a.id for a in actors])
-            cands = [a for a in cold if a.colcache.slab is slab]
-        with telemetry.span(
-            "storage.columns.heads", "storage", feeds=len(cold)
-        ) as sp:
-            snap, probed = self.feeds.resolve_heads([a.feed for a in cold])
-            sp.note(probed=probed)
-            # the staleness rule, batched: a sidecar installs only if
-            # it holds exactly its feed head's count of changes
-            heads = [a.seq_head for a in cands]
-        _M_HEADS_SNAP.add(snap)
-        _M_HEADS_PROBED.add(probed)
-        with self._stats_lock:
-            stats = self.last_bulk_stats
-            for key, n in (
-                ("heads_snapshot_feeds", snap),
-                ("heads_probed_feeds", probed),
-            ):
-                stats[key] = stats.get(key, 0) + n
-        if cands:
-            with telemetry.span(
-                "storage.columns.bulk", "storage", feeds=len(cands)
-            ):
-                done = load_slab_images(
-                    slab, [a.colcache for a in cands], heads
-                )
-            bulk = {a.id for a, d in zip(cands, done) if d}
-        rest = [a for a in actors if a.id not in bulk]
-        if rest:
-            with telemetry.span(
-                "storage.columns.single", "storage", feeds=len(rest)
-            ):
-                for a in rest:
-                    a.columns()  # loads, or catches a loaded one up
-        return len(bulk), len(cold) - len(bulk)
-
-    def _mesh(self):
-        """The device mesh the bulk loader shards over, when >1 device is
-        visible (HM_MESH=0 forces single-device). Cached per backend."""
-        if getattr(self, "_mesh_cached", False):
-            return self._mesh_value
-        self._mesh_cached = True
-        self._mesh_value = None
-        if os.environ.get("HM_MESH", "1") != "0":
-            import jax
-
-            # a JAX error here propagates: a backend that cannot come
-            # up is not "one device"
-            if len(jax.devices()) > 1:
-                from ..parallel.mesh import make_mesh
-
-                self._mesh_value = make_mesh()
-        return self._mesh_value
-
-    def _load_slabs(
-        self, entries, slab, pack_docs_columns, DecodedBatch,
-        decode_patch, ready_ids, clock_rows, pad_docs=None, pad_rows=None,
-    ) -> None:
-        from ..ops.columnar import round_up_pow2
-
-        # NOTE: in this serial twin, slab packing stays strictly
-        # in-order on the calling thread. The streaming pipeline
-        # (HM_PIPELINE=1, the default) runs the same pack on a worker
-        # thread whose native hm_pack_prefix call drops the GIL, so it
-        # overlaps the next slab's sidecar IO and the previous slab's
-        # device work instead.
-        for seq, base in enumerate(range(0, len(entries), slab)):
-            chunk = entries[base : base + slab]
-            # bucket the doc axis (pow2) so every slab of a bulk load —
-            # and every later bulk load — reuses one compiled executable
-            with Stage(
-                "pipeline.pack", self._stat_add, "t_pack", slab=seq
-            ):
-                batch = pack_docs_columns(
-                    [e[1] for e in chunk],
-                    n_docs=pad_docs or round_up_pow2(len(chunk)),
-                    n_rows=pad_rows,
-                )
-            self._dispatch_slab(
-                seq, chunk, batch, DecodedBatch, decode_patch,
-                ready_ids, clock_rows,
-            )
-
-    # the seconds of a dispatch's child spans (recorded where the work
-    # happens: ops/crdt_kernels.py, parallel/sharded.py) -> stats keys
-    _DISPATCH_KIDS = (
-        ("pipeline.narrow", "t_narrow"),
-        ("pipeline.upload", "t_upload"),
-        ("pipeline.enqueue", "t_dispatch"),
-    )
-
-    def _dispatch_slab(
-        self, seq, chunk, batch, DecodedBatch, decode_patch,
-        ready_ids, clock_rows,
-    ):
-        """One packed slab -> async device dispatch + deferred doc init.
-        Returns the pending-summary entry (a mutable list: the pipeline
-        fetch worker replaces its wire slot with parsed host arrays).
-        Shared by the serial twin and the streaming pipeline, which
-        only differ in WHEN stages run, never in what they compute.
-
-        The whole of it is the `pipeline.dispatch` stage (it runs on
-        the dispatching thread and holds back the next slab): host-arg
-        narrowing, upload and the jitted call are its child spans, and
-        their ends feed t_narrow / t_upload / t_dispatch."""
-        # (on the loading thread, inside `pipeline.bulk_load`: the
-        # open's id comes down from that span)
-        with Stage("pipeline.dispatch", busy="dispatch", slab=seq) as sp:
-            entry = self._dispatch_slab_staged(
-                chunk, batch, DecodedBatch, decode_patch,
-                ready_ids, clock_rows,
-            )
-        for kid, key in self._DISPATCH_KIDS:
-            self._stat_add(key, sp.kids.get(kid, 0.0))
-        return entry
-
-    def _dispatch_slab_staged(
-        self, chunk, batch, DecodedBatch, decode_patch,
-        ready_ids, clock_rows,
-    ):
-        from ..ops.crdt_kernels import actor_bucket, run_batch_full
-        from ..ops.host_kernel import run_batch_host
-
-        # small loads aren't worth a device dispatch (let alone a fresh
-        # per-bucket compile): under this many [D, N] cells the numpy
-        # kernel twin wins outright
-        min_cells = int(os.environ.get("HM_DEVICE_MIN_CELLS", "131072"))
-        stats = self.last_bulk_stats
-        # host clocks (authoritative, from sidecar metadata) for
-        # every doc in the slab, padded docs empty — lets the device
-        # path skip the seq wire entirely
-        slab_clocks = [e[2] for e in chunk] + [{}] * (
-            batch.n_docs - len(chunk)
-        )
-        a_loc = actor_bucket(batch)
-        with self._stats_lock:
-            if batch.packed_by == "general":
-                stats["pack_general_docs"] += len(chunk)
-            stats["a_loc_max"] = max(stats["a_loc_max"], a_loc)
-            stats["pred_max"] = max(stats["pred_max"], batch.psrc.shape[1])
-        lean = False
-        if batch.n_docs * batch.n_rows < min_cells:
-            with telemetry.timed("pipeline.enqueue", "pipeline", host=1):
-                out = run_batch_host(batch)
-            summary = None
-            with self._stats_lock:
-                stats["host_slabs"] += 1
-        else:
-            from ..crdt.change import Action
-            from ..ops import compile_cache
-            import numpy as np
-
-            platform = compile_cache.ensure()  # may init the backend
-            with self._stats_lock:
-                stats["device_slabs"] += 1
-                stats["platform"] = platform
-            # no INC ops + host clocks in hand -> skip the seq and
-            # value wires (~4 of 14 bytes/op uploaded) AND the summary
-            # wire's clock section
-            lean = not bool(
-                np.any(batch.cols["action"] == int(Action.INC))
-            )
-            rr = self._slab_rr()
-            mesh = self._mesh() if rr is None else None
-            if rr is not None:
-                # pipelined multi-chip: successive WHOLE slabs land on
-                # successive devices (bounded in-flight queues per
-                # device) — chips run independent programs instead of
-                # lockstep sharded dispatches
-                out, summary = rr.dispatch(batch, lean=lean)
-                with self._stats_lock:
-                    stats["rr_slabs"] = stats.get("rr_slabs", 0) + 1
-                    stats.setdefault("rr_devices", len(rr.devices))
-            elif mesh is not None:
-                # multi-chip: THE same kernel, doc-sharded over dp
-                # (parallel/sharded.py) — this is the v5e-8 path
-                from ..parallel.sharded import sharded_full
-
-                out, summary = sharded_full(batch, mesh, lean=lean)
-                with self._stats_lock:
-                    stats["sharded_slabs"] = (
-                        stats.get("sharded_slabs", 0) + 1
-                    )
-            else:
-                out, summary = run_batch_full(batch, lean=lean)
-            if os.environ.get("HM_ASYNC_SUMMARY_COPY", "1") != "0":
-                # start the device->host copy of the ONE fused wire
-                # buffer now so the barrier (fetch_bulk_summaries)
-                # overlaps the transfer with later slabs' pack +
-                # compute
-                try:
-                    summary.copy_to_host_async()
-                except AttributeError:  # non-device backend
-                    pass
-        dec = DecodedBatch(batch, out, host_clocks=slab_clocks)
-        entry = [[e[0].id for e in chunk], batch, dec, summary, lean]
-        self._pending_summaries.append(entry)
-        with telemetry.span(
-            "pipeline.init_docs", "pipeline", docs=len(chunk)
-        ):
-            for j, (doc, _spec, clock, n_changes, actor_ids) in enumerate(
-                chunk
-            ):
-                self._init_bulk_doc(
-                    doc, clock, n_changes, actor_ids,
-                    lambda dec=dec, j=j: decode_patch(dec.doc_view(j), 0),
-                    ready_ids, clock_rows,
-                )
-        return entry
-
-    def _slab_rr(self):
-        """Round-robin slab scheduler across visible devices (pipeline
-        mode only; HM_SLAB_RR=0 restores mesh-sharded dispatch). The
-        MODE gates re-evaluate on every call — the serial twin
-        (HM_PIPELINE=0) must never round-robin even on a backend that
-        already ran pipelined, and vice versa; only the device
-        discovery / scheduler construction is cached (like _mesh).
-        None when <2 devices or disabled."""
-        from .pipeline import pipeline_enabled
-
-        if (
-            os.environ.get("HM_SLAB_RR", "1") == "0"
-            or os.environ.get("HM_MESH", "1") == "0"
-            or not pipeline_enabled()
-        ):
-            return None
-        if self._rr_cached:
-            return self._rr_value
-        self._rr_cached = True
-        self._rr_value = None
-        import jax
-
-        # a JAX error here propagates (see _mesh)
-        if len(jax.devices()) > 1:
-            from ..parallel.mesh import make_mesh
-            from ..parallel.sharded import MeshBulkScheduler
-
-            # the mesh scheduler: identical streaming dispatch (whole
-            # slabs per chip, same kernels). Resident tracking OFF: the
-            # product barrier fetches per slab on the overlapped fetch
-            # workers, so the collective-reduction refs would pin
-            # every slab's device wire with no consumer.
-            self._rr_value = MeshBulkScheduler(
-                make_mesh(), track_resident=False
-            )
-        return self._rr_value
-
-    def fetch_bulk_summaries(self) -> "BulkSummaries":
-        """The materialization barrier for the preceding bulk load(s):
-        transfers every slab's fused summary wire buffer (winner/liveness
-        masks bit-packed, element order at ceil(log2 N) bits/entry,
-        narrow counts; clock section only on non-lean runs) to host —
-        ONE device buffer per slab — and returns the decoded summaries.
-        Docs the summary memo served (clock unchanged since their last
-        fetch) transfer nothing. After this, any doc in the load renders
-        host-side with no further device work. Clears the pending refs
-        and refreshes the memo with the freshly fetched rows.
-
-        Under the streaming pipeline (HM_PIPELINE=1) the fetch worker
-        already transferred + parsed each slab's wire while later slabs
-        were packing/dispatching; this barrier joins that worker (re-
-        raising any fetch failure) and assembles host-side only —
-        `t_fetch` records the residual (non-overlapped) wait, while
-        `t_fetch_busy` holds the worker's busy time.
-
-        Runs under `repo.bulk` (the guard of the pending accumulators,
-        analysis/guards.py): a barrier racing a new load would
-        otherwise swap the pending lists out from under each other —
-        the load's stale-join path still covers barrier-less loads."""
-        from ..ops.materialize import BulkSummaries
-        with self._bulk_mutex:
-            pending = self._pending_summaries
-            memo_pending = self._pending_memo
-            fetch_ctx = self._fetch_ctx
-            wall_t0 = self._bulk_t0
-            self._pending_summaries = []
-            self._pending_memo = []
-            self._fetch_ctx = None
-            # one barrier per load — cleared up front so neither a
-            # fetch failure below nor a later (empty) barrier call can
-            # restamp the critical path with idle wall time
-            self._bulk_t0 = None
-            with Stage(
-                "pipeline.barrier", open=self._bulk_open,
-                parent="repo.open_many", slabs=len(pending),
-            ) as barrier:
-                if fetch_ctx is not None:
-                    fetch_ctx.join()  # PipelineError on fetch failure
-                out = BulkSummaries(
-                    pending, memo_slabs=self._memo_slabs(memo_pending)
-                )
-                self._memoize_summaries(out, pending, memo_pending)
-        with self._stats_lock:
-            self.last_bulk_stats["t_fetch"] = round(barrier.dur, 3)
-            if wall_t0 is not None:
-                self.last_bulk_stats["wall_critical_path"] = round(
-                    time.perf_counter() - wall_t0, 3
-                )
-        return out
-
-    @staticmethod
-    def _memo_cap_bytes() -> int:
-        return (
-            int(os.environ.get("HM_SUMMARY_MEMO_MB", "256")) * 1024 * 1024
-        )
-
-    @staticmethod
-    def _memo_entry_bytes(m: Dict) -> int:
-        return (
-            m["mw_bits"].nbytes
-            + m["el_bits"].nbytes
-            + m["order"].nbytes
-            + m["clock_row"].nbytes
-            + 512  # dict/key overhead estimate
-        )
-
-    def _memo_slabs(self, memo_pending):
-        """Memo-served docs as BulkSummaries memo groups (grouped by N
-        so rows stack into one arrays dict per bucket)."""
-        if not memo_pending:
-            return []
-        import numpy as np
-
-        groups: Dict[tuple, List] = {}
-        for doc_id, m in memo_pending:
-            key = (m["N"], len(m["clock_row"]))
-            groups.setdefault(key, []).append((doc_id, m))
-        out = []
-        from ..ops.crdt_kernels import unpack_bits_le
-
-        for (N, _A), items in groups.items():
-            def bits(key):
-                return unpack_bits_le(
-                    np.stack([m[key] for _d, m in items]), N
-                )
-
-            arrays = {
-                "map_winner": bits("mw_bits"),
-                "elem_live": bits("el_bits"),
-                "elem_order": np.stack(
-                    [m["order"] for _d, m in items]
-                ).astype(np.int64),
-                "n_live_elems": np.asarray(
-                    [m["n_live"] for _d, m in items], np.int64
-                ),
-                "n_map_entries": np.asarray(
-                    [m["n_map"] for _d, m in items], np.int64
-                ),
-                # the real [A_loc] local-slot clock rows, same columnar
-                # contract as fetched slabs (arrays()['clock'])
-                "clock": np.stack([m["clock_row"] for _d, m in items]),
-            }
-            out.append((
-                [d for d, _m in items],
-                arrays,
-                [m["clock"] for _d, m in items],
-            ))
-        return out
-
-    def _memoize_summaries(self, summaries, pending, memo_pending) -> None:
-        """Refresh the per-doc summary memo from freshly fetched slab
-        rows (byte-bounded LRU)."""
-        cap = self._memo_cap_bytes()
-        if cap <= 0:
-            return
-        import numpy as np
-
-        memo = self._summary_memo
-        for doc_id, m in memo_pending:  # served rows stay warm
-            if doc_id in memo:
-                memo.move_to_end(doc_id)
-        for i, (doc_ids, batch, dec, _wire, _lean) in enumerate(pending):
-            if dec.host_clocks is None:
-                continue  # no authoritative clock: not memoizable
-            arrays = summaries.slabs[i][2]
-            N = batch.n_rows
-            mwb = np.packbits(
-                arrays["map_winner"], axis=1, bitorder="little"
-            )
-            elb = np.packbits(
-                arrays["elem_live"], axis=1, bitorder="little"
-            )
-            odt = np.int16 if N < 2**15 else np.int32
-            order = arrays["elem_order"].astype(odt)
-            clock_arr = np.asarray(arrays["clock"], np.int32)
-            for j, doc_id in enumerate(doc_ids):
-                old = memo.pop(doc_id, None)
-                if old is not None:
-                    self._summary_memo_bytes -= self._memo_entry_bytes(
-                        old
-                    )
-                entry = {
-                    "clock": dict(dec.host_clocks[j]),
-                    "N": N,
-                    "n_live": int(arrays["n_live_elems"][j]),
-                    "n_map": int(arrays["n_map_entries"][j]),
-                    "mw_bits": mwb[j].copy(),
-                    "el_bits": elb[j].copy(),
-                    "order": order[j].copy(),
-                    "clock_row": clock_arr[j].copy(),
-                }
-                memo[doc_id] = entry
-                self._summary_memo_bytes += self._memo_entry_bytes(entry)
-        while memo and self._summary_memo_bytes > cap:
-            _d, old = memo.popitem(last=False)
-            self._summary_memo_bytes -= self._memo_entry_bytes(old)
 
     def _init_bulk_doc(
         self, doc, clock, n_changes, actor_ids, snapshot_fn,
@@ -2574,18 +1612,9 @@ class RepoBackend:
 
     def close(self) -> None:
         self._closed = True
-        # a barrier-less bulk load (frontend OpenBulk) may still have a
-        # fetch worker draining device buffers: settle it before the
-        # feeds / slab mmap / sqlite it indirectly depends on go away,
-        # and surface (as a log) any error nobody barriered to see
-        with self._bulk_mutex:
-            ctx = self._fetch_ctx
-            self._fetch_ctx = None
-        if ctx is not None:
-            try:
-                ctx.join()
-            except Exception as e:
-                log("repo:backend", f"bulk fetch at close: {e}")
+        # before the feeds / slab mmap / sqlite a draining fetch worker
+        # indirectly depends on go away
+        self.loader.close()
         if self.overload is not None:
             self.overload.close()  # stop the ticker before the tier
         if self.serve is not None:

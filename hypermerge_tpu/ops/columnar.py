@@ -568,7 +568,7 @@ def _prefix_single_slab(doc_specs) -> bool:
 
 
 def _pack_prefix_single(
-    doc_specs, n_rows, n_pred, n_docs, device=None
+    doc_specs, n_rows, n_pred, n_docs
 ) -> ColumnarBatch:
     """Fast pack for the dominant cold-open shape: one single-writer feed
     per doc, whole-prefix windows. Rows are already in causal order (ctr
@@ -578,15 +578,12 @@ def _pack_prefix_single(
     M-sized argsorts and composite-key resolution collapse into one
     searchsorted over an already-sorted key.
 
-    The padded-plane emit itself has three bit-identical twins, tried
-    in order: the jitted device kernel (ops/pack_kernels.py, only when
-    HM_DEVICE_PACK=1 — host work collapses to narrow-plane concats),
-    the C++ batch entry point (native/src/hm_native.cpp hm_pack_prefix
-    — one fused pass per column straight from the feeds' narrow planes
-    into preallocated output buffers), and the numpy scatter below (the
-    fallback when the native layer is absent, HM_NATIVE_PACK=0, or a
-    feed is not plane-backed). `device` is the mesh scheduler's
-    placement hint for the device twin; host twins ignore it."""
+    The padded-plane emit itself has two bit-identical twins: the C++
+    batch entry point (native/src/hm_native.cpp hm_pack_prefix — one
+    fused pass per column straight from the feeds' narrow planes into
+    preallocated output buffers), and the numpy scatter below (the
+    reference, and the fallback when the native layer is absent,
+    HM_NATIVE_PACK=0, or a feed is not plane-backed)."""
     D = len(doc_specs)
     Dp = max(n_docs, D) if n_docs is not None else D
 
@@ -729,17 +726,7 @@ def _pack_prefix_single(
     native_lib = _native_pack_lib() if use_planes else None
     cols: Dict[str, np.ndarray] = {}
 
-    from .pack_kernels import device_pack_enabled
-
-    if device_pack_enabled():
-        from .pack_kernels import device_pack_prefix
-
-        cols = device_pack_prefix(
-            fcs, fc_idx, fc_idx_a, ends, writer_g, flat_lut,
-            D, Dp, N, i16ok, row_dt, kdt, device,
-        )
-
-    if not cols and native_lib is not None:
+    if native_lib is not None:
         cols = _native_pack_prefix(
             native_lib, fcs, fc_idx_a, ends, writer_g, flat_lut,
             D, Dp, N, i16ok, row_dt, kdt,
@@ -873,7 +860,6 @@ def pack_docs_columns(
     n_rows: Optional[int] = None,
     n_pred: Optional[int] = None,
     n_docs: Optional[int] = None,
-    device: Optional[Any] = None,
 ) -> ColumnarBatch:
     """Pack documents from columnar feed windows.
 
@@ -889,17 +875,14 @@ def pack_docs_columns(
 
     Single-writer whole-prefix loads (the dominant cold-open shape)
     dispatch to a no-sort fast path; anything else takes the general
-    sorted-composite path below. `device` is a placement hint for the
-    fast path's device pack kernel (HM_DEVICE_PACK=1): the chip the
-    mesh scheduler will dispatch this slab to. Host packs — and the
-    general path, which never runs on device — ignore it.
+    sorted-composite path below.
     """
     n = len(doc_specs)
     if _prefix_single_slab(doc_specs):
         _M_PACK_PREFIX.add(n)
         with telemetry.span("pipeline.pack.prefix", "pipeline", docs=n):
             batch = _pack_prefix_single(
-                doc_specs, n_rows, n_pred, n_docs, device
+                doc_specs, n_rows, n_pred, n_docs
             )
         batch.packed_by = "prefix"
         return batch

@@ -33,9 +33,9 @@ INF = float("inf")
 
 
 def bulk_buckets(n_docs_total: int, slab: Optional[int] = None) -> List[int]:
-    """The doc-axis jit buckets `_load_slabs` will use for a bulk load of
+    """The doc-axis jit buckets the bulk loader will use for a load of
     `n_docs_total` docs: full slabs share one bucket, the tail rounds up
-    to its own pow2 (backend/repo_backend.py:_load_slabs)."""
+    to its own pow2 (backend/bulk_loader.py BulkLoader._load_slabs)."""
     from .columnar import round_up_pow2
 
     if slab is None:

@@ -22,8 +22,8 @@ The reference processes documents one at a time on one Node thread
 - `SlabRoundRobin`: the streaming-pipeline alternative to sharded
   dispatch — whole slabs round-robin (or least-loaded, HM_RR_LEAST_LOADED)
   across devices with bounded per-device in-flight queues, so chips run
-  independent programs while the host packs ahead (RepoBackend bulk
-  loader, HM_PIPELINE=1). Tracks per-chip dispatch busy time.
+  independent programs while the host packs ahead (the bulk loader,
+  backend/bulk_loader.py). Tracks per-chip dispatch busy time.
 - `MeshBulkScheduler`: SlabRoundRobin's streaming married to the mesh —
   whole slabs stay pinned per chip, and the CROSS-DOC reductions over
   everything resident (clock union across every chip's slabs, the bulk
@@ -294,9 +294,9 @@ def _full_program(mesh: Mesh, A: int, K: int, N: int, lean: bool):
 
 def sharded_full(batch: ColumnarBatch, mesh: Mesh, lean: bool = False):
     """(MaterializeOut, summary wire) sharded over dp — the multi-chip
-    twin of ops.crdt_kernels.run_batch_full, and the dispatch the PRODUCT
-    bulk loader uses when a mesh is available (RepoBackend._load_slabs):
-    full lanes stay device-resident per shard for lazy patch decode, the
+    twin of ops.crdt_kernels.run_batch_full (the product bulk loader
+    sends whole slabs round-robin instead, SlabRoundRobin below): full
+    lanes stay device-resident per shard for lazy patch decode, the
     fused summary buffer transfers for the materialization barrier (one
     dp-sharded [D, W] uint8 leaf). `lean` drops the wire's clock section
     — callers holding authoritative host clocks only. Per-doc compute
@@ -548,10 +548,14 @@ def _combine_partials_program(mesh: Mesh):
     return _program(key, build)
 
 
+# per-device in-flight slab bound of the round-robin scheduler
+RR_DEPTH = 2
+
+
 class SlabRoundRobin:
     """Stream WHOLE slabs across visible devices with bounded
     per-device in-flight queues — the streaming pipeline's multi-chip
-    dispatch (RepoBackend._dispatch_slab under HM_PIPELINE=1).
+    dispatch (backend/bulk_loader.py BulkLoader._dispatch).
 
     Where `sharded_full` splits one slab across the mesh (dp sharding:
     one program, every chip in lockstep, the host blocked feeding all
@@ -570,7 +574,7 @@ class SlabRoundRobin:
     chips take new work — with the round-robin cursor as the FIFO
     tiebreak so equal loads still cycle.
 
-    Backpressure: at most `depth` (HM_RR_DEPTH, default 2) unfetched
+    Backpressure: at most `depth` unfetched
     slabs per device; dispatching onto a saturated device blocks on its
     OLDEST outstanding summary, which bounds host staging and device
     memory to depth x n_devices slabs.
@@ -581,16 +585,12 @@ class SlabRoundRobin:
     stats and the fetch stage's chip attribution read these)."""
 
     def __init__(
-        self, devices=None, depth: int = None, least_loaded: bool = None
+        self, devices=None, depth: int = RR_DEPTH, least_loaded: bool = None
     ) -> None:
         self.devices = list(
             devices if devices is not None else jax.devices()
         )
-        self.depth = (
-            depth
-            if depth is not None
-            else max(1, int(os.environ.get("HM_RR_DEPTH", "2")))
-        )
+        self.depth = depth
         self.least_loaded = (
             least_loaded
             if least_loaded is not None
@@ -610,26 +610,6 @@ class SlabRoundRobin:
             return self.devices.index(device)
         except ValueError:
             return None
-
-    def cursor(self) -> int:
-        """Round-robin cursor snapshot. The bulk loader reads it on the
-        caller thread BEFORE the pipeline starts; combined with
-        pack_device_for it lets pack workers predict placement ahead of
-        dispatch."""
-        return self._next
-
-    def pack_device_for(self, seq: int, cursor0: int):
-        """Device slab `seq` of a load will be dispatched to, given the
-        cursor snapshot `cursor0` taken when the load started. Valid
-        because strict round-robin consumes slabs in seq order straight
-        off the cursor — the device-pack path (HM_DEVICE_PACK=1) uses
-        it to build the packed columns ON the chip that will run the
-        materialize kernel, so no cross-chip copy rides the dispatch.
-        Least-loaded placement is load-dependent, so no prediction is
-        possible: returns None (pack uses the default device)."""
-        if self.least_loaded:
-            return None
-        return self.devices[(cursor0 + seq) % len(self.devices)]
 
     def _pick_device(self) -> int:
         """Next device index. Round-robin: the cursor, regardless of
@@ -736,7 +716,7 @@ class MeshBulkScheduler(SlabRoundRobin):
     def __init__(
         self,
         mesh: Mesh,
-        depth: int = None,
+        depth: int = RR_DEPTH,
         least_loaded: bool = None,
         track_resident: bool = True,
     ) -> None:

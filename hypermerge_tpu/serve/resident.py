@@ -194,8 +194,7 @@ def _memo_lanes(backend, doc_id, clock, c, n):
     bulk path share ONE freshness rule (clock equality). Only sound
     when no row needs the lanes the memo does not carry: INC totals and
     element-override SETs fall back to the kernel run."""
-    memo = getattr(backend, "_summary_memo", None)
-    m = memo.get(doc_id) if memo else None
+    m = backend.summary_memo_row(doc_id)
     if m is None or m["clock"] != clock or m["N"] < n:
         return None
     if np.any(c["action"] == int(Action.INC)):

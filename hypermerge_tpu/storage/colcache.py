@@ -984,7 +984,7 @@ class FeedColumnCache:
         self.writer = writer
         self._loaded = False  # storage read is deferred: a bulk cold
         # start creates thousands of caches and loads them a chunk at a
-        # time (RepoBackend._prefetch_columns, load_slab_images)
+        # time (BulkLoader._prefetch_columns, load_slab_images)
 
     @property
     def loaded(self) -> bool:

@@ -30,10 +30,7 @@ Under HM_PACK_WORKERS>1 the pack plane fans out: each pool worker
 emits its own `pipeline.pack` spans from an `hm-pipe-pack-<i>` thread,
 so `--threads` draws one busy lane per pack worker (their sum past the
 `pipeline.pack` row's share of the wall is the pool's realized
-speedup). Device packs (HM_DEVICE_PACK=1) run inside those same spans —
-whether the kernel or the host packed is in the metrics registry, not
-the trace: `pack.device_packs` counts kernel-packed slabs and
-`pack.device_fallbacks` counts silent host fallbacks.
+speedup).
 
 Instrumented runs (HM_LOCKDEP=1 / HM_RACEDEP=1) add two instants in
 the `lock` category: `lock.held_blocking` (a blocking primitive ran

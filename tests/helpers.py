@@ -51,6 +51,26 @@ def plainify(v):
     return v
 
 
+def opset_replay_state(changes):
+    """(summary, plain value) of a host OpSet replay of `changes`: the
+    reference a bulk open's `BulkSummaries.doc(id)` and doc value are
+    held to. The summary counts live sequence elements and visible map
+    entries over every object, beside the clock."""
+    opset = OpSet()
+    opset.apply_changes(changes)
+    elems = entries = 0
+    for obj in opset.objects.values():
+        live = sum(1 for visible in obj.fields.values() if visible)
+        if obj.is_sequence:
+            elems += live
+        else:
+            entries += live
+    summary = {
+        "elems": elems, "map_entries": entries, "clock": dict(opset.clock),
+    }
+    return summary, plainify(opset.materialize())
+
+
 def wait_until(fn, timeout=10.0, interval=0.005):
     """Poll until fn() is truthy (live replication tails are batched
     and asynchronous — net/replication.py flush windows), returning the

@@ -28,7 +28,7 @@ from benchmark.reference.plainify import plain  # noqa: E402
 SEED = 2147483659  # over 2**31: the driver's seeds are large
 CLASSES = (1, 2, 3, 8, 32)
 ENV = {"HM_DEVICE_MIN_CELLS": "0", "HM_BULK_SLAB": "32",
-       "HM_LIVE_INC_BUDGET": "0", "HM_PIPELINE": "1"}
+       "HM_LIVE_INC_BUDGET": "0"}
 
 
 def _rehearsal_corpus() -> dict:

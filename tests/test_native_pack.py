@@ -275,151 +275,6 @@ def test_pack_releases_gil(tmp_path, monkeypatch):
     # the hard GIL enforcement is the spinner assert above
 
 
-# ---------------------------------------------------------------------------
-# Device pack kernel (ops/pack_kernels.py) — the third twin
-# ---------------------------------------------------------------------------
-
-
-def _pack_device(monkeypatch, specs, counted=True, native_ref=False, **kw):
-    """(device_batch, host_batch). The host reference is the numpy twin
-    unless native_ref=True; the device kernel must actually have packed
-    (spied), not silently fallen through."""
-    pytest.importorskip("jax")
-    from hypermerge_tpu.ops import pack_kernels
-
-    calls = []
-    orig = pack_kernels.device_pack_prefix
-
-    def spy(*a, **k):
-        out = orig(*a, **k)
-        calls.append(bool(out))
-        return out
-
-    monkeypatch.setattr(pack_kernels, "device_pack_prefix", spy)
-    monkeypatch.setenv("HM_DEVICE_PACK", "1")
-    monkeypatch.setenv("HM_NATIVE_PACK", "0")
-    b_dev = pack_docs_columns(specs, **kw)
-    monkeypatch.setenv("HM_DEVICE_PACK", "0")
-    monkeypatch.setenv("HM_NATIVE_PACK", "1" if native_ref else "0")
-    b_host = pack_docs_columns(specs, **kw)
-    if counted:
-        assert calls and all(calls), "device pack kernel was not used"
-    return b_dev, b_host
-
-
-def test_device_pack_fuzz_bit_identical(tmp_path, monkeypatch):
-    """Three-way pin over fuzzed single-writer plane-backed feeds: the
-    jitted device kernel must agree bit-for-bit (values AND dtypes) with
-    the numpy twin — and, when the native layer is present, with the C++
-    batch entry too. One kernel per [Mp, Dp, N] shape, shared through
-    the program table."""
-    caches = [
-        _plane_cache(tmp_path, f"dv{seed}", _single_writer_history(seed))
-        for seed in range(6)
-    ]
-    specs = [[(cc.columns(), 0, INF)] for cc in caches]
-    b_dev, b_numpy = _pack_device(monkeypatch, specs)
-    _assert_batches_identical(b_dev, b_numpy)
-    if native.pack_lib() is not None:
-        b_dev2, b_native = _pack_device(
-            monkeypatch, specs, native_ref=True
-        )
-        _assert_batches_identical(b_dev2, b_native)
-        _assert_batches_identical(b_dev, b_dev2)
-    for cc in caches:
-        cc.close()
-
-
-def test_device_pack_ragged_padded_and_empty(tmp_path, monkeypatch):
-    """Doc-axis padding (ragged slab tails), partial end_seq windows, a
-    shared feed, and a zero-change (empty-doc) window: the scatter's
-    pad slots must come out exactly as the numpy twin's defaults."""
-    caches = [
-        _plane_cache(tmp_path, f"dr{seed}", _single_writer_history(seed))
-        for seed in (31, 32)
-    ]
-    fcs = [cc.columns() for cc in caches]
-    half = max(1, fcs[1].n_changes // 2)
-    specs = [
-        [(fcs[0], 0, INF)],
-        [(fcs[1], 0, half)],
-        [(fcs[0], 0, INF)],  # shared feed object
-        [(fcs[1], 0, 0)],  # empty-doc window
-    ]
-    b_dev, b_numpy = _pack_device(
-        monkeypatch, specs, n_docs=8, n_rows=512, n_pred=128
-    )
-    assert b_dev.n_docs == 8
-    assert int(b_dev.n_ops[3]) == 0
-    _assert_batches_identical(b_dev, b_numpy)
-    for cc in caches:
-        cc.close()
-
-
-def test_device_pack_rows_backed_cache(monkeypatch):
-    """Pre-compaction caches carry no checkpoint planes; the marshal
-    reads the materialized rows matrix instead — same bits."""
-    r = random.Random(17)
-    site = Site("actor03")
-    for _ in range(25):
-        random_mutation(site, r)
-    history = list(site.opset.history)
-    cc = FeedColumnCache(MemoryColumnStorage(), writer=history[0].actor)
-    for c in sorted(history, key=lambda c: (c.actor, c.seq)):
-        cc.append_change(c)
-    fc = cc.columns()
-    assert fc.planes is None
-    b_dev, b_numpy = _pack_device(monkeypatch, [[(fc, 0, INF)]])
-    _assert_batches_identical(b_dev, b_numpy)
-
-
-def test_device_pack_env_order_both_ways(tmp_path, monkeypatch):
-    """HM_DEVICE_PACK and HM_NATIVE_PACK are read independently at call
-    time: whichever order they are set in, the device kernel wins the
-    routing race and the bits match the host reference."""
-    pytest.importorskip("jax")
-    cc = _plane_cache(tmp_path, "de0", _single_writer_history(5))
-    specs = [[(cc.columns(), 0, INF)]]
-    monkeypatch.setenv("HM_NATIVE_PACK", "0")
-    monkeypatch.setenv("HM_DEVICE_PACK", "0")
-    b_ref = pack_docs_columns(specs)
-    for order in (
-        ("HM_DEVICE_PACK", "HM_NATIVE_PACK"),
-        ("HM_NATIVE_PACK", "HM_DEVICE_PACK"),
-    ):
-        for var in order:
-            monkeypatch.setenv(var, "1")
-        b = pack_docs_columns(specs)
-        _assert_batches_identical(b, b_ref)
-        for var in order:
-            monkeypatch.setenv(var, "0")
-    cc.close()
-
-
-def test_device_pack_falls_back_bit_identical(tmp_path, monkeypatch):
-    """Any device-kernel failure must fall through to the host twins —
-    identical bits, a counted fallback, never an exception out of the
-    pack."""
-    pytest.importorskip("jax")
-    from hypermerge_tpu.ops import pack_kernels
-
-    cc = _plane_cache(tmp_path, "dfb", _single_writer_history(8))
-    specs = [[(cc.columns(), 0, INF)]]
-    monkeypatch.setenv("HM_NATIVE_PACK", "0")
-    b_ref = pack_docs_columns(specs)
-
-    def boom(*a, **k):
-        raise RuntimeError("boom-device")
-
-    monkeypatch.setattr(pack_kernels, "_pack_program", boom)
-    before = pack_kernels._M_FALLBACKS.value()
-    monkeypatch.setenv("HM_DEVICE_PACK", "1")
-    b_fb = pack_docs_columns(specs)
-    assert pack_kernels._M_FALLBACKS.value() == before + 1
-    _assert_batches_identical(b_fb, b_ref)
-    cc.close()
-
-
 @needs_pack
 def test_counter_and_text_kinds_roundtrip(tmp_path, monkeypatch):
     """INC lanes (dt/ref) and text inserts through both twins, then a

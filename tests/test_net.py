@@ -205,12 +205,19 @@ class TestTwoRepos:
         states = []
         h = rb.open(url)
         h.subscribe(lambda d, i: states.append(dict(d) if d else d))
-        assert states and states[-1]["x"] == 1
+        # (the first state replicates in: it may land after subscribe;
+        # under six loaded workers either step has taken over 10 s)
+        wait_until(
+            lambda: states and states[-1] and states[-1].get("x") == 1,
+            timeout=60,
+        )
         ra.change(url, lambda d: d.__setitem__("x", 2))
         # no re-open: the update must arrive via the live patch stream
-        wait_until(lambda: states and states[-1]["x"] == 2)
+        wait_until(lambda: states and states[-1]["x"] == 2, timeout=60)
         assert h.value()["x"] == 2
         h.close()
+        ra.close()
+        rb.close()
 
     def test_stale_ready_does_not_clobber_local_state(self):
         """A Ready snapshot arriving for a doc already in write mode

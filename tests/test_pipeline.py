@@ -1,13 +1,13 @@
-"""Streaming slab pipeline (backend/pipeline.py): equivalence with the
-serial twin, failure-path hygiene, and round-robin device dispatch.
+"""Streaming slab pipeline (backend/pipeline.py, driven by
+backend/bulk_loader.py): equivalence with the host OpSet replay,
+failure-path hygiene, and round-robin device dispatch.
 
-The pipeline restructures the bulk cold open from sum(stages) to
-~max(stage) by overlapping IO, pack, dispatch, and fetch across slabs
-— but it must be a pure SCHEDULING change: `HM_PIPELINE=1` and
-`HM_PIPELINE=0` must produce byte-identical summary arrays, identical
-summary-memo contents, and identical doc/fast/fallback accounting. A
-stage failure must fail the whole load as a unit: no hung worker
-threads, no pending device refs, queues drained.
+The pipeline overlaps IO, pack, dispatch, and fetch across slabs, so a
+bulk cold open costs ~max(stage), not sum(stages) — and every doc it
+opens must carry the summary and the value the host OpSet replay of its
+feeds gives, whichever pack (numpy reference or native) fed it. A stage
+failure must fail the whole load as a unit: no hung worker threads, no
+pending device refs, queues drained.
 """
 
 import random
@@ -17,11 +17,11 @@ import time
 
 import pytest
 
-from helpers import plainify
+from helpers import opset_replay_state, plainify
 from hypermerge_tpu.backend.pipeline import PipelineError
 from hypermerge_tpu.models import Counter, Text
 from hypermerge_tpu.repo import Repo
-from hypermerge_tpu.utils.ids import validate_doc_url
+from hypermerge_tpu.utils.ids import to_doc_url, validate_doc_url
 
 
 def _make_corpus(path, n_docs=14, seed=7):
@@ -53,7 +53,7 @@ def _make_corpus(path, n_docs=14, seed=7):
 
 def _add_gap_doc(path):
     """One doc with a seq gap in its feed: must fall back to host
-    replay in BOTH modes (fallback accounting equivalence)."""
+    replay (fallback accounting)."""
     from hypermerge_tpu.crdt.change import Action, Change, Op, ROOT
     from hypermerge_tpu.storage import block as blockmod
 
@@ -94,7 +94,7 @@ def _doc_summary_bytes(summ, doc_id):
 
 def _memo_snapshot(back):
     out = {}
-    for doc_id, m in back._summary_memo.items():
+    for doc_id, m in back.loader._summary_memo.items():
         out[doc_id] = {
             "clock": dict(m["clock"]),
             "N": m["N"],
@@ -108,11 +108,12 @@ def _memo_snapshot(back):
     return out
 
 
-def _load_twice(path, ids, mode, monkeypatch, slab):
+def _load_twice(path, ids, native_pack, monkeypatch, slab):
     """Two bulk loads in one backend (the second is all memo hits);
-    returns per-doc summary bytes for both, the memo snapshot, and the
-    stats of each load."""
-    monkeypatch.setenv("HM_PIPELINE", mode)
+    returns per-doc summary bytes for both, the memo snapshot, the
+    counts of each load, and each fast doc's (summary, value) as the
+    load gave it beside the host OpSet replay's."""
+    monkeypatch.setenv("HM_NATIVE_PACK", native_pack)
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")  # force device path
     repo = Repo(path=str(path))
     back = repo.back
@@ -127,34 +128,42 @@ def _load_twice(path, ids, mode, monkeypatch, slab):
     stats2 = dict(back.last_bulk_stats)
     s2 = back.fetch_bulk_summaries()
     second = {d: _doc_summary_bytes(s2, d) for d in s2.doc_ids}
+    replay = {}
+    for d in s2.doc_ids:
+        replay[d] = (
+            (s2.doc(d), plainify(repo.doc(to_doc_url(d)))),
+            opset_replay_state(back._bulk_history_loader(d)()),
+        )
     repo.close()
     counts = [
         {k: st[k] for k in ("docs", "fast", "memo", "fallback")}
         for st in (stats1, stats2)
     ]
-    return first, second, memo, counts
+    return first, second, memo, counts, replay
 
 
-def test_pipeline_serial_equivalence_fuzz(tmp_path, monkeypatch):
-    """Fuzzed docs across >=3 slab boundaries: HM_PIPELINE=1 and =0
-    produce byte-identical summary arrays, identical memo contents, and
-    identical doc/fast/fallback counts — on the first (packed +
-    dispatched) AND second (memo-served) loads."""
+def test_pipeline_matches_opset_replay_fuzz(tmp_path, monkeypatch):
+    """Fuzzed docs across >=3 slab boundaries, against the real
+    reference: every fast doc's summary (elems, map entries, clock) and
+    value equal the host OpSet replay of its feeds — on the first
+    (packed + dispatched) AND second (memo-served) loads — and the
+    numpy and the native pack give byte-identical summary arrays, memo
+    contents and doc/fast/fallback counts under the pipeline."""
     src = tmp_path / "src"
     urls, want = _make_corpus(src, n_docs=14)
     gap_url = _add_gap_doc(src)
     ids = [validate_doc_url(u) for u in urls] + [validate_doc_url(gap_url)]
 
     results = {}
-    for mode in ("0", "1"):
-        copy = tmp_path / f"repo{mode}"
+    for pack in ("0", "1"):
+        copy = tmp_path / f"repo{pack}"
         shutil.copytree(src, copy)
-        results[mode] = _load_twice(
-            copy, ids, mode, monkeypatch, slab=4
+        results[pack] = _load_twice(
+            copy, ids, pack, monkeypatch, slab=4
         )  # 14 fast docs / slab 4 -> 4 slabs (3+ boundaries)
 
-    first0, second0, memo0, counts0 = results["0"]
-    first1, second1, memo1, counts1 = results["1"]
+    first0, second0, memo0, counts0, replay0 = results["0"]
+    first1, second1, memo1, counts1, replay1 = results["1"]
     assert counts0 == counts1
     assert counts0[0]["fallback"] == 1
     assert counts0[1]["memo"] == counts0[1]["fast"]  # 2nd load: all memo
@@ -163,13 +172,19 @@ def test_pipeline_serial_equivalence_fuzz(tmp_path, monkeypatch):
         assert first0[d] == first1[d], f"first-load summary differs: {d}"
     for d in second0:
         assert second0[d] == second1[d], f"memo-load summary differs: {d}"
+        assert first0[d] == second0[d], f"memo row != fetched row: {d}"
     assert memo0 == memo1
+    by_id = {validate_doc_url(u): want[u] for u in urls}
+    for replay in (replay0, replay1):
+        assert len(replay) == 14
+        for d, (loaded, replayed) in replay.items():
+            assert loaded == replayed, d
+            assert loaded[1] == by_id[d], d
 
 
 def test_pipeline_matches_interactive_state(tmp_path, monkeypatch):
     """Pipelined bulk loads materialize the same doc values the writer
     saw (end-to-end through handles, not just summary arrays)."""
-    monkeypatch.setenv("HM_PIPELINE", "1")
     urls, want = _make_corpus(tmp_path / "r", n_docs=9, seed=3)
     repo = Repo(path=str(tmp_path / "r"))
     ids = [validate_doc_url(u) for u in urls]
@@ -223,7 +238,6 @@ def test_pipeline_pack_failure_fails_load_cleanly(tmp_path, monkeypatch):
 
     urls, _want = _make_corpus(tmp_path / "r", n_docs=12, seed=11)
     ids = [validate_doc_url(u) for u in urls]
-    monkeypatch.setenv("HM_PIPELINE", "1")
 
     real = columnar.pack_docs_columns
     calls = {"n": 0}
@@ -242,8 +256,8 @@ def test_pipeline_pack_failure_fails_load_cleanly(tmp_path, monkeypatch):
         )
     assert "boom-pack" in repr(ei.value.__cause__)
     _assert_pipe_threads_drained()
-    assert repo.back._pending_summaries == []
-    assert repo.back._fetch_ctx is None
+    assert repo.back.loader._pending_summaries == []
+    assert repo.back.loader._fetch_ctx is None
     repo.close()
 
     # the corpus itself is intact: a fresh backend loads it fine
@@ -259,14 +273,13 @@ def test_pipeline_fetch_failure_fails_cleanly(tmp_path, monkeypatch):
     """A slab whose summary fetch raises must surface the error (at the
     load or at the barrier, wherever the overlap window puts it) and
     leave no hung workers or pending refs."""
-    from hypermerge_tpu.backend.repo_backend import RepoBackend
+    from hypermerge_tpu.backend.bulk_loader import BulkLoader
 
     urls, _want = _make_corpus(tmp_path / "r", n_docs=10, seed=13)
     ids = [validate_doc_url(u) for u in urls]
-    monkeypatch.setenv("HM_PIPELINE", "1")
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")  # real device fetches
 
-    real = RepoBackend._fetch_slab
+    real = BulkLoader._fetch_slab
     calls = {"n": 0}
 
     def boom(self, entry):
@@ -275,7 +288,7 @@ def test_pipeline_fetch_failure_fails_cleanly(tmp_path, monkeypatch):
             raise RuntimeError("boom-fetch")
         return real(self, entry)
 
-    monkeypatch.setattr(RepoBackend, "_fetch_slab", boom)
+    monkeypatch.setattr(BulkLoader, "_fetch_slab", boom)
     repo = Repo(path=str(tmp_path / "r"))
 
     def load_and_barrier():
@@ -286,20 +299,19 @@ def test_pipeline_fetch_failure_fails_cleanly(tmp_path, monkeypatch):
         _call_with_timeout(load_and_barrier)
     assert "boom-fetch" in repr(ei.value.__cause__)
     _assert_pipe_threads_drained()
-    assert repo.back._pending_summaries == []
-    assert repo.back._fetch_ctx is None
+    assert repo.back.loader._pending_summaries == []
+    assert repo.back.loader._fetch_ctx is None
     repo.close()
 
 
 def test_round_robin_slabs_across_devices(tmp_path, monkeypatch):
-    """With >1 visible device and the pipeline on, successive slabs
+    """With >1 visible device successive slabs
     land whole on successive devices (rr_slabs accounting), with
     results identical to the interactive state."""
     import jax
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >1 (virtual) device")
-    monkeypatch.setenv("HM_PIPELINE", "1")
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
     urls, want = _make_corpus(tmp_path / "r", n_docs=6, seed=5)
     repo = Repo(path=str(tmp_path / "r"))
@@ -357,7 +369,6 @@ def test_pipeline_per_chip_stats(tmp_path, monkeypatch):
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >1 (virtual) device")
-    monkeypatch.setenv("HM_PIPELINE", "1")
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
     urls, want = _make_corpus(tmp_path / "r", n_docs=6, seed=7)
     repo = Repo(path=str(tmp_path / "r"))
@@ -377,7 +388,7 @@ def test_pipeline_per_chip_stats(tmp_path, monkeypatch):
     assert sum(stats["t_fetch_chips"]) > 0
     # the PRODUCT scheduler never tracks collective-reduction refs:
     # nothing may pin slab wires beyond the barrier
-    rr = repo.back._rr_value
+    rr = repo.back.loader._rr
     if hasattr(rr, "track_resident"):
         assert rr.track_resident is False
         assert all(not q for q in rr._resident_wires.values())
@@ -387,14 +398,10 @@ def test_pipeline_per_chip_stats(tmp_path, monkeypatch):
     repo.close()
 
 
-def _load_once(path, ids, monkeypatch, slab, workers, device_pack, order):
-    """One pipelined bulk load under a given pack-plane config; env vars
-    are set in the given order (the routing must not care)."""
-    monkeypatch.setenv("HM_PIPELINE", "1")
+def _load_once(path, ids, monkeypatch, slab, workers):
+    """One pipelined bulk load with a pack pool of `workers`."""
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
-    pair = (("HM_PACK_WORKERS", workers), ("HM_DEVICE_PACK", device_pack))
-    for var, val in pair if order == 0 else pair[::-1]:
-        monkeypatch.setenv(var, val)
+    monkeypatch.setenv("HM_PACK_WORKERS", workers)
     repo = Repo(path=str(path))
     back = repo.back
     back.load_documents_bulk(ids, slab=slab)
@@ -407,30 +414,21 @@ def _load_once(path, ids, monkeypatch, slab, workers, device_pack, order):
 
 
 def test_pipeline_pack_worker_matrix(tmp_path, monkeypatch):
-    """HM_PACK_WORKERS={0,1,4} x HM_DEVICE_PACK={0,1}, both env set
-    orders, over a ragged-tail corpus (10 docs / slab 4 -> 4+4+2):
-    every pack-plane config produces summaries byte-identical to the
-    one-worker host baseline, and the pool reports its shape
+    """HM_PACK_WORKERS={0,1,4} over a ragged-tail corpus (10 docs /
+    slab 4 -> 4+4+2): every pool size produces summaries byte-identical
+    to the one-worker baseline, and the pool reports its shape
     (pack_workers, per-worker busy lanes, lane wall)."""
     from hypermerge_tpu.backend.pipeline import pack_worker_count
-    from hypermerge_tpu.ops import pack_kernels
 
     src = tmp_path / "src"
     urls, _want = _make_corpus(src, n_docs=10, seed=19)
     ids = [validate_doc_url(u) for u in urls]
 
     results = {}
-    matrix = [
-        ("1", "0"), ("0", "0"), ("4", "0"),
-        ("1", "1"), ("4", "1"), ("0", "1"),
-    ]
-    for i, (workers, device) in enumerate(matrix):
+    for i, workers in enumerate(("1", "0", "4")):
         copy = tmp_path / f"m{i}"
         shutil.copytree(src, copy)
-        packs0 = pack_kernels._M_PACKS.value()
-        out, stats = _load_once(
-            copy, ids, monkeypatch, 4, workers, device, order=i % 2
-        )
+        out, stats = _load_once(copy, ids, monkeypatch, 4, workers)
         assert stats["pipeline"] == 1
         want_pool = pack_worker_count()  # env still set from _load_once
         assert stats["pack_workers"] == want_pool
@@ -439,12 +437,8 @@ def test_pipeline_pack_worker_matrix(tmp_path, monkeypatch):
         assert len(stats["t_pack_busy_per_worker"]) == want_pool
         assert stats["t_pack_wall"] >= 0.0
         assert sum(stats["t_pack_busy_per_worker"]) >= 0.0
-        if device == "1":
-            # the device kernel actually packed (it never silently
-            # falls through on these clean single-writer slabs)
-            assert pack_kernels._M_PACKS.value() > packs0
-        results[(workers, device)] = out
-    base = results[("1", "0")]
+        results[workers] = out
+    base = results["1"]
     for cfg, out in results.items():
         assert set(out) == set(base), cfg
         for d in base:
@@ -454,19 +448,19 @@ def test_pipeline_pack_worker_matrix(tmp_path, monkeypatch):
 @pytest.mark.slow
 def test_pipeline_pack_pool_large_shape(tmp_path, monkeypatch):
     """Largest-shape tier: a wider corpus across many slabs with the
-    full pool (4 workers) and the device kernel — still byte-identical
-    to the serial twin, pool accounting intact."""
+    full pool (4 workers) — still byte-identical to one pack worker,
+    pool accounting intact."""
     src = tmp_path / "src"
     urls, _want = _make_corpus(src, n_docs=42, seed=23)
     ids = [validate_doc_url(u) for u in urls]
 
-    copy0 = tmp_path / "serial"
+    copy0 = tmp_path / "one"
     shutil.copytree(src, copy0)
-    base, _ = _load_once(copy0, ids, monkeypatch, 8, "1", "0", order=0)
+    base, _ = _load_once(copy0, ids, monkeypatch, 8, "1")
 
     copy1 = tmp_path / "pool"
     shutil.copytree(src, copy1)
-    out, stats = _load_once(copy1, ids, monkeypatch, 8, "4", "1", order=1)
+    out, stats = _load_once(copy1, ids, monkeypatch, 8, "4")
     assert stats["pack_workers"] == 4
     assert len(stats["t_pack_busy_per_worker"]) == 4
     assert set(out) == set(base) and len(out) == 42
@@ -475,9 +469,8 @@ def test_pipeline_pack_pool_large_shape(tmp_path, monkeypatch):
 
 
 def test_pipeline_stats_report_busy_and_critical_path(tmp_path, monkeypatch):
-    """Pipeline mode reports per-stage busy time (t_*_busy) and the
+    """A load reports per-stage busy time (t_*_busy) and the
     overlapped wall critical path alongside the canonical keys."""
-    monkeypatch.setenv("HM_PIPELINE", "1")
     urls, _want = _make_corpus(tmp_path / "r", n_docs=5, seed=2)
     repo = Repo(path=str(tmp_path / "r"))
     ids = [validate_doc_url(u) for u in urls]
@@ -492,15 +485,15 @@ def test_pipeline_stats_report_busy_and_critical_path(tmp_path, monkeypatch):
     repo.close()
 
 
-@pytest.mark.parametrize("mode", ["0", "1"], ids=["serial", "pipelined"])
-def test_bulk_stats_say_which_kernel_ran(tmp_path, monkeypatch, mode):
+@pytest.mark.parametrize("pack", ["0", "1"], ids=["numpy", "native"])
+def test_bulk_stats_say_which_kernel_ran(tmp_path, monkeypatch, pack):
     """last_bulk_stats names what ran each slab — the device program
     (and on which platform) or the numpy twin below
-    HM_DEVICE_MIN_CELLS — in both HM_PIPELINE modes: a load that
-    quietly ran on the host must be visible in the stats."""
+    HM_DEVICE_MIN_CELLS — whichever pack fed it: a load that quietly
+    ran on the host must be visible in the stats."""
     urls, _want = _make_corpus(tmp_path, n_docs=10)
     ids = [validate_doc_url(u) for u in urls]
-    monkeypatch.setenv("HM_PIPELINE", mode)
+    monkeypatch.setenv("HM_NATIVE_PACK", pack)
 
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")  # every slab: device
     repo = Repo(path=str(tmp_path))
@@ -521,16 +514,16 @@ def test_bulk_stats_say_which_kernel_ran(tmp_path, monkeypatch, mode):
     assert st["platform"] is None  # no slab reached a device
 
 
-@pytest.mark.parametrize("mode", ["0", "1"], ids=["serial", "pipelined"])
-def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, mode):
-    """last_bulk_stats counts, in both twins, the column sidecars that
+@pytest.mark.parametrize("pack", ["0", "1"], ids=["numpy", "native"])
+def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, pack):
+    """last_bulk_stats counts, under either pack, the column sidecars that
     loaded slab-granular (one v3 image a feed, colcache.load_slab_images)
     and those that loaded feed by feed: a checkpointed corpus is all
     bulk, a store the live path wrote (v2 records only) all single, and
     a re-open of docs whose caches are loaded counts neither."""
     from hypermerge_tpu.ops.corpus import make_corpus
 
-    monkeypatch.setenv("HM_PIPELINE", mode)
+    monkeypatch.setenv("HM_NATIVE_PACK", pack)
 
     def cols(st):
         return (
@@ -544,7 +537,7 @@ def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, mode):
     repo.back.load_documents_bulk(ids, slab=4)
     repo.back.fetch_bulk_summaries()
     st = dict(repo.back.last_bulk_stats)
-    assert st["pipeline"] == int(mode)
+    assert st["pipeline"] == 1
     assert cols(st) == (10, 0, 100.0)
     for d in ids[:3]:
         repo.back.docs.pop(d)  # forget three docs; their actors stay

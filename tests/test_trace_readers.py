@@ -41,8 +41,7 @@ def traced_open(tmp_path_factory):
     from hypermerge_tpu.repo import Repo
 
     tmp = tmp_path_factory.mktemp("traced")
-    env = {"HM_DEVICE_MIN_CELLS": "0", "HM_BULK_SLAB": str(SLAB),
-           "HM_PIPELINE": "1"}
+    env = {"HM_DEVICE_MIN_CELLS": "0", "HM_BULK_SLAB": str(SLAB)}
     old = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     try:
