@@ -443,8 +443,11 @@ GUARDS: Tuple[GuardedClass, ...] = (
         doc="The shared snapshot pack workers read CONCURRENTLY. "
             "`rows` is a lazy idempotent latch: ensure_rows() derives "
             "the row matrix from the immutable planes and rebinds "
-            "once (GIL-atomic); racing workers at worst duplicate the "
-            "compute, never observe a torn value. The "
+            "once (GIL-atomic); racing callers at worst duplicate the "
+            "compute, never observe a torn value. No pack path calls "
+            "it any more (both read the planes; the general pack's "
+            "gather since ISSUE 29), so pack workers only ever READ "
+            "`rows`, where a feed is rows-backed to begin with. The "
             "`_prefix_single_ok` bool ops/columnar caches on the "
             "object is the same idiom (set through a foreign "
             "receiver, so only this story covers it — the checkers "

@@ -69,9 +69,12 @@ _STATS0: Dict[str, Any] = {
     "heads_snapshot_feeds": 0, "heads_probed_feeds": 0,
     "heads_snapshot_pct": 0.0,
     # feeds the open read; docs whose slab took the general
-    # (multi-writer) pack and their share; the widest actor and pred
-    # buckets among the slabs' programs
+    # (multi-writer) pack and their share; that pack's feeds by who
+    # gathered their rows (the native entry / its numpy twin); the
+    # widest actor and pred buckets among the slabs' programs
     "feeds": 0, "pack_general_docs": 0, "pack_general_pct": 0.0,
+    "pack_gather_native_feeds": 0, "pack_gather_twin_feeds": 0,
+    "pack_gather_native_pct": 0.0,
     "a_loc_max": 0, "pred_max": 0,
     **dict.fromkeys(_STAGE_KEYS, 0.0),
 }
@@ -230,6 +233,8 @@ class BulkLoader:
                 ("cols_bulk_pct", "cols_bulk_feeds", "cols_single_feeds"),
                 ("heads_snapshot_pct", "heads_snapshot_feeds",
                  "heads_probed_feeds"),
+                ("pack_gather_native_pct", "pack_gather_native_feeds",
+                 "pack_gather_twin_feeds"),
             ):
                 stats[pct] = _pct(stats[part], stats[part] + stats[rest])
             stats["pack_general_pct"] = _pct(
@@ -548,6 +553,9 @@ class BulkLoader:
             with self._stats_lock:
                 if batch.packed_by == "general":
                     stats["pack_general_docs"] += len(chunk)
+                    native, twin = batch.gather_feeds
+                    stats["pack_gather_native_feeds"] += native
+                    stats["pack_gather_twin_feeds"] += twin
                 stats["a_loc_max"] = max(stats["a_loc_max"], a_loc)
                 stats["pred_max"] = max(
                     stats["pred_max"], batch.psrc.shape[1]

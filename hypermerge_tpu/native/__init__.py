@@ -103,10 +103,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     # + codec rather than disabling the whole native layer.
     #
     # GIL contract: the library is loaded with ctypes.CDLL (never
-    # PyDLL), so every foreign call — hm_pack_prefix included — RUNS
-    # WITH THE GIL RELEASED for the duration of the C call. The
-    # streaming slab pipeline (backend/pipeline.py) depends on this:
-    # its pack worker thread spends its time inside hm_pack_prefix
+    # PyDLL), so every foreign call — hm_pack_prefix and hm_pack_gather
+    # included — RUNS WITH THE GIL RELEASED for the duration of the C
+    # call. The streaming slab pipeline (backend/pipeline.py) depends
+    # on this: its pack worker thread spends its time inside them
     # while the io thread reads the next slab's sidecars and the
     # dispatch thread feeds the device. The pack entries touch only
     # caller-owned buffers (no Python objects, no allocation through
@@ -118,6 +118,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib.hm_pack_value_minmax.argtypes = [ll] + [ctypes.c_void_p] * 12
         lib.hm_pack_prefix.restype = ctypes.c_int
         lib.hm_pack_prefix.argtypes = [ll, ll, ll] + [ctypes.c_void_p] * 16
+        lib.hm_pack_gather.restype = ctypes.c_int
+        lib.hm_pack_gather.argtypes = [ll] + [ctypes.c_void_p] * 8
         lib._has_pack = True
     except AttributeError:
         lib._has_pack = False
@@ -191,8 +193,8 @@ def pack_drops_gil() -> bool:
 
 
 def pack_parallel_ok() -> bool:
-    """True when hm_pack_prefix / hm_pack_value_minmax may be called
-    from SEVERAL threads at once — the pack pool's contract
+    """True when hm_pack_prefix / hm_pack_value_minmax / hm_pack_gather
+    may be called from SEVERAL threads at once — the pack pool's contract
     (HM_PACK_WORKERS > 1, backend/pipeline.py).
 
     The entry points are stateless C loops: every pointer they touch

@@ -492,6 +492,156 @@ int hm_pack_prefix(
   return 0;
 }
 
+// The general pack's gather (ops/columnar.py _gather_general): every
+// window of every feed of a slab, read plane by plane in the dtype the
+// plane is stored in, written ONCE into flat [M] columns in the dtype the
+// stages after it (sort, resolve, emit) want, with the gather's remaps
+// folded in: obj_a / ref_a through the actor LUT (sentinels < 0 pass),
+// key through the key LUT, value through the string / float / bigint
+// LUTs by vkind, the writer's global id, the doc column, and the pred
+// rows (src rebased to the flat row index, target actor through the
+// actor LUT, doc). The numpy twin beside the caller is the reference and
+// the two are pinned bit-identical (tests/test_native_pack.py).
+//
+// A source plane is (pointer, dtype code, byte stride): a slice of a
+// checkpoint image, a plane array of its own, or a column of a feed's
+// dense int32 row matrix (stride 56). Planes 0..12 are the sidecar's
+// PLANE_NAMES order (all but `flags`); output column j < 13 is plane j:
+//   int32: 0 action, 2 seq, 6 key, 9 insert, 10 vkind, 11 value, 12 dt
+//   int64: 1 ctr, 3 start_op, 4 obj_ctr, 5 obj_a, 7 ref_ctr, 8 ref_a,
+//          13 actor, 14 doc; preds: 15 src, 16 tgt_ctr, 17 tgt_a, 18 doc
+// win is [W, 6]: feed, doc, lo, rows, pred lo, preds (the caller derives
+// the windows from row_ends and the preds' src column, and has checked
+// lo + rows against the feed's row count; the pred ranges index `preds`,
+// the slab's [*, 3] int32 pred rows, feed after feed). tabs is [5, 3]:
+// (LUT, per-feed offsets, LUT length) for actors, keys, strings, floats,
+// bigints. Every LUT index comes from disk and is clamped (pk_lut), and
+// the dtype codes are pk_ld's.
+
+static const int GATHER_NP = 13;
+static const uint8_t GATHER_WIDE[GATHER_NP] = {0, 1, 0, 1, 1, 1, 0,
+                                               1, 1, 0, 0, 0, 0};
+
+// One source plane's window into a flat output column: the dtype switch
+// (pk_ld's codes) is taken once a window, not once an element, and the
+// load is the same memcpy (planes are rarely aligned, see pk_ld).
+} // extern "C" (templates have no C linkage)
+
+template <typename S, typename O>
+static inline void pk_copy_as(const char *p, long long stride, long long n,
+                              O *out) {
+  for (long long i = 0; i < n; i++) {
+    S v;
+    memcpy(&v, p + i * stride, sizeof(S));
+    out[i] = (O)v;
+  }
+}
+
+template <typename O>
+static inline void pk_copy(const void *p, int dt, long long stride,
+                           long long n, O *out) {
+  const char *c = (const char *)p;
+  switch (dt) {
+  case 0:
+    pk_copy_as<int8_t, O>(c, stride, n, out);
+    break;
+  case 1:
+    pk_copy_as<int16_t, O>(c, stride, n, out);
+    break;
+  case 2:
+    pk_copy_as<int32_t, O>(c, stride, n, out);
+    break;
+  default:
+    pk_copy_as<uint8_t, O>(c, stride, n, out);
+    break;
+  }
+}
+
+extern "C" {
+
+int hm_pack_gather(long long W, const long long *win,
+                   const long long *src_ptrs, const uint8_t *src_dt,
+                   const long long *src_stride, const long long *tabs,
+                   const long long *writer_g, const int32_t *preds,
+                   const long long *out_ptrs) {
+  const long long *lut[5], *off[5];
+  long long len[5];
+  for (int t = 0; t < 5; t++) {
+    lut[t] = (const long long *)tabs[t * 3];
+    off[t] = (const long long *)tabs[t * 3 + 1];
+    len[t] = tabs[t * 3 + 2];
+  }
+  long long base = 0, pbase = 0;
+  for (long long w = 0; w < W; w++) {
+    const long long *wn = win + w * 6;
+    long long f = wn[0], d = wn[1], lo = wn[2], n = wn[3];
+    if (n < 0 || lo < 0 || wn[5] < 0)
+      return -1;
+    const long long *sp = src_ptrs + f * GATHER_NP;
+    const uint8_t *sd = src_dt + f * GATHER_NP;
+    const long long *ss = src_stride + f * GATHER_NP;
+    long long ao = off[0][f];
+    for (int c = 0; c < GATHER_NP; c++) {
+      const void *src = (const char *)sp[c] + lo * ss[c];
+      if (GATHER_WIDE[c])
+        pk_copy(src, sd[c], ss[c], n, (long long *)out_ptrs[c] + base);
+      else
+        pk_copy(src, sd[c], ss[c], n, (int32_t *)out_ptrs[c] + base);
+    }
+    // the remaps, in place on what was just written
+    for (int c = 5; c <= 8; c += 3) { // obj_a / ref_a: sentinels pass
+      long long *a = (long long *)out_ptrs[c] + base;
+      for (long long i = 0; i < n; i++)
+        if (a[i] >= 0)
+          a[i] = pk_lut(lut[0], len[0], ao + a[i]);
+    }
+    { // key: feed-local -> batch-global (-1 none)
+      int32_t *k = (int32_t *)out_ptrs[6] + base;
+      long long ko = off[1][f];
+      for (long long i = 0; i < n; i++)
+        k[i] = (int32_t)(k[i] >= 0 ? pk_lut(lut[1], len[1], ko + k[i]) : -1);
+    }
+    { // value: side-table kinds remap by vkind
+      const int32_t *vk = (const int32_t *)out_ptrs[10] + base;
+      int32_t *v = (int32_t *)out_ptrs[11] + base;
+      for (long long i = 0; i < n; i++) {
+        if (vk[i] == PK_VK_STR)
+          v[i] = (int32_t)pk_lut(lut[2], len[2], off[2][f] + v[i]);
+        else if (vk[i] == PK_VK_FLOAT)
+          v[i] = (int32_t)pk_lut(lut[3], len[3], off[3][f] + v[i]);
+        else if (vk[i] == PK_VK_BIGINT)
+          v[i] = (int32_t)pk_lut(lut[4], len[4], off[4][f] + v[i]);
+      }
+    }
+    {
+      long long *actor = (long long *)out_ptrs[13] + base;
+      long long *doc = (long long *)out_ptrs[14] + base;
+      long long wg = writer_g[f];
+      for (long long i = 0; i < n; i++) {
+        actor[i] = wg;
+        doc[i] = d;
+      }
+    }
+    { // pred rows of the window: (src row in the feed, tgt ctr, tgt actor)
+      const int32_t *pr = preds + wn[4] * 3;
+      long long np_ = wn[5];
+      long long *p_src = (long long *)out_ptrs[15] + pbase;
+      long long *p_ctr = (long long *)out_ptrs[16] + pbase;
+      long long *p_a = (long long *)out_ptrs[17] + pbase;
+      long long *p_doc = (long long *)out_ptrs[18] + pbase;
+      for (long long i = 0; i < np_; i++) {
+        p_src[i] = (long long)pr[i * 3] - lo + base;
+        p_ctr[i] = pr[i * 3 + 1];
+        p_a[i] = pk_lut(lut[0], len[0], ao + pr[i * 3 + 2]);
+        p_doc[i] = d;
+      }
+      pbase += np_;
+    }
+    base += n;
+  }
+  return 0;
+}
+
 // -------------------------------------------------------------------
 // Block codec. codec: 1 = brotli, 2 = zlib. Returns compressed size,
 // -1 on error, -2 if codec unavailable. Caller sizes `out` with

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
+from operator import attrgetter
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -112,6 +113,8 @@ class ColumnarBatch:
     slot: Optional[np.ndarray] = None  # [D, N] int16 local actor slots
     # which pack_docs_columns path made the batch: "prefix" | "general"
     packed_by: str = ""
+    # general path: feeds whose rows the native gather / its numpy twin read
+    gather_feeds: Tuple[int, int] = (0, 0)
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -439,6 +442,11 @@ def _pack_src_idx() -> np.ndarray:
     return got
 
 
+def _ptr(a: np.ndarray) -> int:
+    """The address of an array's first element, for the native ABI."""
+    return a.__array_interface__["data"][0]
+
+
 def _native_pack_lib():
     if os.environ.get("HM_NATIVE_PACK", "1") == "0":
         return None
@@ -519,9 +527,7 @@ def _native_pack_prefix(
     ends = np.ascontiguousarray(ends, np.int64)
     fc_idx_a = np.ascontiguousarray(fc_idx_a, np.int64)
 
-    def ptr(a):
-        return a.__array_interface__["data"][0]
-
+    ptr = _ptr
     mm = np.zeros(2, np.int64)
     rc = lib.hm_pack_value_minmax(
         D, ptr(fc_idx_a), ptr(ends), ptr(srcs), ptr(sdts),
@@ -843,9 +849,12 @@ def _pack_prefix_single(
     return batch
 
 
-# docs packed by each path, over the process's life (tools/top.py)
+# docs packed by each path, over the process's life (tools/top.py), and
+# the general pack's feeds by who gathered their rows
 _M_PACK_PREFIX = telemetry.counter("pipeline.pack_prefix_docs")
 _M_PACK_GENERAL = telemetry.counter("pipeline.pack_general_docs")
+_M_GATHER_NATIVE = telemetry.counter("pipeline.pack_gather_native_feeds")
+_M_GATHER_TWIN = telemetry.counter("pipeline.pack_gather_twin_feeds")
 
 
 def _stage(name: str):
@@ -893,24 +902,255 @@ def pack_docs_columns(
     return batch
 
 
+# what the general pack's gather hands the stages after it: the
+# sidecar's planes but `flags` (PLANE_NAMES order: the source plane order
+# of hm_native.cpp hm_pack_gather), the writer and doc of every row, and
+# the pred rows. A column that enters a composite key or indexes another
+# is int64; the rest are only emitted, as the int32 they are emitted in.
+_GATHER_PLANES = (
+    "action", "ctr", "seq", "start_op", "obj_ctr", "obj_a", "key",
+    "ref_ctr", "ref_a", "insert", "vkind", "value", "dt",
+)
+_GATHER_COLS = _GATHER_PLANES + (
+    "actor", "doc", "pr_src", "pr_tgt_ctr", "pr_tgt_a", "pr_doc",
+)
+_GATHER_I32 = frozenset(
+    ("action", "seq", "key", "insert", "vkind", "value", "dt")
+)
+_DT_ITEMSIZE = np.asarray(
+    [dt.itemsize for dt in sorted(_DT_CODE, key=_DT_CODE.get)], np.int64
+)
+
+
+def _starts(counts: np.ndarray) -> np.ndarray:
+    """[len + 1] exclusive running sum of `counts`."""
+    at = np.zeros(len(counts) + 1, np.int64)
+    np.cumsum(counts, out=at[1:])
+    return at
+
+
+def _ranges(starts: np.ndarray, counts: np.ndarray) -> np.ndarray:
+    """concatenate([arange(s, s + c) for s, c in ...]) without the loop."""
+    at = _starts(counts)
+    return np.repeat(starts - at[:-1], counts) + np.arange(
+        int(at[-1]), dtype=np.int64
+    )
+
+
+def _gather_windows(doc_specs, fcs, fc_of):
+    """Every window of a slab as one [W, 6] int64 table (feed, doc, lo,
+    rows, pred lo, preds), the slab's pred rows feed after feed (what
+    the pred ranges index), and every feed's row count. A window that
+    row_ends puts outside its feed's rows (a corrupt sidecar) raises
+    here, before any loop reads a plane.
+
+    [lo, lo + rows) is FeedColumns.window for all windows at once:
+    changes (start_seq, end_seq] clamped to ok_prefix_len, through
+    row_ends; the pred window is the feed's preds whose src row lies in
+    it (src is nondecreasing within a feed). What Python does per feed
+    is what walking doc_specs forces; a feed listed twice in one doc
+    gives one window."""
+    w_fc: List[int] = []
+    w_doc: List[int] = []
+    w_s: List[int] = []
+    w_e: List[float] = []
+    for d, spec in enumerate(doc_specs):
+        seen = set()
+        for fc, s, e in spec:
+            fci = fc_of[id(fc)]
+            if fci in seen:
+                continue
+            seen.add(fci)
+            w_fc.append(fci)
+            w_doc.append(d)
+            w_s.append(s)
+            w_e.append(e)
+    F = len(fcs)
+    if not F:
+        none = np.zeros(0, np.int64)
+        return none.reshape(0, 6), np.zeros((0, 3), np.int32), none
+    # the feeds' fields in one C-level pass each (no bytecode per feed:
+    # the loading thread's Python competes for the GIL meanwhile)
+    n_changes, ok_prefix, ends, preds = zip(
+        *map(attrgetter("n_changes", "ok_prefix_len", "row_ends", "preds"),
+             fcs)
+    )
+    wf = np.asarray(w_fc, np.int64)
+    n_changes = np.asarray(n_changes, np.int64)[wf]
+    e = np.minimum(
+        np.asarray(w_e, np.float64), np.asarray(ok_prefix, np.float64)[wf]
+    ).astype(np.int64)
+    s_at = np.minimum(np.asarray(w_s, np.int64), n_changes)
+    e_at = np.clip(e, 0, n_changes)
+    ends_at = _starts(np.fromiter(map(len, ends), np.int64, F))
+    if np.any(
+        (s_at < 0) | (np.maximum(s_at, e_at) >= np.diff(ends_at)[wf])
+    ):
+        raise IndexError("window outside its feed's row_ends")
+    ends_all = np.concatenate(ends).astype(np.int64, copy=False)
+    lo = ends_all[ends_at[wf] + s_at]
+    hi = np.maximum(np.where(e > 0, ends_all[ends_at[wf] + e_at], 0), lo)
+    feed_rows = np.fromiter(map(attrgetter("n_rows"), fcs), np.int64, F)
+    if np.any((lo < 0) | (hi > feed_rows[wf])):
+        raise ValueError("row_ends overruns the feed's planes")
+
+    n_preds = np.fromiter(map(len, preds), np.int64, F)
+    if n_preds.any():
+        preds_all = np.ascontiguousarray(np.concatenate(preds), np.int32)
+        # one search for all windows: (feed, src) is sorted as a whole
+        key = np.repeat(np.arange(F, dtype=np.int64) << 32, n_preds)
+        key += preds_all[:, 0]
+        plo = np.searchsorted(key, (wf << 32) + lo)
+        phi = np.searchsorted(key, (wf << 32) + hi)
+    else:
+        preds_all = np.zeros((0, 3), np.int32)
+        plo = phi = np.zeros(len(wf), np.int64)
+    win = np.stack(
+        [wf, np.asarray(w_doc, np.int64), lo, hi - lo, plo, phi - plo],
+        axis=1,
+    )
+    return win, preds_all, feed_rows
+
+
+def _gather_sources(fcs):
+    """([F, 13] pointers, dtype codes, byte strides) of the feeds'
+    source planes for hm_pack_gather, each feed described from
+    what it is: the rows of its plane_meta table where every plane is a
+    slice of one image, the plane arrays' own pointers otherwise, and
+    for a rows-backed feed thirteen int32 columns of its row matrix.
+    None when a plane cannot be described to the native ABI."""
+    F, NP = len(fcs), len(_GATHER_PLANES)
+    srcs = np.empty((F, NP), np.int64)
+    sdts = np.empty((F, NP), np.uint8)
+    strides = np.empty((F, NP), np.int64)
+    imaged = [i for i, fc in enumerate(fcs) if fc.plane_meta is not None]
+    if imaged:
+        metas = [fcs[i].plane_meta for i in imaged]
+        n = len(metas)
+        base = np.fromiter((m[0] for m in metas), np.int64, n)
+        offs = np.concatenate([m[1] for m in metas]).reshape(n, -1)
+        code = np.concatenate([m[2] for m in metas]).reshape(n, -1)[:, :NP]
+        srcs[imaged] = base[:, None] + offs[:, :NP]
+        sdts[imaged] = code
+        strides[imaged] = _DT_ITEMSIZE[code]
+    col4 = 4 * np.arange(NP, dtype=np.int64)
+    for i, fc in enumerate(fcs):
+        if fc.plane_meta is not None:
+            continue
+        if fc.planes is not None:
+            n = fc.n_rows
+            for j, name in enumerate(_GATHER_PLANES):
+                p = fc.planes[name]
+                code = _DT_CODE.get(p.dtype)
+                if code is None or p.ndim != 1 or len(p) != n:
+                    return None
+                srcs[i, j] = p.__array_interface__["data"][0]
+                sdts[i, j] = code
+                strides[i, j] = p.strides[0]
+            continue
+        rows = fc.rows
+        if rows is None or rows.dtype != np.int32 or rows.ndim != 2 or (
+            rows.shape[1] < NP or (len(rows) and rows.strides[1] != 4)
+        ):
+            return None
+        srcs[i] = rows.__array_interface__["data"][0] + col4
+        sdts[i] = _DT_CODE[rows.dtype]
+        strides[i] = rows.strides[0]
+    return srcs, sdts, strides
+
+
+def _native_gather(lib, fcs, win, preds_all, tabs, writer_g):
+    """The gather through the C++ entry point (hm_native.cpp
+    hm_pack_gather, GIL dropped): each source plane read in the dtype
+    it is stored in, each column written once. {} when a plane cannot
+    be described to the native ABI (the caller takes the numpy twin)."""
+    described = _gather_sources(fcs)
+    if described is None:
+        return {}
+    srcs, sdts, strides = described
+    ptr = _ptr
+    tab = np.asarray(
+        [(ptr(lut), ptr(offs), len(lut)) for lut, offs in tabs], np.int64
+    )
+    writer_g = np.ascontiguousarray(writer_g, np.int64)
+    n_rows, n_preds = int(win[:, 3].sum()), int(win[:, 5].sum())
+    g = {
+        name: np.empty(
+            n_preds if name.startswith("pr_") else n_rows,
+            np.int32 if name in _GATHER_I32 else np.int64,
+        )
+        for name in _GATHER_COLS
+    }
+    outs = np.asarray([ptr(g[name]) for name in _GATHER_COLS], np.int64)
+    rc = lib.hm_pack_gather(
+        len(win), ptr(win), ptr(srcs), ptr(sdts), ptr(strides), ptr(tab),
+        ptr(writer_g), ptr(preds_all), ptr(outs),
+    )
+    return g if rc == 0 else {}
+
+
+def _gather_twin(fcs, feed_rows, win, preds_all, tabs, writer_g):
+    """The gather in numpy, column by column over the feeds' planes:
+    the reference of hm_pack_gather, and what runs without the native
+    library, under HM_NATIVE_PACK=0, or for a slab the native ABI
+    cannot take."""
+    (alut, aoffs), (klut, koffs), *value_tabs = tabs
+    wf, wd, lo, cnt, plo, pcnt = win.T
+    # the windows' rows within the feeds' planes laid end to end
+    src = _ranges(_starts(feed_rows)[wf] + lo, cnt)
+
+    def col(name):
+        flat = np.concatenate([fc.plane(name) for fc in fcs])
+        return flat[src].astype(np.int64)
+
+    def lut_where(cond, lut, idx, alt):
+        # np.where evaluates both branches: rows where cond is False
+        # carry a sentinel local index (e.g. -1), and a feed whose table
+        # is empty but sits at the end of the flat LUT would index one
+        # past the end — clamp before gathering, select after.
+        safe = np.minimum(np.maximum(idx, 0), len(lut) - 1)
+        return np.where(cond, lut[safe], alt)
+
+    g = {name: col(name) for name in _GATHER_PLANES}
+    aoff_col = np.repeat(aoffs[wf], cnt)
+    for name in ("obj_a", "ref_a"):  # sentinels (< 0) pass
+        a_l = g[name]
+        g[name] = lut_where(a_l >= 0, alut, aoff_col + a_l, a_l)
+    key_l = g["key"]
+    g["key"] = lut_where(
+        key_l >= 0, klut, np.repeat(koffs[wf], cnt) + key_l, -1
+    )
+    value = g["value"]
+    for code, (lut, offs) in zip((VK_STR, VK_FLOAT, VK_BIGINT), value_tabs):
+        m = g["vkind"] == code
+        if m.any():
+            value[m] = lut[np.repeat(offs[wf], cnt)[m] + value[m]]
+    for name in _GATHER_I32:
+        g[name] = g[name].astype(np.int32)
+    g["actor"] = np.repeat(writer_g[wf], cnt)
+    g["doc"] = np.repeat(wd, cnt)
+
+    # preds: src rebased from the feed's rows to the flat row index
+    pr = preds_all[_ranges(plo, pcnt)].astype(np.int64)
+    g["pr_src"] = pr[:, 0] + np.repeat(_starts(cnt)[:-1] - lo, pcnt)
+    g["pr_tgt_ctr"] = pr[:, 1]
+    g["pr_tgt_a"] = alut[np.repeat(aoffs[wf], pcnt) + pr[:, 2]]
+    g["pr_doc"] = np.repeat(wd, pcnt)
+    return g
+
+
 def _pack_general(
     doc_specs, n_rows, n_pred, n_docs, span
 ) -> ColumnarBatch:
     """The general pack: any number of feeds a doc, any windows. Rows
-    are gathered feed by feed, references and pred targets resolved by
+    are gathered straight from the feeds' planes in one pass (a native
+    entry and its numpy twin), references and pred targets resolved by
     a composite (doc, counter, actor) key over one M-sized argsort, ops
     whose container or referenced element is outside the window dropped
     to a fixpoint, and the rows laid out in causal order by a second
     argsort. `span` (pipeline.pack.general) takes the slab's feeds and
     rows as tags once they are known."""
-    from ..storage.colcache import (
-        OBJ_ROOT,
-        REF_HEAD,
-        REF_NONE,
-        VK_BIGINT,
-        VK_FLOAT,
-        VK_STR,
-    )
+    from ..storage.colcache import OBJ_ROOT, REF_HEAD, REF_NONE
 
     D = len(doc_specs)
     Dp = max(n_docs, D) if n_docs is not None else D
@@ -977,43 +1217,9 @@ def _pack_general(
         blut, boffs = _flat_lut("b")
 
     # -- gather window slices ------------------------------------------
-    with _stage("gather"):
-        row_slices: List[np.ndarray] = []
-        w_doc: List[int] = []
-        w_fc: List[int] = []
-        w_cnt: List[int] = []
-        pred_slices: List[np.ndarray] = []
-        p_doc: List[int] = []
-        p_fc: List[int] = []
-        p_cnt: List[int] = []
-        p_base: List[int] = []
-        flat_base = 0
-        for d, spec in enumerate(doc_specs):
-            seen = set()
-            for fc, s, e in spec:
-                fci = fc_of[id(fc)]
-                if fci in seen:
-                    continue  # same feed listed twice: one window only
-                seen.add(fci)
-                lo, hi = fc.window(int(s), e)
-                if hi <= lo:
-                    continue
-                row_slices.append(fc.ensure_rows()[lo:hi])
-                w_doc.append(d)
-                w_fc.append(fci)
-                w_cnt.append(hi - lo)
-                psrc_col = fc.preds[:, 0]
-                plo = int(np.searchsorted(psrc_col, lo, side="left"))
-                phi = int(np.searchsorted(psrc_col, hi, side="left"))
-                if phi > plo:
-                    pred_slices.append(fc.preds[plo:phi])
-                    p_doc.append(d)
-                    p_fc.append(fci)
-                    p_cnt.append(phi - plo)
-                    p_base.append(flat_base - lo)
-                flat_base += hi - lo
-
-        M = flat_base
+    with _stage("gather") as gather_span:
+        win, preds_all, feed_rows = _gather_windows(doc_specs, fcs, fc_of)
+        M = int(win[:, 3].sum())
         A = max(1, len(sorted_actors))
         if M == 0:
             N = n_rows if n_rows is not None else 1
@@ -1021,72 +1227,33 @@ def _pack_general(
             return _empty_batch(
                 Dp, N, P, sorted_actors, key_int, str_int, float_int, big_int
             )
-
-        w_cnt_a = np.asarray(w_cnt, np.int64)
-        w_doc_a = np.asarray(w_doc, np.int64)
-        w_fc_a = np.asarray(w_fc, np.int64)
-        R = np.concatenate(row_slices, axis=0)
-        doc_col = np.repeat(w_doc_a, w_cnt_a)
-        aoff_col = np.repeat(aoffs[w_fc_a], w_cnt_a)
-
-        action = R[:, 0].astype(np.int64)
-        ctr = R[:, 1].astype(np.int64)
-        seqc = R[:, 2].astype(np.int64)
-        start_op = R[:, 3].astype(np.int64)
-        obj_ctr = R[:, 4].astype(np.int64)
-        obj_a_l = R[:, 5].astype(np.int64)
-        key_l = R[:, 6].astype(np.int64)
-        ref_ctr = R[:, 7].astype(np.int64)
-        ref_a_l = R[:, 8].astype(np.int64)
-        insert = R[:, 9].astype(np.int64)
-        vkind = R[:, 10].astype(np.int64)
-        value_l = R[:, 11].astype(np.int64)
-        dt = R[:, 12].astype(np.int64)
-
+        tabs = (
+            (alut, aoffs), (klut, koffs), (slut, soffs), (flut, foffs),
+            (blut, boffs),
+        )
         # writer (op actor) = feed-local actor 0
-        writer_g = np.asarray(
-            [int(luts["a"][fci][0]) for fci in range(len(fcs))], np.int64
+        if not np.all(np.diff(aoffs)):
+            raise IndexError("a feed's actor table lacks its writer")
+        writer_g = alut[aoffs[:-1]]
+        native_lib = _native_pack_lib()
+        g = (
+            _native_gather(native_lib, fcs, win, preds_all, tabs, writer_g)
+            if native_lib is not None
+            else {}
         )
-        actor_g = np.repeat(writer_g[w_fc_a], w_cnt_a)
-
-        def _lut_where(cond, lut, idx, alt):
-            # np.where evaluates both branches: rows where cond is False
-            # carry a sentinel local index (e.g. -1), and a feed whose table
-            # is empty but sits at the end of the flat LUT would index one
-            # past the end — clamp before gathering, select after.
-            safe = np.minimum(np.maximum(idx, 0), len(lut) - 1)
-            return np.where(cond, lut[safe], alt)
-
-        obj_a_g = _lut_where(obj_a_l >= 0, alut, aoff_col + obj_a_l, obj_a_l)
-        ref_a_g = _lut_where(ref_a_l >= 0, alut, aoff_col + ref_a_l, ref_a_l)
-        key_g = _lut_where(
-            key_l >= 0, klut, np.repeat(koffs[w_fc_a], w_cnt_a) + key_l, -1
-        )
-        value_g = value_l.copy()
-        for code, lut, offs in (
-            (VK_STR, slut, soffs),
-            (VK_FLOAT, flut, foffs),
-            (VK_BIGINT, blut, boffs),
-        ):
-            m = vkind == code
-            if m.any():
-                off_col = np.repeat(offs[w_fc_a], w_cnt_a)
-                value_g[m] = lut[off_col[m] + value_l[m]]
-
-        # preds (flat, pre-sort indices for src)
-        if pred_slices:
-            p_cnt_a = np.asarray(p_cnt, np.int64)
-            p_fc_a = np.asarray(p_fc, np.int64)
-            PR = np.concatenate(pred_slices, axis=0)
-            pr_src = PR[:, 0].astype(np.int64) + np.repeat(
-                np.asarray(p_base, np.int64), p_cnt_a
+        n_native = len(fcs) if g else 0
+        if not g:  # numpy twin (fallback, and the fuzz reference)
+            g = _gather_twin(
+                fcs, feed_rows, win, preds_all, tabs, writer_g
             )
-            pr_tgt_ctr = PR[:, 1].astype(np.int64)
-            pr_aoff = np.repeat(aoffs[p_fc_a], p_cnt_a)
-            pr_tgt_a = alut[pr_aoff + PR[:, 2].astype(np.int64)]
-            pr_doc = np.repeat(np.asarray(p_doc, np.int64), p_cnt_a)
-        else:
-            pr_src = pr_tgt_ctr = pr_tgt_a = pr_doc = np.zeros(0, np.int64)
+        _M_GATHER_NATIVE.add(n_native)
+        _M_GATHER_TWIN.add(len(fcs) - n_native)
+        gather_span.note(native=n_native)
+        (
+            action, ctr, seqc, start_op, obj_ctr, obj_a_g, key_g, ref_ctr,
+            ref_a_g, insert, vkind, value_g, dt, actor_g, doc_col,
+            pr_src, pr_tgt_ctr, pr_tgt_a, pr_doc,
+        ) = (g[name] for name in _GATHER_COLS)
 
     span.note(feeds=len(fcs), rows=M)
 
@@ -1107,8 +1274,8 @@ def _pack_general(
     def _rowkey(doc, c, a):
         return (doc << (cb + ab)) | (c << ab) | a
 
-    need_obj = obj_a_l >= 0
-    need_ref = ref_a_l >= 0
+    need_obj = obj_a_g >= 0  # sentinels pass the actor LUT unchanged
+    need_ref = ref_a_g >= 0
 
     def _resolve(rk_sorted, order_rk, q_doc, q_ctr, q_a):
         q = _rowkey(q_doc, q_ctr, np.maximum(q_a, 0))
@@ -1276,6 +1443,7 @@ def _pack_general(
             floats=list(float_int.items),
             bigints=list(big_int.items),
             doc_actors=doc_actors,
+            gather_feeds=(n_native, len(fcs) - n_native),
         )
 
 

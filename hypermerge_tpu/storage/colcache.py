@@ -96,9 +96,10 @@ class FeedColumns:
     int32 (v2 record streams materialize it directly); a v3 checkpoint
     instead carries `planes` — one contiguous array per column in the
     minimal dtype that holds it — and leaves `rows` None until a
-    consumer calls `ensure_rows()`. The bulk pack fast path
-    (ops/columnar.py) reads planes without ever widening to the row
-    matrix; everything else upgrades transparently.
+    consumer calls `ensure_rows()`. Both bulk pack paths
+    (ops/columnar.py: the prefix pack and the general pack's gather)
+    read planes without ever widening to the row matrix, so a cold
+    open builds none; per-op consumers upgrade transparently.
 
     `preds` is [n_preds, 3] int32. `seq` is nondecreasing, so change
     windows slice via np.searchsorted. `ok_prefix_len` is the number of
@@ -140,7 +141,8 @@ class FeedColumns:
 
     def ensure_rows(self) -> np.ndarray:
         """Materialize (and cache) the [n, ROW_FIELDS] int32 matrix —
-        the general pack path and per-op consumers want row slices."""
+        per-op consumers (live appends after a checkpoint, compaction,
+        the corpus writers) want row slices; no pack path does."""
         if self.rows is None:
             self.rows = rows_from_planes(self.planes)
         return self.rows

@@ -248,7 +248,8 @@ def test_corpus_holds_what_the_config_says(writers):
 
 
 def _traced_open(tmp, urls):
-    """One cold open under the profiler -> (span tree, stats)."""
+    """One cold open under the profiler -> (span tree, stats, the
+    FeedColumns of every feed it read)."""
     import jax
 
     from benchmark.readers import span_tree
@@ -260,13 +261,14 @@ def _traced_open(tmp, urls):
     jax.profiler.start_trace(trace, profiler_options=opts)
     try:
         repo, _handles, _summ, stats = _open(os.path.join(tmp, "repo"), urls)
+        columns = [a.columns() for a in repo.back.actors.values()]
         repo.close()
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(
         trace, "plugins", "profile", "*", "*.xplane.pb"))
     spans, _busy = span_tree.load(path)
-    return span_tree.Tree(spans), stats
+    return span_tree.Tree(spans), stats, columns
 
 
 def test_one_two_writer_doc_sends_its_slab_through_the_general_pack(
@@ -274,8 +276,12 @@ def test_one_two_writer_doc_sends_its_slab_through_the_general_pack(
     """32 single-writer docs and one two-writer doc, slabs of 32: the
     slab with the two-writer doc packs through the general path, the
     other through the prefix path; the new spans nest under
-    `pipeline.pack`, and the general pack's stages add up to it."""
-    from benchmark.readers import span_tree
+    `pipeline.pack`, and the general pack's stages add up to it. The
+    general slab's feeds are gathered by the native entry, or all by
+    its numpy twin under HM_NATIVE_PACK=0, and neither builds a feed's
+    dense row matrix."""
+    from benchmark.readers import bulk_stats, span_tree
+    from hypermerge_tpu import native
 
     corpus = dict(_rehearsal_corpus(), classes=[
         {"writers": 1, "count": 32}, {"writers": 2, "count": 1}])
@@ -283,9 +289,14 @@ def test_one_two_writer_doc_sends_its_slab_through_the_general_pack(
     urls = job.start().finish()
     where = [d["writers"] for d in job.plan].index(2) // 32
     with _env():
-        tree, stats = _traced_open(str(tmp_path), urls)
+        tree, stats, columns = _traced_open(str(tmp_path), urls)
         single = [u for u, d in zip(urls, job.plan) if d["writers"] == 1]
-        _tree1, stats1 = _traced_open(str(tmp_path), single)
+        _tree1, stats1, _columns = _traced_open(str(tmp_path), single)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("HM_NATIVE_PACK", "0")
+            repo0, _h, _summ, stats0 = _open(str(tmp_path / "repo"), urls)
+            columns += [a.columns() for a in repo0.back.actors.values()]
+            repo0.close()
     n_general = 32 if where == 0 else 1
     assert stats["pack_general_docs"] == n_general
     assert stats["pack_general_pct"] == round(100.0 * n_general / 33, 3)
@@ -302,6 +313,32 @@ def test_one_two_writer_doc_sends_its_slab_through_the_general_pack(
     assert general.args["docs"] == n_general
     assert general.args["feeds"] == n_general + 1
     assert general.args["rows"] == corpus["ops"] * n_general
+    # who gathered the general slab's feeds: the native entry where the
+    # library is there, the numpy twin under HM_NATIVE_PACK=0; nothing
+    # is counted where no slab took the general path
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "pack.general_native_pct.json")) as fh:
+        native_pct = json.load(fh)["params"]
+    has_native = native.pack_lib() is not None
+    for st, is_native in ((stats, has_native), (stats0, False)):
+        counted = (st["pack_gather_native_feeds"],
+                   st["pack_gather_twin_feeds"])
+        assert sum(counted) == general.args["feeds"]
+        assert counted[0 if is_native else 1] == general.args["feeds"]
+        assert st["pack_gather_native_pct"] == (100.0 if is_native else 0.0)
+        assert bulk_stats.read(native_pct, {"bulk_stats": [st]}) == (
+            st["pack_gather_native_pct"])
+    (gather,) = tree.named(("pipeline.pack.gather",))
+    assert gather.args["native"] == stats["pack_gather_native_feeds"]
+    assert (stats1["pack_gather_native_feeds"],
+            stats1["pack_gather_twin_feeds"],
+            stats1["pack_gather_native_pct"]) == (0, 0, 0.0)
+    older = {k: v for k, v in stats.items()
+             if not k.startswith("pack_gather_")}
+    assert bulk_stats.read(native_pct, {"bulk_stats": [older]}) is None
+    # no pack of the open built a feed's dense [n, 14] matrix
+    assert len(columns) == 2 * (general.args["feeds"] + 32)
+    assert all(fc.planes is not None and fc.rows is None for fc in columns)
     stages = [s for s in tree.members if s.parent is general]
     assert {s.name for s in stages} == {
         "pipeline.pack." + n
@@ -381,3 +418,17 @@ def test_rehearsal_of_the_cell(control):
     else:
         assert out.returncode == 0 and line["correct"] is True, out.stderr
         assert bad == []
+    # the cell's newest per-layer metric (ISSUE 29) is data only: its
+    # file names a reader the benchmark has, and the benchmark lists it
+    # for this cell alone
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "pack.general_native_pct.json")) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        (entry,) = [m for m in json.load(fh)["per_layer"]
+                    if m["name"] == spec["name"]]
+    assert spec["reader"] == "bulk_stats"
+    assert spec["params"] == {"key": "pack_gather_native_pct"}
+    assert entry["workloads"] == spec["cells"] == [line["workload"]]
+    assert (entry["unit"], entry["better"], entry["layer"],
+            entry["moves"]) == ("%", "higher", "pack", "ops_per_s")
