@@ -42,7 +42,8 @@ REGISTRY: Tuple[EnvVar, ...] = (
            "materialize runs host-side instead of a device dispatch."),
     # -- bulk cold open / pipeline -------------------------------------
     EnvVar("HM_BULK_SLAB", "4096", "Docs per bulk-load slab (the "
-           "streaming pipeline's unit of IO/pack/dispatch)."),
+           "streaming pipeline's unit of IO/pack/dispatch); a slab of "
+           "docs over 1,024 rows holds fewer (4M cells a slab)."),
     EnvVar("HM_PACK_WORKERS", "0", "Pack-pool threads for the bulk "
            "pipeline (slab-granular, order-preserving); 0 = auto: "
            "min(4, cores) when the native pack is concurrency-safe, "

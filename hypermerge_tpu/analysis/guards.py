@@ -392,7 +392,8 @@ GUARDS: Tuple[GuardedClass, ...] = (
         "SlabPipeline", "hypermerge_tpu.backend.pipeline",
         "pipeline.pack_pool",
         guarded=("_pack_turn", "_pack_eof_claimed"),
-        init_only=("docs", "prefetch", "classify", "pack", "dispatch",
+        init_only=("docs", "prefetch", "classify", "rows", "former",
+                   "pack", "dispatch",
                    "fetch", "slab", "fetch_workers", "pack_workers",
                    "pack_q", "disp_q", "fetch_q", "_q_gauges",
                    "abort"),

@@ -2,9 +2,11 @@
 
 Each doc's feed windows come from the columnar sidecars
 (storage/colcache.py), pack vectorized (ops/columnar.py) and
-materialize in slab-sized device dispatches: io -> spec -> pack ->
-dispatch -> fetch over slabs, overlapped by backend/pipeline.py, where
-the reference replays doc by doc (src/RepoBackend.ts:238-257). One
+materialize in slab-sized device dispatches: io -> spec -> form -> pack
+-> dispatch -> fetch over slabs, overlapped by backend/pipeline.py,
+where the reference replays doc by doc (src/RepoBackend.ts:238-257).
+Slabs are formed by length (pipeline.SlabFormer): docs of one row rung,
+in store order, within SLAB_CELLS cells. One
 schedule on every host: without the native pack the numpy pack runs on
 one pack worker, and with several devices whole slabs go round-robin.
 
@@ -22,11 +24,14 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .. import msgs, telemetry
 from ..analysis.lockdep import make_lock
+from ..ops import columnar
 from ..storage.colcache import load_slab_images
 from ..utils.debug import log
 from ..utils.ids import root_actor_id
 from .doc_backend import DocBackend
-from .pipeline import FetchContext, SlabPipeline, Stage, pack_worker_count
+from .pipeline import (
+    FetchContext, SlabFormer, SlabPipeline, Stage, pack_worker_count,
+)
 
 # device->host summary-wire transfer bytes (same series sharded.py's
 # collective gather feeds; handle cached — one per-slab bump)
@@ -39,6 +44,47 @@ _M_COLS_SINGLE = telemetry.counter("loader.cols_single_feeds")
 # (storage/feed.py HeadSnapshot) or a probe of the feed's files
 _M_HEADS_SNAP = telemetry.counter("loader.heads_snapshot_feeds")
 _M_HEADS_PROBED = telemetry.counter("loader.heads_probed_feeds")
+# every dispatched slab's padded cells (docs x rows of its batch) and
+# the real op rows in them: their ratio is what forming slabs costs
+_M_CELLS_PADDED = telemetry.counter("loader.slab_cells_padded")
+_M_ROWS_REAL = telemetry.counter("loader.slab_rows_real")
+
+# the cell budget of one slab (docs x rows): the largest slab of the
+# 1,024-op yardstick store, HM_BULK_SLAB's 4,096 docs x 1,024 rows. The
+# former lets no slab of longer docs count more, so the host's pack
+# buffers and the device memory a slab takes are the same whatever the
+# lengths of the store's docs.
+SLAB_CELLS = 4096 * 1024
+
+
+# a slab's pred axis is at least its row axis over this. The pack sizes
+# it to the pow2 over the slab's widest doc, which follows the data: of
+# two stores of one law, one would ask for a [4, 262144] program of
+# 32,768 pred columns and the other for one of 65,536. With the floor a
+# store whose docs supersede up to a quarter of their ops (the yardstick
+# stores: 15%) asks for one program a slab shape, whatever its seed; one
+# that supersedes more keeps the pow2 over its widest doc.
+PRED_ROWS = 4
+
+
+def pack_slab(specs, n_docs: Optional[int] = None) -> columnar.ColumnarBatch:
+    """The batch of one slab of doc feed specs, as the loader dispatches
+    it (and ops/warmup.py compiles it ahead, `n_docs` given): the doc
+    axis at its pow2, so every slab of a rung, and every later bulk
+    load, reuses one compiled executable; the rows at the pow2 over the
+    longest doc; the pred axis at least rows / PRED_ROWS."""
+    if n_docs is None:
+        n_docs = columnar.round_up_pow2(len(specs))
+    batch = columnar.pack_docs_columns(specs, n_docs=n_docs)
+    return columnar.widen_preds(batch, batch.n_rows // PRED_ROWS)
+
+
+def device_min_cells() -> int:
+    """The device gate: a slab of fewer [D, N] cells is answered by the
+    numpy kernel twin (small loads aren't worth a device dispatch, let
+    alone a fresh compile). Read by the dispatch, and by the former,
+    which merges a thin remainder upward where a slab has room."""
+    return int(os.environ.get("HM_DEVICE_MIN_CELLS", "131072"))
 
 # summary-fetch workers: one per device up to this many (each worker
 # is a host-side parse plus one transfer at a time)
@@ -76,6 +122,13 @@ _STATS0: Dict[str, Any] = {
     "pack_gather_native_feeds": 0, "pack_gather_twin_feeds": 0,
     "pack_gather_native_pct": 0.0,
     "a_loc_max": 0, "pred_max": 0,
+    # what forming the slabs cost: the dispatched slabs, their [D, N]
+    # shapes in dispatch order, their padded cells over the real op
+    # rows in them (1.0: no padding), and the distinct device programs
+    # (D, N, A, K, P, lean) among them; the shares land after the load
+    "slabs": 0, "slab_shapes": (), "rows_real": 0, "cells_padded": 0,
+    "slab_waste_x": 0.0, "slab_programs": 0,
+    "t_form": 0.0,
     **dict.fromkeys(_STAGE_KEYS, 0.0),
 }
 
@@ -240,6 +293,9 @@ class BulkLoader:
             stats["pack_general_pct"] = _pct(
                 stats["pack_general_docs"], len(new_docs)
             )
+            stats["slab_waste_x"] = round(
+                stats["cells_padded"] / max(1, stats["rows_real"]), 3
+            )
             stats["memo"] = len(memo_hits)
             stats["fallback"] = len(fallback_docs)
             stats["fast"] = len(new_docs) - len(fallback_docs)
@@ -315,11 +371,10 @@ class BulkLoader:
     ):
         """Streamed phases 2-4: slab N+1's sidecar IO and pack proceed
         while slab N is on-device and slab N-1's summary is in flight
-        to host (backend/pipeline.py). Slabs are slab-sized chunks of
-        the post-memo-filter entry stream, in doc order. Returns
-        (memo_hits, fallback_docs)."""
-        from ..ops import columnar
-
+        to host (backend/pipeline.py). Slabs are formed by length from
+        the post-memo-filter entry stream (pipeline.SlabFormer), docs
+        in store order inside each. Returns (memo_hits,
+        fallback_docs)."""
         back = self._back
         contiguous: Dict[str, bool] = {}
         open_id = self._bulk_open
@@ -342,17 +397,20 @@ class BulkLoader:
                 return ("memo", (e, m))
             return ("entry", e)
 
-        def pack(chunk):
-            # on a pack-pool worker (HM_PACK_WORKERS). The doc axis is
-            # bucketed (pow2) so every slab of a bulk load — and every
-            # later bulk load — reuses one compiled executable.
-            return columnar.pack_docs_columns(
-                [e[1] for e in chunk],
-                n_docs=columnar.round_up_pow2(len(chunk)),
+        def rows(e):
+            # the op rows of the doc's feed windows: what its slab's
+            # row axis has to hold
+            return sum(
+                hi - lo for lo, hi in (fc.window(s, t) for fc, s, t in e[1])
             )
+
+        def pack(chunk):
+            # on a pack-pool worker (HM_PACK_WORKERS)
+            return pack_slab([e[1] for e in chunk])
 
         stats = self.last_bulk_stats  # captured: the fetch worker can
         # outlive this load; its timings belong to THIS load's stats
+        programs: set = set()
 
         # mesh-aware accounting: the scheduler (built here, before any
         # dispatch, so the fetch stage can size itself) accumulates
@@ -390,9 +448,11 @@ class BulkLoader:
             new_docs,
             prefetch=lambda chunk: self._open_feeds(chunk, cursor_map),
             classify=classify,
+            rows=rows,
+            former=SlabFormer(slab, SLAB_CELLS, device_min_cells()),
             pack=pack,
             dispatch=lambda seq, chunk, batch: self._dispatch(
-                seq, chunk, batch, ready_ids, clock_rows
+                seq, chunk, batch, ready_ids, clock_rows, programs
             ),
             fetch=fetch,
             stat=lambda key, dt: self._stat_add(key, dt, stats),
@@ -521,36 +581,45 @@ class BulkLoader:
                     a.columns()  # loads, or catches a loaded one up
         return len(bulk), len(cold) - len(bulk)
 
-    def _dispatch(self, seq, chunk, batch, ready_ids, clock_rows):
+    def _dispatch(
+        self, seq, chunk, batch, ready_ids, clock_rows, programs
+    ):
         """One packed slab -> async device dispatch + deferred doc init.
         Returns the pending-summary entry (a mutable list: the fetch
         worker replaces its wire slot with parsed host arrays).
+        `programs` gathers the load's distinct device programs,
+        (D, N, A, K, P, lean).
 
         The whole of it is the `pipeline.dispatch` stage (it runs on
         the loading thread, inside `pipeline.bulk_load`, whose open id
         comes down to it, and holds back the next slab): host-arg
         narrowing, upload and the jitted call are its child spans, and
         their ends feed t_narrow / t_upload / t_dispatch."""
-        from ..ops.crdt_kernels import actor_bucket, run_batch_full
+        from ..ops.crdt_kernels import (
+            batch_is_lean, bucket_doc_actors, run_batch_full,
+        )
         from ..ops.host_kernel import run_batch_host
         from ..ops.materialize import DecodedBatch, decode_patch
 
         stats = self.last_bulk_stats
         with Stage("pipeline.dispatch", busy="dispatch", slab=seq) as sp:
-            # small loads aren't worth a device dispatch (let alone a
-            # fresh per-bucket compile): under this many [D, N] cells
-            # the numpy kernel twin wins outright
-            min_cells = int(
-                os.environ.get("HM_DEVICE_MIN_CELLS", "131072")
-            )
+            min_cells = device_min_cells()
             # host clocks (authoritative, from sidecar metadata) for
             # every doc in the slab, padded docs empty — lets the device
             # path skip the seq wire entirely
             slab_clocks = [e[2] for e in chunk] + [{}] * (
                 batch.n_docs - len(chunk)
             )
-            a_loc = actor_bucket(batch)
+            _da, a_loc, k_loc = bucket_doc_actors(batch)
+            shape = (batch.n_docs, batch.n_rows)
+            cells, real = shape[0] * shape[1], int(batch.n_ops.sum())
+            _M_CELLS_PADDED.add(cells)
+            _M_ROWS_REAL.add(real)
             with self._stats_lock:
+                stats["slabs"] += 1
+                stats["slab_shapes"] += (shape,)
+                stats["cells_padded"] += cells
+                stats["rows_real"] += real
                 if batch.packed_by == "general":
                     stats["pack_general_docs"] += len(chunk)
                     native, twin = batch.gather_feeds
@@ -561,18 +630,17 @@ class BulkLoader:
                     stats["pred_max"], batch.psrc.shape[1]
                 )
             lean = False
-            if batch.n_docs * batch.n_rows < min_cells:
+            if cells < min_cells:
                 with telemetry.timed(
-                    "pipeline.enqueue", "pipeline", host=1
+                    "pipeline.enqueue", "pipeline", host=1,
+                    D=shape[0], N=shape[1],
                 ):
                     out = run_batch_host(batch)
                 summary = None
                 with self._stats_lock:
                     stats["host_slabs"] += 1
             else:
-                from ..crdt.change import Action
                 from ..ops import compile_cache
-                import numpy as np
 
                 platform = compile_cache.ensure()  # may init the backend
                 with self._stats_lock:
@@ -581,9 +649,12 @@ class BulkLoader:
                 # no INC ops + host clocks in hand -> skip the seq and
                 # value wires (~4 of 14 bytes/op uploaded) AND the
                 # summary wire's clock section
-                lean = not bool(
-                    np.any(batch.cols["action"] == int(Action.INC))
+                lean = batch_is_lean(batch)
+                programs.add(
+                    shape + (a_loc, k_loc, batch.psrc.shape[1], lean)
                 )
+                with self._stats_lock:
+                    stats["slab_programs"] = len(programs)
                 rr = self._rr
                 if rr is not None:
                     # multi-chip: successive WHOLE slabs land on

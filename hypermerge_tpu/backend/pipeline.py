@@ -19,8 +19,10 @@ staging alive per seam — double buffering, not an unbounded backlog.
                       All of it is Python under the GIL but the feed
                       head lookups, so it runs on this one thread: a
                       pool only took the GIL from the pack worker.
-                      Then per-doc feed specs, emitted as slab-sized
-                      entry groups in doc order.
+                      Then per-doc feed specs, binned into slabs by
+                      length (SlabFormer): a slab goes to the pack
+                      pool when its rung fills, the rest when the
+                      stream ends; docs keep store order in a slab.
     pack pool:        pack_docs_columns on HM_PACK_WORKERS threads —
                       the native hm_pack_prefix call is bound through
                       ctypes.CDLL and therefore RELEASES the GIL
@@ -63,6 +65,7 @@ from typing import Any, Callable, List, Optional, Tuple
 
 from ..analysis.lockdep import make_condition, make_lock
 from .. import telemetry
+from ..ops.columnar import round_up_pow2
 
 # process-wide pipeline series (telemetry registry): cumulative stage
 # busy seconds + slab counts across every bulk load, and live queue
@@ -119,6 +122,99 @@ _JOIN_S = 120.0
 # bounded depth of each stage queue (the fetch seam holds twice it)
 QUEUE_DEPTH = 2
 
+# the ladder of row rungs a load's docs are binned by: these tops, and
+# above the last every power of two (131,072, 262,144 ...). A slab's
+# rows are its rung's top or, under a step of 4, half of it, so a store
+# that fills its rungs asks for one program a rung. The 1,024-op
+# yardstick store sits on a rung of its own. The step is 4 where cells
+# are cheap and 2 where they are dear: a cell of a 262,144-row slab
+# costs the chip two to three times a cell of a 1,024-row one (18
+# rounds of 32-bit gathers for 10 of 16-bit), and such a slab holds so
+# few docs that the pow2 doc axis pads most. Measured on the chip
+# against a step of 4 all the way, on a store whose lengths halve in
+# count as they double (PERF.md, PR 30): 7 programs for 6, 2.1 padded
+# cells a real one for 2.5, 5.3 s of kernel an open for 7.9. A step of
+# 2 all the way (12 programs there, reckoned at 2.2 cells) was not run.
+ROW_RUNGS = (256, 1024, 4096, 16384, 65536)
+
+
+class SlabFormer:
+    """Forms a load's slabs by length, as the specced entries stream
+    in store order.
+
+    An entry of `rows` op rows belongs to the lowest rung of the
+    ladder above whose top is >= rows. A slab holds entries of
+    one rung, in store order, at most `capacity(top)` of them: `slab`
+    docs (HM_BULK_SLAB), or as many as keep docs x top within `cells`
+    where that is fewer. The pack pads it to the pow2 over its longest
+    doc, as ever, so a slab never counts more than `cells` cells and a
+    store of one length forms the slabs that chunks of `slab` docs in
+    store order would: `add` hands a rung's slab back the moment it is
+    full.
+
+    `flush` ends the stream with the rungs' remainders. One that would
+    fall under the device gate (`min_cells`: its pow2 docs x
+    pow2 rows) joins the remainder of the next occupied rung where the
+    two fit one slab of that rung, so a thin bucket is not answered by
+    the host twin while a slab above has room for it, and no merge
+    ever costs more than the one slab it saves. The top rung's
+    remainder has nowhere to go and is dispatched as it is. The
+    remainders go out highest rung first: the slab of the longest docs
+    has the longest kernel and the largest wire, and its fetch then
+    runs beside the others' kernels instead of after the last of
+    them."""
+
+    def __init__(self, slab: int, cells: int, min_cells: int = 0) -> None:
+        self.slab = max(1, int(slab))
+        self.cells = int(cells)
+        self.min_cells = int(min_cells)
+        self._n = 0  # store-order index of the next entry
+        # rung top -> [(index, rows, entry)], in store order
+        self._bins: dict = {}
+
+    def capacity(self, top: int) -> int:
+        return max(1, min(self.slab, self.cells // top))
+
+    @staticmethod
+    def rung(rows: int) -> int:
+        for top in ROW_RUNGS:
+            if rows <= top:
+                return top
+        return round_up_pow2(rows)
+
+    def add(self, rows: int, entry: Any) -> Optional[List[Any]]:
+        """Bin one entry; the slab it completes, or None."""
+        top = self.rung(rows)
+        held = self._bins.setdefault(top, [])
+        held.append((self._n, rows, entry))
+        self._n += 1
+        if len(held) < self.capacity(top):
+            return None
+        del self._bins[top]
+        return [e for _i, _r, e in held]
+
+    def _thin(self, held: List[tuple]) -> bool:
+        rows = max(r for _i, r, _e in held)
+        return round_up_pow2(len(held)) * round_up_pow2(rows) < self.min_cells
+
+    def flush(self) -> List[List[Any]]:
+        """The slabs of what `add` still holds, highest rung first."""
+        out: List[List[Any]] = []
+        tops = sorted(self._bins)
+        for at, top in enumerate(tops):
+            held = self._bins.pop(top)
+            above = tops[at + 1] if at + 1 < len(tops) else None
+            if (
+                above is not None
+                and self._thin(held)
+                and len(held) + len(self._bins[above])
+                <= self.capacity(above)
+            ):
+                self._bins[above] = sorted(held + self._bins[above])
+                continue
+            out.append([e for _i, _r, e in held])
+        return out[::-1]
+
 
 def pack_worker_count() -> int:
     """Size of the pack pool. HM_PACK_WORKERS=N pins N workers; 0 (the
@@ -166,6 +262,8 @@ class SlabPipeline:
       prefetch(doc_chunk)      read-ahead actors + sidecar columns
       classify(doc)            -> ("entry", e) | ("memo", (e, m))
                                   | ("fallback", doc)
+      rows(e)                  an entry's op rows (what SlabFormer
+                                  bins it by)
       pack(entries)            -> ColumnarBatch
       dispatch(seq, entries, batch) -> pending summary entry (runs on
                                   the CALLER thread — device dispatch
@@ -175,9 +273,12 @@ class SlabPipeline:
       stat(key, seconds)       adds a stage's seconds to the load's
                                   stats (t_io, t_spec, t_pack)
 
-    io, spec and pack are timed here (`Stage`); dispatch and fetch time
-    themselves in the loader, which keeps per-chip books from the
-    same readings. Every span carries `open=open_id` and its `slab`;
+    `slab` is the io stage's chunk (docs read ahead at once) and
+    `former` the SlabFormer that cuts the specced entries into slabs.
+    io, spec, form and pack are timed here (`Stage`); dispatch and
+    fetch time themselves in the loader, which keeps per-chip books from the
+    same readings. Every span carries `open=open_id` and its `slab`
+    (io, spec and form their `chunk`: `_io_loop`);
     each blocking queue / turn wait is a `pipeline.wait` span.
     """
 
@@ -187,6 +288,8 @@ class SlabPipeline:
         *,
         prefetch: Callable[[List[Any]], None],
         classify: Callable[[Any], Tuple[str, Any]],
+        rows: Callable[[Any], int],
+        former: SlabFormer,
         pack: Callable[[List[Any]], Any],
         dispatch: Callable[[int, List[Any], Any], Any],
         fetch: Callable[[int, Any], None],
@@ -199,6 +302,8 @@ class SlabPipeline:
         self.docs = docs
         self.prefetch = prefetch
         self.classify = classify
+        self.rows = rows
+        self.former = former
         self.pack = pack
         self.dispatch = dispatch
         self.fetch = fetch
@@ -300,18 +405,27 @@ class SlabPipeline:
     # -- stages ---------------------------------------------------------
 
     def _io_loop(self) -> None:
-        """Read-ahead + spec: emits slab-sized entry groups in doc
-        order. The io and
-        spec spans carry the doc chunk's index as `slab` (the emitted
-        slab's seq unless memo hits or fallbacks thinned the stream)."""
+        """Read-ahead + spec + form: emits slabs as the former fills
+        them, and its remainders when the docs end. The io, spec and
+        form spans carry the doc chunk's index as `chunk`: a chunk's
+        docs land in the slabs of several rungs and a slab holds docs
+        of several chunks, so `slab` is the tag of formed slabs alone
+        (pack, dispatch, fetch: the dispatch order). The io and spec
+        spans carry the chunk's index as `slab` too, as they did when
+        a chunk was a slab: the benchmark's accepted readers pair a
+        slab's spans by it (`loader.queue_wait_s`), which holds in a
+        store of one length. A form span's `slabs` counts what it
+        emitted, and the last one, `flush=1`, is the end of the
+        stream."""
         try:
-            buf: List[Any] = []
             seq = 0
             for base in range(0, len(self.docs), self.slab):
                 if self.abort.is_set():
                     raise _Abort()
                 chunk = self.docs[base : base + self.slab]
-                ids = {"open": self.open_id, "slab": base // self.slab}
+                at = base // self.slab
+                ids = {"open": self.open_id, "chunk": at, "slab": at}
+                entries: List[Any] = []
                 with Stage(
                     "pipeline.io", self.stat, "t_io", "io",
                     parent="pipeline.bulk_load", **ids,
@@ -324,20 +438,14 @@ class SlabPipeline:
                     for doc in chunk:
                         kind, payload = self.classify(doc)
                         if kind == "entry":
-                            buf.append(payload)
+                            entries.append(payload)
                         elif kind == "memo":
                             self.memo_hits.append(payload)
                         else:
                             self.fallbacks.append(payload)
-                # the put blocks on a full queue: that's backpressure
-                # WAIT, not io busy — keep it outside the busy window
-                while len(buf) >= self.slab:
-                    self._put(self.pack_q, (seq, buf[: self.slab]))
-                    seq += 1
-                    buf = buf[self.slab :]
-            if buf:
-                self._put(self.pack_q, (seq, buf))
-                seq += 1
+                seq = self._emit(seq, self._form(entries, False, at))
+            chunks = -(-len(self.docs) // self.slab)
+            seq = self._emit(seq, self._form([], True, chunks))
             # publish the slab count BEFORE the EOF token: the worker
             # that claims EOF forwarding reads it after taking the
             # token off the queue (queue put/get is the happens-before)
@@ -347,6 +455,37 @@ class SlabPipeline:
             pass
         except BaseException as e:
             self._fail("io", e)
+
+    def _form(
+        self, entries: List[Any], flush: bool, chunk: int
+    ) -> List[Any]:
+        """Bin the specced entries of chunk `chunk` (or, `flush`, end
+        the stream); the slabs that are ready for the pack pool."""
+        with Stage(
+            "pipeline.form", self.stat, "t_form", "io",
+            parent="pipeline.bulk_load", docs=len(entries),
+            flush=int(flush), open=self.open_id, chunk=chunk,
+        ) as sp:
+            longest = 0
+            ready: List[Any] = []
+            for e in entries:
+                n = self.rows(e)
+                longest = max(longest, n)
+                full = self.former.add(n, e)
+                if full is not None:
+                    ready.append(full)
+            if flush:
+                ready.extend(self.former.flush())
+            sp.note(N=round_up_pow2(longest) if entries else 0, slabs=len(ready))
+        return ready
+
+    def _emit(self, seq: int, slabs: List[Any]) -> int:
+        # the put blocks on a full queue: that's backpressure WAIT,
+        # not io busy, so it stays outside the busy windows
+        for entries in slabs:
+            self._put(self.pack_q, (seq, entries))
+            seq += 1
+        return seq
 
     def _await_pack_turn(self, seq: int) -> None:
         """Block until slab `seq` may emit into disp_q (ordered merge
@@ -405,6 +544,10 @@ class SlabPipeline:
                     open=self.open_id, slab=seq, parent="pipeline.spec",
                 ) as sp:
                     packed = self.pack(entries)
+                    sp.note(
+                        D=packed.n_docs, N=packed.n_rows,
+                        rows=int(packed.n_ops.sum()),
+                    )
                 self.pack_busy[widx] += sp.dur
                 if self.pack_t0[widx] is None:
                     self.pack_t0[widx] = sp.t0

@@ -129,6 +129,21 @@ class ColumnarBatch:
         return self.shape[1]
 
 
+def widen_preds(batch: ColumnarBatch, n_pred: int) -> ColumnarBatch:
+    """`batch` with a pred axis of at least `n_pred` columns (the new
+    ones empty, -1): a caller that buckets the pred axis by another
+    rule than the pow2 over the widest doc. A batch that wide already
+    is handed back untouched."""
+    D, P = batch.psrc.shape
+    if P < n_pred:
+        for name in ("psrc", "ptgt"):
+            narrow = getattr(batch, name)
+            wide = np.full((D, n_pred), -1, narrow.dtype)
+            wide[:, :P] = narrow
+            setattr(batch, name, wide)
+    return batch
+
+
 def causal_sort(changes: Sequence[Change]) -> List[Change]:
     """Deduplicate by (actor, seq) and sort into a causal linear order.
 

@@ -737,9 +737,18 @@ def run_batch_full(
         (batch.n_docs, batch.n_rows, bool(lean)), {}
     )[(fn.__name__, avals, A, K)] = (fn, avals, statics)
     with telemetry.timed(
-        "pipeline.enqueue", "pipeline", A=A, K=K, P=batch.psrc.shape[1]
+        "pipeline.enqueue", "pipeline", D=batch.n_docs, N=batch.n_rows,
+        A=A, K=K, P=batch.psrc.shape[1],
     ):
         return fn(*args, A=A, K=K)
+
+
+def batch_is_lean(batch: ColumnarBatch) -> bool:
+    """No INC op in the batch: with host clocks in hand the lean entry
+    serves it (no seq and value wires, no clock section)."""
+    import numpy as np
+
+    return not bool(np.any(batch.cols["action"] == _INC))
 
 
 def _full_entry(args, lean: bool):

@@ -21,9 +21,15 @@ flush) — their count ratio IS the read-batching factor.
 
 --by slab follows each cold open in the trace (the span tree of
 benchmark/readers/span_tree.py: `open` / `slab` ids and nesting): the
-head of the open stage by stage, each slab's walk io -> spec -> pack ->
-dispatch -> fetch across its four threads with the seconds the work
-waited between stages, and, from an `.xplane.pb` with a device in it,
+head of the open stage by stage, each chunk of docs on the io thread
+(io -> spec -> form and the slabs it completed; slabs are formed by
+length, so a chunk feeds several and a slab holds docs of several),
+each slab's shape (`[D x N]`, its real rows and padded cells a row,
+from the tags of its `pipeline.pack` span: a ragged open reads at a
+glance) and walk pack -> dispatch -> fetch across its threads with the
+seconds the work waited between stages (a trace from before the
+`chunk` tag: io -> spec -> pack -> dispatch -> fetch, a chunk being a
+slab), and, from an `.xplane.pb` with a device in it,
 each idle gap of device 0 over 50 ms by the span that covers it.
 
 Under HM_PACK_WORKERS>1 the pack plane fans out: each pool worker
@@ -86,6 +92,19 @@ def slab_view(events, busy, out=sys.stdout) -> bool:
     return bool(opens)
 
 
+def _slab_shape(tree, k) -> str:
+    """"; [D x N], R real rows, C.CC cells a row" from the tags of the
+    slab's `pipeline.pack` span ("" in a trace from before them)."""
+    for s in tree.named(("pipeline.pack",), k):
+        a = s.args
+        if "D" in a and "N" in a:
+            d, n, rows = int(a["D"]), int(a["N"]), int(a.get("rows", 0))
+            real = (f", {rows} real rows, {d * n / rows:.2f} cells a row"
+                    if rows else "")
+            return f"; [{d} x {n}]{real}"
+    return ""
+
+
 def _open_view(tree, busy, span_tree, out) -> None:
     root = tree.root
     w = out.write
@@ -94,22 +113,47 @@ def _open_view(tree, busy, span_tree, out) -> None:
       f"{len(tree.members)} spans\n")
     w("head (spans of the open that carry no slab, by start):\n")
     for s in tree.members:
-        if s.slab is None and s.name != span_tree.WAIT:
+        if (s.slab is None and "chunk" not in s.args
+                and s.name != span_tree.WAIT):
             w(f"  {s.t0 - root.t0:9.3f}s {'  ' * s.depth}{s.name:<28}"
               f" {s.dur:8.3f}s  self {tree.self_s(s):.3f}s\n")
-    for k in tree.slabs():
-        w(f"slab {k}: waited {tree.chain_wait(k):.3f}s between stages\n")
+    def walk(chain):
         last = None
-        for s in tree.chain(k):
+        for s in chain:
             gap = "" if last is None else f"  (+{s.t0 - last:.3f}s)"
+            note = (f"  -> {s.args['slabs']} slabs"
+                    if s.name == "pipeline.form" else "")
             w(f"  {s.t0 - root.t0:9.3f}s {s.name:<20} {s.dur:8.3f}s"
-              f"  thread {s.line}{gap}\n")
+              f"  thread {s.line}{gap}{note}\n")
             for kid in tree.members:
                 if (kid.parent is s and kid.line == s.line
                         and kid.name != span_tree.WAIT):
                     w(f"  {kid.t0 - root.t0:9.3f}s   {kid.name:<18} "
                       f"{kid.dur:8.3f}s\n")
             last = s.t1
+
+    # a trace since slabs are formed by length tags the io thread's
+    # stages with their `chunk` of docs; a chunk feeds the slabs of
+    # several rungs, so it is followed apart from them
+    stages = ("pipeline.io", "pipeline.spec", "pipeline.form")
+    by_chunk = defaultdict(list)
+    for s in tree.members:
+        if s.name in stages and "chunk" in s.args:
+            by_chunk[s.args["chunk"]].append(s)
+    for c, chain in sorted(by_chunk.items()):
+        w(f"chunk {c}: {sum(s.dur for s in chain):.3f}s on the io thread\n")
+        walk(chain)
+    for k in tree.slabs():
+        chain = [s for s in tree.chain(k)
+                 if not (by_chunk and s.name in stages)]
+        if not chain:  # an id only a chunk bears: more chunks than slabs
+            continue
+        busy_s = sum(b - a for a, b in span_tree.trace_reduce.union(
+            [(s.t0, s.t1) for s in chain]))
+        waited = max(s.t1 for s in chain) - chain[0].t0 - busy_s
+        w(f"slab {k}: waited {waited:.3f}s between stages"
+          f"{_slab_shape(tree, k)}\n")
+        walk(chain)
     waits = defaultdict(float)
     for s in tree.members:
         if s.name == span_tree.WAIT:

@@ -138,3 +138,177 @@ def test_loader_imports_point_one_way():
     for name in ("load_documents_bulk", "fetch_bulk_summaries",
                  "last_bulk_stats", "summary_memo_row"):
         assert hasattr(RepoBackend, name)
+
+
+# -- slabs formed by length ------------------------------------------------
+
+
+def _ragged_corpus(path, n_docs, seed=11):
+    """Docs whose op rows differ by two orders of magnitude."""
+    import random
+
+    r = random.Random(seed)
+    repo = Repo(path=str(path))
+    urls = []
+    for i in range(n_docs):
+        u = repo.create({"i": i, "t": Text(""), "hits": Counter(0)})
+        size = r.choice((1, 1, 1, 6, 6, 30, 120))
+        repo.change(u, lambda d, i=i, size=size: d["t"].insert(
+            0, "".join(chr(97 + (i + k) % 26) for k in range(size))))
+        repo.change(u, lambda d, i=i: d.__setitem__("k", i))
+        if i % 3 == 0:
+            repo.change(u, lambda d: d.increment("hits", 2))
+        urls.append(u)
+    repo.close()
+    return [validate_doc_url(u) for u in urls]
+
+
+def _record_dispatches(monkeypatch):
+    """[(doc ids, (D, N), real rows, program)] of every slab a load
+    dispatches, in order."""
+    from hypermerge_tpu.backend.bulk_loader import BulkLoader
+    from hypermerge_tpu.ops.crdt_kernels import bucket_doc_actors
+
+    seen = []
+    inner = BulkLoader._dispatch
+
+    def dispatch(self, seq, chunk, batch, *rest):
+        entry = inner(self, seq, chunk, batch, *rest)
+        _da, a, k = bucket_doc_actors(batch)
+        seen.append((
+            [e[0].id for e in chunk], batch.shape, int(batch.n_ops.sum()),
+            batch.shape + (a, k, batch.psrc.shape[1], entry[4]),
+        ))
+        return entry
+
+    monkeypatch.setattr(BulkLoader, "_dispatch", dispatch)
+    return seen
+
+
+def test_a_store_of_one_length_loads_in_store_order_chunks(
+    tmp_path, monkeypatch
+):
+    """Docs of one length: the slabs are chunks of `slab` docs in store
+    order, each packed with the doc axis at its pow2 and the rows left
+    to the pack, as before slabs were formed by length: the same calls
+    of the same pack, so the same bytes."""
+    from hypermerge_tpu.ops import columnar
+
+    ids = _corpus(tmp_path, 7)
+    monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
+    seen = _record_dispatches(monkeypatch)
+    packs = []
+    inner = columnar.pack_docs_columns
+
+    def pack(specs, **kw):
+        packs.append(kw)
+        return inner(specs, **kw)
+
+    monkeypatch.setattr(columnar, "pack_docs_columns", pack)
+    stats, got, want = _load(tmp_path, ids, slab=3)
+    assert got == want
+    assert [s[0] for s in seen] == [ids[0:3], ids[3:6], ids[6:7]]
+    assert packs == [{"n_docs": 4}, {"n_docs": 4}, {"n_docs": 1}]
+    assert len({s[1][1] for s in seen}) == 1  # one row bucket
+    assert stats["slabs"] == 3 and stats["slab_programs"] == 2
+    assert stats["slab_shapes"] == tuple(s[1] for s in seen)
+
+
+@pytest.mark.parametrize("pack", ["1", "0"])
+def test_a_ragged_store_loads_in_slabs_formed_by_length(
+    tmp_path, monkeypatch, pack
+):
+    """Docs of 10-300 rows under a ladder and a budget cut to their
+    size: every doc in exactly one slab, store order inside it, no slab
+    over the budget or on the host twin, the stats the slabs that were
+    dispatched; and every summary and value what a load in store-order
+    chunks gives, and what the host OpSet replays."""
+    from hypermerge_tpu.backend import bulk_loader, pipeline
+
+    ids = _ragged_corpus(tmp_path, 40)
+    monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
+    monkeypatch.setenv("HM_NATIVE_PACK", pack)
+    seen = _record_dispatches(monkeypatch)
+    cells = 16 * 32
+    monkeypatch.setattr(pipeline, "ROW_RUNGS", (16, 64, 256))
+    monkeypatch.setattr(bulk_loader, "SLAB_CELLS", cells)
+    stats, got, want = _load(tmp_path, ids, slab=16)
+    assert got == want
+    order = {d: i for i, d in enumerate(ids)}
+    assert sorted(d for s in seen for d in s[0]) == sorted(ids)
+    shapes = [s[1] for s in seen]
+    assert len({n for _d, n in shapes}) >= 3  # several rungs met
+    for docs, (d, n), _real, _prog in seen:
+        assert [order[x] for x in docs] == sorted(order[x] for x in docs)
+        assert d * n <= cells
+    assert stats["host_slabs"] == 0 and stats["device_slabs"] == len(seen)
+    assert stats["fast"] == len(ids) and stats["fallback"] == 0
+    assert stats["slabs"] == len(seen)
+    assert stats["slab_shapes"] == tuple(shapes)
+    assert stats["cells_padded"] == sum(d * n for d, n in shapes)
+    assert stats["rows_real"] == sum(s[2] for s in seen)
+    assert stats["slab_waste_x"] == round(
+        stats["cells_padded"] / stats["rows_real"], 3)
+    assert stats["slab_programs"] == len({s[3] for s in seen})
+    assert stats["t_form"] >= 0.0
+
+    # the same docs in store-order chunks (one rung holds them all)
+    seen.clear()
+    monkeypatch.setattr(pipeline, "ROW_RUNGS", (1 << 20,))
+    monkeypatch.setattr(bulk_loader, "SLAB_CELLS", 1 << 40)
+    stats2, got2, _want = _load(tmp_path, ids, slab=16)
+    assert [s[0] for s in seen] == [ids[0:16], ids[16:32], ids[32:40]]
+    assert got2 == got
+    assert stats2["cells_padded"] > stats["cells_padded"]
+
+
+def _pred_specs(set_shares, n_ops=96):
+    """One single-writer feed spec a share: a text and `share` of the
+    later ops SETs of ten root keys, each superseding the key's last."""
+    from benchmark.corpora.single_writer_templates import (
+        _TEMPLATE_ACTOR, template_changes,
+    )
+    from hypermerge_tpu.crdt.change import Change
+    from hypermerge_tpu.storage.colcache import (
+        FeedColumnCache, MemoryColumnStorage,
+    )
+
+    specs = []
+    for i, share in enumerate(set_shares):
+        cc = FeedColumnCache(MemoryColumnStorage(), writer=_TEMPLATE_ACTOR)
+        for c in template_changes(n_ops, seed=7 + i, seq_frac=1.0 - share):
+            cc.append_change(Change.from_json(c))
+        specs.append([(cc.columns(), 0, float("inf"))])
+    return specs
+
+
+@pytest.mark.parametrize("shares, n_pred", [
+    ((0.0, 0.0), 32),  # no pred at all: the floor, rows / 4
+    ((0.05, 0.1), 32),  # a pow2 of 16 under the floor
+    ((0.15, 0.2), 32),  # the yardstick's share: the floor is its pow2
+    ((0.6, 0.1), 64),  # over the floor: the pow2 over the widest doc
+])
+def test_slab_pred_axis_follows_the_rows(shares, n_pred):
+    """A slab's pred axis is at least its rows / PRED_ROWS, so stores
+    of one law ask for one program a slab shape whatever their seeds;
+    the edges are the pack's, the columns added are empty, and a slab
+    that wide already is the pack's own batch."""
+    import numpy as np
+
+    from hypermerge_tpu.backend import bulk_loader
+    from hypermerge_tpu.ops import columnar
+
+    specs = _pred_specs(shares)
+    plain = columnar.pack_docs_columns(specs, n_docs=2)
+    batch = bulk_loader.pack_slab(specs)
+    assert batch.shape == plain.shape == (2, 128)
+    assert batch.psrc.shape == batch.ptgt.shape == (2, n_pred)
+    P = plain.psrc.shape[1]
+    assert P <= n_pred
+    for name in ("psrc", "ptgt"):
+        got, want = getattr(batch, name), getattr(plain, name)
+        assert got.dtype == want.dtype
+        assert np.array_equal(got[:, :P], want)
+        assert (got[:, P:] == -1).all()
+    for name, col in plain.cols.items():
+        assert np.array_equal(batch.cols[name], col), name

@@ -552,3 +552,130 @@ def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, pack):
     repo.back.fetch_bulk_summaries()
     assert cols(repo.back.last_bulk_stats) == (0, 6, 0.0)
     repo.close()
+
+
+# -- slab formation by length (pipeline.SlabFormer) ------------------------
+
+
+def _form(rows, slab, cells, min_cells=0):
+    """(slabs emitted as the stream went, slabs of the flush): each a
+    list of (store index, rows)."""
+    from hypermerge_tpu.backend.pipeline import SlabFormer
+
+    former = SlabFormer(slab, cells, min_cells)
+    full = []
+    for i, n in enumerate(rows):
+        got = former.add(n, (i, n))
+        if got is not None:
+            full.append(got)
+    return full, former.flush()
+
+
+def _padded(slab):
+    from hypermerge_tpu.ops.columnar import round_up_pow2 as pow2
+
+    return pow2(len(slab)) * pow2(max(n for _i, n in slab))
+
+
+@pytest.mark.parametrize("n_ops,n_docs,slab", [
+    (1024, 10240, 4096),  # flagship-1w: [4096] x 2 + [2048]
+    (1024, 5120, 4096),   # collab-10k: [4096] + [1024]
+    (128, 72, 32),        # their rehearsal blocks: [32] x 2 + [8]
+    (5, 7, 3),
+])
+def test_a_store_of_one_length_forms_chunks_in_store_order(
+    n_ops, n_docs, slab
+):
+    """Docs of one length stream through the former as chunks of `slab`
+    docs in store order, the tail at the flush: the slabs a loader
+    that knew nothing of length would cut."""
+    full, rest = _form([n_ops] * n_docs, slab, 4096 * 1024)
+    ids = [[i for i, _n in s] for s in full + rest]
+    assert ids == [
+        list(range(b, min(b + slab, n_docs)))
+        for b in range(0, n_docs, slab)
+    ]
+    assert len(rest) == (1 if n_docs % slab else 0)
+
+
+def _longtail_rows(seed, counts=(512, 256, 128, 64, 32, 16, 8, 4, 3, 2)):
+    """Octave k: counts[k] docs of [16 * 2^k, 32 * 2^k) rows, shuffled."""
+    rng = random.Random(seed)
+    rows = [
+        rng.randrange(16 << k, 32 << k)
+        for k, c in enumerate(counts) for _ in range(c)
+    ]
+    rng.shuffle(rows)
+    return rows
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+@pytest.mark.parametrize("min_cells", [0, 4096, 60000])
+def test_a_mixed_store_forms_slabs_by_length(seed, min_cells):
+    """Every doc lands in exactly one slab, in store order inside it,
+    with docs of its own rung (or, merged upward, of rungs below); no
+    slab counts more cells than the budget; what forms over the gate
+    costs under 4 cells a real row."""
+    from hypermerge_tpu.backend.pipeline import SlabFormer
+
+    rows = _longtail_rows(seed)
+    cells = 256 * 256  # the budget: 256 docs of the lowest rung
+    full, rest = _form(rows, 256, cells, min_cells)
+    slabs = full + rest
+    seen = sorted(i for s in slabs for i, _n in s)
+    assert seen == list(range(len(rows)))
+    for s in slabs:
+        assert [i for i, _n in s] == sorted(i for i, _n in s)
+        assert _padded(s) <= cells
+        top = SlabFormer.rung(max(n for _i, n in s))
+        assert all(SlabFormer.rung(n) <= top for _i, n in s)
+        if s in full or min_cells == 0:  # never merged: one rung
+            assert {SlabFormer.rung(n) for _i, n in s} == {top}
+    assert sum(map(_padded, slabs)) < 4 * sum(rows)
+    # the stream, not a sort, decides the order: a rung's slab is
+    # emitted as its last doc arrives, before the docs after it
+    for s in full:
+        later = [r for r in full if r is not s and r[0][0] > s[-1][0]]
+        assert all(full.index(r) > full.index(s) for r in later)
+
+
+def test_a_thin_remainder_joins_the_rung_above_where_it_fits():
+    """Under the gate, a rung's remainder joins the next occupied
+    rung's if the two fit one slab of that rung (store order kept);
+    where they do not it is dispatched thin, and so is the top rung's,
+    which has nowhere to go."""
+    cells, gate = 1 << 16, 1 << 12
+    # 3 docs of 10 rows (rung 256: [4, 16] = 64 cells, thin), 2 of
+    # 3,000 rows (rung 4,096: capacity 16)
+    full, rest = _form([10, 3000, 10, 10, 3000], 64, cells, gate)
+    assert full == []
+    assert rest == [[(0, 10), (1, 3000), (2, 10), (3, 10), (4, 3000)]]
+    # the rung above is full with its own: the thin one goes as it is
+    rows = [10] * 3 + [3000] * 15
+    full, rest = _form(rows, 64, cells, gate)
+    assert [len(s) for s in full + rest] == [15, 3]
+    # and a thin top rung is dispatched thin: a store of one length
+    # keeps the tail it always had
+    full, rest = _form([10] * 67, 64, cells, gate)
+    assert [len(s) for s in full + rest] == [64, 3]
+    # a remainder over the gate stays where it is
+    full, rest = _form([200] * 40 + [3000], 64, cells, gate)
+    assert [len(s) for s in rest] == [1, 40]  # highest rung first
+
+
+def test_former_capacity_follows_the_cell_budget():
+    from hypermerge_tpu.backend.bulk_loader import SLAB_CELLS
+    from hypermerge_tpu.backend.pipeline import SlabFormer
+
+    former = SlabFormer(4096, SLAB_CELLS)
+    assert SLAB_CELLS == 4096 * 1024
+    assert [former.capacity(t) for t in (
+        256, 1024, 4096, 131072, 262144)] == [4096, 4096, 1024, 32, 16]
+    assert former.capacity(SLAB_CELLS * 4) == 1  # one doc over it all
+    # a step of 4 up to 65,536 rows, every power of two from there
+    assert [SlabFormer.rung(n) for n in (
+        0, 1, 256, 257, 1024, 1025, 65536, 65537, 131072, 131073,
+        262143, 262145)] == [
+        256, 256, 256, 1024, 1024, 4096, 65536, 131072, 131072, 262144,
+        262144, 524288]
+    assert SlabFormer(32, SLAB_CELLS).capacity(1024) == 32

@@ -183,6 +183,7 @@ def test_span_metrics_read_from_the_tree(traced_open, monkeypatch):
         "loader.first_dispatch_s", "loader.queue_wait_s",
         "loader.io_feeds_s", "loader.io_columns_s", "loader.upload_s",
         "loader.doc_init_s", "host.gc_s.open", "loader.heads_s",
+        "loader.form_s",
     }
     for name, value in read.items():
         if name in nothing:

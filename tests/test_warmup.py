@@ -35,6 +35,25 @@ def test_bulk_buckets():
     assert bulk_buckets(8192, 4096) == [4096]
     assert bulk_buckets(100, 4096) == [128]
     assert bulk_buckets(1, 4096) == [1]
+    # docs past the cell budget of a slab of 4,096: fewer docs a slab
+    assert bulk_buckets(10240, 4096, n_ops=1024) == [4096, 2048]
+    assert bulk_buckets(3000, 4096, n_ops=4000) == [1024]
+
+
+def test_bulk_shapes_follow_the_loaders_ladder():
+    """A store of ragged lengths: one [docs, rows] a rung, the ones
+    the loader's former cuts (rungs 256 x 4^j up to 65,536 rows, every
+    power of two above; 4M cells a slab)."""
+    from hypermerge_tpu.ops.warmup import bulk_shapes
+
+    ops = ([100] * 5000 + [1000] * 300 + [3000] * 40 + [20000] * 9
+           + [100000] * 5 + [200000] * 3)
+    assert bulk_shapes(ops, 4096) == [
+        (4096, 128), (4, 262144), (8, 131072), (16, 32768), (64, 4096),
+        (512, 1024), (1024, 128)]
+    # store order decides nothing but which docs share a slab
+    assert sorted(bulk_shapes(ops[::-1], 4096)) == sorted(
+        bulk_shapes(ops, 4096))
 
 
 def test_warmup_precompiles_bulk_executables(monkeypatch, tmp_path):
