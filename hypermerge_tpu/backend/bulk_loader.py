@@ -48,6 +48,12 @@ _M_HEADS_PROBED = telemetry.counter("loader.heads_probed_feeds")
 # the real op rows in them: their ratio is what forming slabs costs
 _M_CELLS_PADDED = telemetry.counter("loader.slab_cells_padded")
 _M_ROWS_REAL = telemetry.counter("loader.slab_rows_real")
+# the padded cells again, by where their slab's program runs rga_order's
+# rounds; keyed by the stats key each feeds
+_M_RGA_CELLS = {
+    "rga_vmem_cells": telemetry.counter("kernel.rga_vmem_cells"),
+    "rga_xla_cells": telemetry.counter("kernel.rga_xla_cells"),
+}
 
 # the cell budget of one slab (docs x rows): the largest slab of the
 # 1,024-op yardstick store, HM_BULK_SLAB's 4,096 docs x 1,024 rows. The
@@ -129,6 +135,10 @@ _STATS0: Dict[str, Any] = {
     "slabs": 0, "slab_shapes": (), "rows_real": 0, "cells_padded": 0,
     "slab_waste_x": 0.0, "slab_programs": 0,
     "t_form": 0.0,
+    # padded cells of the device slabs by where their program ran
+    # rga_order's rounds (crdt_kernels.rga_rounds_in_vmem): out of VMEM
+    # / through XLA's gather; the share lands after the load
+    "rga_vmem_cells": 0, "rga_xla_cells": 0, "rga_vmem_cells_pct": 0.0,
     **dict.fromkeys(_STAGE_KEYS, 0.0),
 }
 
@@ -288,6 +298,7 @@ class BulkLoader:
                  "heads_probed_feeds"),
                 ("pack_gather_native_pct", "pack_gather_native_feeds",
                  "pack_gather_twin_feeds"),
+                ("rga_vmem_cells_pct", "rga_vmem_cells", "rga_xla_cells"),
             ):
                 stats[pct] = _pct(stats[part], stats[part] + stats[rest])
             stats["pack_general_pct"] = _pct(
@@ -596,7 +607,8 @@ class BulkLoader:
         narrowing, upload and the jitted call are its child spans, and
         their ends feed t_narrow / t_upload / t_dispatch."""
         from ..ops.crdt_kernels import (
-            batch_is_lean, bucket_doc_actors, run_batch_full,
+            batch_is_lean, bucket_doc_actors, rga_rounds_in_vmem,
+            run_batch_full,
         )
         from ..ops.host_kernel import run_batch_host
         from ..ops.materialize import DecodedBatch, decode_patch
@@ -653,8 +665,14 @@ class BulkLoader:
                 programs.add(
                     shape + (a_loc, k_loc, batch.psrc.shape[1], lean)
                 )
+                rga = (
+                    "rga_vmem_cells" if rga_rounds_in_vmem(shape[1])
+                    else "rga_xla_cells"
+                )
+                _M_RGA_CELLS[rga].add(cells)
                 with self._stats_lock:
                     stats["slab_programs"] = len(programs)
+                    stats[rga] += cells
                 rr = self._rr
                 if rr is not None:
                     # multi-chip: successive WHOLE slabs land on

@@ -11,7 +11,14 @@ loop, SURVEY.md §3.3), as one fused XLA program over the columnar encoding
 4. RGA element order: one forest over all list/text objects — sibling sort
    (parent asc, OpId desc), preorder-successor via pointer-doubling climb,
    Wyllie list-ranking for positions. All data-dependent chasing is
-   log2(N) rounds of gathers — no scalar loops, TPU/XLA friendly.
+   log2(N) rounds of gathers — no scalar loops. Where a round reads its
+   table from is chosen by the slab's shape (`rga_rounds_in_vmem`): on a
+   TPU, up to `RGA_VMEM_MAX_ROWS` rows, one Pallas kernel holds a block
+   of docs' tables in VMEM for all 2 x (log2 N + 1) rounds and gathers
+   with the vector unit's lane gather; longer docs and every other
+   backend run each round as one full-width XLA gather from HBM, which
+   a TPU executes element by element (~10 ns each). Same rounds, same
+   `rank`, either way.
 5. element liveness + winner value op per element (scatter-max)
 6. per-doc vector clock (scatter-max of seq per actor)
 
@@ -29,10 +36,12 @@ from __future__ import annotations
 import functools
 import math
 import re
-from typing import Dict, NamedTuple
+from typing import Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry
 from ..crdt.change import Action
@@ -84,7 +93,15 @@ def _doc_kernel(
     constant independent of how many docs (and therefore distinct actors)
     share the batch, so the jit cache key and the [A] clock output don't
     scale with slab size. Slot order == actor-string sort order, the OpId
-    tie-break order within this doc."""
+    tie-break order within this doc.
+
+    Returns the doc's lanes with `rank` left open, and the forest tables
+    `(jump, nsib, first_child, in_forest)` of section 5: the sibling sort
+    runs here, per doc under `vmap`; the climb's and the ranking's
+    rounds over those tables (`_rga_chase`) run on the whole slab
+    (`_rga_rank`), so that a TPU can keep a block of docs' tables in VMEM
+    for all the rounds instead of reading and writing [D, N] in HBM once
+    a round."""
     N = action.shape[0]
     idx = jnp.arange(N, dtype=jnp.int32)
     valid = action != PAD
@@ -177,49 +194,15 @@ def _doc_kernel(
             .set(jnp.where(same_parent, nxt_in_sort, -1).astype(jnp.int32))
         )
 
-        # climb-to-sibling fixpoint via pointer doubling (terminal = N);
-        # int16 payload when it fits — gathers move half the bytes
+        # the climb's table: a node with a next sibling stays, the last
+        # sibling steps to its parent, a root (and every row outside
+        # the forest) to the terminal N; the rounds over it and the
+        # ranking are `_rga_chase`, hoisted to the slab (`_rga_rank`)
         has_sib = nsib != -1
         jump = jnp.where(
             has_sib, idx, jnp.where(parent >= 0, parent, N)
         ).astype(jnp.int32)
         jump = jnp.where(in_forest, jump, N)
-        jump_ext = jnp.concatenate([jump, jnp.array([N], jnp.int32)])
-        if N < 2**15:
-            j16 = jump_ext.astype(jnp.int16)
-            for _ in range(_ceil_log2(N) + 1):
-                j16 = j16[j16.astype(jnp.int32)]
-            jump_ext = j16.astype(jnp.int32)
-        else:
-            for _ in range(_ceil_log2(N) + 1):
-                jump_ext = jump_ext[jump_ext]
-        fix = jump_ext[:N]
-        nsib_ext = jnp.concatenate([nsib, jnp.array([-1], jnp.int32)])
-        succ = jnp.where(first_child != -1, first_child, nsib_ext[fix])
-        succ = jnp.where(in_forest, succ, -1)
-        nxt = jnp.where(succ == -1, N, succ).astype(jnp.int32)
-
-        # Wyllie list-ranking: rank = #nodes from here to end of chain
-        rank = jnp.where(in_forest, 1, 0).astype(jnp.int32)
-        if N < 2**15:
-            # pack (rank, nxt) into one int32 lane: rank <= chain length <= N
-            # < 2^15 and nxt <= N, so `nxt | rank<<16` fits — one gather per
-            # round instead of two (the gathers, not the VPU work, bound
-            # these loops on TPU)
-            p = jnp.concatenate([nxt, jnp.array([N], jnp.int32)]) | (
-                jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)]) << 16
-            )
-            for _ in range(_ceil_log2(N) + 1):
-                q = p[p & 0xFFFF]
-                p = (q & 0xFFFF) | ((p >> 16) + (q >> 16)) << 16
-            rank = (p >> 16)[:N]
-        else:
-            rank_ext = jnp.concatenate([rank, jnp.zeros((1,), jnp.int32)])
-            nxt_ext = jnp.concatenate([nxt, jnp.array([N], jnp.int32)])
-            for _ in range(_ceil_log2(N) + 1):
-                rank_ext = rank_ext + rank_ext[nxt_ext]
-                nxt_ext = nxt_ext[nxt_ext]
-            rank = rank_ext[:N]
 
     # -- 6. clock (local slots; [A_loc], decoded via doc_actors) -------
     with jax.named_scope("clock"):
@@ -229,16 +212,250 @@ def _doc_kernel(
             .max(jnp.where(valid, seq, 0))
         )
 
-    return MaterializeOut(
+    out = MaterializeOut(
         dead=dead,
         visible=visible,
         map_winner=map_winner,
         elem_winner=elem_winner,
         elem_live=elem_live,
-        rank=rank,
+        rank=None,  # `_rga_rank` over the whole slab's forest tables
         inc_total=inc_total,
         clock=clock,
     )
+    return out, (jump, nsib, first_child, in_forest)
+
+
+# -- section 5's rounds: where the gather reads its table from ----------
+# The climb and the ranking are 2 x (ceil(log2 N) + 1) rounds of
+# `table[idx]` along a doc's rows. `_rga_chase` writes them once, over
+# the four operations of an arm (`_ChaseArm`); `_rga_rank` picks the
+# arm from the slab's static shape and the backend.
+
+# Longest doc (rows) whose rounds run out of VMEM on a TPU; above it,
+# and on every other backend, XLA's gather. Set from the chip's
+# per-rung readings (PERF.md section 6, PR 31).
+RGA_VMEM_MAX_ROWS = 65536
+_LANES = 128  # a vreg's lanes: what one in-VMEM lane gather spans
+_VMEM_BLOCK_CELLS = 131072  # cells of a doc block's table, 512 KB
+_TAKE_UNROLL = 128  # source chunks a step of the lane gather's loop
+
+
+class _ChaseArm(NamedTuple):
+    """How the rounds hold and read a table whose terminal is index N.
+    table(x, fill, dtype): rows x [..., N] as a table whose terminal
+    slot holds `fill`; take(table, idx, fill): table[idx] along the last
+    axis; rows(table): the N real rows, int32; rounds(n, step, state):
+    `step` applied n times."""
+
+    table: Callable
+    take: Callable
+    rows: Callable
+    rounds: Callable
+
+
+def _rga_chase(jump, nsib, first_child, in_forest, *, N: int, arm):
+    """RGA rank of every row from the forest tables ([..., N]): the
+    climb-to-sibling fixpoint by pointer doubling, the preorder
+    successor, then Wyllie list-ranking (rank = #nodes from here to the
+    end of the chain)."""
+    i32 = jnp.int32
+    rounds = _ceil_log2(N) + 1
+    narrow = N < 2**15
+    # int16 payload when it fits: XLA's gathers move half the bytes
+    j = arm.rounds(
+        rounds, lambda j: arm.take(j, j, N),
+        arm.table(jump, N, jnp.int16 if narrow else i32),
+    )
+    fix = arm.rows(j)
+    succ = jnp.where(
+        first_child != -1, first_child,
+        arm.take(arm.table(nsib, -1, i32), fix, -1),
+    )
+    succ = jnp.where(in_forest, succ, -1)
+    nxt = jnp.where(succ == -1, N, succ).astype(i32)
+    rank = jnp.where(in_forest, 1, 0).astype(i32)
+    if narrow:
+        # pack (rank, nxt) into one int32 lane: rank <= chain length <= N
+        # < 2^15 and nxt <= N, so `nxt | rank<<16` fits: one gather per
+        # round instead of two (the gathers, not the VPU work, bound
+        # these loops where XLA's element-by-element gather runs them)
+        def packed(p):
+            q = arm.take(p, p & 0xFFFF, N)
+            return (q & 0xFFFF) | ((p >> 16) + (q >> 16)) << 16
+
+        p = arm.table(nxt, N, i32) | (arm.table(rank, 0, i32) << 16)
+        return arm.rows(arm.rounds(rounds, packed, p) >> 16)
+
+    def wide(state):
+        r, nx = state
+        return r + arm.take(r, nx, 0), arm.take(nx, nx, N)
+
+    r, _nx = arm.rounds(
+        rounds, wide, (arm.table(rank, 0, i32), arm.table(nxt, N, i32))
+    )
+    return arm.rows(r)
+
+
+def _ext(x, fill, dtype):
+    slot = jnp.full(x.shape[:-1] + (1,), fill, x.dtype)
+    return jnp.concatenate([x, slot], axis=-1).astype(dtype)
+
+
+def _unrolled(n, step, state):
+    for _ in range(n):
+        state = step(state)
+    return state
+
+
+# one doc under vmap: the table is N + 1 long, the terminal its last
+# slot, and a round is one full-width gather from HBM in a program that
+# spells every round out
+_XLA_ARM = _ChaseArm(
+    table=_ext,
+    take=lambda table, idx, fill: table[idx.astype(jnp.int32)],
+    rows=lambda table: table[..., :-1].astype(jnp.int32),
+    rounds=_unrolled,
+)
+
+
+def _tpu_backend() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def rga_rounds_in_vmem(n_rows: int) -> bool:
+    """Whether a slab of `n_rows`-row docs runs its `rga_order` rounds
+    out of VMEM (`_rga_rank_vmem`): a TPU backend, rows that tile into
+    whole vregs, and no more of them than the lane gather's N^2 / 128
+    work a doc a round still beats XLA's gather at."""
+    return (
+        _tpu_backend()
+        and n_rows % _LANES == 0
+        and n_rows <= RGA_VMEM_MAX_ROWS
+    )
+
+
+def _rga_rank(jump, nsib, first_child, in_forest):
+    """[D, N] forest tables -> [D, N] rank, by the arm the slab's shape
+    selects."""
+    N = jump.shape[1]
+    if rga_rounds_in_vmem(N):
+        return _rga_rank_vmem(jump, nsib, first_child, in_forest)
+    return jax.vmap(functools.partial(_rga_chase, N=N, arm=_XLA_ARM))(
+        jump, nsib, first_child, in_forest
+    )
+
+
+# what Mosaic lowers to `tpu.dynamic_gather` along the lanes
+_LANE_GATHER = jax.lax.GatherDimensionNumbers(
+    offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+    operand_batching_dims=(0,), start_indices_batching_dims=(0,),
+)
+
+
+def _vmem_take(tab_ref, idx_ref, out_ref, fill: int):
+    """out[d, i] = tab[d, idx[d, i]] over [db, N] VMEM refs; an idx of
+    N (the terminal, which has no slot here) reads `fill`. The vector
+    unit gathers within one vreg of 128 lanes, so a gather over N lanes
+    is composed: for each source chunk c, `dynamic_gather(tab[c],
+    idx & 127)` kept where `idx >> 7 == c`. The source chunks are
+    unrolled, `_TAKE_UNROLL` to a loop step: a gather + compare + select
+    costs about 2.4 cycles a vreg, a loop step several times that
+    (PERF.md section 6, PR 31). The body is traced once and of bare
+    `lax` operations: a process traces every slab program it runs, on
+    the thread that dispatches, and this is its longest trace."""
+    db, N = tab_ref.shape
+    chunks = N // _LANES
+    unroll = min(_TAKE_UNROLL, chunks)
+
+    def out_chunk(o, carry):
+        at = pl.ds(pl.multiple_of(o * _LANES, _LANES), _LANES)
+        idx = idx_ref[:, at]
+        hi = idx >> 7
+        lo = (idx & (_LANES - 1))[..., None]
+
+        def src_chunk(c, acc):
+            src = tab_ref[:, pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)]
+            got = jax.lax.gather(
+                src, lo, _LANE_GATHER, slice_sizes=(1, 1),
+                mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+            )
+            return jax.lax.select(hi == c, got, acc)
+
+        def src_group(g, acc):
+            return jax.lax.fori_loop(
+                0, unroll, lambda u, acc: src_chunk(g * unroll + u, acc),
+                acc, unroll=True,
+            )
+
+        out_ref[:, at] = jax.lax.fori_loop(
+            0, chunks // unroll, src_group,
+            jnp.full((db, _LANES), fill, jnp.int32),
+        )
+        return carry
+
+    jax.lax.fori_loop(0, chunks, out_chunk, 0)
+
+
+def _rga_vmem_kernel(
+    jump_ref, nsib_ref, fc_ref, forest_ref, rank_ref,
+    tab_ref, idx_ref, out_ref,
+):
+    """One block of docs: its tables come into VMEM once, every round
+    of `_rga_chase` gathers there, its rank leaves once."""
+    N = jump_ref.shape[1]
+
+    def take(table, idx, fill):
+        tab_ref[...] = table
+        idx_ref[...] = idx
+        _vmem_take(tab_ref, idx_ref, out_ref, fill)
+        return out_ref[...]
+
+    # tables are N long, int32 (the lane gather's width), the terminal
+    # is the index that matches no chunk, and the rounds are a loop (a
+    # round's code is as long as its table: spelt out, a rung of 65,536
+    # rows compiles for half a minute)
+    arm = _ChaseArm(
+        table=lambda x, fill, dtype: x,
+        take=take,
+        rows=lambda table: table,
+        rounds=lambda n, step, state: jax.lax.fori_loop(
+            0, n, lambda _, s: step(s), state
+        ),
+    )
+    rank_ref[...] = _rga_chase(
+        jump_ref[...], nsib_ref[...], fc_ref[...], forest_ref[...] != 0,
+        N=N, arm=arm,
+    )
+
+
+def _rga_rank_vmem(jump, nsib, first_child, in_forest):
+    """`_rga_chase` as one Pallas TPU kernel over blocks of docs (the
+    interpreter off a TPU, which only a test asks for)."""
+    D, N = jump.shape
+    db = max(8, min(_VMEM_BLOCK_CELLS // N, round_up_pow2(D)))
+    pad = (-D) % db
+    args = [
+        jnp.pad(a.astype(jnp.int32), ((0, pad), (0, 0)))
+        for a in (jump, nsib, first_child, in_forest)
+    ]
+    block = pl.BlockSpec((db, N), lambda i: (i, 0))
+    rank = pl.pallas_call(
+        _rga_vmem_kernel,
+        grid=((D + pad) // db,),
+        in_specs=[block] * 4,
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((D + pad, N), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((db, N), jnp.int32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            # five blocks in and out, double-buffered, three scratch
+            # tables and the rounds' temporaries
+            vmem_limit_bytes=max(32 << 20, 20 * db * N * 4),
+        ),
+        interpret=not _tpu_backend(),
+        name="rga_chase",
+    )(*args)
+    return rank[:D]
 
 
 def _widen(flags, slot, ctr, seq, obj, key, ref, value, psrc, ptgt):
@@ -268,10 +485,12 @@ def batched_kernel(A: int, K: int):
          value_w, psrc_w, ptgt_w) = _widen(
             flags, slot, ctr, seq, obj, key, ref, value, psrc, ptgt
         )
-        return jax.vmap(lambda *xs: _doc_kernel(*xs, A=A, K=K))(
+        out, forest = jax.vmap(lambda *xs: _doc_kernel(*xs, A=A, K=K))(
             action, slot_w, ctr_w, seq_w, obj_w, key_w, ref_w, insert,
             value_w, psrc_w, ptgt_w, doc_actors,
         )
+        with jax.named_scope("rga_order"):
+            return out._replace(rank=_rga_rank(*forest))
 
     return fn
 

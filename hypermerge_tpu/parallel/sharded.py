@@ -234,13 +234,24 @@ def shard_batch(batch: ColumnarBatch, mesh: Mesh):
     return args, A, K, D_pad
 
 
+def _docwise(mesh: Mesh, fn):
+    """`fn` over [D, ...] arrays whose docs are independent, as a
+    shard_map over dp: each chip runs it on its own docs. Not left to
+    the partitioner, which cannot split the Pallas kernel a slab's
+    rga_order rounds may be (crdt_kernels._rga_rank_vmem)."""
+    return shard_map(
+        fn, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+        check_vma=False,
+    )
+
+
 def _materialize_program(mesh: Mesh, A: int, K: int):
     key = ("materialize", mesh, A, K)
 
     def build():
         sh = doc_sharding(mesh)
         return jax.jit(
-            _traced(key, batched_kernel(A, K)),
+            _traced(key, _docwise(mesh, batched_kernel(A, K))),
             in_shardings=(sh,) * _N_ARGS,
             out_shardings=MaterializeOut(
                 *([sh] * len(MaterializeOut._fields))
@@ -281,7 +292,7 @@ def _full_program(mesh: Mesh, A: int, K: int, N: int, lean: bool):
             return out, _summarize_wire(out, N, A, lean)
 
         return jax.jit(
-            _traced(key, fn),
+            _traced(key, _docwise(mesh, fn)),
             in_shardings=(sh,) * _N_ARGS,
             out_shardings=(
                 MaterializeOut(*([sh] * len(MaterializeOut._fields))),

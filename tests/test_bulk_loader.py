@@ -262,6 +262,43 @@ def test_a_ragged_store_loads_in_slabs_formed_by_length(
     assert stats2["cells_padded"] > stats["cells_padded"]
 
 
+@pytest.mark.parametrize("vmem_from", [None, 128])
+def test_a_load_counts_its_cells_by_rga_arm(tmp_path, monkeypatch, vmem_from):
+    """`rga_vmem_cells` / `rga_xla_cells` split a load's dispatched
+    padded cells by where each slab's program runs rga_order's rounds:
+    all of them through XLA's gather off a TPU, and those of the slabs
+    the selector takes (here: rows from 128, the Pallas interpreter)
+    out of VMEM, with every summary and value what the OpSet replays."""
+    from hypermerge_tpu.backend import bulk_loader, pipeline
+    from hypermerge_tpu.ops import compile_cache, crdt_kernels as ck
+
+    ids = _ragged_corpus(tmp_path, 40)
+    monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
+    monkeypatch.setattr(pipeline, "ROW_RUNGS", (16, 64, 256))
+    monkeypatch.setattr(bulk_loader, "SLAB_CELLS", 16 * 32)
+    if vmem_from:
+        monkeypatch.setattr(
+            ck, "rga_rounds_in_vmem", lambda n: n >= vmem_from
+        )
+        # a jit of its own: the module's may hold these shapes' programs
+        # traced with the other arm
+        for name in ("materialize_full_device",
+                     "materialize_full_lean_device"):
+            monkeypatch.setattr(ck, name, compile_cache.jit(
+                getattr(ck, name).__wrapped__, static_argnames=("A", "K")
+            ))
+    stats, got, want = _load(tmp_path, ids, slab=16)
+    assert got == want
+    in_vmem = sum(
+        d * n for d, n in stats["slab_shapes"] if n >= (vmem_from or 1e9)
+    )
+    assert bool(in_vmem) == bool(vmem_from)
+    assert stats["rga_vmem_cells"] == in_vmem
+    assert stats["rga_xla_cells"] == stats["cells_padded"] - in_vmem
+    assert stats["rga_vmem_cells_pct"] == round(
+        100.0 * in_vmem / stats["cells_padded"], 3)
+
+
 def _pred_specs(set_shares, n_ops=96):
     """One single-writer feed spec a share: a text and `share` of the
     later ops SETs of ten root keys, each superseding the key's last."""

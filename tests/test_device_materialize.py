@@ -219,3 +219,224 @@ def test_host_kernel_matches_device():
             np.testing.assert_array_equal(
                 np.asarray(getattr(dev, f)), getattr(host, f), err_msg=f
             )
+
+
+# -- rga_order's two gather arms (PR 31) --------------------------------
+# The rounds of `_rga_chase` read their table through XLA's gather or,
+# on a TPU at short rows, out of VMEM (`_rga_rank_vmem`; here through
+# the Pallas interpreter): same rounds, `rank` equal to the bit.
+
+_SET, _DEL, _MAKE_TEXT = 4, 5, 3  # crdt.change.Action
+
+
+def _arm_doc(law: str, n_rows: int, rng):
+    """One doc's kernel columns [n_rows] under `law`, and its pred
+    edges."""
+    from hypermerge_tpu.ops.columnar import PAD
+    from hypermerge_tpu.ops.synth import synth_columns
+
+    n = n_rows
+    c = {
+        "action": np.full(n, PAD, np.int32),
+        "slot": np.zeros(n, np.int32),
+        "ctr": np.zeros(n, np.int32),
+        "seq": np.zeros(n, np.int32),
+        "obj": np.full(n, -1, np.int32),
+        "key": np.full(n, -1, np.int32),
+        "ref": np.full(n, -3, np.int32),
+        "insert": np.zeros(n, np.int32),
+        "value": np.zeros(n, np.int32),
+    }
+    psrc, ptgt = [], []
+    if law == "pad":
+        return c, psrc, ptgt
+    if law == "forest":  # the flagship's: inserts after a uniform pick
+        n_ops = n - int(rng.integers(0, 9))
+        s, ps, pt = synth_columns(
+            n_ops, n_actors=3, seed=int(rng.integers(1 << 30))
+        )
+        for name in c:
+            src = "actor" if name == "slot" else name
+            c[name][:n_ops] = s[src]
+        return c, list(ps), list(pt)
+    rows = np.arange(n)
+    c["action"][:] = _SET
+    c["action"][0] = _MAKE_TEXT
+    c["key"][0] = 0
+    c["obj"][1:] = 0
+    c["insert"][1:] = 1
+    c["ctr"][:] = rows + 1
+    c["seq"][:] = rows + 1
+    if law == "chain":  # the longtail's typing run: a chain of N - 1
+        c["ref"][1:] = rows[:-1]
+        c["ref"][1] = -2
+        return c, psrc, ptgt
+    assert law == "collab"
+    # rounds of 32 writers on EQUAL counters, all under the round's
+    # anchor; every fourth op deletes an element of an earlier round
+    c["ref"][1] = -2
+    for lo in range(2, n, 32):
+        hi = min(lo + 32, n)
+        k = hi - lo
+        anchor = int(rng.integers(1, lo))
+        while c["insert"][anchor] != 1:
+            anchor -= 1
+        c["slot"][lo:hi] = rng.permutation(32)[:k]
+        c["ctr"][lo:hi] = lo + 1
+        c["ref"][lo:hi] = anchor
+        for r in range(lo + 3, hi, 4):
+            victim = int(rng.integers(1, lo))
+            if c["insert"][victim] != 1:
+                continue
+            c["action"][r], c["insert"][r] = _DEL, 0
+            c["ref"][r] = victim
+            psrc.append(r)
+            ptgt.append(victim)
+    return c, psrc, ptgt
+
+
+def _arm_args(law: str, n_docs: int, n_rows: int, seed: int):
+    """Narrow wire args of a [n_docs, n_rows] slab whose docs follow
+    `law`, and its actor bucket."""
+    rng = np.random.default_rng(seed)
+    docs = [_arm_doc(law, n_rows, rng) for _ in range(n_docs)]
+    P = max(8, max(len(ps) for _c, ps, _pt in docs))
+    psrc = np.full((n_docs, P), -1, np.int32)
+    ptgt = np.full((n_docs, P), -1, np.int32)
+    for d, (_c, ps, pt) in enumerate(docs):
+        psrc[d, : len(ps)], ptgt[d, : len(pt)] = ps, pt
+    col = {k: np.stack([c[k] for c, _ps, _pt in docs]) for k in docs[0][0]}
+    A = 32 if law == "collab" else 4
+    flags = (col["action"] | (col["insert"] << 3)).astype(np.uint8)
+    da = np.tile(np.arange(A, dtype=np.int32), (n_docs, 1))
+    return (
+        flags, col["slot"].astype(np.int8), col["ctr"], col["seq"],
+        col["obj"], col["key"], col["ref"], col["value"], psrc, ptgt, da,
+    ), A
+
+
+_ARM_CASES = [
+    # (law, docs): D of 8 fills doc blocks whole, 11 does not, 1 is a
+    # live tick's
+    ("forest", 8), ("chain", 8), ("collab", 8), ("pad", 8),
+    ("forest", 11), ("chain", 1),
+]
+
+
+@pytest.mark.parametrize("n_rows", [256, 1024, 4096])
+@pytest.mark.parametrize("law,n_docs", _ARM_CASES)
+def test_rga_vmem_arm_equals_xla_arm(law, n_docs, n_rows, monkeypatch):
+    import jax
+
+    from hypermerge_tpu.ops import crdt_kernels as ck
+
+    args, A = _arm_args(law, n_docs, n_rows, seed=n_rows + n_docs)
+    assert not ck.rga_rounds_in_vmem(n_rows)  # tier-1 runs on the CPU
+    want = jax.jit(ck.batched_kernel(A, 16))(*args)
+    monkeypatch.setattr(ck, "rga_rounds_in_vmem", lambda n: True)
+    got = jax.jit(ck.batched_kernel(A, 16))(*args)
+    rank = np.asarray(want.rank)
+    if law == "chain":  # the chain is walked to its end
+        assert rank[0, 1] == n_rows - 1 and rank[0, n_rows - 1] == 1
+    if law == "pad":
+        assert not rank.any()
+    for f in want._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
+            err_msg=f,
+        )
+
+
+def test_rga_arm_selector(monkeypatch):
+    """The VMEM arm is for a TPU backend, whole vregs of rows and no
+    more rows than the threshold; everything else keeps XLA's gather."""
+    from hypermerge_tpu.ops import crdt_kernels as ck
+
+    top = ck.RGA_VMEM_MAX_ROWS
+    assert not any(ck.rga_rounds_in_vmem(n) for n in (256, 1024, top))
+    monkeypatch.setattr(ck, "_tpu_backend", lambda: True)
+    assert all(ck.rga_rounds_in_vmem(n) for n in (128, 256, 1024, top))
+    assert not any(ck.rga_rounds_in_vmem(n) for n in (64, 192, 2 * top))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a described v5e: the TPU's compiler without a TPU."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_vmem_arm_compiles_for_v5e_and_is_booked_under_rga_order(
+    v5e_chip, monkeypatch
+):
+    """The lean slab program of a [16, 256] slab, compiled for a v5e
+    with the VMEM arm selected: Mosaic takes the kernel (the lane
+    gather, the scratch tables), and `phases_of_hlo` books its custom
+    call under `rga_order`, so `kernel.phase.rga_order_s` keeps reading
+    the rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    from hypermerge_tpu.ops import crdt_kernels as ck
+
+    monkeypatch.setattr(ck, "_tpu_backend", lambda: True)
+    D, N, P = 16, 256, 64
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    i16 = jnp.int16
+    avals = (
+        aval((D, N), jnp.uint8), aval((D, N), jnp.int8), aval((D, N), i16),
+        aval((D, N), i16), aval((D, N), i16), aval((D, N), i16),
+        aval((D, P), i16), aval((D, P), i16), aval((D, 4), jnp.int32),
+    )
+    fn = ck.materialize_full_lean_device.__wrapped__
+    hlo = (
+        jax.jit(fn, static_argnames=("A", "K"))
+        .lower(*avals, A=4, K=16).compile().as_text()
+    )
+    phases = ck.phases_of_hlo(hlo)
+    calls = [
+        line.split("=")[0].strip() for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    ]
+    assert len(calls) == 1 and "rga_chase" in calls[0]
+    assert phases[calls[0]] == "rga_order"
+
+
+@pytest.mark.parametrize("n_rows", [256, 1024, 4096, 16384, 65536])
+def test_vmem_arm_compiles_at_a_slab_of_every_rung(
+    v5e_chip, monkeypatch, n_rows
+):
+    """The rounds' kernel alone, at the real width of a full slab of
+    each rung the selector gives it (`bulk_loader.SLAB_CELLS` cells),
+    compiled for a v5e: what the interpreter cannot show (tiling, the
+    VMEM a block of docs' tables and the rounds' temporaries take)."""
+    import jax
+    import jax.numpy as jnp
+
+    from hypermerge_tpu.backend.bulk_loader import SLAB_CELLS
+    from hypermerge_tpu.ops import crdt_kernels as ck
+
+    monkeypatch.setattr(ck, "_tpu_backend", lambda: True)
+    assert ck.rga_rounds_in_vmem(n_rows)
+    shape = (SLAB_CELLS // n_rows, n_rows)
+    table = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+    forest = jax.ShapeDtypeStruct(shape, jnp.bool_, sharding=v5e_chip)
+    hlo = (
+        jax.jit(ck._rga_rank_vmem)
+        .lower(table, table, table, forest).compile().as_text()
+    )
+    assert 'custom_call_target="tpu_custom_call"' in hlo
