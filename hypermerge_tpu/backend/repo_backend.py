@@ -326,7 +326,7 @@ class RepoBackend:
             )
 
             self._serve_p99 = (
-                HistogramWindow(self.serve._hist)
+                HistogramWindow(self.serve._hist_warm)
                 if self.serve is not None
                 else None
             )

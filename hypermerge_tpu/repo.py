@@ -59,7 +59,8 @@ class Repo:
         return self.front.doc(url, cb)
 
     def read(
-        self, url: str, query: dict, cb: Optional[Callable] = None
+        self, url: str, query: dict, cb: Optional[Callable] = None,
+        timeout: float = 30.0,
     ) -> Any:
         """One-shot read served WITHOUT materializing the doc
         host-side: under HM_SERVE=1 (default) the backend's serving
@@ -68,8 +69,10 @@ class Repo:
         per-request host twin. Query kinds: {"kind": "text", "path":
         ["body"]}, {"kind": "lookup", "path": ["a", "b"]}, {"kind":
         "index", "path": ["list"], "index": 3}, {"kind": "len",
-        "path": []}, {"kind": "clock"}, {"kind": "history"}."""
-        return self.front.read(url, query, cb)
+        "path": []}, {"kind": "clock"}, {"kind": "history"}. Without
+        `cb` the call blocks for the answer, `timeout` seconds at most
+        (TimeoutError past them)."""
+        return self.front.read(url, query, cb, timeout=timeout)
 
     def watch(self, url: str, cb: Callable[[Any, int], None]) -> Handle:
         return self.front.watch(url, cb)

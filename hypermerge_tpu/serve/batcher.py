@@ -38,7 +38,7 @@ class ReadRequest:
 
     __slots__ = (
         "doc_id", "query", "cb", "t0", "span",
-        "entry", "obj_row", "steps", "done",
+        "entry", "obj_row", "steps", "done", "cold",
     )
 
     def __init__(self, doc_id: str, query: Dict, cb: Callable) -> None:
@@ -51,6 +51,7 @@ class ReadRequest:
         self.obj_row = -1
         self.steps: List = []
         self.done = False
+        self.cold = False  # its flush installed its doc for it
 
 
 class ReadBatcher:

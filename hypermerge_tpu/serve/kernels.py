@@ -38,6 +38,22 @@ _INT32_MAX = 2**31 - 1
 NO_OBJ = -7
 
 
+# a dispatch's batch axis: a power of four up to MAX_BATCH, so that a
+# kind and a row bucket have five programs whatever the flushes hold (a
+# flush of more reads goes in dispatches of MAX_BATCH: a program takes
+# each resident array as an argument of its own, and one of thousands
+# of arguments compiles for half a minute)
+BATCH_BUCKETS = (1, 4, 16, 64, 256)
+MAX_BATCH = BATCH_BUCKETS[-1]
+
+
+def batch_bucket(n: int) -> int:
+    for b in BATCH_BUCKETS:
+        if n <= b:
+            return b
+    raise ValueError(f"serve batch of {n} over {MAX_BATCH}")
+
+
 def _jnp():
     import jax.numpy as jnp
 
@@ -51,9 +67,16 @@ def _program(kind: str, B: int, N: int, build):
     from ..parallel import sharded
 
     key = ("serve", kind, B, N)
-    return sharded._program(
-        key, lambda: _jit(sharded._traced(key, build()))
-    )
+
+    def named():
+        # the XLA program's name: `jit_serve_seq_order_b32_n1024` in a
+        # device trace carries the kind and the shape it ran at
+        fn = sharded._traced(key, build())
+        rows = N if isinstance(N, int) else "x".join(map(str, N))
+        fn.__name__ = f"serve_{kind}_b{B}_n{rows}"
+        return _jit(fn)
+
+    return sharded._program(key, named)
 
 
 def _jit(fn):
@@ -63,15 +86,14 @@ def _jit(fn):
 
 
 def stack_entries(entries: Sequence) -> tuple:
-    """The batch's resident lanes as a pow2-padded TUPLE of [LANES, N]
-    device arrays. The stack into [B, LANES, N] happens INSIDE the
-    jitted program (a pytree argument), so it fuses into the one
-    dispatch instead of paying a per-buffer concat on the way in.
+    """The batch's resident lanes as a TUPLE of [LANES, N] device
+    arrays, padded to its `batch_bucket`. The stack into [B, LANES, N]
+    happens INSIDE the jitted program (a pytree argument), so it fuses
+    into the one dispatch instead of paying a per-buffer concat on the
+    way in.
     Padding repeats the first entry's array — zero new device
     allocations; pad lanes are masked out by the NO_OBJ query pad."""
-    from ..ops.columnar import round_up_pow2
-
-    B = round_up_pow2(max(1, len(entries)))
+    B = batch_bucket(len(entries))
     devs = [e.dev for e in entries]
     if len(devs) < B:
         devs.extend([devs[0]] * (B - len(devs)))
@@ -132,6 +154,50 @@ def _build_counts():
         return n_elems, n_map
 
     return fn
+
+
+def _build_install_merge():
+    def fn(lanes, live, rank, mapwin, take):
+        """An install group's uploaded lanes with the kernel lanes of
+        the docs `take` marks written over them (the others hold the
+        summary memo's): the slab program's outputs never leave the
+        device."""
+        jnp = _jnp()
+        n = live.shape[1]
+        for lane, src in ((L_LIVE, live), (L_RANK, rank), (L_MAPWIN, mapwin)):
+            merged = jnp.where(
+                take[:, None], src.astype(jnp.int32), lanes[:, lane, :n]
+            )
+            lanes = lanes.at[:, lane, :n].set(merged)
+        return lanes
+
+    return fn
+
+
+def install_merge(lanes, live, rank, mapwin, take: np.ndarray):
+    """[D, LANES, N] device lanes <- the slab program's [D, n] outputs
+    for the docs `take` ([D] bool) marks. One dispatch a page."""
+    D, _l, N = lanes.shape
+    fn = _program(
+        "install_merge", D, (N, live.shape[1]), _build_install_merge
+    )
+    return fn(lanes, live, rank, mapwin, _jnp().asarray(take))
+
+
+def _build_install_split():
+    def fn(lanes):
+        return tuple(lanes[d] for d in range(lanes.shape[0]))
+
+    return fn
+
+
+def install_split(lanes, n_docs: int) -> list:
+    """The first `n_docs` docs of an install page's [D, LANES, N] array
+    as [LANES, N] arrays of their own (an entry owns its device bytes,
+    so the LRU frees them doc by doc). One dispatch a page."""
+    D, _l, N = lanes.shape
+    fn = _program("install_split", D, N, _build_install_split)
+    return list(fn(lanes))[:n_docs]
 
 
 def map_lookup(

@@ -28,17 +28,19 @@ from __future__ import annotations
 
 import os
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..analysis.lockdep import make_rlock
 from ..crdt.change import Action
+from . import kernels
 from .kernels import N_LANES, L_INSERT, L_KEY, L_LIVE, L_MAPWIN, L_OBJ, L_RANK
 
-# below this row bucket, shape buckets would proliferate programs for
-# no win; every tiny doc shares the 64-row executable
+# under the loader's lowest rung (256 rows) every tiny doc shares the
+# 64-row executables and pays for 64 rows of device lanes
 SERVE_MIN_ROWS = 64
+
 
 def serve_max_bytes() -> int:
     """HM_SERVE_MAX_BYTES — read per enforcement pass so tests and
@@ -48,7 +50,8 @@ def serve_max_bytes() -> int:
 
 class _Tables:
     """The batch side tables decode_value needs, without pinning the
-    whole ColumnarBatch (its [D, N] column dict) in the entry."""
+    whole ColumnarBatch (its [D, N] column dict) in the entry. One per
+    install page: the page's docs share it and its key index."""
 
     __slots__ = ("strings", "floats", "bigints")
 
@@ -86,10 +89,12 @@ class ResidentDoc:
         self.elem_val = elem_val  # [n] element row -> winner value row
         self.tables = tables
         self.key_index = key_index
+        # a doc with no INC and no element SET points at its group's
+        # shared zeros / identity rows (base set): not its bytes
         self.nbytes = int(getattr(dev, "nbytes", 0)) + sum(
-            int(host_cols[k].nbytes)
-            for k in ("action", "vkind", "value", "dt", "inc_total")
-        ) + int(elem_val.nbytes) + 512
+            int(a.nbytes)
+            for a in (*host_cols.values(), elem_val) if a.base is None
+        ) + 512
         self.last_use = 0
         self.stale = False
 
@@ -104,68 +109,196 @@ class ResidentDoc:
 
 
 def _to_device(stacked: np.ndarray):
-    """The install's one host->device transfer — a module seam so the
-    OOM tests can make the device refuse without faking a whole
+    """An install page's one host->device transfer — a module seam so
+    the OOM tests can make the device refuse without faking a whole
     backend."""
     import jax.numpy as jnp
 
     return jnp.asarray(stacked)
 
 
-def build_entry(backend, doc_id: str, clock: Dict[str, int]):
-    """Build one doc's resident entry at `clock` — pack from the
-    columnar sidecars, run the host summary kernel (or reuse the
-    backend's per-doc summary memo when it already holds this clock's
-    lanes), derive the host decode half, and upload the stacked device
-    lanes. Runs with NO lock held. Returns (entry, memo_hit) or
-    (None, False) when the sidecars cannot serve this clock.
+def rung_of(spec) -> int:
+    """The length rung (backend/pipeline.py ROW_RUNGS, the ladder bulk
+    slabs are formed by, with SERVE_MIN_ROWS under it) over the op rows
+    of one doc's feed windows: the row bucket of its resident lanes,
+    whichever docs it is installed beside, so the query programs see
+    six row shapes up to 65,536."""
+    from ..backend.pipeline import SlabFormer
 
-    Raises whatever the device upload raises (the tier's OOM
-    evict-and-retry wraps this call).
-    """
-    from ..ops.columnar import pack_docs_columns, round_up_pow2
+    rows = 0
+    for fc, start, end in spec:
+        lo, hi = fc.window(start, end)
+        rows += hi - lo
+    if rows <= SERVE_MIN_ROWS:
+        return SERVE_MIN_ROWS
+    return SlabFormer.rung(rows)
 
-    spec = backend._serveable_spec(clock)
-    if spec is None:
-        return None, False
-    batch = pack_docs_columns([spec])
-    c = {k: np.asarray(v[0], np.int32) for k, v in batch.cols.items()}
-    n = batch.n_rows
-    memo_lanes = _memo_lanes(backend, doc_id, clock, c, n)
-    if memo_lanes is not None:
-        live, rank, mapwin = memo_lanes
-        elem_val = np.arange(n, dtype=np.int32)
-        inc_total = np.zeros(n, np.int32)
-    else:
+
+# docs of an install page: a group is built a page at a time, each
+# padded to one of these, so that the programs of an install (the
+# split, the merge, the slab program of a rung) have two doc shapes
+# whatever number of cold docs a flush happened to hold
+PAGE_DOCS = (16, 256)
+
+
+def build_group(
+    backend, items: List[Tuple[str, Dict[str, int], Any]], bucket: int,
+    count: Callable[[str, int], None],
+) -> List[ResidentDoc]:
+    """Build the resident entries of one install group: the docs
+    `items` = [(doc_id, clock, feed spec)] of one length rung
+    (`bucket` rows), a page of at most PAGE_DOCS[-1] docs at a time.
+    A page is one `pack_slab` (the loader's pack), the three kernel
+    lanes of all its docs at once (`_memo_lanes` where the bulk
+    loader's summary memo holds the doc's clock, else ONE run of the
+    slab program), the host decode halves as rows of the page's
+    columns, one upload, and the page's [D, LANES, bucket] array cut
+    into the entries' own [LANES, bucket] arrays on the device. The
+    one-doc install is the group of one. Runs with NO lock held.
+
+    `count(name, n)` feeds the tier's counters (memo_hits,
+    install_device_docs, install_host_kernel_docs). Raises whatever the
+    pack, the kernel or the upload raises (the tier's OOM
+    evict-and-retry wraps this call)."""
+    from .. import telemetry
+
+    entries: List[ResidentDoc] = []
+    with telemetry.span(
+        "serve.install", "serve", docs=len(items), rung=bucket
+    ) as sp:
+        memo = 0
+        for at in range(0, len(items), PAGE_DOCS[-1]):
+            page = items[at:at + PAGE_DOCS[-1]]
+            n_docs = next(d for d in PAGE_DOCS if len(page) <= d)
+            built, n_memo = _build_page(
+                backend, page, bucket, n_docs, count
+            )
+            entries.extend(built)
+            memo += n_memo
+        count("memo_hits", memo)
+        sp.note(memo=memo)
+    return entries
+
+
+def _build_page(backend, items, bucket: int, n_docs: int, count):
+    """(entries, docs whose lanes the memo held) of one page of a
+    group, packed at `n_docs` docs."""
+    from .. import telemetry
+    from ..backend.bulk_loader import pack_slab
+
+    n_real = len(items)
+    with telemetry.span("serve.install.pack", "serve"):
+        batch = pack_slab([spec for _d, _c, spec in items], n_docs)
+    cols = batch.cols
+    D, N = batch.shape
+    n_ops = np.asarray(batch.n_ops[:n_real], np.int64)
+    action = cols["action"]
+    # rows whose lanes the memo does not carry (its wire has no INC
+    # totals and no element-override SETs)
+    has_inc = (action[:n_real] == int(Action.INC)).any(axis=1)
+    has_eset = (
+        (cols["insert"][:n_real] == 0) & (cols["key"][:n_real] < 0)
+        & (cols["ref"][:n_real] >= 0)
+        & (action[:n_real] == int(Action.SET))
+    ).any(axis=1)
+    lanes = np.zeros((D, N_LANES, bucket), np.int32)
+    pad = np.arange(N)[None, :] >= batch.n_ops[:, None]
+    lanes[:, L_OBJ, :N] = np.where(pad, -3, cols["obj"])
+    lanes[:, L_OBJ, N:] = -3  # pad rows match no container (root: -1)
+    lanes[:, L_INSERT, :N] = cols["insert"]
+    lanes[:, L_KEY, :N] = cols["key"]
+    lanes[:, L_KEY, N:] = -1
+    with telemetry.span("serve.install.lanes", "serve") as lsp:
+        from_memo = _memo_lanes(
+            backend, items, n_ops, has_inc | has_eset, lanes, N
+        )
+        n_memo = int(from_memo.sum())
+        out = on_device = None
+        if n_memo < n_real:
+            out, on_device = _kernel_lanes(batch)
+            count(
+                "install_device_docs" if on_device
+                else "install_host_kernel_docs", n_real - n_memo,
+            )
+        lsp.note(device=int(bool(on_device)), memo=n_memo)
+    if out is not None and not on_device:
+        for lane, src in (
+            (L_LIVE, out.elem_live), (L_RANK, out.rank),
+            (L_MAPWIN, out.map_winner),
+        ):
+            src = np.asarray(src)[:n_real].astype(np.int32)
+            lanes[:n_real, lane, :N] = np.where(
+                from_memo[:, None], lanes[:n_real, lane, :N], src
+            )
+    with telemetry.span(
+        "serve.install.upload", "serve", bytes=int(lanes.nbytes)
+    ):
+        dev = _to_device(lanes)  # ONE upload per page
+        if on_device:
+            take = np.zeros(D, bool)
+            take[:n_real] = ~from_memo
+            dev = kernels.install_merge(
+                dev, out.elem_live, out.rank, out.map_winner, take
+            )
+        devs = kernels.install_split(dev, n_real)
+    with telemetry.span("serve.install.host_half", "serve"):
+        # only a doc with INC totals or element SETs needs the
+        # kernel's other lanes on the host; the rest share two rows
+        full = np.nonzero(~from_memo & (has_inc | has_eset))[0]
+        inc_total = visible = elem_winner = None
+        if len(full):
+            inc_total = np.asarray(out.inc_total)
+            visible = np.asarray(out.visible)
+            elem_winner = np.asarray(out.elem_winner)
+        full_set = set(full.tolist())
+        zeros = np.zeros(N, np.int32)
+        ident = np.arange(N, dtype=np.int32)
+        tables = _Tables(batch)
+        key_index = {k: i for i, k in enumerate(batch.keys)}
+        entries = []
+        for d, (doc_id, clock, _spec) in enumerate(items):
+            n = int(n_ops[d])
+            host_cols = {
+                k: cols[k][d, :n].copy()
+                for k in ("action", "vkind", "value", "dt")
+            }
+            if d in full_set:
+                c = {k: np.asarray(cols[k][d, :n], np.int32)
+                     for k in ("insert", "key", "ref")}
+                host_cols["inc_total"] = np.asarray(
+                    inc_total[d, :n], np.int32
+                ).copy()
+                elem_val = _elem_val_map(
+                    c, visible[d, :n], elem_winner[d, :n]
+                )
+            else:
+                host_cols["inc_total"] = zeros[:n]
+                elem_val = ident[:n]
+            entries.append(ResidentDoc(
+                doc_id, dict(clock), n, bucket, devs[d], host_cols,
+                elem_val, tables, key_index,
+            ))
+    return entries, n_memo
+
+
+def _kernel_lanes(batch):
+    """(MaterializeOut-shaped lanes of the whole group, on_device): one
+    run of the slab program a cold open runs (`materialize_full*`). On
+    an accelerator always; a CPU process answers a group under the
+    loader's own gate (HM_DEVICE_MIN_CELLS) with the numpy twin, as its
+    bulk loads do."""
+    from ..backend.bulk_loader import device_min_cells
+    from ..ops import compile_cache
+
+    cells = batch.n_docs * batch.n_rows
+    if compile_cache.ensure() == "cpu" and cells < device_min_cells():
         from ..ops.host_kernel import run_batch_host
 
-        out = run_batch_host(batch)
-        live = np.asarray(out.elem_live[0])
-        rank = np.asarray(out.rank[0], np.int32)
-        mapwin = np.asarray(out.map_winner[0])
-        inc_total = np.asarray(out.inc_total[0], np.int32)
-        elem_val = _elem_val_map(c, np.asarray(out.visible[0]),
-                                 np.asarray(out.elem_winner[0]))
-    bucket = round_up_pow2(max(n, SERVE_MIN_ROWS))
-    stacked = np.zeros((N_LANES, bucket), np.int32)
-    stacked[L_LIVE, :n] = live.astype(np.int32)
-    stacked[L_RANK, :n] = rank
-    stacked[L_OBJ, :n] = c["obj"]
-    stacked[L_OBJ, n:] = -3  # pad rows match no container (root is -1)
-    stacked[L_INSERT, :n] = c["insert"]
-    stacked[L_KEY, :n] = c["key"]
-    stacked[L_KEY, n:] = -1
-    stacked[L_MAPWIN, :n] = mapwin.astype(np.int32)
-    dev = _to_device(stacked)  # ONE upload per install
-    host_cols = {
-        "action": c["action"], "vkind": c["vkind"],
-        "value": c["value"], "dt": c["dt"], "inc_total": inc_total,
-    }
-    entry = ResidentDoc(
-        doc_id, dict(clock), n, bucket, dev, host_cols, elem_val,
-        _Tables(batch), {k: i for i, k in enumerate(batch.keys)},
-    )
-    return entry, memo_lanes is not None
+        return run_batch_host(batch), False
+    from ..ops.crdt_kernels import batch_is_lean, run_batch_full
+
+    out, _wire = run_batch_full(batch, lean=batch_is_lean(batch))
+    return out, True
 
 
 def _elem_val_map(
@@ -187,36 +320,41 @@ def _elem_val_map(
     return ev
 
 
-def _memo_lanes(backend, doc_id, clock, c, n):
-    """Reuse the backend's per-doc summary memo (the bulk loader's host
-    half) when it already holds this exact clock's summary: the install
-    then skips the host kernel run entirely — the serving tier and the
-    bulk path share ONE freshness rule (clock equality). Only sound
-    when no row needs the lanes the memo does not carry: INC totals and
-    element-override SETs fall back to the kernel run."""
-    m = backend.summary_memo_row(doc_id)
-    if m is None or m["clock"] != clock or m["N"] < n:
-        return None
-    if np.any(c["action"] == int(Action.INC)):
-        return None
-    if np.any(
-        (c["insert"] == 0)
-        & (c["key"] < 0)
-        & (c["ref"] >= 0)
-        & (c["action"] == int(Action.SET))
-    ):
-        return None
+def _memo_lanes(backend, items, n_ops, needs_kernel, lanes, N):
+    """Fill the kernel lanes (live, rank, map winner) of every doc the
+    backend's per-doc summary memo (the bulk loader's host half) holds
+    at exactly its serving clock: those docs skip the kernel run — the
+    serving tier and the bulk path share ONE freshness rule (clock
+    equality). Only sound when no row needs the lanes the memo does not
+    carry (`needs_kernel`: INC totals, element-override SETs). Returns
+    the [docs] mask of the docs served; rows of one memo width decode
+    together."""
     from ..ops.crdt_kernels import unpack_bits_le
 
-    N = m["N"]
-    mapwin = unpack_bits_le(m["mw_bits"][None], N)[0][:n]
-    live = unpack_bits_le(m["el_bits"][None], N)[0][:n]
-    # pseudo-rank from the memo'd element order: rank[order[i]] = N - i
-    # reproduces the order under the seq_order kernel's argsort
-    pos = np.empty(N, np.int64)
-    pos[np.asarray(m["order"], np.int64)] = np.arange(N)
-    rank = (N - pos[:n]).astype(np.int32)
-    return live, rank, mapwin
+    served = np.zeros(len(items), bool)
+    by_width: Dict[int, List[Tuple[int, Dict]]] = {}
+    for d, (doc_id, clock, _spec) in enumerate(items):
+        m = backend.summary_memo_row(doc_id)
+        if (
+            m is None or needs_kernel[d] or m["clock"] != clock
+            or m["N"] < n_ops[d]
+        ):
+            continue
+        by_width.setdefault(m["N"], []).append((d, m))
+    for M, rows in by_width.items():
+        idx = np.asarray([d for d, _m in rows])
+        w = min(M, N)
+        for lane, key in ((L_MAPWIN, "mw_bits"), (L_LIVE, "el_bits")):
+            bits = unpack_bits_le(np.stack([m[key] for _d, m in rows]), M)
+            lanes[idx, lane, :w] = bits[:, :w]
+        # pseudo-rank from the memo'd element order: rank[order[i]] =
+        # M - i reproduces the order under the seq_order kernel's argsort
+        order = np.stack([m["order"] for _d, m in rows]).astype(np.int64)
+        pos = np.empty_like(order)
+        np.put_along_axis(pos, order, np.arange(M)[None, :], axis=1)
+        lanes[idx, L_RANK, :w] = (M - pos[:, :w]).astype(np.int32)
+        served[idx] = True
+    return served
 
 
 class ResidencyCache:
@@ -325,6 +463,15 @@ class ResidencyCache:
     def resident_bytes(self) -> int:
         # atomic_read_ok (analysis/guards.py): monitoring snapshot
         return self._bytes
+
+    @property
+    def device_bytes(self) -> int:
+        """Bytes of the resident entries' device arrays."""
+        with self._lock:
+            return sum(
+                int(getattr(e.dev, "nbytes", 0))
+                for e in self._entries.values()
+            )
 
     @property
     def resident_docs(self) -> int:

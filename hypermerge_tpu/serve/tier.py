@@ -51,11 +51,20 @@ from ..models import Counter, Table, Text
 from ..ops.columnar import decode_value
 from ..utils.debug import log
 from .batcher import ReadBatcher, ReadRequest
-from .resident import ResidencyCache, build_entry
+from .resident import ResidencyCache, build_group, rung_of
 
 READ_KINDS = ("lookup", "index", "text", "len", "clock", "history")
 
 _MAX_PATH_ROUNDS = 64  # path depth bound (per-level batched dispatches)
+
+# serve.read_s: six edges a decade, the service plane's SLO (50 ms) and
+# its half among them. The default decades (.., 10 ms, 50 ms, ..) read
+# every read over 10 ms as one AT the SLO (a quantile is its bucket's
+# upper edge), which sent the ladder up under 12-18 ms of healthy reads
+READ_BUCKETS_S = tuple(
+    round(m * 10.0 ** e, 9)
+    for e in range(-4, 2) for m in (1.0, 1.5, 2.5, 3.5, 5.0, 7.5)
+)
 
 
 def _leaf(v: Any) -> Any:
@@ -187,11 +196,30 @@ class ServeTier:
                 "fallbacks", "evictions", "evictions_pressure",
                 "batches", "memo_hits", "host_memo_hits", "dispatches",
                 "overload_shed", "flush_errors",
+                # installs by group (one pack, one upload) and by who
+                # computed the docs' kernel lanes where the summary memo
+                # did not hold them: the slab program on the device, or
+                # its numpy twin (a CPU process under the loader's gate)
+                "install_groups", "install_device_docs",
+                "install_host_kernel_docs",
             )
         }
-        for k in ("resident_docs", "resident_bytes", "queue_depth"):
+        for k in (
+            "resident_docs", "resident_bytes", "resident_device_bytes",
+            "queue_depth",
+        ):
             self._m[k] = reg.gauge("serve." + k, inst=inst)
-        self._hist = reg.histogram("serve.read_s", inst=inst)
+        self._hist = reg.histogram(
+            "serve.read_s", buckets=READ_BUCKETS_S, inst=inst
+        )
+        # the service plane's p99 feed: reads answered from resident
+        # state. A read that paid for its own doc's install is the cold
+        # reader's cost, not pressure: counted as overload it walks the
+        # ladder to BROWNOUT, which defers the very installs that would
+        # end the cold reads
+        self._hist_warm = reg.histogram(
+            "serve.read_warm_s", buckets=READ_BUCKETS_S, inst=inst
+        )
 
     # ------------------------------------------------------------------
     # public surface (RepoBackend routes reads here)
@@ -287,7 +315,7 @@ class ServeTier:
         self._batcher.close()
         self._cache.clear()
         telemetry.REGISTRY.retire(
-            *self._m.values(), self._hist
+            *self._m.values(), self._hist, self._hist_warm
         )
 
     # ------------------------------------------------------------------
@@ -302,9 +330,11 @@ class ServeTier:
         pending is answered by the host twin — never None, which is
         the legitimate answer for a path that does not exist."""
         try:
-            with telemetry.span("serve.batch", "serve", reads=len(reqs)):
+            with telemetry.span(
+                "serve.batch", "serve", reads=len(reqs)
+            ) as sp:
                 self._m["batches"].add(1)
-                self._flush_inner(reqs)
+                sp.note(cold=self._flush_inner(reqs))
         except Exception as e:
             self._m["flush_errors"].add(1)
             log("serve", f"batch flush failed: {e!r}")
@@ -321,7 +351,8 @@ class ServeTier:
         finally:
             self._m["queue_depth"].set(self._batcher.depth)
 
-    def _flush_inner(self, reqs: List[ReadRequest]) -> None:
+    def _flush_inner(self, reqs: List[ReadRequest]) -> int:
+        """Resolve the batch; the cold docs it installed."""
         by_doc: Dict[str, List[ReadRequest]] = {}
         for r in reqs:
             by_doc.setdefault(r.doc_id, []).append(r)
@@ -347,7 +378,10 @@ class ServeTier:
             self._resolve(ready)
         ready = []
         ctl = getattr(self._back, "overload", None)
+        install: List = []
         for doc, clock, rs in cold:
+            for r in rs:
+                r.cold = True
             if ctl is not None and ctl.defer_install(len(rs)):
                 # brownout: cold installs shed first — the reads
                 # still answer (host memo path), the device install
@@ -355,7 +389,12 @@ class ServeTier:
                 for r in rs:
                     self._fallback(r, doc)
                 continue
-            entry = self._install(doc, clock)
+            install.append((doc, clock, rs))
+        entries = self._install(
+            [(doc, clock) for doc, clock, _rs in install]
+        )
+        for doc, _clock, rs in install:
+            entry = entries.get(doc.id)
             if entry is None:
                 self._m["fallbacks"].add(len(rs))
                 for r in rs:
@@ -364,6 +403,7 @@ class ServeTier:
             self._attach(entry, rs, ready)
         if ready:
             self._resolve(ready)
+        return len(entries)
 
     @staticmethod
     def _attach(entry, rs, ready) -> None:
@@ -373,50 +413,88 @@ class ServeTier:
             r.steps = list(r.query.get("path") or [])
             ready.append(r)
 
-    def _install(self, doc, clock):
-        """Build + install a resident entry at `clock` (outside every
-        lock), with the OOM ladder: evict LRU + retry once, then None
-        (host path). A build that loses a clock race still serves this
-        batch but is not cached."""
-        entry = memo_hit = None
-        for attempt in (0, 1):
-            try:
-                entry, memo_hit = build_entry(self._back, doc.id, clock)
-                break
-            except Exception as e:
-                if (
-                    attempt == 1
-                    or not _looks_like_oom(e)
-                    or self._cache.resident_docs == 0
-                ):
-                    # a deterministic build failure (corrupt sidecar,
-                    # pack bug) must NOT thrash healthy residents out
-                    # of the cache on every read of the one broken
-                    # doc — only genuine memory pressure earns a shed
-                    log("serve", f"install {doc.id[:6]} failed: {e!r}")
-                    self._last_install_error = repr(e)[:500]
-                    return None
-                # device memory pressure: shed LRU residents and give
-                # the install one more chance before degrading
-                shed = self._cache.evict_lru(serve_max_bytes_retry())
-                self._m["evictions_pressure"].add(len(shed))
-                log(
-                    "serve",
-                    f"install {doc.id[:6]} hit device pressure; "
-                    f"evicted {len(shed)} LRU entries, retrying",
-                )
-        if entry is None:
-            return None  # sidecars cannot rebuild: dirty/unbacked
-        self._m["installs"].add(1)
-        if memo_hit:
-            self._m["memo_hits"].add(1)
-        if doc.clock == clock:  # install-and-recheck
-            evicted = self._cache.install(entry)
-            if evicted:
-                self._m["evictions"].add(len(evicted))
+    def _install(self, cold: List) -> Dict[str, Any]:
+        """Build + install the resident entries of a flush's cold docs
+        `cold` = [(doc, clock)], a length rung (resident.rung_of) at a
+        time, outside every lock. {doc id: entry} of the docs built;
+        a doc the sidecars cannot rebuild (dirty/unbacked), or whose
+        group failed, is absent (host path). A build that loses a clock
+        race still serves this batch but is not cached."""
+        groups: Dict[int, List] = {}
+        docs = {}
+        for doc, clock in cold:
+            spec = self._back._serveable_spec(clock)
+            if spec is None:
+                continue
+            docs[doc.id] = doc
+            groups.setdefault(rung_of(spec), []).append(
+                (doc.id, clock, spec)
+            )
+        built: Dict[str, Any] = {}
+        for bucket in sorted(groups):
+            for entry in self._build(groups[bucket], bucket):
+                built[entry.doc_id] = entry
+        if not built:
+            return built
+        self._m["installs"].add(len(built))
+        evicted = 0
+        for doc_id, entry in built.items():
+            if docs[doc_id].clock == entry.clock:  # install-and-recheck
+                evicted += len(self._cache.install(entry))
+        if evicted:
+            self._m["evictions"].add(evicted)
         self._m["resident_docs"].set(self._cache.resident_docs)
         self._m["resident_bytes"].set(self._cache.resident_bytes)
-        return entry
+        self._m["resident_device_bytes"].set(self._cache.device_bytes)
+        return built
+
+    def _build(self, items: List, bucket: int) -> List:
+        """One group's entries, with the OOM ladder: evict LRU + retry
+        once, then nothing (host path). A group that fails for another
+        reason is built again doc by doc, so that one corrupt sidecar
+        sends its own doc to the host path and not its neighbours'."""
+        self._m["install_groups"].add(1)
+
+        def count(name: str, n: int) -> None:
+            self._m[name].add(n)
+
+        for attempt in (0, 1):
+            try:
+                return build_group(self._back, items, bucket, count)
+            except Exception as e:
+                if (
+                    attempt == 0
+                    and _looks_like_oom(e)
+                    and self._cache.resident_docs > 0
+                ):
+                    # device memory pressure: shed LRU residents and
+                    # give the install one more chance before degrading
+                    shed = self._cache.evict_lru(serve_max_bytes_retry())
+                    self._m["evictions_pressure"].add(len(shed))
+                    log(
+                        "serve",
+                        f"install of {len(items)} docs hit device "
+                        f"pressure; evicted {len(shed)} LRU entries, "
+                        f"retrying",
+                    )
+                    continue
+                # a deterministic build failure (corrupt sidecar, pack
+                # bug) must NOT thrash healthy residents out of the
+                # cache on every read of the one broken doc — only
+                # genuine memory pressure earns a shed
+                log(
+                    "serve",
+                    f"install {items[0][0][:6]} (+{len(items) - 1}) "
+                    f"failed: {e!r}",
+                )
+                self._last_install_error = repr(e)[:500]
+                if len(items) > 1 and not _looks_like_oom(e):
+                    return [
+                        entry for item in items
+                        for entry in self._build([item], bucket)
+                    ]
+                return []
+        return []
 
     # ------------------------------------------------------------------
     # batched path resolution + query dispatch
@@ -488,16 +566,25 @@ class ServeTier:
                 self._m["fallbacks"].add(1)
                 self._fallback(r, doc)
 
-    def _by_bucket(self, rs: List[ReadRequest]) -> Dict[int, List]:
+    @staticmethod
+    def _by_bucket(rs: List[ReadRequest]) -> List[List[ReadRequest]]:
+        """The requests of one dispatch each: one row bucket, at most
+        kernels.MAX_BATCH of them."""
+        from .kernels import MAX_BATCH
+
         groups: Dict[int, List[ReadRequest]] = {}
         for r in rs:
             groups.setdefault(r.entry.bucket, []).append(r)
-        return groups
+        return [
+            group[at:at + MAX_BATCH]
+            for group in groups.values()
+            for at in range(0, len(group), MAX_BATCH)
+        ]
 
     def _dispatch_lookups(self, kernels, rs: List[ReadRequest]) -> None:
         """One map_lookup dispatch per shape bucket: resolve the next
         (string) path step of every request in the group."""
-        for _bucket, group in self._by_bucket(rs).items():
+        for group in self._by_bucket(rs):
             keys = [r.steps[0] for r in group]
             rows, found = kernels.map_lookup(
                 [r.entry for r in group],
@@ -521,7 +608,7 @@ class ServeTier:
     def _dispatch_orders(self, kernels, rs: List[ReadRequest]) -> None:
         """One seq_order dispatch per bucket serves int path steps,
         final index lookups, and text joins together."""
-        for _bucket, group in self._by_bucket(rs).items():
+        for group in self._by_bucket(rs):
             order, count = kernels.seq_order(
                 [r.entry for r in group], [r.obj_row for r in group]
             )
@@ -552,7 +639,7 @@ class ServeTier:
                     self._finish(r, None)  # scalar mid-path
 
     def _dispatch_counts(self, kernels, rs: List[ReadRequest]) -> None:
-        for _bucket, group in self._by_bucket(rs).items():
+        for group in self._by_bucket(rs):
             n_elems, n_map = kernels.counts(
                 [r.entry for r in group], [r.obj_row for r in group]
             )
@@ -632,7 +719,10 @@ class ServeTier:
         if req.done:
             return
         req.done = True
-        self._hist.observe(time.perf_counter() - req.t0)
+        took = time.perf_counter() - req.t0
+        self._hist.observe(took)
+        if not req.cold:
+            self._hist_warm.observe(took)
         if req.span is not None:
             req.span.end()
         try:

@@ -386,10 +386,10 @@ def test_non_oom_install_failure_does_not_shed(repo, monkeypatch):
         assert repo.read(u, {"kind": "lookup", "path": ["n"]}) == 41
     n0 = repo.back.serve._cache.resident_docs
 
-    def broken(backend, doc_id, clock):
+    def broken(backend, items, bucket, count):
         raise ValueError("corrupt sidecar (not oom)")
 
-    monkeypatch.setattr(tiermod, "build_entry", broken)
+    monkeypatch.setattr(tiermod, "build_group", broken)
     cold = _seed(repo)
     p0 = serve_counter("evictions_pressure")
     f0 = serve_counter("fallbacks")

@@ -158,8 +158,8 @@ def test_serve_first_process_writes_its_program(tmp_path):
     p = _run_cached(cache_dir, body=_SERVE_FIRST)
     assert p.returncode == 0, p.stderr
     entries = os.listdir(cache_dir)
-    # the serve programs jit a `wrapper` closure (sharded._traced)
-    assert any(e.startswith("jit_wrapper") for e in entries), entries
+    # a serve program is named by its kind and shape (serve/kernels.py)
+    assert any(e.startswith("jit_serve_") for e in entries), entries
 
 
 def test_cache_dir_from_env_is_never_set_in_code(monkeypatch, tmp_path):
