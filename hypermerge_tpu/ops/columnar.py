@@ -1683,33 +1683,12 @@ class LiveColumns:
 
     def decode_values(self, rows: np.ndarray) -> List[Any]:
         """Decoded Python values for the given row indices — the batch
-        twin of `decode_row_value`, vectorized by value kind (one
-        nonzero + one tight fixup pass per kind present instead of a
-        per-row Python call). The live decode's value hot path."""
-        vk = self.cols["vkind"][rows]
-        out: List[Any] = self.cols["value"][rows].tolist()
-        if not out:
-            return out
-        # VK_INT rows are already right (tolist yields Python ints);
-        # patch the other kinds in place
-        m = vk == VK_NONE
-        if m.any():
-            for i in np.nonzero(m)[0].tolist():
-                out[i] = None
-        m = vk == VK_BOOL
-        if m.any():
-            for i in np.nonzero(m)[0].tolist():
-                out[i] = bool(out[i])
-        for code, table in (
-            (VK_FLOAT, self.floats.items),
-            (VK_STR, self.strings.items),
-            (VK_BIGINT, self.bigints.items),
-        ):
-            m = vk == code
-            if m.any():
-                for i in np.nonzero(m)[0].tolist():
-                    out[i] = table[out[i]]
-        return out
+        twin of `decode_row_value` (`decode_value_rows` over this doc's
+        columns and side tables). The live decode's value hot path."""
+        return decode_value_rows(
+            self.cols["vkind"][rows], self.cols["value"][rows],
+            self.strings.items, self.floats.items, self.bigints.items,
+        )
 
     @property
     def nbytes(self) -> int:
@@ -1740,6 +1719,40 @@ def decode_live_value(vkind: int, value: int, lv: "LiveColumns") -> Any:
     if vkind == VK_BIGINT:
         return lv.bigints.items[value]
     raise ValueError(f"bad vkind {vkind}")
+
+
+def decode_value_rows(
+    vkind: np.ndarray, value: np.ndarray, strings: Sequence[str],
+    floats: Sequence[float], bigints: Sequence[int],
+) -> List[Any]:
+    """Decoded Python values of a run of rows, from their value kinds
+    and codes and the side tables the codes index — the batch twin of
+    `decode_value` / `decode_live_value`, vectorized by value kind (one
+    nonzero + one tight fixup pass per kind present instead of a
+    per-row Python call). Shared by the live engine
+    (`LiveColumns.decode_values`) and the read tier's text join
+    (serve/tier.py)."""
+    out: List[Any] = value.tolist()
+    if not out:
+        return out
+    # VK_INT rows are already right (tolist yields Python ints);
+    # patch the other kinds in place
+    m = vkind == VK_NONE
+    if m.any():
+        for i in np.nonzero(m)[0].tolist():
+            out[i] = None
+    m = vkind == VK_BOOL
+    if m.any():
+        for i in np.nonzero(m)[0].tolist():
+            out[i] = bool(out[i])
+    for code, table in (
+        (VK_FLOAT, floats), (VK_STR, strings), (VK_BIGINT, bigints),
+    ):
+        m = vkind == code
+        if m.any():
+            for i in np.nonzero(m)[0].tolist():
+                out[i] = table[out[i]]
+    return out
 
 
 def decode_value(

@@ -23,6 +23,8 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
+
 # stacked-lane row indices (ResidentDoc.dev is [LANES, N] int32)
 L_LIVE = 0     # elem_live: INS rows whose element has a visible value
 L_RANK = 1     # RGA order key (higher = earlier)
@@ -200,21 +202,34 @@ def install_split(lanes, n_docs: int) -> list:
     return list(fn(lanes))[:n_docs]
 
 
+def _query(kind: str, build, entries: Sequence, *qs) -> tuple:
+    """One query dispatch over the group's resident lanes: the stack of
+    the entries, the program call, the device->host fetch of its
+    outputs as numpy arrays. `qs` = (per-read values, pad fill) a query
+    argument. The `serve.dispatch{kind,B,N}` span is the host's whole
+    cost of the dispatch; `serve.dispatch.fetch` under it is the wait
+    for the program and the transfer of what it returned."""
+    jnp = _jnp()
+    B, N = batch_bucket(len(entries)), entries[0].dev.shape[1]
+    with telemetry.span("serve.dispatch", "serve", kind=kind, B=B, N=N):
+        fn = _program(kind, B, N, build)
+        out = fn(
+            stack_entries(entries),
+            *(jnp.asarray(_pad_q(vals, B, fill)) for vals, fill in qs),
+        )
+        with telemetry.span("serve.dispatch.fetch", "serve"):
+            return tuple(np.asarray(o) for o in out)
+
+
 def map_lookup(
     entries: Sequence, qobjs: List[int], qkeys: List[int]
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Winner value row per (doc, container, key): [B] rows + [B] found
     mask. One dispatch for the whole group."""
-    jnp = _jnp()
-    arrs = stack_entries(entries)
-    B, N = len(arrs), arrs[0].shape[1]
-    fn = _program("map_lookup", B, N, _build_map_lookup)
-    row, found = fn(
-        arrs,
-        jnp.asarray(_pad_q(qobjs, B, NO_OBJ)),
-        jnp.asarray(_pad_q(qkeys, B, -1)),
+    return _query(
+        "map_lookup", _build_map_lookup, entries,
+        (qobjs, NO_OBJ), (qkeys, -1),
     )
-    return np.asarray(row), np.asarray(found)
 
 
 def seq_order(
@@ -222,25 +237,11 @@ def seq_order(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Element order (live INS rows, descending rank) per (doc,
     container): [B, N] row order + [B] live counts."""
-    jnp = _jnp()
-    arrs = stack_entries(entries)
-    B, N = len(arrs), arrs[0].shape[1]
-    fn = _program("seq_order", B, N, _build_seq_order)
-    order, count = fn(
-        arrs, jnp.asarray(_pad_q(qobjs, B, NO_OBJ))
-    )
-    return np.asarray(order), np.asarray(count)
+    return _query("seq_order", _build_seq_order, entries, (qobjs, NO_OBJ))
 
 
 def counts(
     entries: Sequence, qobjs: List[int]
 ) -> Tuple[np.ndarray, np.ndarray]:
     """([B] live element counts, [B] map entry counts) per container."""
-    jnp = _jnp()
-    arrs = stack_entries(entries)
-    B, N = len(arrs), arrs[0].shape[1]
-    fn = _program("counts", B, N, _build_counts)
-    n_elems, n_map = fn(
-        arrs, jnp.asarray(_pad_q(qobjs, B, NO_OBJ))
-    )
-    return np.asarray(n_elems), np.asarray(n_map)
+    return _query("counts", _build_counts, entries, (qobjs, NO_OBJ))

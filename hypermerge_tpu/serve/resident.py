@@ -4,8 +4,10 @@ A resident doc is the device half of a read: six structural lanes
 (serve/kernels.py layout) stacked into ONE [LANES, N] int32 array — a
 single upload per install — keyed by the serving clock the columns
 were built at. The host half stays host: the value/str/float side
-tables, the per-row value columns, and the element->winner-value map,
-all of which only ever decode a handful of rows per read.
+tables, the per-row value columns, and the element->winner-value map.
+A `lookup` or `index` read decodes one row of them; a `text` read
+takes every live element's row at once (serve/tier.py `_join_text`),
+which is why they are kept as arrays and never as Python objects.
 
 Install follows the PR-4 adoption idiom: the build (sidecar pack +
 summary kernel + upload) runs with NO lock held; the install takes the
@@ -51,14 +53,18 @@ def serve_max_bytes() -> int:
 class _Tables:
     """The batch side tables decode_value needs, without pinning the
     whole ColumnarBatch (its [D, N] column dict) in the entry. One per
-    install page: the page's docs share it and its key index."""
+    install page: the page's docs share it and its key index. `chars`
+    is the strings table as an object array: a text read takes all
+    its characters from it at once (serve/tier.py `_join_text`)."""
 
-    __slots__ = ("strings", "floats", "bigints")
+    __slots__ = ("strings", "floats", "bigints", "chars")
 
     def __init__(self, batch) -> None:
         self.strings = batch.strings
         self.floats = batch.floats
         self.bigints = batch.bigints
+        self.chars = np.empty(len(batch.strings), object)
+        self.chars[:] = batch.strings
 
 
 class ResidentDoc:

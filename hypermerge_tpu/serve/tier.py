@@ -6,7 +6,19 @@ mostly readers": instead of materializing a doc host-side per request
 keeps each warm doc's summary columns resident in device memory
 (serve/resident.py) and answers reads with batched query kernels
 (serve/kernels.py) over the whole concurrent read batch
-(serve/batcher.py). Host work per read is a handful of scalar decodes.
+(serve/batcher.py). The host half of a read: a `lookup`, `index` or
+`len` read decodes one row (`_row_leaf`) or none; a `text` read joins
+every live character, as array work over the resident entry's host
+columns (`_join_text`: three takes, ops/columnar.decode_value_rows for
+the whole run, one join — no Python call a character).
+
+A flush is one `serve.batch{reads,cold}` span; below it, also when
+nothing is cold: `serve.batch.attach{docs}` (the residency check),
+one `serve.dispatch{kind,B,N}` a query dispatch with its blocking
+`serve.dispatch.fetch` (serve/kernels.py), one `serve.decode{reads,
+rows}` a seq_order group (its text joins and index / path steps; the
+answers go out after it). Counters `serve.text_reads` / `serve.text_rows`
+give the joins' work.
 
 Read queries (all JSON-safe; `path` is map keys (str) / sequence
 indices (int) from the root):
@@ -44,11 +56,13 @@ import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional
 
+import numpy as np
+
 from .. import telemetry
 from ..crdt import clock as clockmod
 from ..crdt.frontend_state import FrontendDoc
 from ..models import Counter, Table, Text
-from ..ops.columnar import decode_value
+from ..ops.columnar import VK_STR, decode_value, decode_value_rows
 from ..utils.debug import log
 from .batcher import ReadBatcher, ReadRequest
 from .resident import ResidencyCache, build_group, rung_of
@@ -169,6 +183,32 @@ def host_read(doc, query: Dict) -> Optional[Dict[str, Any]]:
     return {"value": host_value(doc, query)}
 
 
+def _join_text(e, order) -> str:
+    """The string of a `text` read: the resident entry's elements in
+    `order` (the live rows the seq_order program returned), joined by
+    array work over the entry's host columns. One take for the
+    elements' winning value rows, one for their kinds, one for their
+    codes; a text of strings is then one take of the page's strings
+    table and one join — no Python call a character. A text that holds
+    anything else goes through `decode_value_rows` (a fix-up pass a
+    kind present): such an element contributes str(value), a counter
+    element its value plus its INCs."""
+    rows = e.elem_val[order]
+    vkind = e.vkind[rows]
+    t = e.tables
+    if not np.count_nonzero(vkind != VK_STR):
+        return "".join(t.chars[e.value[rows]].tolist())
+    vals = decode_value_rows(
+        vkind, e.value[rows], t.strings, t.floats, t.bigints
+    )
+    counters = np.nonzero(e.dt[rows] == 1)[0]
+    for j, total in zip(
+        counters.tolist(), e.inc_total[rows[counters]].tolist()
+    ):
+        vals[j] = (vals[j] or 0) + total  # fold accumulated INCs
+    return "".join(map(str, vals))
+
+
 class ServeTier:
     """One per RepoBackend (HM_SERVE=1, the default)."""
 
@@ -202,6 +242,9 @@ class ServeTier:
                 # its numpy twin (a CPU process under the loader's gate)
                 "install_groups", "install_device_docs",
                 "install_host_kernel_docs",
+                # the text joins of the flushes: reads joined, and the
+                # characters (element rows) they held
+                "text_reads", "text_rows",
             )
         }
         for k in (
@@ -358,19 +401,22 @@ class ServeTier:
             by_doc.setdefault(r.doc_id, []).append(r)
         ready: List[ReadRequest] = []
         cold: List = []  # (doc, clock, reqs) needing an install
-        for doc_id, rs in by_doc.items():
-            doc = self._back.docs.get(doc_id)
-            if doc is None or not doc._announced:
-                for r in rs:
-                    self._finish_raw(r, None)
-                continue
-            clock = doc.clock
-            entry = self._cache.get_fresh(doc_id, clock)
-            if entry is None:
-                cold.append((doc, clock, rs))
-                continue
-            self._m["hits"].add(len(rs))
-            self._attach(entry, rs, ready)
+        with telemetry.span(
+            "serve.batch.attach", "serve", docs=len(by_doc)
+        ):
+            for doc_id, rs in by_doc.items():
+                doc = self._back.docs.get(doc_id)
+                if doc is None or not doc._announced:
+                    for r in rs:
+                        self._finish_raw(r, None)
+                    continue
+                clock = doc.clock
+                entry = self._cache.get_fresh(doc_id, clock)
+                if entry is None:
+                    cold.append((doc, clock, rs))
+                    continue
+                self._m["hits"].add(len(rs))
+                self._attach(entry, rs, ready)
         # warm requests dispatch BEFORE any cold doc's install runs:
         # a hot read's latency must not absorb a cold neighbor's
         # pack+kernel (the install cost belongs to the cold reader)
@@ -607,36 +653,46 @@ class ServeTier:
 
     def _dispatch_orders(self, kernels, rs: List[ReadRequest]) -> None:
         """One seq_order dispatch per bucket serves int path steps,
-        final index lookups, and text joins together."""
+        final index lookups, and text joins together. The group's host
+        half (`serve.decode`) runs to its end before any of its
+        answers goes out, so the span holds no reader's callback."""
         for group in self._by_bucket(rs):
             order, count = kernels.seq_order(
                 [r.entry for r in group], [r.obj_row for r in group]
             )
             self._m["dispatches"].add(1)
-            for i, r in enumerate(group):
-                e = r.entry
-                n = int(count[i])
-                if not r.steps and r.query.get("kind") == "text":
-                    chars = [
-                        str(self._row_value(e, int(e.elem_val[row])))
-                        for row in order[i][:n]
-                    ]
-                    self._finish(r, "".join(chars))
-                    continue
-                if r.steps:  # int path step: descend through it
-                    idx, descend = r.steps.pop(0), True
-                else:  # final "index" query on the resolved sequence
-                    idx, descend = r.query.get("index"), False
-                if not isinstance(idx, int) or not 0 <= idx < n:
-                    self._finish(r, None)
-                    continue
-                w = int(e.elem_val[int(order[i][idx])])
-                if not descend:
-                    self._finish(r, self._row_leaf(e, w))
-                elif e.obj_type(w) is not None:
-                    r.obj_row = w
-                else:
-                    self._finish(r, None)  # scalar mid-path
+            answers: List = []  # (request, value) this group finished
+            texts = rows = 0
+            with telemetry.span(
+                "serve.decode", "serve", reads=len(group)
+            ) as sp:
+                for i, r in enumerate(group):
+                    e = r.entry
+                    n = int(count[i])
+                    if not r.steps and r.query.get("kind") == "text":
+                        answers.append((r, _join_text(e, order[i, :n])))
+                        texts += 1
+                        rows += n
+                        continue
+                    if r.steps:  # int path step: descend through it
+                        idx, descend = r.steps.pop(0), True
+                    else:  # final "index" query on the resolved sequence
+                        idx, descend = r.query.get("index"), False
+                    if not isinstance(idx, int) or not 0 <= idx < n:
+                        answers.append((r, None))
+                        continue
+                    w = int(e.elem_val[int(order[i][idx])])
+                    if not descend:
+                        answers.append((r, self._row_leaf(e, w)))
+                    elif e.obj_type(w) is not None:
+                        r.obj_row = w
+                    else:
+                        answers.append((r, None))  # scalar mid-path
+                sp.note(rows=rows)
+            self._m["text_reads"].add(texts)
+            self._m["text_rows"].add(rows)
+            for r, value in answers:
+                self._finish(r, value)
 
     def _dispatch_counts(self, kernels, rs: List[ReadRequest]) -> None:
         for group in self._by_bucket(rs):

@@ -1,0 +1,374 @@
+"""The read tier's text join as array work (ISSUE 33): `_join_text`
+gives, for every input, the string the per-row decode gave (the oracle
+below: one `_row_value` call a character, as `_dispatch_orders` had
+it); a flush says what it is made of (`serve.batch.attach`,
+`serve.dispatch`, `serve.decode`, the counters `serve.text_reads` /
+`serve.text_rows`); and the three metric files of the issue read a
+number from a rehearsal-size traced run of `reads.resident`. CPU, small
+sizes; counts and answers only, no clock is asserted.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+from unittest import mock
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from hypermerge_tpu import telemetry  # noqa: E402
+from hypermerge_tpu.models import Counter, Text  # noqa: E402
+from hypermerge_tpu.ops import columnar as col  # noqa: E402
+from hypermerge_tpu.repo import Repo  # noqa: E402
+from hypermerge_tpu.serve import host_read, kernels, resident  # noqa: E402
+from hypermerge_tpu.serve.batcher import ReadRequest  # noqa: E402
+from hypermerge_tpu.serve.tier import ServeTier, _join_text  # noqa: E402
+from hypermerge_tpu.telemetry import trace as ttrace  # noqa: E402
+from hypermerge_tpu.utils.ids import validate_doc_url  # noqa: E402
+
+BIG = 2**70 + 3
+
+
+def oracle(e, order) -> str:
+    """The join as the tier had it before: one decode call a row."""
+    return "".join(
+        str(ServeTier._row_value(None, e, int(e.elem_val[row])))
+        for row in order
+    )
+
+
+def resolve(e, path):
+    """(order, n) of the sequence at `path` in the resident entry `e`,
+    through the query programs themselves."""
+    obj = -1
+    for step in path:
+        if isinstance(step, str):
+            rows, found = kernels.map_lookup([e], [obj], [e.key_index[step]])
+            assert found[0]
+            obj = int(rows[0])
+        else:
+            order, count = kernels.seq_order([e], [obj])
+            assert 0 <= step < int(count[0])
+            obj = int(e.elem_val[int(order[0][step])])
+    order, count = kernels.seq_order([e], [obj])
+    return order[0], int(count[0])
+
+
+@pytest.fixture(scope="module")
+def repo():
+    r = Repo(memory=True)
+    yield r
+    r.close()
+
+
+def _every_kind(d):
+    d["t"] = Text("ab")
+    d["t"].insert(1, [None, 7, True, False, 2.5, "x", "several", BIG, -3])
+
+
+def _deleted(d):
+    d["t"] = Text("hello world")
+    d["t"].delete(2, 4)
+    d["t"].insert(3, "XY")
+
+
+def _nested(d):
+    d["deep"] = {"x": {"t": Text("nested text")}}
+
+
+def _in_a_list(d):
+    d["l"] = [Text("first"), 5, Text("second one")]
+
+
+def _plain(d):
+    d["t"] = Text("the quick brown fox jumps over the lazy dog " * 20)
+
+
+TEXTS = {
+    "every-value-kind": (_every_kind, ["t"],
+                         "aNone7TrueFalse2.5xseveral%d-3b" % BIG),
+    "deleted-elements": (_deleted, ["t"], "hewXYorld"),
+    "empty": (lambda d: d.__setitem__("t", Text("")), ["t"], ""),
+    "nested-by-path": (_nested, ["deep", "x", "t"], "nested text"),
+    "through-an-int-step": (_in_a_list, ["l", 2], "second one"),
+    "strings-only": (_plain, ["t"],
+                     "the quick brown fox jumps over the lazy dog " * 20),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TEXTS))
+def test_join_equals_the_per_row_decode(repo, case):
+    """The tier's answer, the array join on its entry, the per-row
+    oracle on the same entry, the host twin and the hand-worked string
+    are one string."""
+    edit, path, want = TEXTS[case]
+    url = repo.create()
+    repo.change(url, edit)
+    q = {"kind": "text", "path": path}
+    assert repo.read(url, q) == want
+    doc = repo.back.docs[validate_doc_url(url)]
+    assert host_read(doc, q)["value"] == want
+    e = repo.back.serve._cache.get_fresh(doc.id, doc.clock)
+    order, n = resolve(e, path)
+    assert _join_text(e, order[:n]) == oracle(e, order[:n]) == want
+
+
+LIST = [1, "x", False, None, 2.5, BIG, "several", Counter(4)]
+
+
+@pytest.fixture(scope="module")
+def list_url(repo):
+    url = repo.create()
+    repo.change(url, lambda d: d.__setitem__("l", list(LIST)))
+    return url
+
+
+@pytest.mark.parametrize("i", range(-1, len(LIST) + 1))
+def test_list_read_through_index(repo, list_url, i):
+    """`index` keeps its single-row decode (`_row_leaf`): every kind,
+    and both ends out of range, against the host twin."""
+    q = {"kind": "index", "path": ["l"], "index": i}
+    want = (
+        None if not 0 <= i < len(LIST)
+        else 4 if i == len(LIST) - 1 else LIST[i]
+    )
+    got = repo.read(list_url, q)
+    assert got == want and type(got) is type(want)
+    doc = repo.back.docs[validate_doc_url(list_url)]
+    assert host_read(doc, q)["value"] == want
+
+
+class _Batch:
+    strings = ["a", "bc", "", "\x00z"]
+    floats = [0.5, -1e300]
+    bigints = [BIG, -BIG]
+
+
+_Tables = resident._Tables(_Batch)
+
+
+class _Entry:
+    """A resident entry's host half, made by hand: rows the frontend's
+    proxies cannot write (a counter inside a text, with INCs)."""
+
+    tables = _Tables
+
+    def __init__(self, rows, elem_val=None):
+        # rows: (vkind, code, dt, inc_total)
+        cols = np.asarray(rows, np.int64).reshape(-1, 4)
+        self.vkind = cols[:, 0].astype(np.int8)
+        self.value = cols[:, 1].astype(np.int32)
+        self.dt = cols[:, 2].astype(np.int8)
+        self.inc_total = cols[:, 3].astype(np.int32)
+        self.elem_val = np.asarray(
+            range(len(cols)) if elem_val is None else elem_val, np.int32
+        )
+
+
+S, I, N, B, F, G = (col.VK_STR, col.VK_INT, col.VK_NONE, col.VK_BOOL,
+                    col.VK_FLOAT, col.VK_BIGINT)
+HAND = {
+    "counter-with-incs": (
+        [(S, 0, 0, 0), (I, 10, 1, 32), (S, 1, 0, 0)], None, "a42bc"),
+    "counter-of-none-and-negative-incs": (
+        [(N, 0, 1, -5), (I, 3, 1, 0), (I, -3, 0, 9)], None, "-53-3"),
+    "all-one-table-kind-floats": (
+        [(F, 1, 0, 0), (F, 0, 0, 0)], None, "-1e+3000.5"),
+    "all-bigints": ([(G, 1, 0, 0), (G, 0, 0, 0)], None, f"-{BIG}{BIG}"),
+    "bools-nones-ints": (
+        [(B, 1, 0, 0), (B, 0, 0, 0), (N, 0, 0, 0), (I, 0, 0, 0)], None,
+        "TrueFalseNone0"),
+    "strings-with-empty-and-nul": (
+        [(S, 3, 0, 0), (S, 2, 0, 0), (S, 1, 0, 0)], None, "\x00zbc"),
+    "one-string": ([(S, 1, 0, 0)], None, "bc"),
+    # element 0's winning value is row 2 (an element SET over it)
+    "elem-val-indirection": (
+        [(S, 0, 0, 0), (S, 1, 0, 0), (I, 9, 0, 0)], [2, 1, 2], "9bc9"),
+    "no-rows": ([], None, ""),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HAND))
+def test_join_on_hand_made_rows(case):
+    rows, elem_val, want = HAND[case]
+    e = _Entry(rows, elem_val)
+    order = np.arange(len(e.elem_val), dtype=np.int32)
+    assert _join_text(e, order) == oracle(e, order) == want
+    back = order[::-1]
+    assert _join_text(e, back) == oracle(e, back)
+
+
+@pytest.mark.parametrize("case", sorted(HAND))
+def test_decode_value_rows_equals_decode_value(case):
+    """The lifted vector decoder against the scalar one, value for
+    value and type for type (True is not 1)."""
+    e = _Entry(HAND[case][0])
+    t = e.tables
+    got = col.decode_value_rows(
+        e.vkind, e.value, t.strings, t.floats, t.bigints
+    )
+    want = [
+        col.decode_value(int(k), int(v), 0, t)
+        for k, v in zip(e.vkind, e.value)
+    ]
+    assert got == want
+    assert [type(x) for x in got] == [type(x) for x in want]
+
+
+# -- what a flush is made of -------------------------------------------------
+
+
+def _flush(repo, reads):
+    """ONE batch flush of `reads` = [(url, query)]; the answers."""
+    got = {}
+    reqs = []
+    for i, (u, q) in enumerate(reads):
+        req = ReadRequest(
+            validate_doc_url(u), dict(q),
+            lambda p, i=i: got.__setitem__(i, p["value"]),
+        )
+        req.t0 = time.perf_counter()
+        reqs.append(req)
+    repo.back.serve._flush(reqs)
+    return [got[i] for i in range(len(reads))]
+
+
+def test_a_warm_flush_has_attach_dispatch_and_decode_spans(repo):
+    """Two text reads of 5 and 12 characters, one index read and one
+    lookup, nothing cold: under the flush's `serve.batch` one
+    `serve.batch.attach{docs=2}`, a `serve.dispatch{kind,B,N}` (with
+    its `serve.dispatch.fetch`) for the lookup round and one for the
+    seq_order group, one `serve.decode{reads=3,rows=17}`; and the
+    counters moved by 2 reads and 17 rows."""
+    a = repo.create()
+    repo.change(a, lambda d: d.__setitem__("t", Text("hello")))
+    b = repo.create()
+    repo.change(b, lambda d: d.__setitem__("t", Text("twelve chars")))
+    text = {"kind": "text", "path": ["t"]}
+    reads = [
+        (a, text), (b, text),
+        (b, {"kind": "index", "path": ["t"], "index": 3}),
+        (a, {"kind": "lookup", "path": ["t"]}),
+    ]
+    _flush(repo, reads)  # installs both docs
+    was_on = ttrace.enabled()
+    ttrace.reset()
+    ttrace.enable()
+    try:
+        before = telemetry.snapshot()
+        answers = _flush(repo, reads)
+        after = telemetry.snapshot()
+        events = [e for e in telemetry.trace_events() if e[0] == "X"]
+    finally:
+        if not was_on:
+            ttrace.disable()
+        ttrace.reset()
+    assert answers == ["hello", "twelve chars", "l", {"_type": "text"}]
+
+    def moved(name):
+        return after["serve." + name] - before.get("serve." + name, 0)
+
+    assert (moved("text_reads"), moved("text_rows")) == (2, 17)
+    assert (moved("batches"), moved("installs")) == (1, 0)
+    (batch,) = [e for e in events if e[1] == "serve.batch"]
+    assert batch[6] == {"reads": 4, "cold": 0}
+    inside = [
+        e for e in events
+        if e is not batch and e[5] == batch[5] and e[3] >= batch[3]
+        and e[3] + e[4] <= batch[3] + batch[4]
+    ]
+    by_name = {}
+    for e in inside:
+        by_name.setdefault(e[1], []).append(e[6])
+    assert by_name["serve.batch.attach"] == [{"docs": 2}]
+    # every read's first step is a string key: one map_lookup round,
+    # then the three sequence reads share one seq_order dispatch
+    assert by_name["serve.dispatch"] == [
+        {"kind": "map_lookup", "B": 4, "N": 64},
+        {"kind": "seq_order", "B": 4, "N": 64},
+    ]
+    assert len(by_name["serve.dispatch.fetch"]) == 2
+    assert by_name["serve.decode"] == [{"reads": 3, "rows": 17}]
+    assert moved("dispatches") == 2
+
+
+def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
+    """`reads.resident` at its rehearsal size, traced, through the
+    harness's own cell, driver and readers (`benchmark/selftest/run.py`
+    is not edited): `serve.decode_s`, `serve.dispatch_s` and
+    `serve.text_rows_per_read` each find a number, the last the
+    counters' ratio; laid over a program without the spans and the
+    counters (the parent) the readers find nothing and do not raise."""
+    from benchmark import harness
+    from benchmark.readers import span_tree
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    args = argparse.Namespace(
+        workload="reads.resident", seed=2147483999, seconds=2.0, trace=1,
+        rehearse=True, control=False, mix=None,
+    )
+    names = ("serve.decode_s", "serve.dispatch_s", "serve.text_rows_per_read")
+    listed = {m["name"]: m for m in bench["per_layer"]}
+    with mock.patch.dict(os.environ):
+        cell = harness.Cell(args, bench, time.perf_counter())
+        cell.work = str(tmp_path / "run")
+        cell.tracer = harness.Tracer(True, str(tmp_path / "trace"))
+        harness.apply_env(cell)
+        driver = harness.load_module("drivers", cell.mix["driver"])
+        os.makedirs(cell.work)
+        early = driver.before_jax(cell)
+        cell.cache_watch = harness.CacheWatch()
+        state = driver.setup(cell, early)
+        try:
+            before = cell.counters()
+            win = driver.window(cell, state, float(args.seconds))
+            cell.tracer.close()
+            after = cell.counters()
+        finally:
+            cell.tracer.close()
+            driver.teardown(cell, state)
+    assert win.failed == 0 and win.attempted > 0
+    assert cell.tracer.path is not None
+    monkeypatch.setattr(span_tree, "newest_trace", lambda: cell.tracer.path)
+    obs = dict(win.obs, trace={"busy_s": 0.0}, counters_before=before,
+               counters_after=after)
+    cell.bench = dict(bench, per_layer=[listed[n] for n in names])
+    got = harness.layer_metrics(cell, obs)
+    assert set(got) == set(names)
+    assert got["serve.decode_s"]["value"] > 0.0
+    assert got["serve.dispatch_s"]["value"] > 0.0
+    reads = after["serve.text_reads"] - before["serve.text_reads"]
+    rows = after["serve.text_rows"] - before["serve.text_rows"]
+    assert reads > 0
+    assert got["serve.text_rows_per_read"]["value"] == rows / reads
+    spans, _busy = span_tree.load(cell.tracer.path)
+    tags = {
+        s.name: set(s.args) for s in spans if s.name.startswith("serve.")
+    }
+    assert tags["serve.dispatch"] >= {"kind", "B", "N"}
+    assert tags["serve.decode"] >= {"reads", "rows"}
+    assert tags["serve.batch.attach"] >= {"docs"}
+    # every entry and its file agree, and list the cell alone
+    for n in names:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               n + ".json")) as fh:
+            spec = json.load(fh)
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert listed[n][key] == spec[key], (n, key)
+        assert listed[n]["workloads"] == spec["cells"] == ["reads.resident"]
+    # the parent: its trace holds program spans but none of these, its
+    # counters lack the two
+    older = {k: v for k, v in after.items() if "text_" not in k}
+    obs_parent = dict(obs, counters_before=older, counters_after=older)
+    real_load = span_tree.load
+    monkeypatch.setattr(span_tree, "load", lambda p: (
+        [s for s in real_load(p)[0]
+         if s.name in ("serve.batch", "serve.read")], []))
+    assert harness.layer_metrics(cell, obs_parent) == {}
