@@ -82,7 +82,9 @@ def pack_slab(specs, n_docs: Optional[int] = None) -> columnar.ColumnarBatch:
     if n_docs is None:
         n_docs = columnar.round_up_pow2(len(specs))
     batch = columnar.pack_docs_columns(specs, n_docs=n_docs)
-    return columnar.widen_preds(batch, batch.n_rows // PRED_ROWS)
+    n_pred = batch.n_rows // PRED_ROWS
+    with telemetry.span("pipeline.pack.widen", "pipeline", P=n_pred):
+        return columnar.widen_preds(batch, n_pred)
 
 
 def device_min_cells() -> int:

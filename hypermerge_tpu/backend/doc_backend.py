@@ -13,11 +13,12 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
+from .. import telemetry
 from ..analysis.lockdep import make_rlock
 from ..crdt import clock as clockmod
 from ..crdt.change import Change, ChangeRequest
 from ..crdt.opset import OpSet
-from ..utils.debug import bench, log
+from ..utils.debug import log
 from ..utils.queue import Queue
 from . import emission
 from .emission import EmissionDomain
@@ -109,8 +110,7 @@ class DocBackend:
         with self._lock:
             if self.opset is None:
                 self.opset = OpSet()
-            with bench(f"doc:init"):
-                self.opset.apply_changes(changes)
+            self.opset.apply_changes(changes)
             if actor_id is not None:
                 self.actor_id = actor_id
         self._check_ready()
@@ -160,15 +160,14 @@ class DocBackend:
             self._snapshot_cache = None
             self._replay_cache = None
             if loader is not None:
-                with bench("doc:lazyReplay"):
-                    changes = loader()
-                    if base_clock is not None:
-                        changes = [
-                            c
-                            for c in changes
-                            if c.seq <= base_clock.get(c.actor, 0)
-                        ]
-                    self.opset.apply_changes(changes)
+                changes = loader()
+                if base_clock is not None:
+                    changes = [
+                        c
+                        for c in changes
+                        if c.seq <= base_clock.get(c.actor, 0)
+                    ]
+                self.opset.apply_changes(changes)
 
     def demote_from_live(
         self,
@@ -246,14 +245,13 @@ class DocBackend:
                     return cached[1]
                 sub = OpSet()
                 if loader is not None:
-                    with bench("doc:historyReplay"):
-                        sub.apply_changes(
-                            [
-                                c
-                                for c in loader()
-                                if c.seq <= base_clock.get(c.actor, 0)
-                            ]
-                        )
+                    sub.apply_changes(
+                        [
+                            c
+                            for c in loader()
+                            if c.seq <= base_clock.get(c.actor, 0)
+                        ]
+                    )
                 self._replay_cache = (base_clock, sub)
                 return sub
             if self._lazy_loader is None:
@@ -357,7 +355,9 @@ class DocBackend:
             with self._lock:
                 if self.opset is None:
                     self._ensure_opset()
-                with bench("doc:applyLocalChange"):
+                # the host twin's apply (a doc the live engine does not
+                # hold): an operator's trace wants it beside `live.tick`
+                with telemetry.span("live.host.apply_local", "live"):
                     try:
                         change, patch = self.opset.apply_local_request(req)
                     except ValueError as e:
@@ -385,7 +385,9 @@ class DocBackend:
             with self._lock:
                 if self.opset is None:
                     self._ensure_opset()
-                with bench("doc:applyRemoteChanges"):
+                with telemetry.span(
+                    "live.host.apply_remote", "live", changes=len(changes)
+                ):
                     patch = self.opset.apply_changes(changes)
             if self._announced and not patch.is_empty:
                 self._notify(

@@ -16,7 +16,7 @@ from .. import telemetry
 from ..analysis.lockdep import make_rlock
 from ..crdt.frontend_state import FrontendDoc
 from ..crdt.patch import Patch
-from ..utils.debug import bench, log
+from ..utils.debug import log
 from ..utils.ids import to_doc_url
 from .handle import Handle
 
@@ -132,7 +132,7 @@ class DocFrontend:
                     # fn would read is stale — run it when the echo lands
                     self._change_queue.append((fn, message))
                     return
-                with bench("front:change"), telemetry.span(
+                with telemetry.span(
                     "frontend.change.resolve", "frontend"
                 ) as sp:
                     request, preview = self.front.change(
@@ -171,8 +171,7 @@ class DocFrontend:
                 # backend's state reaches us through Patch echoes).
                 return
             if patch_json is not None:
-                with bench("front:patch"):
-                    self.front.apply_patch(Patch.from_json(patch_json))
+                self.front.apply_patch(Patch.from_json(patch_json))
             if actor_id is not None:
                 self.actor_id = actor_id
                 self.seq = self.front.clock.get(actor_id, 0) + 1
@@ -222,8 +221,7 @@ class DocFrontend:
                 # the baseline and silently poison every later patch.
                 return
             patch = Patch.from_json(patch_json)
-            with bench("front:patch"):
-                self.front.apply_patch(patch)
+            self.front.apply_patch(patch)
             self.history = history
             if (
                 self._inflight is not None

@@ -499,77 +499,79 @@ def _native_pack_prefix(
     filled in place (real rows AND pad cells — no np.full prepass, no [M]
     intermediates). Returns {} when a plane can't be described to the
     native ABI (caller falls back to the numpy twin)."""
-    F = len(fcs)
-    srcs = np.empty((F, len(_PACK_SRC_PLANES)), np.int64)
-    sdts = np.empty((F, len(_PACK_SRC_PLANES)), np.uint8)
-    keep_alive = []  # converted planes must outlive the call
-    src_idx = _pack_src_idx()
-    for i, fc in enumerate(fcs):
-        meta = fc.plane_meta
-        if meta is not None:
-            # every plane is a slice of one checkpoint buffer: all 12
-            # pointers derive from the base address in two gathers
-            base_addr, offs, dts = meta[0], meta[1], meta[2]
-            srcs[i] = base_addr + offs[src_idx]
-            sdts[i] = dts[src_idx]
-            keep_alive.append(meta)
-            continue
-        planes = fc.planes
-        for j, name in enumerate(_PACK_SRC_PLANES):
-            p = planes[name]
-            code = _DT_CODE.get(p.dtype)
-            if code is None or not p.flags["C_CONTIGUOUS"]:
-                p = np.ascontiguousarray(p, np.int32)
-                keep_alive.append(p)
-                code = 2
-            srcs[i, j] = p.__array_interface__["data"][0]
-            sdts[i, j] = code
+    with _stage("prefix.tables"):
+        F = len(fcs)
+        srcs = np.empty((F, len(_PACK_SRC_PLANES)), np.int64)
+        sdts = np.empty((F, len(_PACK_SRC_PLANES)), np.uint8)
+        keep_alive = []  # converted planes must outlive the call
+        src_idx = _pack_src_idx()
+        for i, fc in enumerate(fcs):
+            meta = fc.plane_meta
+            if meta is not None:
+                # every plane is a slice of one checkpoint buffer: all 12
+                # pointers derive from the base address in two gathers
+                base_addr, offs, dts = meta[0], meta[1], meta[2]
+                srcs[i] = base_addr + offs[src_idx]
+                sdts[i] = dts[src_idx]
+                keep_alive.append(meta)
+                continue
+            planes = fc.planes
+            for j, name in enumerate(_PACK_SRC_PLANES):
+                p = planes[name]
+                code = _DT_CODE.get(p.dtype)
+                if code is None or not p.flags["C_CONTIGUOUS"]:
+                    p = np.ascontiguousarray(p, np.int32)
+                    keep_alive.append(p)
+                    code = 2
+                srcs[i, j] = p.__array_interface__["data"][0]
+                sdts[i, j] = code
 
-    # a corrupt sidecar whose row_ends overrun its planes must not reach
-    # the C loops (the numpy twin fails loudly on the length mismatch)
-    feed_rows = np.asarray([fc.n_rows for fc in fcs], np.int64)
-    if np.any(ends > feed_rows[fc_idx_a]):
-        return {}
+        # a corrupt sidecar whose row_ends overrun its planes must not reach
+        # the C loops (the numpy twin fails loudly on the length mismatch)
+        feed_rows = np.asarray([fc.n_rows for fc in fcs], np.int64)
+        if np.any(ends > feed_rows[fc_idx_a]):
+            return {}
 
-    klut, koffs = flat_lut("k")
-    slut, soffs = flat_lut("s")
-    flut, foffs = flat_lut("f")
-    blut, boffs = flat_lut("b")
-    lut_lens = np.asarray(
-        [len(klut), len(slut), len(flut), len(blut)], np.int64
-    )
-    writer_g = np.ascontiguousarray(writer_g, np.int64)
-    ends = np.ascontiguousarray(ends, np.int64)
-    fc_idx_a = np.ascontiguousarray(fc_idx_a, np.int64)
+        klut, koffs = flat_lut("k")
+        slut, soffs = flat_lut("s")
+        flut, foffs = flat_lut("f")
+        blut, boffs = flat_lut("b")
+        lut_lens = np.asarray(
+            [len(klut), len(slut), len(flut), len(blut)], np.int64
+        )
+        writer_g = np.ascontiguousarray(writer_g, np.int64)
+        ends = np.ascontiguousarray(ends, np.int64)
+        fc_idx_a = np.ascontiguousarray(fc_idx_a, np.int64)
 
-    ptr = _ptr
-    mm = np.zeros(2, np.int64)
-    rc = lib.hm_pack_value_minmax(
-        D, ptr(fc_idx_a), ptr(ends), ptr(srcs), ptr(sdts),
-        ptr(slut), ptr(soffs), ptr(flut), ptr(foffs), ptr(blut),
-        ptr(boffs), ptr(lut_lens), ptr(mm),
-    )
-    if rc != 0:
-        return {}
-    dtypes = _pack_wire_dtypes(i16ok, row_dt, kdt, int(mm[0]), int(mm[1]))
+    with _stage("prefix.native", native=1):
+        ptr = _ptr
+        mm = np.zeros(2, np.int64)
+        rc = lib.hm_pack_value_minmax(
+            D, ptr(fc_idx_a), ptr(ends), ptr(srcs), ptr(sdts),
+            ptr(slut), ptr(soffs), ptr(flut), ptr(foffs), ptr(blut),
+            ptr(boffs), ptr(lut_lens), ptr(mm),
+        )
+        if rc != 0:
+            return {}
+        dtypes = _pack_wire_dtypes(i16ok, row_dt, kdt, int(mm[0]), int(mm[1]))
 
-    cols: Dict[str, np.ndarray] = {}
-    out_ptrs = np.empty(len(COLUMNS), np.int64)
-    out_dts = np.empty(len(COLUMNS), np.uint8)
-    for ci, name in enumerate(COLUMNS):
-        arr = np.empty(Dp * N, dtypes[name])
-        cols[name] = arr
-        out_ptrs[ci] = arr.__array_interface__["data"][0]
-        out_dts[ci] = _DT_CODE[arr.dtype]
-    rc = lib.hm_pack_prefix(
-        D, Dp, N, ptr(fc_idx_a), ptr(ends), ptr(srcs), ptr(sdts),
-        ptr(klut), ptr(koffs), ptr(slut), ptr(soffs), ptr(flut),
-        ptr(foffs), ptr(blut), ptr(boffs), ptr(lut_lens),
-        ptr(writer_g), ptr(out_ptrs), ptr(out_dts),
-    )
-    del keep_alive
-    if rc != 0:
-        return {}
+        cols: Dict[str, np.ndarray] = {}
+        out_ptrs = np.empty(len(COLUMNS), np.int64)
+        out_dts = np.empty(len(COLUMNS), np.uint8)
+        for ci, name in enumerate(COLUMNS):
+            arr = np.empty(Dp * N, dtypes[name])
+            cols[name] = arr
+            out_ptrs[ci] = arr.__array_interface__["data"][0]
+            out_dts[ci] = _DT_CODE[arr.dtype]
+        rc = lib.hm_pack_prefix(
+            D, Dp, N, ptr(fc_idx_a), ptr(ends), ptr(srcs), ptr(sdts),
+            ptr(klut), ptr(koffs), ptr(slut), ptr(soffs), ptr(flut),
+            ptr(foffs), ptr(blut), ptr(boffs), ptr(lut_lens),
+            ptr(writer_g), ptr(out_ptrs), ptr(out_dts),
+        )
+        del keep_alive
+        if rc != 0:
+            return {}
     return {
         name: cols[name].reshape(Dp, N) for name in COLUMNS
     }
@@ -608,67 +610,68 @@ def _pack_prefix_single(
     D = len(doc_specs)
     Dp = max(n_docs, D) if n_docs is not None else D
 
-    fcs: List[Any] = []
-    fc_idx: List[int] = []
-    fc_of: Dict[int, int] = {}
-    ends = np.zeros(D, np.int64)  # prefix row counts
-    for d, spec in enumerate(doc_specs):
-        fc, _s, e = spec[0]
-        i = fc_of.get(id(fc))
-        if i is None:
-            i = fc_of[id(fc)] = len(fcs)
-            fcs.append(fc)
-        fc_idx.append(i)
-        ends[d] = fc.window(0, e)[1]
+    with _stage("prefix.tables"):
+        fcs: List[Any] = []
+        fc_idx: List[int] = []
+        fc_of: Dict[int, int] = {}
+        ends = np.zeros(D, np.int64)  # prefix row counts
+        for d, spec in enumerate(doc_specs):
+            fc, _s, e = spec[0]
+            i = fc_of.get(id(fc))
+            if i is None:
+                i = fc_of[id(fc)] = len(fcs)
+                fcs.append(fc)
+            fc_idx.append(i)
+            ends[d] = fc.window(0, e)[1]
 
-    # -- global tables (same interning as the general path). Feeds
-    # instantiated from shared templates carry IDENTICAL local tables,
-    # so the per-item interning loop memoizes on the table tuple — the
-    # global id sequence is unchanged (a memo hit means every item was
-    # already interned, in the same order).
-    actor_int = _Interner()
-    key_int = _Interner()
-    str_int = _Interner()
-    float_int = _Interner()
-    big_int = _Interner()
-    luts = {"k": [], "s": [], "f": [], "b": []}
-    writers: List[int] = []
-    lut_memo: Dict[Any, np.ndarray] = {}
+        # -- global tables (same interning as the general path). Feeds
+        # instantiated from shared templates carry IDENTICAL local tables,
+        # so the per-item interning loop memoizes on the table tuple — the
+        # global id sequence is unchanged (a memo hit means every item was
+        # already interned, in the same order).
+        actor_int = _Interner()
+        key_int = _Interner()
+        str_int = _Interner()
+        float_int = _Interner()
+        big_int = _Interner()
+        luts = {"k": [], "s": [], "f": [], "b": []}
+        writers: List[int] = []
+        lut_memo: Dict[Any, np.ndarray] = {}
 
-    def lut_of(kind, interner, items):
-        key = (kind, tuple(items))
-        got = lut_memo.get(key)
-        if got is None:
-            got = np.asarray([interner(x) for x in items], np.int64)
-            lut_memo[key] = got
-        return got
+        def lut_of(kind, interner, items):
+            key = (kind, tuple(items))
+            got = lut_memo.get(key)
+            if got is None:
+                got = np.asarray([interner(x) for x in items], np.int64)
+                lut_memo[key] = got
+            return got
 
-    writer_memo: Dict[Any, int] = {}
-    for fc in fcs:
-        akey = tuple(fc.actors)
-        w = writer_memo.get(akey)
-        if w is None:
-            for x in fc.actors:
-                actor_int(x)
-            w = actor_int(fc.actors[0]) if fc.actors else 0
-            writer_memo[akey] = w
-        writers.append(w)
-        luts["k"].append(lut_of("k", key_int, fc.keys))
-        luts["s"].append(lut_of("s", str_int, fc.strings))
-        luts["f"].append(lut_of("f", float_int, fc.floats))
-        luts["b"].append(lut_of("b", big_int, fc.bigints))
-    sorted_actors = sorted(actor_int.items)
-    rank_of = {name: i for i, name in enumerate(sorted_actors)}
-    arank = np.asarray(
-        [rank_of[a] for a in actor_int.items], np.int64
-    )
-    writer_g = (
-        arank[np.asarray(writers, np.int64)]
-        if writers
-        else np.zeros(0, np.int64)
-    )
+        writer_memo: Dict[Any, int] = {}
+        for fc in fcs:
+            akey = tuple(fc.actors)
+            w = writer_memo.get(akey)
+            if w is None:
+                for x in fc.actors:
+                    actor_int(x)
+                w = actor_int(fc.actors[0]) if fc.actors else 0
+                writer_memo[akey] = w
+            writers.append(w)
+            luts["k"].append(lut_of("k", key_int, fc.keys))
+            luts["s"].append(lut_of("s", str_int, fc.strings))
+            luts["f"].append(lut_of("f", float_int, fc.floats))
+            luts["b"].append(lut_of("b", big_int, fc.bigints))
+        sorted_actors = sorted(actor_int.items)
+        rank_of = {name: i for i, name in enumerate(sorted_actors)}
+        arank = np.asarray(
+            [rank_of[a] for a in actor_int.items], np.int64
+        )
+        writer_g = (
+            arank[np.asarray(writers, np.int64)]
+            if writers
+            else np.zeros(0, np.int64)
+        )
 
-    M = int(ends.sum())
+        M = int(ends.sum())
     if M == 0:
         N = n_rows if n_rows is not None else 1
         P = n_pred if n_pred is not None else 1
@@ -680,49 +683,53 @@ def _pack_prefix_single(
 
     from ..storage.colcache import OBJ_ROOT, REF_HEAD, REF_NONE
 
-    # -- preds ----------------------------------------------------------
-    pr_docs_l: List[int] = []
-    pr_cnt_l: List[int] = []
-    pr_rows: List[np.ndarray] = []
-    for d in range(D):
-        fc = fcs[fc_idx[d]]
-        n_pr = len(fc.preds)
-        if not n_pr:
-            continue
-        e = int(ends[d])
-        phi = (
-            n_pr  # whole-prefix window: every pred src is inside it
-            if e >= fc.n_rows
-            else int(np.searchsorted(fc.preds[:, 0], e, side="left"))
-        )
-        if phi:
-            pr_rows.append(fc.preds[:phi])
-            pr_docs_l.append(d)
-            pr_cnt_l.append(phi)
-    if pr_rows:
-        PR = np.concatenate(pr_rows, axis=0)
-        pr_doc = np.repeat(
-            np.asarray(pr_docs_l, np.int64), np.asarray(pr_cnt_l, np.int64)
-        )
-        p_src_row = PR[:, 0].astype(np.int64)  # feed row == doc row
-        p_tgt_row = PR[:, 1].astype(np.int64) - 1  # dense ctr -> row
-        pred_counts = np.bincount(pr_doc, minlength=Dp).astype(np.int64)
-        pred_starts = np.zeros(Dp + 1, np.int64)
-        np.cumsum(pred_counts, out=pred_starts[1:])
-        p_pos = np.arange(len(pr_doc), dtype=np.int64) - pred_starts[pr_doc]
-    else:
-        pred_counts = np.zeros(Dp, np.int64)
-        p_src_row = p_tgt_row = p_pos = pr_doc = np.zeros(0, np.int64)
+    with _stage("prefix.preds"):
+        # -- preds ------------------------------------------------------
+        pr_docs_l: List[int] = []
+        pr_cnt_l: List[int] = []
+        pr_rows: List[np.ndarray] = []
+        for d in range(D):
+            fc = fcs[fc_idx[d]]
+            n_pr = len(fc.preds)
+            if not n_pr:
+                continue
+            e = int(ends[d])
+            phi = (
+                n_pr  # whole-prefix window: every pred src is inside it
+                if e >= fc.n_rows
+                else int(np.searchsorted(fc.preds[:, 0], e, side="left"))
+            )
+            if phi:
+                pr_rows.append(fc.preds[:phi])
+                pr_docs_l.append(d)
+                pr_cnt_l.append(phi)
+        if pr_rows:
+            PR = np.concatenate(pr_rows, axis=0)
+            pr_doc = np.repeat(
+                np.asarray(pr_docs_l, np.int64), np.asarray(pr_cnt_l, np.int64)
+            )
+            p_src_row = PR[:, 0].astype(np.int64)  # feed row == doc row
+            p_tgt_row = PR[:, 1].astype(np.int64) - 1  # dense ctr -> row
+            pred_counts = np.bincount(pr_doc, minlength=Dp).astype(np.int64)
+            pred_starts = np.zeros(Dp + 1, np.int64)
+            np.cumsum(pred_counts, out=pred_starts[1:])
+            p_pos = (
+                np.arange(len(pr_doc), dtype=np.int64) - pred_starts[pr_doc]
+            )
+        else:
+            pred_counts = np.zeros(Dp, np.int64)
+            p_src_row = p_tgt_row = p_pos = pr_doc = np.zeros(0, np.int64)
 
-    # -- bucket shapes ---------------------------------------------------
-    max_ops = int(ends.max(initial=0))
-    max_preds = int(pred_counts.max(initial=0))
-    N = n_rows if n_rows is not None else _round_up(max(max_ops, 1))
-    P = n_pred if n_pred is not None else _round_up(max(max_preds, 1))
-    if max_ops > N or max_preds > P:
-        raise ValueError(
-            f"doc exceeds bucket: ops {max_ops}>{N} or preds {max_preds}>{P}"
-        )
+        # -- bucket shapes ----------------------------------------------
+        max_ops = int(ends.max(initial=0))
+        max_preds = int(pred_counts.max(initial=0))
+        N = n_rows if n_rows is not None else _round_up(max(max_ops, 1))
+        P = n_pred if n_pred is not None else _round_up(max(max_preds, 1))
+        if max_ops > N or max_preds > P:
+            raise ValueError(
+                f"doc exceeds bucket: ops {max_ops}>{N} "
+                f"or preds {max_preds}>{P}"
+            )
 
     # wire dtypes are a function of the bucket + value ranges so native
     # and numpy twins allocate identically (host_args passes the planes
@@ -754,113 +761,117 @@ def _pack_prefix_single(
         )
 
     if not cols:  # numpy twin (fallback, and the fuzz reference)
-        doc_col = np.repeat(np.arange(D, dtype=np.int64), ends)
-        doc_starts = np.zeros(D + 1, np.int64)
-        np.cumsum(ends, out=doc_starts[1:])
-        pos = (
-            np.arange(M, dtype=np.int64) - doc_starts[doc_col]
-        ).astype(np.int32)
-        flat_idx = doc_col * N + pos
+        with _stage("prefix.native", native=0):
+            doc_col = np.repeat(np.arange(D, dtype=np.int64), ends)
+            doc_starts = np.zeros(D + 1, np.int64)
+            np.cumsum(ends, out=doc_starts[1:])
+            pos = (
+                np.arange(M, dtype=np.int64) - doc_starts[doc_col]
+            ).astype(np.int32)
+            flat_idx = doc_col * N + pos
 
-        # column sources: v3 plane-backed feeds serve each column as a
-        # contiguous narrow array (concat promotes mixed widths); v2
-        # feeds fall back to strided slices of the dense row matrix.
-        if use_planes:
-            def col(name):
-                return np.concatenate(
+            # column sources: v3 plane-backed feeds serve each column as a
+            # contiguous narrow array (concat promotes mixed widths); v2
+            # feeds fall back to strided slices of the dense row matrix.
+            if use_planes:
+                def col(name):
+                    return np.concatenate(
+                        [
+                            fcs[fc_idx[d]].plane(name)[: ends[d]]
+                            for d in range(D)
+                        ]
+                    )
+            else:
+                R = np.concatenate(
                     [
-                        fcs[fc_idx[d]].plane(name)[: ends[d]]
+                        fcs[fc_idx[d]].ensure_rows()[: ends[d]]
                         for d in range(D)
-                    ]
+                    ],
+                    axis=0,
                 )
-        else:
-            R = np.concatenate(
-                [
-                    fcs[fc_idx[d]].ensure_rows()[: ends[d]]
-                    for d in range(D)
-                ],
-                axis=0,
+                from ..storage.colcache import PLANE_NAMES
+
+                def col(name):
+                    return R[:, PLANE_NAMES.index(name)]
+
+            # -- derived columns, computed in (near-)wire dtypes ------------
+            obj_a = col("obj_a")
+            obj_row = np.where(
+                obj_a == 0, col("obj_ctr").astype(row_dt) - 1, row_dt(OBJ_ROOT)
             )
-            from ..storage.colcache import PLANE_NAMES
+            del obj_a
+            ref_a = col("ref_a")
+            ref_row = np.where(
+                ref_a == 0,
+                col("ref_ctr").astype(row_dt) - 1,
+                np.where(
+                    ref_a == -2, row_dt(REF_HEAD), row_dt(REF_NONE)
+                ).astype(row_dt),
+            )
+            del ref_a
 
-            def col(name):
-                return R[:, PLANE_NAMES.index(name)]
+            # -- key/value global remap -------------------------------------
+            klut, koffs = flat_lut("k")
+            key_l = col("key").astype(np.int64)
+            off_doc = np.repeat(koffs[fc_idx_a], ends)
+            safe = np.minimum(np.maximum(off_doc + key_l, 0), len(klut) - 1)
+            key_g = np.where(key_l >= 0, klut[safe].astype(kdt), kdt(-1))
+            del safe, off_doc, key_l
+            vkind = col("vkind")
+            value_g = col("value").astype(np.int64)
+            from ..storage.colcache import VK_BIGINT, VK_FLOAT, VK_STR
 
-        # -- derived columns, computed in (near-)wire dtypes ------------
-        obj_a = col("obj_a")
-        obj_row = np.where(
-            obj_a == 0, col("obj_ctr").astype(row_dt) - 1, row_dt(OBJ_ROOT)
+            for code, kind in (
+                (VK_STR, "s"), (VK_FLOAT, "f"), (VK_BIGINT, "b")
+            ):
+                m = vkind == code
+                if m.any():
+                    lut, offs = flat_lut(kind)
+                    oc = np.repeat(offs[fc_idx_a], ends)
+                    value_g[m] = lut[oc[m] + value_g[m]]
+
+            # -- scatter into padded [Dp, N] --------------------------------
+            defaults = {"action": PAD, "obj": -1, "key": -1, "ref": -3}
+            sources = {
+                "action": col("action"),
+                "actor": np.repeat(writer_g[fc_idx_a], ends),
+                "ctr": col("ctr"), "seq": col("seq"), "obj": obj_row,
+                "key": key_g, "ref": ref_row, "insert": col("insert"),
+                "vkind": vkind, "value": value_g, "dt": col("dt"),
+            }
+            vmin = int(value_g.min(initial=0))
+            vmax = int(value_g.max(initial=0))
+            dtypes = _pack_wire_dtypes(i16ok, row_dt, kdt, vmin, vmax)
+            for name in COLUMNS:
+                flat = np.full(Dp * N, defaults.get(name, 0), dtypes[name])
+                flat[flat_idx] = sources[name]
+                cols[name] = flat.reshape(Dp, N)
+    with _stage("prefix.emit"):
+        pdt = np.int16 if i16ok else np.int32
+        psrc = np.full(Dp * P, -1, pdt)
+        ptgt = np.full(Dp * P, -1, pdt)
+        if len(p_src_row):
+            pidx = pr_doc * P + p_pos
+            psrc[pidx] = p_src_row
+            ptgt[pidx] = p_tgt_row
+
+        doc_actors = np.full((Dp, 1), -1, np.int32)
+        doc_actors[:D, 0] = writer_g.astype(np.int32)[fc_idx_a]
+        n_ops = np.zeros(Dp, np.int32)
+        n_ops[:D] = ends
+        batch = ColumnarBatch(
+            cols=cols,
+            psrc=psrc.reshape(Dp, P),
+            ptgt=ptgt.reshape(Dp, P),
+            n_ops=n_ops,
+            actors=list(sorted_actors),
+            keys=list(key_int.items),
+            strings=list(str_int.items),
+            floats=list(float_int.items),
+            bigints=list(big_int.items),
+            doc_actors=doc_actors,
         )
-        del obj_a
-        ref_a = col("ref_a")
-        ref_row = np.where(
-            ref_a == 0,
-            col("ref_ctr").astype(row_dt) - 1,
-            np.where(
-                ref_a == -2, row_dt(REF_HEAD), row_dt(REF_NONE)
-            ).astype(row_dt),
-        )
-        del ref_a
-
-        # -- key/value global remap -------------------------------------
-        klut, koffs = flat_lut("k")
-        key_l = col("key").astype(np.int64)
-        off_doc = np.repeat(koffs[fc_idx_a], ends)
-        safe = np.minimum(np.maximum(off_doc + key_l, 0), len(klut) - 1)
-        key_g = np.where(key_l >= 0, klut[safe].astype(kdt), kdt(-1))
-        del safe, off_doc, key_l
-        vkind = col("vkind")
-        value_g = col("value").astype(np.int64)
-        from ..storage.colcache import VK_BIGINT, VK_FLOAT, VK_STR
-
-        for code, kind in ((VK_STR, "s"), (VK_FLOAT, "f"), (VK_BIGINT, "b")):
-            m = vkind == code
-            if m.any():
-                lut, offs = flat_lut(kind)
-                oc = np.repeat(offs[fc_idx_a], ends)
-                value_g[m] = lut[oc[m] + value_g[m]]
-
-        # -- scatter into padded [Dp, N] --------------------------------
-        defaults = {"action": PAD, "obj": -1, "key": -1, "ref": -3}
-        sources = {
-            "action": col("action"),
-            "actor": np.repeat(writer_g[fc_idx_a], ends),
-            "ctr": col("ctr"), "seq": col("seq"), "obj": obj_row,
-            "key": key_g, "ref": ref_row, "insert": col("insert"),
-            "vkind": vkind, "value": value_g, "dt": col("dt"),
-        }
-        vmin = int(value_g.min(initial=0))
-        vmax = int(value_g.max(initial=0))
-        dtypes = _pack_wire_dtypes(i16ok, row_dt, kdt, vmin, vmax)
-        for name in COLUMNS:
-            flat = np.full(Dp * N, defaults.get(name, 0), dtypes[name])
-            flat[flat_idx] = sources[name]
-            cols[name] = flat.reshape(Dp, N)
-    pdt = np.int16 if i16ok else np.int32
-    psrc = np.full(Dp * P, -1, pdt)
-    ptgt = np.full(Dp * P, -1, pdt)
-    if len(p_src_row):
-        pidx = pr_doc * P + p_pos
-        psrc[pidx] = p_src_row
-        ptgt[pidx] = p_tgt_row
-
-    doc_actors = np.full((Dp, 1), -1, np.int32)
-    doc_actors[:D, 0] = writer_g.astype(np.int32)[fc_idx_a]
-    n_ops = np.zeros(Dp, np.int32)
-    n_ops[:D] = ends
-    batch = ColumnarBatch(
-        cols=cols,
-        psrc=psrc.reshape(Dp, P),
-        ptgt=ptgt.reshape(Dp, P),
-        n_ops=n_ops,
-        actors=list(sorted_actors),
-        keys=list(key_int.items),
-        strings=list(str_int.items),
-        floats=list(float_int.items),
-        bigints=list(big_int.items),
-        doc_actors=doc_actors,
-    )
-    batch.slot = np.zeros((Dp, N), np.int8)  # single writer: slot 0
+        batch.slot = np.zeros((Dp, N), np.int8)  # single writer: slot 0
     return batch
 
 
@@ -872,11 +883,13 @@ _M_GATHER_NATIVE = telemetry.counter("pipeline.pack_gather_native_feeds")
 _M_GATHER_TWIN = telemetry.counter("pipeline.pack_gather_twin_feeds")
 
 
-def _stage(name: str):
-    """A stage of the general pack: a span below `pipeline.pack.general`
-    (the stages follow one another and never nest, so their seconds add
-    up to the general span's)."""
-    return telemetry.span("pipeline.pack." + name, "pipeline")
+def _stage(name: str, **tags: Any):
+    """A stage of a pack path: a span below `pipeline.pack.general`
+    (`tables`, `gather`, ...) or, with the path's name in front, below
+    `pipeline.pack.prefix` (`prefix.tables`, ...). A path's stages
+    follow one another and never nest, so their seconds add up to the
+    path's span."""
+    return telemetry.span("pipeline.pack." + name, "pipeline", **tags)
 
 
 def pack_docs_columns(
@@ -902,7 +915,10 @@ def pack_docs_columns(
     sorted-composite path below.
     """
     n = len(doc_specs)
-    if _prefix_single_slab(doc_specs):
+    with _stage("gate", docs=n) as gate:
+        prefix = _prefix_single_slab(doc_specs)
+        gate.note(prefix=int(prefix))
+    if prefix:
         _M_PACK_PREFIX.add(n)
         with telemetry.span("pipeline.pack.prefix", "pipeline", docs=n):
             batch = _pack_prefix_single(

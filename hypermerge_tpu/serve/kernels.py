@@ -207,16 +207,23 @@ def _query(kind: str, build, entries: Sequence, *qs) -> tuple:
     the entries, the program call, the device->host fetch of its
     outputs as numpy arrays. `qs` = (per-read values, pad fill) a query
     argument. The `serve.dispatch{kind,B,N}` span is the host's whole
-    cost of the dispatch; `serve.dispatch.fetch` under it is the wait
-    for the program and the transfer of what it returned."""
+    cost of the dispatch, its three children follow one another:
+    `serve.dispatch.stack` (the program's look-up and its arguments:
+    the entries' arrays, the padded query as device arrays),
+    `serve.dispatch.call` (the program call, until it returns) and
+    `serve.dispatch.fetch` (the wait for the program and the transfer
+    of what it returned)."""
     jnp = _jnp()
     B, N = batch_bucket(len(entries)), entries[0].dev.shape[1]
     with telemetry.span("serve.dispatch", "serve", kind=kind, B=B, N=N):
-        fn = _program(kind, B, N, build)
-        out = fn(
-            stack_entries(entries),
-            *(jnp.asarray(_pad_q(vals, B, fill)) for vals, fill in qs),
-        )
+        with telemetry.span("serve.dispatch.stack", "serve"):
+            fn = _program(kind, B, N, build)
+            args = (
+                stack_entries(entries),
+                *(jnp.asarray(_pad_q(vals, B, fill)) for vals, fill in qs),
+            )
+        with telemetry.span("serve.dispatch.call", "serve"):
+            out = fn(*args)
         with telemetry.span("serve.dispatch.fetch", "serve"):
             return tuple(np.asarray(o) for o in out)
 

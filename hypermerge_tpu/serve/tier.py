@@ -782,7 +782,10 @@ class ServeTier:
         if req.span is not None:
             req.span.end()
         try:
-            req.cb(payload)
+            # a reader's code on the flusher's thread: the one place
+            # where a client can hold every other client
+            with telemetry.span("serve.callback", "serve"):
+                req.cb(payload)
         except Exception as e:  # a reader's cb must not kill the batch
             log("serve", f"read callback failed: {e!r}")
 

@@ -14,6 +14,15 @@ round 13). Every subsystem registers into the same two instruments:
   JSON written at exit, loadable in Perfetto) or ``enable_tracing()``,
   or a running ``jax.profiler`` session, where the span becomes a
   ``TraceAnnotation`` beside the device ops on the device's clock.
+  A live span has two clocks: its wall seconds (``dur``) and the
+  seconds its thread had the CPU in them (``cpu`` on the handle, the
+  stat ``cpu_us`` in the profiler's trace, ``tdur`` in the exported
+  ring). The CPU value is absent, never wrong, on a span that ended on
+  another thread than it began on, with both sinks off, and on a
+  platform without ``time.thread_time_ns``. Wall minus CPU is what the
+  thread waited: for the GIL (or a page) in a span whose body is Python
+  and numpy, for the blocking call in a span around one (a device
+  fetch, a queue, a file), where it says nothing of the GIL.
 
 Naming convention: ``<subsystem>.<metric>`` with subsystems
 ``live`` (apply engine), ``pipeline`` (bulk cold open), ``mesh``

@@ -96,7 +96,7 @@ def chrome_trace_events(
     pid = os.getpid()
     tid_map: Dict[int, int] = {}
     out: List[Dict[str, Any]] = []
-    for ph, name, cat, ts, dur, tid, args in events:
+    for ph, name, cat, ts, dur, tid, args, cpu_us in events:
         small = tid_map.setdefault(tid, len(tid_map) + 1)
         ev: Dict[str, Any] = {
             "ph": ph,
@@ -108,6 +108,11 @@ def chrome_trace_events(
         }
         if ph == "X":
             ev["dur"] = round(dur, 3)
+            # the span's thread-clock duration (Trace Event Format
+            # `tdur`): the CPU microseconds of its thread inside it;
+            # absent where the span has no CPU value (trace.py)
+            if cpu_us is not None:
+                ev["tdur"] = round(cpu_us, 3)
         elif ph == "i":
             ev["s"] = "t"  # thread-scoped instant
         if args:

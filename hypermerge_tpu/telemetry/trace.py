@@ -23,10 +23,30 @@ collects the seconds of the spans that ran under it on its thread, by
 name (``kids``), so a stage can feed a stat from its child spans' own
 clock readings.
 
+Two clocks: while a sink is live a span reads its thread's CPU clock
+(``time.thread_time_ns``: CLOCK_THREAD_CPUTIME_ID, native code that
+runs on the thread with the GIL dropped included) beside the wall
+clock at each end. The CPU seconds go with the span: ``cpu`` on the
+handle beside ``dur``, the integer stat ``cpu_us`` in the profiler
+sink (set at the end, as ``note()`` sets tags), the Chrome field
+``tdur`` in the exported ring. A span has NO CPU value, never a wrong
+one, when it ended on another thread than it began on (``serve.read``:
+begun by the caller, ended by the flusher), when it was made with both
+sinks off, or where the platform has no ``thread_time_ns``. Wall minus
+CPU is the time the thread stood without the CPU: for a span whose
+body is Python and numpy that is waiting for the GIL (or for a page to
+come in); for a span around a blocking call (a fetch from the device,
+a queue, a file) it is that call's wait, and says nothing of the GIL.
+The CPU clock's resolution is the platform's: nanoseconds on Linux
+proper, 10 ms ticks under gVisor (the chip machine's sandbox kernel),
+where a short span reads 0 or 10,000 us and only a sum over many
+spans, or a span of seconds, is a measurement.
+
 With both sinks off ``span()`` checks two flags and returns a shared
 no-op singleton — no object allocation, no timestamp read. ``timed()``
-is for a stage whose seconds also feed a stat: it reads the clock once
-at each end whether or not a sink is on. Enable the ring with:
+is for a stage whose seconds also feed a stat: it reads the wall clock
+once at each end whether or not a sink is on, and the CPU clock only
+while one is. Enable the ring with:
 
 - ``HM_TRACE=<path>`` in the environment (read at import): tracing on
   for the process lifetime, the trace file written at exit (atexit)
@@ -53,8 +73,13 @@ from typing import Any, Dict, List, Optional, Tuple
 from .registry import REGISTRY
 
 # event tuples: (seq implicit via slot, ph, name, cat, ts_us, dur_us,
-# tid, args) — converted to Chrome dicts at export time (export.py)
-EventT = Tuple[str, str, str, float, float, int, Optional[Dict]]
+# tid, args, cpu_us) — converted to Chrome dicts at export time
+# (export.py); cpu_us is None where the span has no CPU value
+EventT = Tuple[str, str, str, float, float, int, Optional[Dict],
+               Optional[float]]
+
+# the calling thread's CPU clock, where the platform has one
+_thread_ns = getattr(time, "thread_time_ns", None)
 
 
 def _ring_capacity() -> int:
@@ -177,12 +202,14 @@ class SpanHandle:
     """An open span: ``end()`` records it and returns its seconds. Use
     via ``span()`` / ``timed()`` as a context manager, or ``begin()`` /
     ``end()`` across seams where the window opens and closes on
-    different code paths. After the end ``dur`` holds the seconds and
-    ``kids`` the seconds, by name, of the spans that ran under it on
-    its thread (those entered with ``with``)."""
+    different code paths. After the end ``dur`` holds the seconds,
+    ``cpu`` the seconds its thread had the CPU in them (None where the
+    span has no CPU value: module docstring) and ``kids`` the seconds,
+    by name, of the spans that ran under it on its thread (those
+    entered with ``with``)."""
 
-    __slots__ = ("name", "cat", "args", "t0", "dur", "kids", "_ring",
-                 "_ann", "_up")
+    __slots__ = ("name", "cat", "args", "t0", "dur", "cpu", "kids",
+                 "_ring", "_ann", "_up", "_c0", "_tid")
 
     def __init__(self, name: str, cat: str, args: Dict, ring: bool,
                  prof: bool):
@@ -195,12 +222,21 @@ class SpanHandle:
         self.cat = cat
         self.args = args or None
         self.dur = 0.0
+        self.cpu: Optional[float] = None
         self.kids: Dict[str, float] = {}
         self._ring = ring
         self._up: Optional["SpanHandle"] = None
         # the annotation's clock starts where it is made
         self._ann = _TA(name, **args) if prof else None
         self.t0 = time.perf_counter()
+        # the CPU clock is its thread's own: read only for a sink (and
+        # inside the wall window, so cpu <= dur), and only comparable
+        # on the thread that read it
+        if (ring or prof) and _thread_ns is not None:
+            self._tid = threading.get_ident()
+            self._c0: Optional[int] = _thread_ns()
+        else:
+            self._c0 = None
 
     def note(self, **more: Any) -> None:
         """Tags known only once the work is done (`docs=`, `ops=`)."""
@@ -209,16 +245,21 @@ class SpanHandle:
             self._ann.set_metadata(**more)
 
     def end(self, **more: Any) -> float:
-        t1 = time.perf_counter()
-        self.dur = t1 - self.t0
+        cpu_us = None
+        if self._c0 is not None and threading.get_ident() == self._tid:
+            cpu_ns = _thread_ns() - self._c0
+            self.cpu = cpu_ns / 1e9
+            cpu_us = cpu_ns / 1e3
+        self.dur = time.perf_counter() - self.t0
         if more:
             self.note(**more)
         ann = self._ann
         if ann is not None:
             self._ann = None
+            if cpu_us is not None:
+                ann.set_metadata(cpu_us=int(cpu_us))
             ann.__exit__(None, None, None)
         if self._ring and _T.on:
-            args = self.args
             _T.ring.add((
                 "X",
                 self.name,
@@ -226,7 +267,8 @@ class SpanHandle:
                 (self.t0 - _T.t0) * 1e6,
                 self.dur * 1e6,
                 _note_thread(),
-                args,
+                self.args,
+                cpu_us,
             ))
         return self.dur
 
@@ -284,8 +326,9 @@ begin = span
 
 def timed(name: str, cat: str = "", **args: Any) -> SpanHandle:
     """A span whose seconds the caller needs too (a stage that feeds a
-    stat or a counter): always a real handle, the clock read once at
-    each end, recorded only into the sinks that are on."""
+    stat or a counter): always a real handle, the wall clock read once
+    at each end (the CPU clock only while a sink is on), recorded only
+    into the sinks that are on."""
     return SpanHandle(name, cat, args, _T.on, _profiling())
 
 
@@ -303,6 +346,7 @@ def instant(name: str, cat: str = "", **args: Any) -> None:
         0.0,
         _note_thread(),
         args or None,
+        None,
     ))
 
 

@@ -1,10 +1,9 @@
-"""Namespaced debug logging + micro-bench timers.
+"""Namespaced debug logging.
 
 Mirrors the reference's observability story (SURVEY.md §5): the `debug`
 library with per-component namespaces gated by the DEBUG env var (reference
-src/Debug.ts:1-8, src/RepoBackend.ts:42), plus per-apply wall-clock timers
-(reference src/DocBackend.ts:207-212). Timers additionally aggregate into a
-process-wide registry that bench.py reads.
+src/Debug.ts:1-8, src/RepoBackend.ts:42). Timing a section is the span
+seam's (`hypermerge_tpu.telemetry.span`), not this module's.
 """
 
 from __future__ import annotations
@@ -13,11 +12,7 @@ import fnmatch
 import os
 import re
 import sys
-import threading
-import time
-from collections import defaultdict
-from contextlib import contextmanager
-from typing import Any, Callable, Dict, Iterator, Tuple
+from typing import Any, Callable
 
 from ..analysis.lockdep import make_lock
 
@@ -82,33 +77,3 @@ def trace(label: str) -> Callable[..., Any]:
         return first
 
     return _trace
-
-
-# -- timers ----------------------------------------------------------------
-
-_TIMINGS: Dict[str, Tuple[int, float]] = defaultdict(lambda: (0, 0.0))
-_TIMINGS_LOCK = make_lock("util.debug")
-
-
-@contextmanager
-def bench(label: str) -> Iterator[None]:
-    """Wall-clock one section; aggregates (count, total_seconds) per label
-    (reference src/DocBackend.ts:207-212 logs per-apply ms; we also keep a
-    cumulative registry like src/Metadata.ts:244-251)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        with _TIMINGS_LOCK:
-            count, total = _TIMINGS[label]
-            _TIMINGS[label] = (count + 1, total + dt)
-        log("bench", f"{label}: {dt * 1e3:.3f}ms")
-
-
-def timings() -> Dict[str, Tuple[int, float]]:
-    return dict(_TIMINGS)
-
-
-def reset_timings() -> None:
-    _TIMINGS.clear()

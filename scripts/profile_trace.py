@@ -26,8 +26,12 @@ head of the open stage by stage, each chunk of docs on the io thread
 length, so a chunk feeds several and a slab holds docs of several),
 each slab's shape (`[D x N]`, its real rows and padded cells a row,
 from the tags of its `pipeline.pack` span: a ragged open reads at a
-glance) and walk pack -> dispatch -> fetch across its threads with the
-seconds the work waited between stages (a trace from before the
+glance) and walk pack -> dispatch -> fetch across its threads, each
+span with its wall seconds, the CPU seconds of its thread inside it
+(`cpu`: the span's `cpu_us` stat, `tdur` in a ring file) and the rest
+(`off` = wall - CPU: the thread stood without the CPU: a blocking
+call, a page, or the GIL; `-` on a span that carries no CPU value),
+with the seconds the work waited between stages (a trace from before the
 `chunk` tag: io -> spec -> pack -> dispatch -> fetch, a chunk being a
 slab), and, from an `.xplane.pb` with a device in it,
 each idle gap of device 0 over 50 ms by the span that covers it.
@@ -80,10 +84,26 @@ def load_events(path):
     return [e for e in events if isinstance(e, dict)], []
 
 
+def _cpu_cols(s) -> str:
+    """"  cpu 0.412s  off 1.688s" from the span's CPU stat."""
+    from benchmark.readers.span_cpu import cpu_s
+
+    cpu = cpu_s(s)
+    if cpu is None:
+        return "  cpu        -  off        -"
+    return f"  cpu {cpu:8.3f}s  off {s.dur - cpu:8.3f}s"
+
+
 def slab_view(events, busy, out=sys.stdout) -> bool:
     """Every cold open in the trace, followed by request and slab."""
     from benchmark.readers import span_tree
 
+    # a ring file carries a span's CPU microseconds as the event's
+    # `tdur`, an `.xplane.pb` as the stat `cpu_us`: one name from here
+    events = [
+        dict(e, args=dict(e.get("args") or {}, cpu_us=e["tdur"]))
+        if "tdur" in e else e for e in events
+    ]
     spans = span_tree.from_chrome(events)
     opens = sorted({s.args["open"] for s in spans
                     if s.name == span_tree.ROOT and "open" in s.args})
@@ -116,7 +136,9 @@ def _open_view(tree, busy, span_tree, out) -> None:
         if (s.slab is None and "chunk" not in s.args
                 and s.name != span_tree.WAIT):
             w(f"  {s.t0 - root.t0:9.3f}s {'  ' * s.depth}{s.name:<28}"
-              f" {s.dur:8.3f}s  self {tree.self_s(s):.3f}s\n")
+              f" {s.dur:8.3f}s{_cpu_cols(s)}"
+              f"  self {tree.self_s(s):.3f}s\n")
+
     def walk(chain):
         last = None
         for s in chain:
@@ -124,13 +146,18 @@ def _open_view(tree, busy, span_tree, out) -> None:
             note = (f"  -> {s.args['slabs']} slabs"
                     if s.name == "pipeline.form" else "")
             w(f"  {s.t0 - root.t0:9.3f}s {s.name:<20} {s.dur:8.3f}s"
-              f"  thread {s.line}{gap}{note}\n")
-            for kid in tree.members:
-                if (kid.parent is s and kid.line == s.line
-                        and kid.name != span_tree.WAIT):
-                    w(f"  {kid.t0 - root.t0:9.3f}s   {kid.name:<18} "
-                      f"{kid.dur:8.3f}s\n")
+              f"{_cpu_cols(s)}  thread {s.line}{gap}{note}\n")
+            kids(s, 1)
             last = s.t1
+
+    def kids(s, depth):
+        for kid in tree.members:
+            if (kid.parent is s and kid.line == s.line
+                    and kid.name != span_tree.WAIT):
+                name = "  " * depth + kid.name
+                w(f"  {kid.t0 - root.t0:9.3f}s {name:<32} "
+                  f"{kid.dur:8.3f}s{_cpu_cols(kid)}\n")
+                kids(kid, depth + 1)
 
     # a trace since slabs are formed by length tags the io thread's
     # stages with their `chunk` of docs; a chunk feeds the slabs of

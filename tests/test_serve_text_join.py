@@ -296,6 +296,24 @@ def test_a_warm_flush_has_attach_dispatch_and_decode_spans(repo):
     assert len(by_name["serve.dispatch.fetch"]) == 2
     assert by_name["serve.decode"] == [{"reads": 3, "rows": 17}]
     assert moved("dispatches") == 2
+    # ISSUE 34: a dispatch is stack -> call -> fetch, one after the
+    # other and nothing else; a read's callback is a span of its own,
+    # on the flusher's thread; every one has its thread's CPU seconds
+    inside.sort(key=lambda e: e[3])
+    for d in (e for e in inside if e[1] == "serve.dispatch"):
+        kids = [e for e in inside if e is not d and e[3] >= d[3]
+                and e[3] + e[4] <= d[3] + d[4]]
+        assert [e[1] for e in kids] == [
+            "serve.dispatch.stack", "serve.dispatch.call",
+            "serve.dispatch.fetch"]
+        for x, y in zip(kids, kids[1:]):
+            assert x[3] + x[4] <= y[3]
+        assert sum(e[4] for e in kids) <= d[4]
+    assert len(by_name["serve.callback"]) == len(reads)
+    assert all(e[7] is not None and 0 <= e[7] <= e[4] for e in inside)
+    # a read is begun by its caller and ended by the flusher; here
+    # both are this thread, so it has a CPU value like the others
+    assert batch[7] is not None and batch[7] <= batch[4]
 
 
 def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
@@ -315,6 +333,13 @@ def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
         rehearse=True, control=False, mix=None,
     )
     names = ("serve.decode_s", "serve.dispatch_s", "serve.text_rows_per_read")
+    # ISSUE 34: the dispatch's split, the callbacks, and the CPU clock
+    cpu_names = (
+        "serve.stack_s", "serve.call_s", "serve.fetch_s",
+        "serve.fetch_cpu_pct", "serve.python_offcpu_s", "serve.callback_s",
+        "serve.dispatch_max_s",
+    )
+    names += cpu_names
     listed = {m["name"]: m for m in bench["per_layer"]}
     with mock.patch.dict(os.environ):
         cell = harness.Cell(args, bench, time.perf_counter())
@@ -344,6 +369,14 @@ def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
     assert set(got) == set(names)
     assert got["serve.decode_s"]["value"] > 0.0
     assert got["serve.dispatch_s"]["value"] > 0.0
+    split = sum(got[n]["value"] for n in
+                ("serve.stack_s", "serve.call_s", "serve.fetch_s"))
+    assert 0.0 < split <= got["serve.dispatch_s"]["value"]
+    assert 0.0 <= got["serve.fetch_cpu_pct"]["value"] <= 100.0
+    assert got["serve.python_offcpu_s"]["value"] >= -1e-4
+    assert got["serve.callback_s"]["value"] > 0.0
+    assert (got["serve.dispatch_max_s"]["value"]
+            >= got["serve.dispatch_s"]["value"])
     reads = after["serve.text_reads"] - before["serve.text_reads"]
     rows = after["serve.text_rows"] - before["serve.text_rows"]
     assert reads > 0
@@ -369,6 +402,8 @@ def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
     obs_parent = dict(obs, counters_before=older, counters_after=older)
     real_load = span_tree.load
     monkeypatch.setattr(span_tree, "load", lambda p: (
-        [s for s in real_load(p)[0]
+        [span_tree.Span(s.name, s.t0, s.t1, s.line, {
+            k: v for k, v in s.args.items() if k != "cpu_us"})
+         for s in real_load(p)[0]
          if s.name in ("serve.batch", "serve.read")], []))
     assert harness.layer_metrics(cell, obs_parent) == {}

@@ -202,13 +202,15 @@ def test_span_begin_end_tags(tracer):
     telemetry.instant("t.point", cat="storage", k="v")
     evs = telemetry.trace_events()
     by_name = {e[1]: e for e in evs}
-    ph, name, cat, ts, dur, tid, args = by_name["t.window"]
+    ph, name, cat, ts, dur, tid, args, cpu_us = by_name["t.window"]
     assert ph == "X" and cat == "net"
     assert dur >= 1000  # the 1ms sleep, in µs
+    assert 0 <= cpu_us <= dur  # begun and ended on this thread
     assert args == {"a": 1, "b": 2}  # begin tags merged with end tags
     assert by_name["t.block"][0] == "X"
     assert by_name["t.point"][0] == "i"
     assert by_name["t.point"][6] == {"k": "v"}
+    assert by_name["t.point"][7] is None  # an instant has no CPU value
 
 
 def test_disabled_span_is_shared_noop():
@@ -256,10 +258,17 @@ def test_chrome_trace_golden(tracer, tmp_path):
     assert {m["name"] for m in meta} >= {"process_name", "thread_name"}
     x, feeds, io = [e for e in evs if e["ph"] == "X"]
     assert x["name"] == "live.tick" and x["cat"] == "live"
-    assert x["args"] == {"docs": 3}
-    assert {"ts", "dur", "pid", "tid"} <= set(x)
+    assert x["args"] == {"docs": 3}  # the CPU value is no tag
+    assert set(x) == {"ph", "name", "cat", "ts", "dur", "tdur", "pid",
+                      "tid", "args"}
+    # `tdur` (Trace Event Format: the thread-clock duration): the CPU
+    # microseconds of the span's thread inside it, under its wall `dur`
+    for e in (x, feeds, io):
+        assert 0 <= e["tdur"] <= e["dur"]
+    assert feeds["tdur"] <= io["tdur"]  # the child's CPU is the parent's
     (i,) = [e for e in evs if e["ph"] == "i"]
     assert i["name"] == "net.resync" and i["s"] == "t"
+    assert "tdur" not in i and "dur" not in i
     assert io["name"] == "pipeline.io" and io["args"] == {
         "open": 7, "slab": 2, "parent": "pipeline.bulk_load"}
     # the child inherits the request and slab ids, not the parent tag
@@ -319,7 +328,104 @@ def test_timed_reads_the_clock_with_tracing_off():
         assert telemetry.event_count() == n0  # nothing recorded
         assert set(sp.kids) == {"t.a", "t.b"}  # descendants, by name
         assert sp.dur >= sp.kids["t.a"] >= sp.kids["t.b"] >= 0.002
+        assert sp.cpu is None  # no sink: the CPU clock was not read
     finally:
+        if was_on:
+            ttrace.enable()
+
+
+def _spin(seconds: float) -> None:
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def test_span_cpu_tells_work_from_waiting(tracer):
+    """The second clock (ISSUE 34): a thread that sleeps inside a span
+    reads `cpu` far under `dur`, one that spins reads them equal within
+    20%; both sinks get the value (the ring's 8th field, the exported
+    `tdur`; the profiler's `cpu_us` is held by
+    test_span_reaches_a_running_profiler_session)."""
+    with telemetry.span("t.sleep") as sl:
+        time.sleep(0.05)
+    assert sl.dur >= 0.05 and sl.cpu is not None and sl.cpu < 0.2 * sl.dur
+    for _ in range(5):  # a neighbour may take the core mid-spin: retry
+        with telemetry.span("t.spin") as sn:
+            _spin(0.05)
+        assert sn.cpu <= sn.dur
+        if sn.cpu >= 0.8 * sn.dur:
+            break
+    else:
+        pytest.fail(f"a spinning span read cpu {sn.cpu} of dur {sn.dur}")
+    by = {e[1]: e for e in telemetry.trace_events()}
+    assert by["t.sleep"][7] == pytest.approx(sl.cpu * 1e6)
+    assert by["t.spin"][7] == pytest.approx(sn.cpu * 1e6)
+    out = {e["name"]: e for e in telemetry.chrome_trace_events(
+        telemetry.trace_events())}
+    assert out["t.sleep"]["tdur"] == pytest.approx(sl.cpu * 1e6, abs=1e-3)
+    assert out["t.sleep"]["tdur"] < 0.2 * out["t.sleep"]["dur"]
+
+
+def test_span_ended_on_another_thread_has_no_cpu_value(tracer):
+    """A thread's CPU clock is its own: `serve.read` is begun by the
+    caller and ended by the flusher, and records wall seconds only."""
+    sp = telemetry.begin("t.cross", cat="serve")
+    t = threading.Thread(target=sp.end)
+    t.start()
+    t.join(10)
+    assert not t.is_alive() and sp.dur > 0 and sp.cpu is None
+    (ev,) = [e for e in telemetry.trace_events() if e[1] == "t.cross"]
+    assert ev[7] is None
+    (out,) = [e for e in telemetry.chrome_trace_events([ev])
+              if e["ph"] == "X"]
+    assert "tdur" not in out and out["dur"] > 0
+    # `host.gc` spans record it like any other
+    import gc
+
+    gc.collect()
+    (g,) = [e for e in telemetry.trace_events() if e[1] == "host.gc"]
+    assert g[7] is not None and 0 <= g[7] <= g[4]
+
+
+def test_no_cpu_clock_read_with_both_sinks_off(monkeypatch):
+    """Off, the untraced path gains no clock read: `span()` is NOOP and
+    `timed()` (always a real handle, for `Stage`) never calls
+    `thread_time_ns`; on, a span reads it once at each end; on a
+    platform without the clock a span has no CPU value, never a wrong
+    one."""
+    calls = []
+    real = ttrace._thread_ns
+
+    def counted():
+        calls.append(1)
+        return real()
+
+    monkeypatch.setattr(ttrace, "_thread_ns", counted)
+    was_on = ttrace.enabled()
+    ttrace.disable()
+    try:
+        assert telemetry.span("t.off") is telemetry.NOOP
+        with telemetry.timed("t.stage", "pipeline") as sp:
+            with telemetry.timed("t.kid"):
+                pass
+        sp2 = telemetry.timed("t.begin_end")
+        sp2.end()
+        assert calls == [] and sp.cpu is None and sp2.cpu is None
+        assert sp.dur > 0 and set(sp.kids) == {"t.kid"}
+        ttrace.enable()
+        with telemetry.timed("t.stage", "pipeline") as sp:
+            pass
+        assert len(calls) == 2 and sp.cpu is not None
+        with telemetry.span("t.span") as sp:
+            pass
+        assert len(calls) == 4 and 0 <= sp.cpu <= sp.dur
+        monkeypatch.setattr(ttrace, "_thread_ns", None)
+        with telemetry.span("t.no_clock") as sp:
+            pass
+        assert sp.cpu is None and sp.dur > 0
+    finally:
+        ttrace.disable()
+        ttrace.reset()
         if was_on:
             ttrace.enable()
 
@@ -382,6 +488,10 @@ def test_span_reaches_a_running_profiler_session(tmp_path):
         for line in plane.lines for e in line.events
         if e.name in ("pipeline.pack", "live.demote")
     }
+    # the span's CPU microseconds ride as one more stat, set at its end
+    cpu_us = found["pipeline.pack"].pop("cpu_us")
+    assert isinstance(cpu_us, int) and 0 <= cpu_us <= sp.dur * 1e6 + 1000
+    assert cpu_us == int(sp.cpu * 1e6)
     assert found == {
         "pipeline.pack": {"open": 4, "slab": 1, "docs": 9},
         "live.demote": {"k": "v"},

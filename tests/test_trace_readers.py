@@ -110,7 +110,9 @@ def test_every_span_of_the_open_carries_its_id(traced_open):
     }
     assert {"repo.init", "repo.close"} <= {s.name for s in spans}
     # stage and slab granularity: tens of spans an open, none per doc
-    assert len(tree.members) < 40 * len(tree.slabs())
+    # (ISSUE 34: seven more a slab, the gate, the prefix pack's stages
+    # and the widen)
+    assert len(tree.members) < 48 * len(tree.slabs())
 
 
 @pytest.mark.parametrize("slab", [0, 1, 2])
@@ -183,7 +185,8 @@ def test_span_metrics_read_from_the_tree(traced_open, monkeypatch):
         "loader.first_dispatch_s", "loader.queue_wait_s",
         "loader.io_feeds_s", "loader.io_columns_s", "loader.upload_s",
         "loader.doc_init_s", "host.gc_s.open", "loader.heads_s",
-        "loader.form_s",
+        "loader.form_s", "pack.gate_s", "pack.prefix_native_s",
+        "pack.widen_s",
     }
     for name, value in read.items():
         if name in nothing:
@@ -484,5 +487,202 @@ def test_profile_trace_by_slab(traced_open, tmp_path, capsys):
     for k in range(3):
         assert f"slab {k}: waited" in out
     assert "pipeline.register" in out and "pipeline.wait, thread-seconds" in out
+    # ISSUE 34: `cpu` and `off` (wall - CPU) beside each span's seconds,
+    # the pack's children down to the prefix stages
+    rows = [ln for ln in out.splitlines() if " pipeline.pack " in ln
+            or "pipeline.pack.prefix.native" in ln]
+    # (two opens of three slabs)
+    assert len(rows) == 12 and all(" cpu " in r and " off " in r
+                                   and "-" not in r for r in rows)
+    # a ring file carries the CPU microseconds as `tdur`; a span
+    # without it prints dashes
+    ring = [dict({k: v for k, v in e.items() if k != "args"},
+                 args={k: v for k, v in e["args"].items() if k != "cpu_us"},
+                 **({"tdur": e["args"]["cpu_us"]}
+                    if e["name"] != "pipeline.pack" else {}))
+            for e in events]
+    assert mod.slab_view(ring, [])
+    again = capsys.readouterr().out.splitlines()
+    assert [ln for ln in again if "pipeline.pack.prefix.native" in ln] == [
+        r for r in rows if "pipeline.pack.prefix.native" in r]
+    assert all("cpu        -  off        -" in ln for ln in again
+               if " pipeline.pack " in ln)
     assert not mod.slab_view(
         [e for e in events if e["name"] != "repo.open_many"], [])
+
+
+# -- the second clock: CPU seconds beside wall seconds (ISSUE 34) ----------
+
+PREFIX_STAGES = ("pipeline.pack.prefix.tables", "pipeline.pack.prefix.preds",
+                 "pipeline.pack.prefix.native", "pipeline.pack.prefix.emit")
+
+
+def test_pack_is_split_where_its_seconds_are(traced_open):
+    """Every `pipeline.pack` holds one gate, one prefix path whose
+    stages follow one another without overlap and sum to it, and one
+    widen; all carry the open's and the slab's ids; none is per doc."""
+    spans, _ = traced_open
+    tree = Tree(spans)
+    for k in tree.slabs():
+        (pack,) = tree.named(("pipeline.pack",), k)
+        under = sorted(
+            (s for s in tree.members
+             if s.line == pack.line and s is not pack
+             and pack.t0 <= s.t0 and s.t1 <= pack.t1),
+            key=lambda s: s.t0)
+        assert all(s.slab == k and s.args["open"] == tree.open
+                   for s in under)
+        kids = [s for s in under if s.parent is pack]
+        assert [s.name for s in kids] == [
+            "pipeline.pack.gate", "pipeline.pack.prefix",
+            "pipeline.pack.widen"]
+        gate, prefix, widen = kids
+        assert gate.args["docs"] == prefix.args["docs"] == (16, 16, 8)[k]
+        assert gate.args["prefix"] == 1 and widen.args["P"] == N_OPS // 4
+        stages = [s for s in under if s.parent is prefix]
+        names = [s.name for s in stages]
+        # tables twice: the interning loops, then the pointer table
+        # just before the native call
+        assert names == [PREFIX_STAGES[0], PREFIX_STAGES[1],
+                         PREFIX_STAGES[0], *PREFIX_STAGES[2:]]
+        assert stages[3].args["native"] == 1
+        for a, b in zip(stages, stages[1:]):
+            assert a.t1 <= b.t0, (a, b)
+        covered = sum(s.dur for s in stages)
+        assert covered <= prefix.dur
+        # a 16-doc slab packs in under a millisecond: the span
+        # overhead between stages is the rest
+        assert covered >= 0.95 * prefix.dur or prefix.dur - covered < 5e-4
+
+
+def test_cpu_us_on_every_span_that_has_one(traced_open):
+    """`cpu_us` comes back from the profiler as an integer stat, at
+    most the span's wall seconds (+ 1 ms), on every span that began
+    and ended on one thread: all of an open's."""
+    spans, _ = traced_open
+    tree = Tree(spans)
+    for s in tree.members:
+        assert "cpu_us" in s.args, s
+    for s in spans:
+        if "cpu_us" in s.args:
+            assert isinstance(s.args["cpu_us"], int)
+            assert 0 <= s.args["cpu_us"] <= s.dur * 1e6 + 1000, s
+
+
+def _cpu_spans():
+    """Hand-made: a flusher thread `f` with two flushes, a client `c`.
+    (name, t0, t1, line, cpu seconds or None)."""
+    rows = [
+        ("serve.batch", 0.0, 10.0, "f", 4.0),       # off 6.0
+        ("serve.dispatch", 1.0, 7.0, "f", 1.0),
+        ("serve.dispatch.stack", 1.0, 2.0, "f", 0.5),
+        ("serve.dispatch.call", 2.0, 4.0, "f", 0.5),  # off 1.5
+        ("serve.dispatch.fetch", 4.0, 7.0, "f", 0.0),  # off 3.0
+        ("serve.callback", 8.0, 9.0, "f", 1.0),
+        ("serve.batch", 20.0, 24.0, "f", 3.0),      # off 1.0
+        ("serve.dispatch", 20.0, 23.0, "f", 2.5),
+        ("serve.dispatch.fetch", 21.0, 23.0, "f", 1.5),  # off 0.5
+        ("serve.callback", 23.0, 23.5, "f", 0.5),
+        # another thread's fetch: inside the first batch by the clock,
+        # not on its thread, so `except` leaves it alone
+        ("serve.dispatch.fetch", 5.0, 6.0, "c", 0.0),
+        ("serve.read", 0.5, 9.0, "c", None),        # ended on `f`
+    ]
+    return [
+        Span(n, a, b, line, {} if cpu is None else {"cpu_us": int(cpu * 1e6)})
+        for n, a, b, line, cpu in rows
+    ]
+
+
+@pytest.mark.parametrize("params, want, parent", [
+    # offcpu with `except`: (6.0 + 1.0) - (1.5 + 3.0 + 0.5)
+    ({"measure": "offcpu", "names": ["serve.batch"],
+      "except": ["serve.dispatch.call", "serve.dispatch.fetch"]}, 2.0, None),
+    # ... a flush
+    ({"measure": "offcpu", "names": ["serve.batch"], "per": ["serve.batch"],
+      "except": ["serve.dispatch.call", "serve.dispatch.fetch"]}, 1.0, None),
+    # no `except`; a span without the stat counts for neither clock
+    ({"measure": "offcpu", "names": ["serve.batch", "serve.read"]}, 7.0,
+     None),
+    # cpu of the three fetches 1.5 s over their 6 s
+    ({"measure": "cpu_pct", "names": ["serve.dispatch.fetch"]}, 25.0, None),
+    # none of the named spans carries the stat: None, not 0
+    ({"measure": "offcpu", "names": ["serve.read"]}, None, None),
+    ({"measure": "cpu_pct", "names": ["serve.read"]}, None, None),
+    # the two wall-clock measures read the parent's trace too
+    ({"measure": "minus", "names": ["serve.dispatch"],
+      "less": ["serve.dispatch.fetch"]}, 3.0, 3.0),
+    ({"measure": "minus", "names": ["serve.callback"],
+      "per": ["serve.batch"]}, 0.75, 0.75),
+    ({"measure": "max", "names": ["serve.dispatch"]}, 6.0, 6.0),
+    # no span of the name
+    ({"measure": "max", "names": ["serve.decode"]}, None, None),
+    ({"measure": "minus", "names": ["serve.callback"],
+      "per": ["serve.install"]}, None, None),
+], ids=["offcpu-except", "offcpu-per", "offcpu-plain", "cpu_pct",
+        "offcpu-no-stat", "cpu_pct-no-stat", "minus", "minus-per", "max",
+        "max-nothing", "per-nothing"])
+def test_span_cpu_measures_on_handmade_spans(params, want, parent,
+                                             monkeypatch):
+    """`readers/span_cpu.py` over the traced seconds (`window`): its
+    four measures, and over the same spans without `cpu_us` (the
+    parent's trace): None from the two that read the CPU clock."""
+    from benchmark.readers import span_cpu
+
+    spans = _cpu_spans()
+    bare = [Span(s.name, s.t0, s.t1, s.line, {}) for s in spans]
+    monkeypatch.setattr(span_tree, "newest_trace", lambda: "x.pb")
+    for trace, expect in ((spans, want), (bare, parent)):
+        monkeypatch.setattr(span_tree, "load", lambda p, t=trace: (t, []))
+        got = span_cpu.read(dict(params, window=True), {"trace": {"a": 1}})
+        assert got == (None if expect is None else pytest.approx(expect))
+    assert span_cpu.read(dict(params, window=True), {}) is None  # untraced
+
+
+def test_span_cpu_reads_the_traced_open(traced_open, monkeypatch):
+    """Every new metric file of the cold-open cells (ISSUE 34) finds a
+    number in a traced open, through its own reader; entry and file
+    agree; over the same open without `cpu_us` and without the new
+    spans (the parent) each reads None and none raises."""
+    import json
+
+    from benchmark.readers import span_cpu
+
+    spans, _ = traced_open
+    new = ("pack.offcpu_s", "loader.io_offcpu_s", "loader.caller_offcpu_s",
+           "pack.gate_s", "pack.prefix_native_s", "pack.prefix_python_s",
+           "pack.widen_s")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        listed = {m["name"]: m for m in json.load(fh)["per_layer"]}
+    readers = {"span_cpu": span_cpu, "span_tree": span_tree}
+    added = ("pipeline.pack.gate", "pipeline.pack.widen") + PREFIX_STAGES
+    old = [Span(s.name, s.t0, s.t1, s.line,
+                {k: v for k, v in s.args.items() if k != "cpu_us"})
+           for s in spans if s.name not in added]
+    for name in new:
+        with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                               name + ".json")) as fh:
+            spec = json.load(fh)
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert listed[name][key] == spec[key], (name, key)
+        assert listed[name]["workloads"] == spec["cells"]
+        assert "reads.resident" not in spec["cells"]
+        read = readers[spec["reader"]].read
+        monkeypatch.setattr(span_tree, "tree_of",
+                            lambda obs: (Tree(spans), []))
+        value = read(spec["params"], {})
+        assert value is not None and value >= 0.0, name
+        monkeypatch.setattr(span_tree, "tree_of",
+                            lambda obs: (Tree(old), []))
+        if name != "pack.prefix_python_s":
+            assert read(spec["params"], {}) is None, name
+    tree = Tree(spans)
+    # the io thread and the caller wait (files, queues); what they read
+    # is what the tree holds
+    packs = tree.named(("pipeline.pack",))
+    assert span_cpu.read({"measure": "offcpu", "names": ["pipeline.pack"]},
+                         {}) is None  # `old` is in place: no stat
+    monkeypatch.setattr(span_tree, "tree_of", lambda obs: (tree, []))
+    assert span_cpu.read(
+        {"measure": "offcpu", "names": ["pipeline.pack"]}, {}
+    ) == pytest.approx(sum(s.dur - s.args["cpu_us"] / 1e6 for s in packs))
