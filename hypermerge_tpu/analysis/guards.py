@@ -452,7 +452,15 @@ GUARDS: Tuple[GuardedClass, ...] = (
             "`_prefix_single_ok` bool ops/columnar caches on the "
             "object is the same idiom (set through a foreign "
             "receiver, so only this story covers it — the checkers "
-            "cannot see it). Every other field is written by the "
+            "cannot see it). Two writers since ISSUE 35, one value: "
+            "the prefix pack's gate (_prefix_single_slab) rebinds it "
+            "from hm_prefix_gate's verdict AFTER the native call has "
+            "returned, with the GIL held again (the call itself "
+            "writes only the caller's fresh verdict array), and "
+            "_prefix_single_ok, the numpy twin, for a feed the call "
+            "cannot read; both derive the bool from the same "
+            "immutable planes, so racing workers rebind what is "
+            "already there. Every other field is written by the "
             "cache build under store.colcache before the object "
             "escapes.",
     ),

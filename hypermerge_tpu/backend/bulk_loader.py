@@ -125,10 +125,14 @@ _STATS0: Dict[str, Any] = {
     # feeds the open read; docs whose slab took the general
     # (multi-writer) pack and their share; that pack's feeds by who
     # gathered their rows (the native entry / its numpy twin); the
-    # widest actor and pred buckets among the slabs' programs
+    # feeds the prefix pack's gate judged, by who judged them (a feed
+    # that carries its latch already counts as neither); the widest
+    # actor and pred buckets among the slabs' programs
     "feeds": 0, "pack_general_docs": 0, "pack_general_pct": 0.0,
     "pack_gather_native_feeds": 0, "pack_gather_twin_feeds": 0,
     "pack_gather_native_pct": 0.0,
+    "pack_gate_native_feeds": 0, "pack_gate_twin_feeds": 0,
+    "pack_gate_native_pct": 0.0,
     "a_loc_max": 0, "pred_max": 0,
     # what forming the slabs cost: the dispatched slabs, their [D, N]
     # shapes in dispatch order, their padded cells over the real op
@@ -300,6 +304,8 @@ class BulkLoader:
                  "heads_probed_feeds"),
                 ("pack_gather_native_pct", "pack_gather_native_feeds",
                  "pack_gather_twin_feeds"),
+                ("pack_gate_native_pct", "pack_gate_native_feeds",
+                 "pack_gate_twin_feeds"),
                 ("rga_vmem_cells_pct", "rga_vmem_cells", "rga_xla_cells"),
             ):
                 stats[pct] = _pct(stats[part], stats[part] + stats[rest])
@@ -639,6 +645,9 @@ class BulkLoader:
                     native, twin = batch.gather_feeds
                     stats["pack_gather_native_feeds"] += native
                     stats["pack_gather_twin_feeds"] += twin
+                native, twin = batch.gate_feeds
+                stats["pack_gate_native_feeds"] += native
+                stats["pack_gate_twin_feeds"] += twin
                 stats["a_loc_max"] = max(stats["a_loc_max"], a_loc)
                 stats["pred_max"] = max(
                     stats["pred_max"], batch.psrc.shape[1]

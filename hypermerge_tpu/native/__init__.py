@@ -103,9 +103,10 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     # + codec rather than disabling the whole native layer.
     #
     # GIL contract: the library is loaded with ctypes.CDLL (never
-    # PyDLL), so every foreign call — hm_pack_prefix and hm_pack_gather
-    # included — RUNS WITH THE GIL RELEASED for the duration of the C
-    # call. The streaming slab pipeline (backend/pipeline.py) depends
+    # PyDLL), so every foreign call — hm_pack_prefix, hm_pack_gather
+    # and hm_prefix_gate included — RUNS WITH THE GIL RELEASED for the
+    # duration of the C call. The streaming slab pipeline
+    # (backend/pipeline.py) depends
     # on this: its pack worker thread spends its time inside them
     # while the io thread reads the next slab's sidecars and the
     # dispatch thread feeds the device. The pack entries touch only
@@ -120,6 +121,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib.hm_pack_prefix.argtypes = [ll, ll, ll] + [ctypes.c_void_p] * 16
         lib.hm_pack_gather.restype = ctypes.c_int
         lib.hm_pack_gather.argtypes = [ll] + [ctypes.c_void_p] * 8
+        lib.hm_prefix_gate.restype = ctypes.c_int
+        lib.hm_prefix_gate.argtypes = [ll] + [ctypes.c_void_p] * 6
         lib._has_pack = True
     except AttributeError:
         lib._has_pack = False
@@ -193,9 +196,9 @@ def pack_drops_gil() -> bool:
 
 
 def pack_parallel_ok() -> bool:
-    """True when hm_pack_prefix / hm_pack_value_minmax / hm_pack_gather
-    may be called from SEVERAL threads at once — the pack pool's contract
-    (HM_PACK_WORKERS > 1, backend/pipeline.py).
+    """True when hm_pack_prefix / hm_pack_value_minmax / hm_pack_gather /
+    hm_prefix_gate may be called from SEVERAL threads at once — the pack
+    pool's contract (HM_PACK_WORKERS > 1, backend/pipeline.py).
 
     The entry points are stateless C loops: every pointer they touch
     (source planes, LUTs, output buffers) is a caller-owned argument,
@@ -203,7 +206,9 @@ def pack_parallel_ok() -> bool:
     channels, so concurrent calls with DISTINCT output buffers are
     safe by construction. Distinctness is the caller's obligation and
     holds trivially for the pool: each worker packs a different slab
-    into buffers it just allocated. Combined with the GIL release
+    into buffers it just allocated (hm_prefix_gate's only output is the
+    caller's fresh verdict array; two workers gating one feed read the
+    same immutable planes). Combined with the GIL release
     (pack_drops_gil) this is what makes N pack workers N-core real
     rather than time-sliced."""
     return pack_drops_gil()

@@ -557,6 +557,42 @@ static inline void pk_copy(const void *p, int dt, long long stride,
   }
 }
 
+// The prefix pack's gate (ops/columnar.py _prefix_single_ok, the numpy
+// twin): does every element of a plane pass a test. The element loop is
+// branch-free over blocks of 256 (it vectorises) and a plane is left at
+// the first block that holds a failure.
+template <typename S, typename Bad>
+static inline bool pk_none_as(const char *p, long long n, Bad bad) {
+  const long long B = 256;
+  for (long long at = 0; at < n; at += B) {
+    long long m = n - at < B ? n - at : B;
+    int any = 0;
+    for (long long i = 0; i < m; i++) {
+      S v;
+      memcpy(&v, p + (at + i) * (long long)sizeof(S), sizeof(S));
+      any |= bad((long long)v, at + i);
+    }
+    if (any)
+      return false;
+  }
+  return true;
+}
+
+template <typename Bad>
+static inline bool pk_none(const void *p, int dt, long long n, Bad bad) {
+  const char *c = (const char *)p;
+  switch (dt) {
+  case 0:
+    return pk_none_as<int8_t>(c, n, bad);
+  case 1:
+    return pk_none_as<int16_t>(c, n, bad);
+  case 2:
+    return pk_none_as<int32_t>(c, n, bad);
+  default:
+    return pk_none_as<uint8_t>(c, n, bad);
+  }
+}
+
 extern "C" {
 
 int hm_pack_gather(long long W, const long long *win,
@@ -638,6 +674,52 @@ int hm_pack_gather(long long W, const long long *win,
       pbase += np_;
     }
     base += n;
+  }
+  return 0;
+}
+
+// The gate of the prefix pack for a slab's feeds in one call
+// (ops/columnar.py _prefix_single_slab; _prefix_single_ok is the numpy
+// twin and the two are pinned to the same verdict,
+// tests/test_native_pack.py): feed f qualifies for the no-sort pack iff,
+// over ALL its n_rows[f] rows, obj_a <= 0 and ref_a <= 0 (no op names
+// another actor's), ctr[i] == i + 1 (dense lamport counters: a ctr plane
+// too narrow to hold its row count fails here), and no pred row names
+// another actor (column 2 of its [n_preds, 3] int32 rows is 0).
+// src_ptrs / src_dt are [F, 3]: obj_a, ref_a, ctr (pk_ld's dtype codes).
+// out_ok[f] is 1 or 0; the call ends at the first feed that fails (its
+// caller wants the slab's verdict, all or nothing) and the feeds after
+// it read 2, not judged. Stateless, caller-owned buffers only.
+int hm_prefix_gate(long long F, const long long *src_ptrs,
+                   const uint8_t *src_dt, const long long *n_rows,
+                   const long long *pred_ptrs, const long long *n_preds,
+                   uint8_t *out_ok) {
+  auto foreign = [](long long v, long long) { return (int)(v > 0); };
+  auto gap = [](long long v, long long i) { return (int)(v != i + 1); };
+  for (long long f = 0; f < F; f++) {
+    long long n = n_rows[f], np_ = n_preds[f];
+    const long long *sp = src_ptrs + f * 3;
+    const uint8_t *sd = src_dt + f * 3;
+    if (n < 0 || np_ < 0 || sd[0] > 3 || sd[1] > 3 || sd[2] > 3)
+      return -1;
+    bool ok = pk_none((const void *)sp[0], sd[0], n, foreign) &&
+              pk_none((const void *)sp[1], sd[1], n, foreign) &&
+              pk_none((const void *)sp[2], sd[2], n, gap);
+    if (ok && np_) {
+      const char *pr = (const char *)pred_ptrs[f] + 8;
+      int any = 0;
+      for (long long i = 0; i < np_; i++) {
+        int32_t a;
+        memcpy(&a, pr + i * 12, 4);
+        any |= a != 0;
+      }
+      ok = !any;
+    }
+    out_ok[f] = ok ? 1 : 0;
+    if (!ok) {
+      memset(out_ok + f + 1, 2, (size_t)(F - f - 1));
+      return 0;
+    }
   }
   return 0;
 }

@@ -115,6 +115,8 @@ class ColumnarBatch:
     packed_by: str = ""
     # general path: feeds whose rows the native gather / its numpy twin read
     gather_feeds: Tuple[int, int] = (0, 0)
+    # the gate: feeds whose verdict hm_prefix_gate / the numpy twin gave
+    gate_feeds: Tuple[int, int] = (0, 0)
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -577,17 +579,138 @@ def _native_pack_prefix(
     }
 
 
-def _prefix_single_slab(doc_specs) -> bool:
+# the gate's source planes (hm_native.cpp hm_prefix_gate)
+_GATE_PLANES = ("obj_a", "ref_a", "ctr")
+
+
+def _image_planes(metas):
+    """([F, planes] plane pointers, dtype codes) of feeds whose planes
+    are slices of one image each, from their plane_meta tables
+    (PLANE_NAMES order)."""
+    n = len(metas)
+    base = np.fromiter((m[0] for m in metas), np.int64, n)
+    offs = np.concatenate([m[1] for m in metas]).reshape(n, -1)
+    code = np.concatenate([m[2] for m in metas]).reshape(n, -1)
+    return base[:, None] + offs, code
+
+
+def _gate_sources(fcs):
+    """The feeds of `fcs` that hm_prefix_gate can read where they lie,
+    and their table: (feeds, [F, 3] plane pointers, dtype codes, row
+    counts, pred pointers, pred counts). A feed is described from what
+    it is, as _native_pack_prefix does: the gate's rows of its
+    plane_meta table where every plane is a slice of one image, the
+    plane arrays' own pointers otherwise; a rows-backed feed, or one
+    whose planes or preds the ABI cannot take, is left to the twin."""
+    fcs = list(fcs)
+    srcs = np.empty((len(fcs), 3), np.int64)
+    sdts = np.empty((len(fcs), 3), np.uint8)
+
+    def own_planes(i, fc) -> bool:
+        if fc.planes is None:
+            return False
+        n = fc.n_rows
+        for j, name in enumerate(_GATE_PLANES):
+            p = fc.planes[name]
+            code = _DT_CODE.get(p.dtype)
+            if code is None or p.ndim != 1 or len(p) != n or (
+                not p.flags["C_CONTIGUOUS"]
+            ):
+                return False
+            srcs[i, j] = _ptr(p)
+            sdts[i, j] = code
+        return True
+
+    keep, imaged = [], []
+    for i, fc in enumerate(fcs):
+        preds = fc.preds
+        if preds.dtype != np.int32 or preds.ndim != 2 or (
+            preds.shape[1] != 3 or not preds.flags["C_CONTIGUOUS"]
+        ):
+            continue
+        if fc.plane_meta is not None:
+            imaged.append(i)
+        elif not own_planes(i, fc):
+            continue
+        keep.append(i)
+    if imaged:
+        from ..storage.colcache import PLANE_NAMES
+
+        at = [PLANE_NAMES.index(name) for name in _GATE_PLANES]
+        ptrs, code = _image_planes([fcs[i].plane_meta for i in imaged])
+        srcs[imaged] = ptrs[:, at]
+        sdts[imaged] = code[:, at]
+    took = [fcs[i] for i in keep]
+    F = len(took)
+    n_rows = np.fromiter((fc.n_rows for fc in took), np.int64, F)
+    n_preds = np.fromiter((len(fc.preds) for fc in took), np.int64, F)
+    pred_ptrs = np.fromiter(
+        (_ptr(fc.preds) if len(fc.preds) else 0 for fc in took), np.int64, F
+    )
+    return took, srcs[keep], sdts[keep], n_rows, pred_ptrs, n_preds
+
+
+def _prefix_single_slab(doc_specs) -> Tuple[bool, int, int]:
     """The gate of the prefix path, per slab and all or nothing: every
     doc is one single-writer feed read from its start (one doc of two
-    writers sends its whole slab through the general path)."""
+    writers sends its whole slab through the general path). Returns
+    (the slab's verdict, feeds the native call judged, feeds the numpy
+    twin judged); a feed that carries its latch already counts as
+    neither.
+
+    The structural part comes first, over all docs, and reads no plane
+    (it collects the latches the feeds carry on its way). The verdicts
+    still missing (`_prefix_single_ok`'s, latched on the FeedColumns as
+    that function latches them) then come from ONE call of
+    hm_prefix_gate with the GIL dropped for every feed it can read
+    where it lies, and from the twin for the rest (a rows-backed feed,
+    no library, HM_NATIVE_PACK=0). Both stop at the first feed that
+    fails: the slab leaves for the general path either way."""
+    todo: Dict[int, Any] = {}
+    failed = False
     for spec in doc_specs:
         if len(spec) != 1:
-            return False
+            return False, 0, 0
         fc, s, _e = spec[0]
-        if s != 0 or not _prefix_single_ok(fc):
-            return False
-    return True
+        if s != 0:
+            return False, 0, 0
+        ok = getattr(fc, "_prefix_single_ok", None)
+        if ok is None:
+            todo[id(fc)] = fc
+        elif not ok:
+            failed = True
+    if failed or not todo:
+        return not failed, 0, 0
+    rest = todo.values()
+    n_native = 0
+    lib = _native_pack_lib()
+    if lib is not None:
+        took, srcs, sdts, n_rows, pred_ptrs, n_preds = _gate_sources(rest)
+        out = np.empty(len(took), np.uint8)
+        ptr = _ptr
+        if took and lib.hm_prefix_gate(
+            len(took), ptr(srcs), ptr(sdts), ptr(n_rows), ptr(pred_ptrs),
+            ptr(n_preds), ptr(out),
+        ) == 0:
+            # the latch, set with the GIL held after the call returned:
+            # the same idempotent rebind _prefix_single_ok makes. The
+            # call stops at the first feed that fails (the feeds after
+            # it read 2), so the judged feeds are the first n_native
+            n_native = int(np.count_nonzero(out != 2))
+            for fc, ok in zip(took, out[:n_native].tolist()):
+                fc._prefix_single_ok = bool(ok)
+            if not out[n_native - 1]:
+                return False, n_native, 0
+            if n_native == len(todo):
+                return True, n_native, 0
+            for fc in took:
+                del todo[id(fc)]
+    n_twin = 0
+    for fc in rest:
+        n_twin += 1
+        if not _prefix_single_ok(fc):
+            return False, n_native, n_twin
+    return True, n_native, n_twin
 
 
 def _pack_prefix_single(
@@ -875,12 +998,15 @@ def _pack_prefix_single(
     return batch
 
 
-# docs packed by each path, over the process's life (tools/top.py), and
-# the general pack's feeds by who gathered their rows
+# docs packed by each path, over the process's life (tools/top.py), the
+# general pack's feeds by who gathered their rows, and the gate's feeds
+# by who judged them (a feed judged once keeps its latch)
 _M_PACK_PREFIX = telemetry.counter("pipeline.pack_prefix_docs")
 _M_PACK_GENERAL = telemetry.counter("pipeline.pack_general_docs")
 _M_GATHER_NATIVE = telemetry.counter("pipeline.pack_gather_native_feeds")
 _M_GATHER_TWIN = telemetry.counter("pipeline.pack_gather_twin_feeds")
+_M_GATE_NATIVE = telemetry.counter("pipeline.pack_gate_native_feeds")
+_M_GATE_TWIN = telemetry.counter("pipeline.pack_gate_twin_feeds")
 
 
 def _stage(name: str, **tags: Any):
@@ -916,8 +1042,10 @@ def pack_docs_columns(
     """
     n = len(doc_specs)
     with _stage("gate", docs=n) as gate:
-        prefix = _prefix_single_slab(doc_specs)
-        gate.note(prefix=int(prefix))
+        prefix, n_native, n_twin = _prefix_single_slab(doc_specs)
+        gate.note(prefix=int(prefix), native=n_native)
+    _M_GATE_NATIVE.add(n_native)
+    _M_GATE_TWIN.add(n_twin)
     if prefix:
         _M_PACK_PREFIX.add(n)
         with telemetry.span("pipeline.pack.prefix", "pipeline", docs=n):
@@ -925,11 +1053,14 @@ def pack_docs_columns(
                 doc_specs, n_rows, n_pred, n_docs
             )
         batch.packed_by = "prefix"
-        return batch
-    _M_PACK_GENERAL.add(n)
-    with telemetry.span("pipeline.pack.general", "pipeline", docs=n) as sp:
-        batch = _pack_general(doc_specs, n_rows, n_pred, n_docs, sp)
-    batch.packed_by = "general"
+    else:
+        _M_PACK_GENERAL.add(n)
+        with telemetry.span(
+            "pipeline.pack.general", "pipeline", docs=n
+        ) as sp:
+            batch = _pack_general(doc_specs, n_rows, n_pred, n_docs, sp)
+        batch.packed_by = "general"
+    batch.gate_feeds = (n_native, n_twin)
     return batch
 
 
@@ -1056,12 +1187,9 @@ def _gather_sources(fcs):
     strides = np.empty((F, NP), np.int64)
     imaged = [i for i, fc in enumerate(fcs) if fc.plane_meta is not None]
     if imaged:
-        metas = [fcs[i].plane_meta for i in imaged]
-        n = len(metas)
-        base = np.fromiter((m[0] for m in metas), np.int64, n)
-        offs = np.concatenate([m[1] for m in metas]).reshape(n, -1)
-        code = np.concatenate([m[2] for m in metas]).reshape(n, -1)[:, :NP]
-        srcs[imaged] = base[:, None] + offs[:, :NP]
+        ptrs, code = _image_planes([fcs[i].plane_meta for i in imaged])
+        code = code[:, :NP]
+        srcs[imaged] = ptrs[:, :NP]
         sdts[imaged] = code
         strides[imaged] = _DT_ITEMSIZE[code]
     col4 = 4 * np.arange(NP, dtype=np.int64)

@@ -115,6 +115,28 @@ def test_bulk_stats_hold_what_the_driver_checks(small_load_stats):
     assert small_load_stats["pipeline"] == 1
 
 
+def test_gate_native_pct_is_data_only_and_lists_the_single_writer_cells():
+    """ISSUE 35's per-layer metric is a data file over a reader the
+    benchmark has, listed for the three cells whose every slab runs the
+    whole gate (the stores of single-writer docs) and no other."""
+    spec = json.loads((LAYER_METRICS / "pack.gate_native_pct.json").read_text())
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry = bench["per_layer"][-1]
+    assert entry["name"] == spec["name"] == "pack.gate_native_pct"
+    assert spec["reader"] == "bulk_stats"
+    assert spec["params"] == {"key": "pack_gate_native_pct"}
+    assert entry["workloads"] == spec["cells"] == [
+        "coldopen.flagship", "coldopen.flagship-x4", "coldopen.longtail"]
+    assert (entry["unit"], entry["better"], entry["source"], entry["layer"],
+            entry["moves"]) == (
+        "%", "higher", "program_counter", "pack", "ops_per_s")
+    single_writer = {c["name"] for c in bench["configs"]
+                     if "single-writer" in c["why"]}
+    assert {w["name"] for w in bench["workloads"]
+            if w["config"] in single_writer
+            and w["traffic"].startswith("coldopen")} == set(spec["cells"])
+
+
 def test_loader_imports_point_one_way():
     """repo_backend -> bulk_loader, never back: the loader's module
     names repo_backend in no import, and RepoBackend holds no load path

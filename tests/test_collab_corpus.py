@@ -336,6 +336,31 @@ def test_one_two_writer_doc_sends_its_slab_through_the_general_pack(
     older = {k: v for k, v in stats.items()
              if not k.startswith("pack_gather_")}
     assert bulk_stats.read(native_pct, {"bulk_stats": [older]}) is None
+    # who judged the feeds at the prefix pack's gate (ISSUE 35): the
+    # slab with the two-writer doc leaves at the structural pass and
+    # judges none; the other slab's feeds go to the native call, or to
+    # the numpy twin under HM_NATIVE_PACK=0
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           "pack.gate_native_pct.json")) as fh:
+        gate_pct = json.load(fh)["params"]
+    n_prefix = 33 - n_general
+    for st, is_native, feeds in ((stats, has_native, n_prefix),
+                                 (stats1, has_native, 32),
+                                 (stats0, False, n_prefix)):
+        counted = (st["pack_gate_native_feeds"], st["pack_gate_twin_feeds"])
+        assert counted == ((feeds, 0) if is_native else (0, feeds))
+        assert st["pack_gate_native_pct"] == (100.0 if is_native else 0.0)
+        assert bulk_stats.read(gate_pct, {"bulk_stats": [st]}) == (
+            st["pack_gate_native_pct"])
+    gates = {g.parent.slab: g for g in tree.named(("pipeline.pack.gate",))}
+    assert gates[where].args["prefix"] == 0
+    assert gates[where].args["native"] == 0
+    assert gates[1 - where].args["prefix"] == 1
+    assert gates[1 - where].args["native"] == (
+        stats["pack_gate_native_feeds"])
+    older = {k: v for k, v in stats.items()
+             if not k.startswith("pack_gate_")}
+    assert bulk_stats.read(gate_pct, {"bulk_stats": [older]}) is None
     # no pack of the open built a feed's dense [n, 14] matrix
     assert len(columns) == 2 * (general.args["feeds"] + 32)
     assert all(fc.planes is not None and fc.rows is None for fc in columns)

@@ -683,3 +683,352 @@ def test_counter_and_text_kinds_roundtrip(tmp_path, monkeypatch):
     assert got["n"] == ("__counter__", 7)
     assert got["t"] == ("__text__", "hey!")
     cc.close()
+
+
+# ---------------------------------------------------------------------------
+# the prefix pack's gate: hm_prefix_gate == _prefix_single_ok, feed by feed
+
+_GATE_DTYPES = sorted(columnar._DT_CODE, key=columnar._DT_CODE.get)
+
+
+def _gate_feed(n, fault=None, dtype=np.int16, backing="planes", n_preds=5):
+    """A FeedColumns of `n` rows holding what the gate reads (and the
+    `action` plane its row count comes from), sound unless `fault`
+    names the way it fails; `backing` "planes": arrays of their own,
+    "image": slices of one buffer behind one-byte tags, so no plane is
+    aligned, described by plane_meta as a v3 image's are."""
+    from hypermerge_tpu.storage.colcache import PLANE_NAMES, FeedColumns
+
+    r = random.Random(n * 31 + len(fault or ""))
+    cols = {name: np.zeros(n, np.int64) for name in PLANE_NAMES}
+    cols["ctr"] = np.arange(1, n + 1)
+    cols["obj_a"] = np.asarray([r.choice((0, -1)) for _ in range(n)], np.int64)
+    cols["ref_a"] = np.asarray(
+        [r.choice((0, -2, -3)) for _ in range(n)], np.int64
+    )
+    preds = np.zeros((n_preds if n else 0, 3), np.int32)
+    if n:
+        preds[:, 0] = sorted(r.randrange(n) for _ in range(len(preds)))
+        preds[:, 1] = 1
+    at = r.randrange(n) if n else 0
+    if fault == "foreign_obj_a":
+        cols["obj_a"][at] = 1
+    elif fault == "foreign_ref_a":
+        cols["ref_a"][at] = 2
+    elif fault == "ctr_gap":
+        cols["ctr"][at:] += 1
+    elif fault == "ctr_not_from_1":
+        cols["ctr"] = cols["ctr"] - 1 if dtype == np.uint8 else cols["ctr"] + 1
+    elif fault == "foreign_pred":
+        preds[r.randrange(len(preds)), 2] = 1
+    elif fault == "ctr_too_narrow":
+        pass  # the caller hands a dtype that cannot hold n: values wrap
+    else:
+        assert fault is None, fault
+    signed = np.int8 if dtype == np.uint8 else dtype
+    dts = {name: np.dtype(np.uint8) for name in PLANE_NAMES}
+    dts.update(ctr=np.dtype(dtype), obj_a=np.dtype(signed),
+               ref_a=np.dtype(signed))
+    if dtype == np.uint8 and fault != "foreign_obj_a":
+        cols["obj_a"][:] = 0  # an all-zero plane narrows to uint8
+        dts["obj_a"] = np.dtype(np.uint8)
+    with np.errstate(over="ignore"):
+        planes = {k: v.astype(dts[k]) for k, v in cols.items()}
+    meta = None
+    if backing == "image":
+        size = sum(1 + p.nbytes for p in planes.values()) + 1 + preds.nbytes
+        buf = np.zeros(size, np.uint8)
+        offs = np.empty(len(PLANE_NAMES), np.int64)
+        code = np.empty(len(PLANE_NAMES), np.uint8)
+        pos = 0
+        for i, name in enumerate(PLANE_NAMES):
+            p = planes[name]
+            pos += 1
+            offs[i], code[i] = pos, columnar._DT_CODE[p.dtype]
+            buf[pos:pos + p.nbytes] = p.view(np.uint8)
+            planes[name] = buf[pos:pos + p.nbytes].view(p.dtype)
+            pos += p.nbytes
+        pos += 1
+        buf[pos:pos + preds.nbytes] = preds.reshape(-1).view(np.uint8)
+        preds = buf[pos:pos + preds.nbytes].view(np.int32).reshape(-1, 3)
+        meta = (columnar._ptr(buf), offs, code, buf)
+    return FeedColumns(
+        rows=None, preds=preds, actors=["w"], keys=[], strings=[],
+        floats=[], bigints=[], n_changes=1, ok_prefix_len=1,
+        row_ends=np.asarray([0, n], np.int64), planes=planes,
+        plane_meta=meta,
+    )
+
+
+def _twin_verdict(fc):
+    """_prefix_single_ok's verdict, leaving no latch behind."""
+    ok = columnar._prefix_single_ok(fc)
+    del fc._prefix_single_ok
+    return ok
+
+
+GATE_CASES = {
+    "sound": (40, None, True),
+    "foreign_obj_a": (40, "foreign_obj_a", False),
+    "foreign_ref_a": (40, "foreign_ref_a", False),
+    "ctr_gap": (40, "ctr_gap", False),
+    "ctr_not_from_1": (40, "ctr_not_from_1", False),
+    "foreign_pred": (40, "foreign_pred", False),
+    "empty": (0, None, True),
+    "one_row": (1, None, True),
+    "one_row_foreign": (1, "foreign_ref_a", False),
+    "long": (3000, None, True),
+    "long_gap_late": (3000, "ctr_gap", False),
+}
+
+
+@needs_pack
+@pytest.mark.parametrize("backing", ["planes", "image"])
+@pytest.mark.parametrize("case", sorted(GATE_CASES))
+def test_gate_native_verdict_is_the_twins(case, backing, monkeypatch):
+    """hm_prefix_gate and _prefix_single_ok give one verdict: a sound
+    feed, each way to fail, an empty feed, a feed of one row; planes of
+    their own and unaligned slices of one image. The native verdict is
+    latched, and the slab's verdict is the feed's."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    n, fault, want = GATE_CASES[case]
+    dtype = np.int16 if n > 100 else np.int8
+    fc = _gate_feed(n, fault, dtype, backing)
+    assert _twin_verdict(fc) is want
+    assert columnar._prefix_single_slab([[(fc, 0, INF)]]) == (want, 1, 0)
+    assert fc._prefix_single_ok is want
+
+
+@needs_pack
+@pytest.mark.parametrize("dtype", _GATE_DTYPES, ids=str)
+@pytest.mark.parametrize("backing", ["planes", "image"])
+def test_gate_reads_every_narrow_dtype(dtype, backing, monkeypatch):
+    """Every dtype a v3 image narrows a plane to (_DT_CODE), sound and
+    failing each way, fuzzed: the native verdict of a slab's feeds is
+    the twin's, feed by feed."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    r = random.Random(str(dtype) + backing)
+    faults = [None, "foreign_obj_a", "foreign_ref_a", "ctr_gap",
+              "ctr_not_from_1", "foreign_pred"]
+    top = 100 if np.dtype(dtype).itemsize == 1 else 700
+    fcs = [
+        _gate_feed(r.randrange(2, top), r.choice(faults), dtype, backing)
+        for _ in range(40)
+    ]
+    want = [_twin_verdict(fc) for fc in fcs]
+    assert True in want and False in want
+    for fc, ok in zip(fcs, want):
+        assert columnar._prefix_single_slab([[(fc, 0, INF)]]) == (ok, 1, 0)
+        assert fc._prefix_single_ok is ok
+
+
+@needs_pack
+def test_gate_ctr_too_narrow_for_its_rows_answers_no(monkeypatch):
+    """A ctr plane whose dtype cannot hold the feed's row count (a
+    corrupt sidecar: the writer narrows to what holds the column)
+    answers "no" and raises nothing: its wrapped values are not the
+    dense counters the prefix pack resolves references by."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    for dtype, n in ((np.int8, 200), (np.uint8, 300), (np.int16, 40_000)):
+        fc = _gate_feed(n, "ctr_too_narrow", dtype)
+        assert fc.planes["ctr"].dtype == dtype and fc.n_rows == n
+        assert columnar._prefix_single_slab([[(fc, 0, INF)]]) == (
+            False, 1, 0)
+
+
+class _NoPlanes(dict):
+    def __getitem__(self, name):
+        raise AssertionError(f"the gate read plane {name!r}")
+
+    get = __getitem__
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("how", ["two_feeds", "late_start"])
+def test_gate_mixed_slab_reads_no_plane(where, how, monkeypatch):
+    """One doc of two feeds (or one read from past its start) anywhere
+    in a slab: the structural pass answers, before any plane or pred is
+    read and with no native call."""
+    from hypermerge_tpu.storage.colcache import FeedColumns
+
+    class _NoPreds:
+        def __getattr__(self, name):
+            raise AssertionError(f"the gate read preds.{name}")
+
+    def untouchable():
+        return FeedColumns(
+            rows=None, preds=_NoPreds(), actors=["w"], keys=[], strings=[],
+            floats=[], bigints=[], n_changes=1, ok_prefix_len=1,
+            row_ends=np.asarray([0, 4], np.int64), planes=_NoPlanes(),
+        )
+
+    monkeypatch.setattr(
+        columnar, "_gate_sources",
+        lambda fcs: pytest.fail("the gate described feeds"),
+    )
+    specs = [[(untouchable(), 0, INF)] for _ in range(9)]
+    odd = (
+        [(untouchable(), 0, INF), (untouchable(), 0, INF)]
+        if how == "two_feeds"
+        else [(untouchable(), 1, INF)]
+    )
+    specs[{"first": 0, "middle": 4, "last": 8}[where]] = odd
+    assert columnar._prefix_single_slab(specs) == (False, 0, 0)
+
+
+@needs_pack
+def test_gate_stops_at_the_first_feed_that_fails(monkeypatch):
+    """A slab of single-feed docs of which one fails: the feeds before
+    it are judged and latched, the feeds after it are not (the slab's
+    verdict is all the caller asked for), so the case costs no more
+    than the feed-by-feed gate did."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    fcs = [_gate_feed(30 + i, None, np.int8, "image") for i in range(8)]
+    fcs[3] = _gate_feed(33, "foreign_ref_a", np.int8, "image")
+    specs = [[(fc, 0, INF)] for fc in fcs]
+    assert columnar._prefix_single_slab(specs) == (False, 4, 0)
+    latches = [getattr(fc, "_prefix_single_ok", None) for fc in fcs]
+    assert latches == [True, True, True, False] + [None] * 4
+    # the failed latch answers the next gate with no call at all
+    monkeypatch.setattr(
+        columnar, "_gate_sources",
+        lambda fcs: pytest.fail("a latched failure was judged again"),
+    )
+    assert columnar._prefix_single_slab(specs) == (False, 0, 0)
+
+
+def _gate_counters():
+    from hypermerge_tpu import telemetry
+
+    snap = telemetry.snapshot()
+    return (snap.get("pipeline.pack_gate_native_feeds", 0),
+            snap.get("pipeline.pack_gate_twin_feeds", 0))
+
+
+@pytest.mark.parametrize("arm", ["rows_backed", "native_pack_off", "mixed"])
+def test_gate_twin_arm_is_chosen_from_what_it_sees(arm, tmp_path,
+                                                   monkeypatch):
+    """A rows-backed feed and HM_NATIVE_PACK=0 take the numpy twin and
+    count as twin feeds (batch.gate_feeds, the telemetry counters); a
+    slab of both kinds splits feed by feed; the packed batch is the
+    same bytes whoever judged."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "0" if arm == "native_pack_off"
+                       else "1")
+    has_native = native.pack_lib() is not None
+    histories = [_single_writer_history(s) for s in (21, 22, 23, 24)]
+    imaged = [_plane_cache(tmp_path, f"g{i}", h)
+              for i, h in enumerate(histories)]
+    rows = [next(iter(_rows_feeds(h).values())) for h in histories]
+    if arm == "rows_backed":
+        fcs, want = rows, (0, 4)
+    elif arm == "native_pack_off":
+        fcs, want = [cc.columns() for cc in imaged], (0, 4)
+    else:
+        fcs = [imaged[0].columns(), rows[1], imaged[2].columns(), rows[3]]
+        want = (2, 2) if has_native else (0, 4)
+    specs = [[(fc, 0, INF)] for fc in fcs]
+    before = _gate_counters()
+    batch = pack_docs_columns(specs)
+    after = _gate_counters()
+    assert batch.packed_by == "prefix" and batch.gate_feeds == want
+    assert (after[0] - before[0], after[1] - before[1]) == want
+    assert all(fc._prefix_single_ok is True for fc in fcs)
+    # every feed carries its latch: a second gate judges none
+    again = pack_docs_columns(specs)
+    assert again.gate_feeds == (0, 0) and _gate_counters() == after
+    _assert_batches_identical(batch, again)
+    for fc in fcs:
+        del fc._prefix_single_ok
+    monkeypatch.setenv("HM_NATIVE_PACK", "0")
+    _assert_batches_identical(batch, pack_docs_columns(specs))
+    for cc in imaged:
+        cc.close()
+
+
+@needs_pack
+def test_gate_latches_the_native_verdict_and_calls_once(tmp_path,
+                                                        monkeypatch):
+    """The latch is set from the native verdict, and a second gate over
+    the same feeds makes no native call and builds no table."""
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    caches = [_plane_cache(tmp_path, f"l{s}", _single_writer_history(s))
+              for s in (31, 32, 33)]
+    fcs = [cc.columns() for cc in caches]
+    specs = [[(fc, 0, INF)] for fc in fcs] + [[(fcs[0], 0, INF)]]
+    monkeypatch.setattr(
+        columnar, "_prefix_single_ok",
+        lambda fc: pytest.fail("the twin judged a plane-backed feed"),
+    )
+    assert not any(hasattr(fc, "_prefix_single_ok") for fc in fcs)
+    # a feed two docs share is judged once
+    assert columnar._prefix_single_slab(specs) == (True, 3, 0)
+    assert all(fc._prefix_single_ok is True for fc in fcs)
+    monkeypatch.setattr(
+        columnar, "_gate_sources",
+        lambda fcs: pytest.fail("a latched feed was described again"),
+    )
+    assert columnar._prefix_single_slab(specs) == (True, 0, 0)
+    for cc in caches:
+        cc.close()
+
+
+@needs_pack
+def test_gate_releases_gil(monkeypatch):
+    """hm_prefix_gate runs with the GIL dropped: a second Python thread
+    advances WHILE the native gate of a slab runs (counted inside the
+    calls only, the pattern of test_gather_releases_gil)."""
+    import threading
+    import time
+
+    assert native.pack_drops_gil()
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    fcs = [_gate_feed(2_000_000, None, np.int32, "image", n_preds=50_000)
+           for _ in range(4)]
+    specs = [[(fc, 0, INF)] for fc in fcs]
+    lib = columnar._native_pack_lib()
+    stop = [False]
+    spins = [0]
+
+    def spinner():
+        while not stop[0]:
+            spins[0] += 1
+
+    inside = {"spins": 0, "s": 0.0, "calls": 0}
+
+    class _Timed:
+        def __getattr__(self, name):
+            return getattr(lib, name)
+
+        def hm_prefix_gate(self, *a):
+            n0, t0 = spins[0], time.perf_counter()
+            rc = lib.hm_prefix_gate(*a)
+            inside["s"] += time.perf_counter() - t0
+            inside["spins"] += spins[0] - n0
+            inside["calls"] += rc == 0
+            return rc
+
+    monkeypatch.setattr(columnar, "_native_pack_lib", lambda: _Timed())
+
+    def gate():
+        for fc in fcs:
+            fc.__dict__.pop("_prefix_single_ok", None)
+        assert columnar._prefix_single_slab(specs) == (True, 4, 0)
+
+    gate()  # warm
+    t = threading.Thread(target=spinner, daemon=True)
+    t.start()
+    time.sleep(0.02)
+    inside.update(spins=0, s=0.0, calls=0)
+    t0 = time.perf_counter()
+    while inside["s"] < 0.2 and time.perf_counter() - t0 < 20:
+        gate()
+    stop[0] = True
+    t.join(5)
+    assert inside["calls"] >= 1
+    # a binding that held the GIL would leave the spinner NO iteration
+    # inside the calls (a C call never yields it)
+    assert inside["spins"] > 2_000, (
+        f"spinner starved inside {inside['calls']} native gates "
+        f"({inside['spins']} iterations in {inside['s']:.3f} s): is "
+        "hm_prefix_gate bound with the GIL held?"
+    )
