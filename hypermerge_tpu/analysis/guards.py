@@ -210,7 +210,7 @@ GUARDS: Tuple[GuardedClass, ...] = (
     ),
     GuardedClass(
         "ResidencyCache", "hypermerge_tpu.serve.resident", "serve.cache",
-        guarded=("_entries", "_evicted", "_use"),
+        guarded=("_entries", "_evicted", "_invalidated", "_use"),
         atomic_read_ok=("_bytes",),
         doc="The residency table mutates under serve.cache only "
             "(builds/uploads run outside it); `resident_bytes` is a "

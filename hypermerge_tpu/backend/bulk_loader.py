@@ -73,15 +73,19 @@ SLAB_CELLS = 4096 * 1024
 PRED_ROWS = 4
 
 
-def pack_slab(specs, n_docs: Optional[int] = None) -> columnar.ColumnarBatch:
+def pack_slab(
+    specs, n_docs: Optional[int] = None, n_rows: Optional[int] = None
+) -> columnar.ColumnarBatch:
     """The batch of one slab of doc feed specs, as the loader dispatches
     it (and ops/warmup.py compiles it ahead, `n_docs` given): the doc
     axis at its pow2, so every slab of a rung, and every later bulk
     load, reuses one compiled executable; the rows at the pow2 over the
-    longest doc; the pred axis at least rows / PRED_ROWS."""
+    longest doc, or at `n_rows` (the read tier's installs: their docs'
+    length rung, so that a rung has one program however long the docs
+    of a page happen to be); the pred axis at least rows / PRED_ROWS."""
     if n_docs is None:
         n_docs = columnar.round_up_pow2(len(specs))
-    batch = columnar.pack_docs_columns(specs, n_docs=n_docs)
+    batch = columnar.pack_docs_columns(specs, n_rows=n_rows, n_docs=n_docs)
     n_pred = batch.n_rows // PRED_ROWS
     with telemetry.span("pipeline.pack.widen", "pipeline", P=n_pred):
         return columnar.widen_preds(batch, n_pred)

@@ -89,6 +89,14 @@ class EmissionDomain:
     def held_by_me(self) -> bool:
         return self.doc_id in _stack()
 
+    def wait_out(self, timeout: float) -> None:
+        """If another thread is inside this domain right now, wait (at
+        most `timeout` seconds) until it has left. For a reader of
+        state that an emission moves in two steps (the doc's clock,
+        then its feed): it keeps nothing."""
+        if self._lock.acquire(timeout=timeout):
+            self._lock.release()
+
 
 # ---------------------------------------------------------------------------
 # deferred-emission worker (cross-doc re-entry escape hatch)

@@ -760,16 +760,23 @@ class LiveApplyEngine:
                 # path would. The catch-up may evict the doc to the
                 # host path (range overflow) — the caller retries
                 # host-side.
-                if not self._catch_up_locked(ld):
-                    return None
-                expected = ld.clock.get(req.actor, 0) + 1
-                if req.seq != expected:
-                    raise ValueError(
-                        f"out-of-order local change: seq {req.seq} != "
-                        f"{expected}"
-                    )
-                change, patch = self._apply_local_locked(ld, req)
-                self._sync_doc_meta(ld)
+                # the engine's own local apply (the host twin's is
+                # `live.host.apply_local`): catch-up, intent
+                # resolution, the state and the columns advanced; the
+                # emission (feed append, frontend push) is outside it
+                with telemetry.span(
+                    "live.apply_local", cat="live", ops=len(req.intents)
+                ):
+                    if not self._catch_up_locked(ld):
+                        return None
+                    expected = ld.clock.get(req.actor, 0) + 1
+                    if req.seq != expected:
+                        raise ValueError(
+                            f"out-of-order local change: seq {req.seq} "
+                            f"!= {expected}"
+                        )
+                    change, patch = self._apply_local_locked(ld, req)
+                    self._sync_doc_meta(ld)
                 self._m["local_changes"].add(1)
                 if emit is not None:
                     emit(change, patch)
@@ -891,7 +898,9 @@ class LiveApplyEngine:
                 outcome = status
                 break
         finally:
-            sp.end(outcome=outcome)
+            sp.end(
+                outcome=outcome, rows=0 if ld is None else ld.cols.n
+            )
             with self._lock:
                 self._adopting.pop(doc.id, None)
                 gate.outcome = outcome

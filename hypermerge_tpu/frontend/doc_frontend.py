@@ -48,6 +48,10 @@ class DocFrontend:
         # change() does (unchanged behavior); cross-process (net/ipc.py)
         # later fns queue here instead of running against stale state.
         self._inflight: Optional[int] = None
+        # the thread that is between taking an item off the queue and
+        # having sent it (`_run_queue`): what another thread brings
+        # then queues behind it
+        self._runner: Optional[int] = None
 
     # ------------------------------------------------------------------
 
@@ -104,7 +108,11 @@ class DocFrontend:
         self.poke()
         with self._lock:
             needs_actor = self.mode == "pending" or self.actor_id is None
-            if needs_actor:
+            # behind whatever waits already: changes reach the backend
+            # in the order `change` was called in
+            wait = needs_actor or bool(self._change_queue) or (
+                self._runner not in (None, threading.get_ident()))
+            if wait:
                 self._change_queue.append((fn, message))
         if needs_actor:
             # OUTSIDE self._lock: pushing to the backend queue can make
@@ -117,20 +125,78 @@ class DocFrontend:
             # queue never run concurrently, so the append above is
             # already safely ordered.
             self._repo.needs_actor(self.doc_id)
-            return
-        self._run_change(fn, message)
+        if wait:
+            self._run_queue()
+        else:
+            self._run_change(fn, message)
 
-    def _run_change(self, fn: Callable, message: str) -> None:
+    def in_turn(self, send: Callable[[], None]) -> None:
+        """Send a read of this doc in the order it was asked for: after
+        every change that waits in the queue (no actor yet, an echo
+        outstanding) or is on its way out of it, whose `change` has
+        returned to its caller but whose request the backend has not
+        been sent. `send()` runs now when nothing waits, else on the
+        thread that runs the queue, once what is ahead of it has been
+        sent. One frontend's queue to the backend is first in, first
+        out, so a read never overtakes a change made before it."""
+        with self._lock:
+            # (asked from inside this thread's own run of the queue, a
+            # watch callback: what it follows is already on its way)
+            wait = self._runner != threading.get_ident() and (
+                bool(self._change_queue) or self._runner is not None)
+            if wait:
+                self._change_queue.append((None, send))
+        if wait:
+            self._run_queue()
+        else:
+            send()
+
+    def _run_queue(self) -> None:
+        """Run what waits in the queue, in order, until something stops
+        it: an echo outstanding (its arrival resumes the queue), no
+        Ready yet, a change at the head and no actor. One thread at a
+        time (`_runner`): what arrives meanwhile queues behind and is
+        run by that thread before it leaves."""
+        while True:
+            with self._lock:
+                q = self._change_queue
+                if (
+                    self._runner is not None or not q
+                    or self._inflight is not None
+                    or self.mode == "pending"
+                    or (q[0][0] is not None and self.actor_id is None)
+                ):
+                    return
+                fn, message = q.pop(0)
+                self._runner = threading.get_ident()
+            try:
+                if fn is None:
+                    message()  # a read whose turn has come
+                else:
+                    self._run_change(fn, message, from_queue=True)
+            finally:
+                with self._lock:
+                    self._runner = None
+
+    def _run_change(
+        self, fn: Callable, message: str, from_queue: bool = False
+    ) -> None:
         # `frontend.change` is the whole local change as the caller
         # waits on it (in-process the request is applied before
-        # send_request returns); its child is intent resolution: the
-        # change fn run over a scratch mirror of the doc, O(doc length)
+        # send_request returns, unless another thread is handling the
+        # backend's queue just then); its child is intent resolution:
+        # the change fn run over a scratch mirror of the doc, O(doc
+        # length)
         with telemetry.span("frontend.change", "frontend"):
             with self._lock:
                 if self._inflight is not None:
                     # an echo is outstanding: the committed state this
-                    # fn would read is stale — run it when the echo lands
-                    self._change_queue.append((fn, message))
+                    # fn would read is stale — run it when the echo
+                    # lands (a queued one keeps its place at the head)
+                    if from_queue:
+                        self._change_queue.insert(0, (fn, message))
+                    else:
+                        self._change_queue.append((fn, message))
                     return
                 with telemetry.span(
                     "frontend.change.resolve", "frontend"
@@ -177,11 +243,13 @@ class DocFrontend:
                 self.seq = self.front.clock.get(actor_id, 0) + 1
             self.history = history
             self.mode = "write" if self.actor_id else "read"
-            queued = list(self._change_queue)
-            self._change_queue.clear()
         self._fan_out(self.front.materialize())
-        for fn, message in queued:
-            self._run_change(fn, message)
+        # (a change queued before this Ready on a doc that has no actor
+        # yet, a bulk-opened doc's first write from one thread while
+        # another drains the backend's queue, stays queued: its
+        # NeedsActorId is on its way and on_actor_id runs it; run here
+        # it would be a change of no actor)
+        self._run_queue()
 
     def on_actor_id(self, actor_id: str) -> None:
         with self._lock:
@@ -203,13 +271,9 @@ class DocFrontend:
                 return
             self.seq = self.front.clock.get(actor_id, 0) + 1
             self.mode = "write"
-            queued = list(self._change_queue)
-            self._change_queue.clear()
-        for fn, message in queued:
-            self._run_change(fn, message)
+        self._run_queue()
 
     def on_patch(self, patch_json: Dict, history: int) -> None:
-        queued = None
         with self._lock:
             if self.mode == "pending":
                 # A patch can only precede this doc's Ready in the
@@ -229,22 +293,14 @@ class DocFrontend:
                 and patch.seq == self._inflight
             ):
                 self._inflight = None
-                if self._change_queue:
-                    queued = self._change_queue.pop(0)
             empty = patch.is_empty
         if not empty:
             self._fan_out(self.front.materialize())  # «change final» echo
-        if queued is not None:
-            self._run_change(*queued)
-            # a no-op change fn produces no request and leaves _inflight
-            # unset — keep draining, or the remaining queued changes
-            # would strand until an unrelated patch happened to arrive
-            while True:
-                with self._lock:
-                    if self._inflight is not None or not self._change_queue:
-                        break
-                    nxt = self._change_queue.pop(0)
-                self._run_change(*nxt)
+        # the echo resumes the queue: the next change runs (one per
+        # echo: committed state only advances by echoes), and the reads
+        # that waited behind the one just echoed. A no-op change fn
+        # makes no request and leaves _inflight unset: the queue runs on
+        self._run_queue()
 
     def on_message(self, contents: Any) -> None:
         with self._lock:

@@ -207,7 +207,7 @@ class RepoFrontend:
                     return
                 cb(None if p is None else p.get("value"))
 
-            self._query(msgs.read_query(doc_id, query), on_reply)
+            self._read_in_turn(doc_id, query, on_reply)
             return None
         done = threading.Event()
         slot: list = [None]
@@ -216,7 +216,7 @@ class RepoFrontend:
             slot[0] = payload
             done.set()
 
-        self._query(msgs.read_query(doc_id, query), fin)
+        self._read_in_turn(doc_id, query, fin)
         if not done.wait(timeout):
             raise TimeoutError(f"read of {doc_id[:6]} timed out")
         payload = slot[0]
@@ -225,6 +225,21 @@ class RepoFrontend:
 
             raise overload_error(payload["overload"])
         return None if payload is None else payload.get("value")
+
+    def _read_in_turn(self, doc_id: str, query: Dict, cb) -> None:
+        """A read follows the changes this frontend made to its doc
+        before it, those still waiting for their turn too (a doc this
+        frontend never opened has none)."""
+        with self._lock:
+            df = self.docs.get(doc_id)
+
+        def send() -> None:
+            self._query(msgs.read_query(doc_id, query), cb)
+
+        if df is None:
+            send()
+        else:
+            df.in_turn(send)
 
     def meta(self, url: str, cb: Callable[[Any], None]) -> None:
         _scheme, id_ = validate_url(url)

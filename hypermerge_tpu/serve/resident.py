@@ -154,7 +154,8 @@ def build_group(
     """Build the resident entries of one install group: the docs
     `items` = [(doc_id, clock, feed spec)] of one length rung
     (`bucket` rows), a page of at most PAGE_DOCS[-1] docs at a time.
-    A page is one `pack_slab` (the loader's pack), the three kernel
+    A page is one `pack_slab` (the loader's pack, its rows at the
+    rung), the three kernel
     lanes of all its docs at once (`_memo_lanes` where the bulk
     loader's summary memo holds the doc's clock, else ONE run of the
     slab program), the host decode halves as rows of the page's
@@ -169,8 +170,16 @@ def build_group(
     from .. import telemetry
 
     entries: List[ResidentDoc] = []
+    tier = getattr(backend, "serve", None)
     with telemetry.span(
-        "serve.install", "serve", docs=len(items), rung=bucket
+        "serve.install", "serve", docs=len(items), rung=bucket,
+        # docs of the group that were resident at an older clock (a
+        # write made them stale), and the feeds the pack reads
+        stale=0 if tier is None else sum(
+            1 for doc_id, _c, _s in items
+            if tier._cache.prior_bucket(doc_id) is not None
+        ),
+        feeds=sum(len(spec) for _d, _c, spec in items),
     ) as sp:
         memo = 0
         for at in range(0, len(items), PAGE_DOCS[-1]):
@@ -194,7 +203,12 @@ def _build_page(backend, items, bucket: int, n_docs: int, count):
 
     n_real = len(items)
     with telemetry.span("serve.install.pack", "serve"):
-        batch = pack_slab([spec for _d, _c, spec in items], n_docs)
+        # rows at the rung, not at the pow2 over the page's longest
+        # doc: the slab program of an install follows from (page, rung)
+        # as the query programs do, and is warm once it ran once
+        batch = pack_slab(
+            [spec for _d, _c, spec in items], n_docs, n_rows=bucket
+        )
     cols = batch.cols
     D, N = batch.shape
     n_ops = np.asarray(batch.n_ops[:n_real], np.int64)
@@ -377,6 +391,10 @@ class ResidencyCache:
         self._lock = make_rlock("serve.cache")
         self._entries: "OrderedDict[str, ResidentDoc]" = OrderedDict()
         self._evicted: "OrderedDict[str, None]" = OrderedDict()
+        # doc_id -> row bucket of the entry a write invalidated, until
+        # the doc is installed again (or dropped): what tells a
+        # re-install, and a promotion to the next rung, from a first one
+        self._invalidated: Dict[str, int] = {}
         self._bytes = 0
         self._use = 0
 
@@ -405,6 +423,7 @@ class ResidencyCache:
             old = self._entries.pop(entry.doc_id, None)
             if old is not None:
                 self._bytes -= old.nbytes
+            self._invalidated.pop(entry.doc_id, None)
             self._use += 1
             entry.last_use = self._use
             self._entries[entry.doc_id] = entry
@@ -456,7 +475,18 @@ class ResidencyCache:
                 return False
             e.stale = True
             self._bytes -= e.nbytes
+            self._invalidated[doc_id] = e.bucket
             return True
+
+    def prior_bucket(self, doc_id: str) -> Optional[int]:
+        """The row bucket this doc was resident at before (the entry a
+        write invalidated, or one still held at an older clock); None
+        for a doc not resident since it was opened or evicted."""
+        with self._lock:
+            e = self._entries.get(doc_id)
+            if e is not None:
+                return e.bucket
+            return self._invalidated.get(doc_id)
 
     def drop(self, doc_id: str) -> None:
         with self._lock:
@@ -464,6 +494,7 @@ class ResidencyCache:
             if e is not None:
                 self._bytes -= e.nbytes
             self._evicted.pop(doc_id, None)
+            self._invalidated.pop(doc_id, None)
 
     @property
     def resident_bytes(self) -> int:
@@ -506,4 +537,5 @@ class ResidencyCache:
         with self._lock:
             self._entries.clear()
             self._evicted.clear()
+            self._invalidated.clear()
             self._bytes = 0

@@ -71,6 +71,12 @@ READ_KINDS = ("lookup", "index", "text", "len", "clock", "history")
 
 _MAX_PATH_ROUNDS = 64  # path depth bound (per-level batched dispatches)
 
+# the longest a flush waits for a writer to leave a cold doc's emission
+# domain (clock moved, block not appended yet) before it lets the host
+# path answer: an append is well under a millisecond, a durable ack
+# (HM_ACK_DURABLE) one group commit
+EMISSION_WAIT_S = 1.0
+
 # serve.read_s: six edges a decade, the service plane's SLO (50 ms) and
 # its half among them. The default decades (.., 10 ms, 50 ms, ..) read
 # every read over 10 ms as one AT the SLO (a quantile is its bucket's
@@ -223,6 +229,9 @@ class ServeTier:
         self._host_memo: "OrderedDict[str, tuple]" = OrderedDict()
         self._host_memo_bytes = 0
         self._closed = False
+        # when the flusher last came out of an install (perf_counter):
+        # the reads admitted before it waited behind that install
+        self._install_end = 0.0
         # why the last failed install degraded to the host path (the
         # Telemetry reply carries it: a worker that cannot reach its
         # device says so instead of quietly serving from host)
@@ -245,6 +254,11 @@ class ServeTier:
                 # the text joins of the flushes: reads joined, and the
                 # characters (element rows) they held
                 "text_reads", "text_rows",
+                # what writes cost the tier: reads whose doc had no
+                # fresh entry when their flush looked, installs of a doc
+                # that was resident before, and those of them that went
+                # up a length rung (four times the lanes, other programs)
+                "cold_reads", "reinstalls", "rung_promotions",
             )
         }
         for k in (
@@ -256,10 +270,11 @@ class ServeTier:
             "serve.read_s", buckets=READ_BUCKETS_S, inst=inst
         )
         # the service plane's p99 feed: reads answered from resident
-        # state. A read that paid for its own doc's install is the cold
-        # reader's cost, not pressure: counted as overload it walks the
-        # ladder to BROWNOUT, which defers the very installs that would
-        # end the cold reads
+        # state. A read that paid for its own doc's install, or waited
+        # on this thread behind another's, is the cold reader's cost,
+        # not pressure: counted as overload it walks the ladder to
+        # BROWNOUT, which defers the very installs that would end the
+        # cold reads
         self._hist_warm = reg.histogram(
             "serve.read_warm_s", buckets=READ_BUCKETS_S, inst=inst
         )
@@ -399,6 +414,14 @@ class ServeTier:
         by_doc: Dict[str, List[ReadRequest]] = {}
         for r in reqs:
             by_doc.setdefault(r.doc_id, []).append(r)
+            if r.t0 < self._install_end:
+                # admitted while this thread was installing another
+                # flush's cold docs: it waited for that install, so it
+                # paid an install's cost as the cold reader did, and is
+                # no more pressure than that one (the cure is installs
+                # off this thread, not BROWNOUT, which would hand the
+                # stale docs of a written store to the host twin)
+                r.cold = True
         ready: List[ReadRequest] = []
         cold: List = []  # (doc, clock, reqs) needing an install
         with telemetry.span(
@@ -413,6 +436,7 @@ class ServeTier:
                 clock = doc.clock
                 entry = self._cache.get_fresh(doc_id, clock)
                 if entry is None:
+                    self._m["cold_reads"].add(len(rs))
                     cold.append((doc, clock, rs))
                     continue
                 self._m["hits"].add(len(rs))
@@ -439,6 +463,8 @@ class ServeTier:
         entries = self._install(
             [(doc, clock) for doc, clock, _rs in install]
         )
+        if install:
+            self._install_end = time.perf_counter()
         for doc, _clock, rs in install:
             entry = entries.get(doc.id)
             if entry is None:
@@ -471,6 +497,16 @@ class ServeTier:
         for doc, clock in cold:
             spec = self._back._serveable_spec(clock)
             if spec is None:
+                # a local change moves the doc's clock and then appends
+                # its block, both inside the doc's emission domain: a
+                # flush that looks in between finds the feed one change
+                # short of the clock. That is a writer at work, not a
+                # doc the sidecars cannot serve: once it has left (it
+                # may have, since the look), clock and feed agree again
+                doc.emission.wait_out(EMISSION_WAIT_S)
+                clock = doc.clock
+                spec = self._back._serveable_spec(clock)
+            if spec is None:
                 continue
             docs[doc.id] = doc
             groups.setdefault(rung_of(spec), []).append(
@@ -485,6 +521,11 @@ class ServeTier:
         self._m["installs"].add(len(built))
         evicted = 0
         for doc_id, entry in built.items():
+            prior = self._cache.prior_bucket(doc_id)
+            if prior is not None:
+                self._m["reinstalls"].add(1)
+                if entry.bucket > prior:
+                    self._m["rung_promotions"].add(1)
             if docs[doc_id].clock == entry.clock:  # install-and-recheck
                 evicted += len(self._cache.install(entry))
         if evicted:

@@ -25,6 +25,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..analysis.lockdep import make_rlock
 from ..utils import keys as keymod
 from ..utils.debug import log
@@ -666,10 +667,15 @@ class Feed:
         if not self.writable:
             raise PermissionError(f"feed {self.public_key[:8]} not writable")
         with self._lock:
-            self._storage.append(data)
-            index = len(self._storage) - 1
-            if self.integrity is not None:
-                self.integrity.sign_append(self, index, data)
+            # the block made durable as the tier has it: the log write,
+            # its `.len`, the journal, and the chain's signature
+            with telemetry.span(
+                "storage.feed.append", "storage", bytes=len(data)
+            ):
+                self._storage.append(data)
+                index = len(self._storage) - 1
+                if self.integrity is not None:
+                    self.integrity.sign_append(self, index, data)
             self._prune_sparse_locked()
             listeners = list(self._append_listeners)
             extended = list(self._extend_listeners)

@@ -121,7 +121,9 @@ def test_gate_native_pct_is_data_only_and_lists_the_single_writer_cells():
     whole gate (the stores of single-writer docs) and no other."""
     spec = json.loads((LAYER_METRICS / "pack.gate_native_pct.json").read_text())
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    entry = bench["per_layer"][-1]
+    # (the last entry of PR 35's benchmark; later PRs append after it)
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "pack.gate_native_pct")
     assert entry["name"] == spec["name"] == "pack.gate_native_pct"
     assert spec["reader"] == "bulk_stats"
     assert spec["params"] == {"key": "pack_gate_native_pct"}
@@ -230,7 +232,7 @@ def test_a_store_of_one_length_loads_in_store_order_chunks(
     stats, got, want = _load(tmp_path, ids, slab=3)
     assert got == want
     assert [s[0] for s in seen] == [ids[0:3], ids[3:6], ids[6:7]]
-    assert packs == [{"n_docs": 4}, {"n_docs": 4}, {"n_docs": 1}]
+    assert packs == [{"n_docs": d, "n_rows": None} for d in (4, 4, 1)]
     assert len({s[1][1] for s in seen}) == 1  # one row bucket
     assert stats["slabs"] == 3 and stats["slab_programs"] == 2
     assert stats["slab_shapes"] == tuple(s[1] for s in seen)

@@ -395,7 +395,10 @@ def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
             spec = json.load(fh)
         for key in ("unit", "better", "source", "layer", "moves"):
             assert listed[n][key] == spec[key], (n, key)
-        assert listed[n]["workloads"] == spec["cells"] == ["reads.resident"]
+        # (a later cell that reports the metric is appended to the
+        # benchmark's list; the accepted file's note stays as it was)
+        assert listed[n]["workloads"][:1] == spec["cells"] == [
+            "reads.resident"]
     # the parent: its trace holds program spans but none of these, its
     # counters lack the two
     older = {k: v for k, v in after.items() if "text_" not in k}
