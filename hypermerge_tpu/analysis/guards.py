@@ -210,11 +210,18 @@ GUARDS: Tuple[GuardedClass, ...] = (
     ),
     GuardedClass(
         "ResidencyCache", "hypermerge_tpu.serve.resident", "serve.cache",
-        guarded=("_entries", "_evicted", "_invalidated", "_use"),
+        guarded=("_entries", "_evicted", "_invalidated", "_noted",
+                 "_use"),
         atomic_read_ok=("_bytes",),
         doc="The residency table mutates under serve.cache only "
             "(builds/uploads run outside it); `resident_bytes` is a "
-            "monitoring snapshot read.",
+            "monitoring snapshot read. `_noted` (the local changes an "
+            "entry has yet to follow) is written by the write path's "
+            "hook (`note`) and trimmed by the flush thread (`follow`), "
+            "which makes the entry's new host rows outside the lock "
+            "and swaps them in under it (`note` reads them there); an "
+            "entry's device lanes and tables are the flush thread's "
+            "alone.",
     ),
     GuardedClass(
         "SessionSupervisor", "hypermerge_tpu.net.resilience", "net.sup",

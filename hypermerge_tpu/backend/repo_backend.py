@@ -1110,12 +1110,13 @@ class RepoBackend:
         t = event["type"]
         doc: DocBackend = event["doc"]
         if t in ("LocalPatch", "RemotePatch") and self.serve is not None:
-            # serving invalidation hook: every patch emission — host
-            # paths AND live-engine ticks (_emit_tick notifies through
-            # here) — moves the doc's serving clock, so its resident
-            # read entry can never serve again. Bookkeeping only
-            # (this runs under the emission lock).
-            self.serve.note_clock_moved(doc.id)
+            # serving hook: every patch emission — host paths AND
+            # live-engine ticks (_emit_tick notifies through here) —
+            # moves the doc's serving clock: its resident read entry
+            # notes a local change it can follow in place, and is
+            # released by anything else. Bookkeeping only (this runs
+            # under the emission lock).
+            self.serve.note_clock_moved(doc.id, event)
         if t == "DocReady":
             self._send_ready(doc)
         elif t == "LocalPatch":

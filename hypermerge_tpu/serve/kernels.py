@@ -13,6 +13,13 @@ for the life of the process, pinned by the same trace_counts regression
 mechanism the mesh programs use. Batch axes bucket to pow2 so a varying
 read mix reuses a handful of executables.
 
+One more program writes instead of reading: `advance` applies the
+local changes a resident entry noted to the lanes the device holds, in
+place (donated), so that a written doc is not packed, materialized and
+uploaded again for the one row a local change adds (`_build_advance`
+has the rule; serve/resident.py `ResidencyCache.note` decides what it
+may be asked to apply).
+
 Lane layout (serve/resident.py uploads one stacked [LANES, N] int32
 array per resident doc — a single host->device transfer per install):
 """
@@ -49,6 +56,17 @@ BATCH_BUCKETS = (1, 4, 16, 64, 256)
 MAX_BATCH = BATCH_BUCKETS[-1]
 
 
+# an advance: the local changes a resident entry noted, applied to its
+# lanes in place. One descriptor an op, DELTA_WIDTH int32s: (kind, the
+# op's new row, its object's row (-1: the root map), the element row
+# an insert follows (-1: the head) or a DEL removes, the key index of a
+# SET); one program a row bucket applies ADVANCE_OPS of them a call
+# (unrolled; a short run is padded with D_NOOP, a long one takes calls)
+D_NOOP, D_INSERT, D_SET, D_DEL = 0, 1, 2, 3
+DELTA_WIDTH = 5
+ADVANCE_OPS = 8
+
+
 def batch_bucket(n: int) -> int:
     for b in BATCH_BUCKETS:
         if n <= b:
@@ -62,7 +80,7 @@ def _jnp():
     return jnp
 
 
-def _program(kind: str, B: int, N: int, build):
+def _program(kind: str, B: int, N: int, build, donate=()):
     """A jitted serve program from the shared mesh program table —
     ("serve", kind, B, N) keys sit next to the mesh keys, and
     sharded.trace_counts pins the one-trace contract for both."""
@@ -76,15 +94,15 @@ def _program(kind: str, B: int, N: int, build):
         fn = sharded._traced(key, build())
         rows = N if isinstance(N, int) else "x".join(map(str, N))
         fn.__name__ = f"serve_{kind}_b{B}_n{rows}"
-        return _jit(fn)
+        return _jit(fn, donate)
 
     return sharded._program(key, named)
 
 
-def _jit(fn):
+def _jit(fn, donate=()):
     import jax
 
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate)
 
 
 def stack_entries(entries: Sequence) -> tuple:
@@ -200,6 +218,77 @@ def install_split(lanes, n_docs: int) -> list:
     D, _l, N = lanes.shape
     fn = _program("install_split", D, N, _build_install_split)
     return list(fn(lanes))[:n_docs]
+
+
+def _build_advance():
+    def fn(lanes, desc):
+        """[LANES, N] lanes with the ops `desc` ([K, DELTA_WIDTH])
+        describes applied in order: what the slab program would give
+        for the doc with those ops appended, for an op that is the
+        newest of its doc and whose preds are every visible value of
+        its cell (a local change: backend/live.py `_apply_local_locked`).
+        An inserted element is its parent's greatest child, so it
+        follows its reference at once: it takes the reference's rank
+        and the object's elements from there up move one up (ranks are
+        distinct within an object, higher = earlier; at the head it
+        takes the greatest + 1). A SET wins its (object, key) alone. A
+        DEL leaves its element not live."""
+        import jax
+
+        jnp = _jnp()
+        K, N = desc.shape[0], lanes.shape[1]
+        at_row = jnp.arange(N, dtype=jnp.int32)
+
+        def step(i, lanes):
+            kind, row, obj, ref, key = (desc[i, j] for j in range(5))
+            live, rank, lobj, ins, lkey, mapwin = (
+                lanes[j] for j in (
+                    L_LIVE, L_RANK, L_OBJ, L_INSERT, L_KEY, L_MAPWIN)
+            )
+            is_ins, is_set, is_del = (
+                kind == D_INSERT, kind == D_SET, kind == D_DEL
+            )
+            new = (at_row == row) & (kind != D_NOOP)
+            elems = (lobj == obj) & (ins == 1)
+            slot = jnp.where(
+                ref >= 0, rank[jnp.maximum(ref, 0)],
+                jnp.max(jnp.where(elems, rank, 0)) + 1,
+            )
+            rank = jnp.where(is_ins & elems & (rank >= slot), rank + 1, rank)
+            rank = jnp.where(new, jnp.where(is_ins, slot, 0), rank)
+            live = jnp.where(is_del & (at_row == ref), 0, live)
+            live = jnp.where(new, is_ins.astype(jnp.int32), live)
+            mapwin = jnp.where(
+                is_set & (lobj == obj) & (lkey == key), 0, mapwin
+            )
+            mapwin = jnp.where(new, is_set.astype(jnp.int32), mapwin)
+            out = [None] * N_LANES
+            out[L_LIVE], out[L_RANK], out[L_MAPWIN] = live, rank, mapwin
+            out[L_OBJ] = jnp.where(new, obj, lobj)
+            out[L_INSERT] = jnp.where(new, is_ins.astype(jnp.int32), ins)
+            out[L_KEY] = jnp.where(new, jnp.where(is_set, key, -1), lkey)
+            return jnp.stack(out)
+
+        return jax.lax.fori_loop(0, K, step, lanes, unroll=True)
+
+    return fn
+
+
+def advance(dev, desc: np.ndarray):
+    """`dev` ([LANES, N] device lanes, DONATED: the caller drops its
+    reference) advanced by the ops `desc` ([ops, DELTA_WIDTH] int32)
+    describes: one dispatch an ADVANCE_OPS of them and no fetch (the
+    query programs read the result on the device). The program of a
+    row bucket compiles at the bucket's first advance."""
+    fn = _program(
+        "advance", ADVANCE_OPS, dev.shape[1], _build_advance, donate=(0,)
+    )
+    for i in range(0, len(desc), ADVANCE_OPS):
+        padded = np.zeros((ADVANCE_OPS, DELTA_WIDTH), np.int32)
+        part = desc[i:i + ADVANCE_OPS]
+        padded[: len(part)] = part
+        dev = fn(dev, padded)
+    return dev
 
 
 def _query(kind: str, build, entries: Sequence, *qs) -> tuple:

@@ -21,6 +21,17 @@ cannot rebuild (_serveable_spec None — dirty/unbacked feeds) are never
 installed at all: they stay on the host path rather than risk a stale
 resurrection.
 
+A LOCAL change does not have to cost an entry its place: where the
+change's ops are the newest of their doc and supersede every visible
+value of their cell (backend/live.py `_apply_local_locked`, the host
+OpSet's `apply_local_request`), the lanes after it follow from the
+lanes before it in closed form. `ResidencyCache.note` (the write
+path's hook, bookkeeping under the cache lock) records such a change on
+the entry, resolved to the entry's own rows; the flush thread applies
+what was noted before it serves (`follow` for the host half,
+serve/kernels.py `advance` for the lanes). Everything else takes
+`mark_stale`.
+
 Eviction is a byte-bounded LRU under HM_SERVE_MAX_BYTES; device OOM
 during an install sheds LRU entries and retries once before degrading
 to the host path (serve/tier.py owns those counters).
@@ -30,14 +41,17 @@ from __future__ import annotations
 
 import os
 from collections import OrderedDict
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..analysis.lockdep import make_rlock
-from ..crdt.change import Action
+from ..crdt.change import HEAD, ROOT, Action, Change, Op, OpId
 from . import kernels
-from .kernels import N_LANES, L_INSERT, L_KEY, L_LIVE, L_MAPWIN, L_OBJ, L_RANK
+from .kernels import (
+    D_DEL, D_INSERT, D_SET, N_LANES, L_INSERT, L_KEY, L_LIVE, L_MAPWIN,
+    L_OBJ, L_RANK,
+)
 
 # under the loader's lowest rung (256 rows) every tiny doc shares the
 # 64-row executables and pays for 64 rows of device lanes
@@ -55,9 +69,15 @@ class _Tables:
     whole ColumnarBatch (its [D, N] column dict) in the entry. One per
     install page: the page's docs share it and its key index. `chars`
     is the strings table as an object array: a text read takes all
-    its characters from it at once (serve/tier.py `_join_text`)."""
+    its characters from it at once (serve/tier.py `_join_text`). A
+    page's tables are never written: an entry that follows a local
+    change takes a copy of its own first (`own`) and interns the
+    change's values there. Read and, once owned, written by the flush
+    thread alone."""
 
-    __slots__ = ("strings", "floats", "bigints", "chars")
+    __slots__ = (
+        "strings", "floats", "bigints", "chars", "owned", "_str_index",
+    )
 
     def __init__(self, batch) -> None:
         self.strings = batch.strings
@@ -65,22 +85,61 @@ class _Tables:
         self.bigints = batch.bigints
         self.chars = np.empty(len(batch.strings), object)
         self.chars[:] = batch.strings
+        self.owned = False
+        self._str_index: Optional[Dict[str, int]] = None
+
+    def own(self) -> "_Tables":
+        """These tables if an entry owns them, else a copy it may
+        append to."""
+        if self.owned:
+            return self
+        t = _Tables(self)
+        t.strings, t.floats, t.bigints = (
+            list(self.strings), list(self.floats), list(self.bigints)
+        )
+        t.owned = True
+        return t
+
+    def intern_string(self, s: str) -> int:
+        """REQUIRES `owned`."""
+        if self._str_index is None:
+            self._str_index = {v: i for i, v in enumerate(self.strings)}
+        i = self._str_index.get(s)
+        if i is None:
+            i = self._str_index[s] = len(self.strings)
+            self.strings.append(s)
+            self.chars = np.append(self.chars, np.asarray([s], object))
+        return i
+
+    def intern_float(self, v: float) -> int:
+        """REQUIRES `owned`."""
+        self.floats.append(v)
+        return len(self.floats) - 1
+
+    def intern_bigint(self, v: int) -> int:
+        """REQUIRES `owned`."""
+        self.bigints.append(v)
+        return len(self.bigints) - 1
 
 
 class ResidentDoc:
-    """One doc's device lanes + host decode half, valid at `clock`."""
+    """One doc's device lanes + host decode half, valid at `clock`.
+    The device lanes (`dev`) and the tables are the flush thread's
+    alone; the host half's columns, `actors`, `n` and `clock` are
+    written by it under the cache lock (`ResidencyCache.follow`), where
+    the write path's hook reads them (`ResidencyCache.note`)."""
 
     __slots__ = (
         "doc_id", "clock", "n", "bucket", "dev", "action", "vkind",
-        "value", "dt", "inc_total", "elem_val", "tables", "key_index",
-        "nbytes", "last_use", "stale",
+        "value", "dt", "ctr", "actor", "actors", "inc_total", "elem_val",
+        "tables", "key_index", "nbytes", "last_use", "stale",
     )
 
     def __init__(
         self, doc_id: str, clock: Dict[str, int], n: int, bucket: int,
         dev: Any, host_cols: Dict[str, np.ndarray],
         elem_val: np.ndarray, tables: _Tables,
-        key_index: Dict[str, int],
+        key_index: Dict[str, int], actors: Dict[str, int],
     ) -> None:
         self.doc_id = doc_id
         self.clock = clock
@@ -91,18 +150,89 @@ class ResidentDoc:
         self.vkind = host_cols["vkind"]
         self.value = host_cols["value"]
         self.dt = host_cols["dt"]
+        # the rows' op ids, (ctr[r], the actor `actors` maps to
+        # actor[r]): what resolves the object and element a local
+        # change names to THIS entry's rows (the live engine numbers
+        # its rows otherwise). `actors` is the page's, shared and never
+        # written: an entry that meets a new actor takes a grown copy
+        self.ctr = host_cols["ctr"]
+        self.actor = host_cols["actor"]
+        self.actors = actors
         self.inc_total = host_cols["inc_total"]
         self.elem_val = elem_val  # [n] element row -> winner value row
         self.tables = tables
         self.key_index = key_index
-        # a doc with no INC and no element SET points at its group's
-        # shared zeros / identity rows (base set): not its bytes
-        self.nbytes = int(getattr(dev, "nbytes", 0)) + sum(
-            int(a.nbytes)
-            for a in (*host_cols.values(), elem_val) if a.base is None
-        ) + 512
+        self.nbytes = self._bytes()
         self.last_use = 0
         self.stale = False
+
+    _HOST = (
+        "action", "vkind", "value", "dt", "ctr", "actor", "inc_total",
+        "elem_val",
+    )
+
+    def _bytes(self) -> int:
+        # a doc with no INC and no element SET points at its group's
+        # shared zeros / identity rows (base set): not its bytes
+        return int(getattr(self.dev, "nbytes", 0)) + sum(
+            int(a.nbytes)
+            for a in (getattr(self, k) for k in self._HOST)
+            if a.base is None
+        ) + 512
+
+    def row_of(self, opid: OpId) -> int:
+        """The row of the op `opid`, or -4."""
+        a = self.actors.get(opid.actor)
+        if a is None:
+            return -4
+        hit = np.nonzero((self.ctr == opid.ctr) & (self.actor == a))[0]
+        return int(hit[0]) if len(hit) else -4
+
+    def followed(
+        self, deltas: Sequence[tuple], clock: Dict[str, int]
+    ) -> Dict[str, Any]:
+        """The host half of an advance, beside the entry (which is not
+        written: `ResidencyCache.follow` swaps the result in under its
+        lock): one appended row a delta (the rows `kernels.advance`
+        writes on the device), `n` and `clock`. The change's values go
+        into tables the entry owns (its first follow copies its
+        page's). Flush thread only."""
+        from ..ops.columnar import _encode_value
+
+        t = self.tables.own()
+        actors = self.actors
+        rows: Dict[str, List[int]] = {
+            k: [] for k in ("action", "vkind", "value", "dt", "ctr", "actor")
+        }
+        for _kind, _row, _obj, _ref, _key, op, opid in deltas:
+            vkind, value = _encode_value(
+                op, t.intern_string, t.intern_float, t.intern_bigint
+            )
+            rows["action"].append(int(op.action))
+            rows["vkind"].append(vkind)
+            rows["value"].append(value)
+            rows["dt"].append(
+                1 if op.datatype == "counter"
+                else 2 if op.datatype == "timestamp" else 0
+            )
+            rows["ctr"].append(opid.ctr)
+            if opid.actor not in actors:
+                actors = dict(actors)
+                actors[opid.actor] = len(actors)
+            rows["actor"].append(actors[opid.actor])
+        k = len(deltas)
+        new: Dict[str, Any] = {
+            "tables": t, "actors": actors, "n": self.n + k,
+            "clock": dict(clock),
+        }
+        if k:
+            for name, vals in rows.items():
+                new[name] = _extended(getattr(self, name), vals)
+            new["inc_total"] = _extended(self.inc_total, [0] * k)
+            new["elem_val"] = _extended(
+                self.elem_val, range(self.n, self.n + k)
+            )
+        return new
 
     def obj_type(self, row: int) -> Optional[str]:
         """'map'/'list'/'text'/'table' for a MAKE row, 'map' for the
@@ -112,6 +242,17 @@ class ResidentDoc:
         if row < 0:
             return "map"
         return _OBJ_TYPES.get(int(self.action[row]))
+
+
+def _extended(old: np.ndarray, vals) -> np.ndarray:
+    """`old` with `vals` after it, in `old`'s dtype where they fit (the
+    host half keeps the pack's narrow columns), else int32."""
+    new = np.asarray(list(vals), np.int64)
+    info = np.iinfo(old.dtype)
+    fits = info.min <= int(new.min()) and int(new.max()) <= info.max
+    return np.concatenate(
+        [old, new.astype(old.dtype if fits else np.int32)]
+    )
 
 
 def _to_device(stacked: np.ndarray):
@@ -275,13 +416,16 @@ def _build_page(backend, items, bucket: int, n_docs: int, count):
         ident = np.arange(N, dtype=np.int32)
         tables = _Tables(batch)
         key_index = {k: i for i, k in enumerate(batch.keys)}
+        actors = {a: i for i, a in enumerate(batch.actors)}
+        actor_dt = np.int16 if len(batch.actors) < 2 ** 15 else np.int32
         entries = []
         for d, (doc_id, clock, _spec) in enumerate(items):
             n = int(n_ops[d])
             host_cols = {
                 k: cols[k][d, :n].copy()
-                for k in ("action", "vkind", "value", "dt")
+                for k in ("action", "vkind", "value", "dt", "ctr")
             }
+            host_cols["actor"] = cols["actor"][d, :n].astype(actor_dt)
             if d in full_set:
                 c = {k: np.asarray(cols[k][d, :n], np.int32)
                      for k in ("insert", "key", "ref")}
@@ -296,7 +440,7 @@ def _build_page(backend, items, bucket: int, n_docs: int, count):
                 elem_val = ident[:n]
             entries.append(ResidentDoc(
                 doc_id, dict(clock), n, bucket, devs[d], host_cols,
-                elem_val, tables, key_index,
+                elem_val, tables, key_index, actors,
             ))
     return entries, n_memo
 
@@ -377,6 +521,63 @@ def _memo_lanes(backend, items, n_ops, needs_kernel, lanes, N):
     return served
 
 
+# the local changes an entry may have noted before a read applies them
+MAX_NOTED = 64
+# why a write that met an entry released it (`ResidencyCache.note`'s
+# answers, and "remote": no local change at all: a remote patch, a tick)
+REFUSALS = (
+    "remote", "absent", "clock", "cap", "full", "order", "ref", "shape",
+    "key",
+)
+
+
+class _Noted:
+    """The local changes a resident entry has yet to follow: one delta
+    an op, (kind, new row, object row, element row, key index, op, op
+    id) with the rows the ENTRY's, in the order they were made; `clock`
+    is the doc's clock after the last of them."""
+
+    __slots__ = ("deltas", "clock", "rows", "top")
+
+    def __init__(self, entry: ResidentDoc) -> None:
+        self.deltas: List[tuple] = []
+        self.clock = entry.clock
+        self.rows: Dict[OpId, int] = {}  # the noted ops' rows-to-be
+        self.top = int(entry.ctr.max()) if entry.n else 0  # highest ctr
+
+
+def _delta_of(e: ResidentDoc, noted: _Noted, op: Op, opid: OpId, row: int):
+    """The delta of one op of a local change, or (a str) why the entry
+    cannot follow it. The three shapes whose lanes are closed-form for
+    an op that is the newest of its doc and supersedes every visible
+    value of its cell (serve/kernels.py `advance`): one element
+    inserted, one map key SET, one element deleted."""
+
+    def row_of(target: OpId) -> int:
+        r = noted.rows.get(target)
+        return e.row_of(target) if r is None else r
+
+    obj = -1 if op.obj == ROOT else row_of(op.obj)
+    if obj < -1:
+        return "ref"
+    if op.insert:
+        if op.action != Action.SET or op.key is not None or op.ref is None:
+            return "shape"  # an object made in a list
+        ref = -1 if op.ref == HEAD else row_of(op.ref)
+        return "ref" if ref < -1 else (D_INSERT, row, obj, ref, -1, op, opid)
+    if op.action == Action.SET and op.key is not None and op.ref is None:
+        key = e.key_index.get(op.key)
+        if key is None:
+            return "key"  # the page's key table is shared: no new key
+        return (D_SET, row, obj, -1, key, op, opid)
+    if op.action == Action.DEL and op.key is None and op.ref not in (
+        None, HEAD
+    ):
+        ref = row_of(op.ref)
+        return "ref" if ref < 0 else (D_DEL, row, obj, ref, -1, op, opid)
+    return "shape"  # MAKE, INC, a SET on an element, a DEL of a key
+
+
 class ResidencyCache:
     """doc_id -> ResidentDoc under a byte-bounded LRU. The lock guards
     table bookkeeping only — builds and uploads always run outside it
@@ -395,23 +596,106 @@ class ResidencyCache:
         # the doc is installed again (or dropped): what tells a
         # re-install, and a promotion to the next rung, from a first one
         self._invalidated: Dict[str, int] = {}
+        # doc_id -> the local changes its entry noted and no read has
+        # applied yet (`note` / `follow`); gone with the entry
+        self._noted: Dict[str, _Noted] = {}
         self._bytes = 0
         self._use = 0
 
     def get_fresh(
         self, doc_id: str, clock: Dict[str, int]
-    ) -> Optional[ResidentDoc]:
-        """The serving invalidation check: an entry serves only when
-        its build clock EQUALS the doc's current serving clock and no
-        write marked it stale since."""
+    ) -> Tuple[Optional[ResidentDoc], Optional[tuple]]:
+        """The serving invalidation check, in ONE look: the doc's live
+        entry (None: it has none, or a write marked it stale) and the
+        deltas it noted and has yet to apply, if its clock after them
+        EQUALS `clock`, the doc's current serving clock: it serves
+        then, once the caller has applied them (`follow`: whenever
+        `entry.clock != clock`). Deltas None: the entry is at another
+        clock: a writer has moved the doc's clock and not yet noted its
+        change (it is inside the doc's emission domain), or the entry
+        lost a build race."""
         with self._lock:
             e = self._entries.get(doc_id)
-            if e is None or e.stale or e.clock != clock:
-                return None
+            if e is None or e.stale:
+                return None, None
+            noted = self._noted.get(doc_id)
+            if (e.clock if noted is None else noted.clock) != clock:
+                return e, None
             self._use += 1
             e.last_use = self._use
             self._entries.move_to_end(doc_id)
-            return e
+            return e, () if noted is None else tuple(noted.deltas)
+
+    def note(
+        self, doc_id: str, change: Change, clock: Dict[str, int]
+    ) -> Optional[str]:
+        """A local change moved the doc's clock to `clock`: note it on
+        the doc's entry, for the next read to apply, if the entry can
+        follow it: it is fresh at the clock the change was made at, has
+        a row free for every op, and every op is of a shape `_delta_of`
+        takes. None when noted, else the reason it was not: the caller
+        then releases the entry (`mark_stale`), and with it what it
+        had noted. Dict and list bookkeeping and one compare over the
+        entry's op ids an op."""
+        with self._lock:
+            e = self._entries.get(doc_id)
+            if e is None or e.stale:
+                return "absent"
+            noted = self._noted.get(doc_id) or _Noted(e)
+            was = dict(noted.clock)
+            if was.get(change.actor, 0) != change.seq - 1:
+                return "clock"
+            was[change.actor] = change.seq
+            if was != clock:
+                return "clock"  # the entry missed a change before it
+            n_ops = len(change.ops)
+            if len(noted.deltas) + n_ops > MAX_NOTED:
+                return "cap"
+            row = e.n + len(noted.deltas)
+            if row + n_ops > e.bucket:
+                return "full"
+            if n_ops and change.start_op <= noted.top:
+                return "order"  # not the newest op of its doc
+            deltas = []  # noted whole or not at all: a read may look
+            for i, op in enumerate(change.ops):
+                opid = change.op_id(i)
+                d = _delta_of(e, noted, op, opid, row + i)
+                if isinstance(d, str):
+                    return d
+                deltas.append(d)
+                noted.rows[opid] = row + i
+            noted.deltas.extend(deltas)
+            noted.clock = was
+            noted.top = max(noted.top, change.start_op + n_ops - 1)
+            self._noted[doc_id] = noted
+            return None
+
+    def follow(
+        self, entry: ResidentDoc, deltas: Sequence[tuple],
+        clock: Dict[str, int],
+    ) -> bool:
+        """Apply `deltas`, what `get_fresh` said `entry` had noted up to
+        `clock`, to its host half: the rows, tables and clock are made
+        beside the entry, outside the lock (`followed`), and swapped in
+        under it, where what a writer noted meanwhile stays noted.
+        False, and nothing written, if the entry left the cache since
+        `get_fresh` (a write released it, and what it had noted with
+        it). Called by the flush thread alone, between dispatches,
+        which then advances the lanes (`kernels.advance`)."""
+        new = entry.followed(deltas, clock)
+        with self._lock:
+            noted = self._noted.get(entry.doc_id)
+            if self._entries.get(entry.doc_id) is not entry or noted is None:
+                return False
+            del noted.deltas[: len(deltas)]
+            if noted.clock == clock:
+                del self._noted[entry.doc_id]
+            for k, v in new.items():
+                setattr(entry, k, v)
+            grown = entry._bytes() - entry.nbytes
+            entry.nbytes += grown
+            self._bytes += grown
+            return True
 
     def install(self, entry: ResidentDoc) -> List[ResidentDoc]:
         """Install a built entry (replacing any older clock's entry)
@@ -423,6 +707,7 @@ class ResidencyCache:
             old = self._entries.pop(entry.doc_id, None)
             if old is not None:
                 self._bytes -= old.nbytes
+            self._noted.pop(entry.doc_id, None)
             self._invalidated.pop(entry.doc_id, None)
             self._use += 1
             entry.last_use = self._use
@@ -440,6 +725,7 @@ class ResidencyCache:
     def _note_evicted(self, doc_id: str) -> None:
         """Remember (bounded) that this id was resident once.
         REQUIRES serve.cache (analysis/guards.py)."""
+        self._noted.pop(doc_id, None)
         self._evicted[doc_id] = None
         self._evicted.move_to_end(doc_id)
         while len(self._evicted) > self.EVICTED_REMEMBERED:
@@ -475,6 +761,7 @@ class ResidencyCache:
                 return False
             e.stale = True
             self._bytes -= e.nbytes
+            self._noted.pop(doc_id, None)
             self._invalidated[doc_id] = e.bucket
             return True
 
@@ -493,6 +780,7 @@ class ResidencyCache:
             e = self._entries.pop(doc_id, None)
             if e is not None:
                 self._bytes -= e.nbytes
+            self._noted.pop(doc_id, None)
             self._evicted.pop(doc_id, None)
             self._invalidated.pop(doc_id, None)
 
@@ -538,4 +826,5 @@ class ResidencyCache:
             self._entries.clear()
             self._evicted.clear()
             self._invalidated.clear()
+            self._noted.clear()
             self._bytes = 0

@@ -12,8 +12,20 @@ every live character, as array work over the resident entry's host
 columns (`_join_text`: three takes, ops/columnar.decode_value_rows for
 the whole run, one join — no Python call a character).
 
+A write does not cost its doc its residency: a local change of a
+shape whose lanes are closed-form (one element inserted, one key SET,
+one element deleted) is noted on the doc's entry by the write path's
+hook (`note_clock_moved`) and applied by the next read's flush, on the
+device, to the lanes it already holds (`_advance`: one
+`serve.advance{ops,rung}` span, counters `serve.advance_notes` /
+`serve.advances` / `serve.advance_dispatches`); every other write
+(remote patches, ticks, the shapes and cases `ResidencyCache.note`
+refuses: `serve.advance_refusals{why}`, one series a reason) releases
+the entry and the next read installs the doc again.
+
 A flush is one `serve.batch{reads,cold}` span; below it, also when
-nothing is cold: `serve.batch.attach{docs}` (the residency check),
+nothing is cold: `serve.batch.attach{docs}` (the residency check, and
+under it a `serve.advance` for each entry that had changes noted),
 one `serve.dispatch{kind,B,N}` a query dispatch with its blocking
 `serve.dispatch.fetch` (serve/kernels.py), one `serve.decode{reads,
 rows}` a seq_order group (its text joins and index / path steps; the
@@ -65,7 +77,7 @@ from ..models import Counter, Table, Text
 from ..ops.columnar import VK_STR, decode_value, decode_value_rows
 from ..utils.debug import log
 from .batcher import ReadBatcher, ReadRequest
-from .resident import ResidencyCache, build_group, rung_of
+from .resident import REFUSALS, ResidencyCache, build_group, rung_of
 
 READ_KINDS = ("lookup", "index", "text", "len", "clock", "history")
 
@@ -259,7 +271,17 @@ class ServeTier:
                 # that was resident before, and those of them that went
                 # up a length rung (four times the lanes, other programs)
                 "cold_reads", "reinstalls", "rung_promotions",
+                # entries that follow local changes in place: changes
+                # noted on an entry, ops applied to entries' lanes and
+                # rows, program calls they took
+                "advance_notes", "advances", "advance_dispatches",
             )
+        }
+        # why each write that met an entry released it all the same
+        # (`mark_stale`): ONE counter, labelled by the reason
+        self._refused: Dict[str, Any] = {
+            why: reg.counter("serve.advance_refusals", inst=inst, why=why)
+            for why in REFUSALS
         }
         for k in (
             "resident_docs", "resident_bytes", "resident_device_bytes",
@@ -337,15 +359,29 @@ class ServeTier:
             raise TimeoutError("serve tier read timed out")
         return slot[0]
 
-    def note_clock_moved(self, doc_id: str) -> None:
-        """Write-path invalidation hook (patch emissions, live ticks):
-        the doc's serving clock moved, so its resident entry and host
-        memo row can never serve again. Reads would catch this at
-        their own clock check anyway — the hook makes the invalidation
-        eager and the counter exact. Called under the engine lock:
-        bookkeeping only."""
-        if self._cache.mark_stale(doc_id):
+    def note_clock_moved(self, doc_id: str, event=None) -> None:
+        """Write-path hook (patch emissions, live ticks): the doc's
+        serving clock moved. A local change of a shape the resident
+        entry can follow (serve/resident.py `ResidencyCache.note`) is
+        noted on the entry, and the next read's flush applies it to
+        the lanes the device holds (`_advance`); after anything else (a
+        remote patch, a tick, a full bucket, a new key, ...) the entry
+        can never serve again and is released, as the host memo row
+        always is. Reads would catch a moved clock at their own check
+        anyway — the hook makes the invalidation eager and the counters
+        exact. Called under the doc's emission domain: bookkeeping
+        only."""
+        if event is not None and event["type"] == "LocalPatch":
+            why = self._cache.note(
+                doc_id, event["change"], event["patch"].clock
+            )
+        else:
+            why = "remote"
+        if why is None:
+            self._m["advance_notes"].add(1)
+        elif self._cache.mark_stale(doc_id):
             self._m["invalidations"].add(1)
+            self._refused[why].add(1)
         with self._cache._lock:
             row = self._host_memo.pop(doc_id, None)
             if row is not None:
@@ -365,6 +401,14 @@ class ServeTier:
             rep["last_install_error"] = self._last_install_error
         return rep
 
+    @property
+    def refusals(self) -> Dict[str, int]:
+        """Writes that met an entry and released it, by reason."""
+        return {
+            why: int(c.value()) for why, c in self._refused.items()
+            if c.value()
+        }
+
     def flush_now(self, timeout: float = 5.0) -> bool:
         return self._batcher.flush_now(timeout)
 
@@ -373,7 +417,8 @@ class ServeTier:
         self._batcher.close()
         self._cache.clear()
         telemetry.REGISTRY.retire(
-            *self._m.values(), self._hist, self._hist_warm
+            *self._m.values(), *self._refused.values(), self._hist,
+            self._hist_warm,
         )
 
     # ------------------------------------------------------------------
@@ -434,10 +479,10 @@ class ServeTier:
                         self._finish_raw(r, None)
                     continue
                 clock = doc.clock
-                entry = self._cache.get_fresh(doc_id, clock)
+                entry, behind = self._fresh(doc_id, clock)
                 if entry is None:
                     self._m["cold_reads"].add(len(rs))
-                    cold.append((doc, clock, rs))
+                    cold.append((doc, clock, rs, behind))
                     continue
                 self._m["hits"].add(len(rs))
                 self._attach(entry, rs, ready)
@@ -449,9 +494,19 @@ class ServeTier:
         ready = []
         ctl = getattr(self._back, "overload", None)
         install: List = []
-        for doc, clock, rs in cold:
+        for doc, clock, rs, behind in cold:
             for r in rs:
                 r.cold = True
+            if behind:
+                # a live entry the clock has left behind: a writer is
+                # between its clock move and its note (inside the doc's
+                # emission domain). Once it has left, the entry has the
+                # change noted and follows it: no install
+                doc.emission.wait_out(EMISSION_WAIT_S)
+                entry, _behind = self._fresh(doc.id, doc.clock)
+                if entry is not None:
+                    self._attach(entry, rs, ready)
+                    continue
             if ctl is not None and ctl.defer_install(len(rs)):
                 # brownout: cold installs shed first — the reads
                 # still answer (host memo path), the device install
@@ -476,6 +531,52 @@ class ServeTier:
         if ready:
             self._resolve(ready)
         return len(entries)
+
+    def _fresh(self, doc_id: str, clock: Dict[str, int]) -> tuple:
+        """(the doc's entry if it serves `clock`, with the local
+        changes it noted applied, else None: the doc has to be
+        installed; whether a live entry is there all the same, at
+        another clock)."""
+        entry, deltas = self._cache.get_fresh(doc_id, clock)
+        if entry is None or deltas is None:
+            return None, entry is not None
+        if entry.clock != clock and not self._advance(entry, deltas, clock):
+            return None, False
+        return entry, False
+
+    def _advance(self, entry, deltas, clock: Dict[str, int]) -> bool:
+        """Apply `deltas`, the local changes `entry` noted up to
+        `clock`: its host half's rows (`ResidencyCache.follow`), then
+        the `serve.advance` program over the lanes the device holds,
+        which are donated to it (this thread, the flusher, is their
+        only reader, and is between dispatches). False if a write
+        released the entry since it was looked up, or the device
+        refused (the entry is released): the doc installs as a stale
+        one does."""
+        from . import kernels
+
+        with telemetry.span(
+            "serve.advance", "serve", ops=len(deltas), rung=entry.bucket
+        ):
+            if not self._cache.follow(entry, deltas, clock):
+                return False
+            if not deltas:
+                return True  # a change of no op: the clock alone
+            desc = np.asarray(
+                [d[:kernels.DELTA_WIDTH] for d in deltas], np.int32
+            )
+            try:
+                entry.dev = kernels.advance(entry.dev, desc)
+            except Exception as e:
+                log("serve", f"advance {entry.doc_id[:6]} failed: {e!r}")
+                self._last_install_error = repr(e)[:500]
+                self._cache.mark_stale(entry.doc_id)
+                return False
+            self._m["advances"].add(len(desc))
+            self._m["advance_dispatches"].add(
+                -(-len(desc) // kernels.ADVANCE_OPS)
+            )
+        return True
 
     @staticmethod
     def _attach(entry, rs, ready) -> None:

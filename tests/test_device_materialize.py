@@ -402,7 +402,13 @@ def test_vmem_arm_compiles_for_v5e_and_is_booked_under_rga_order(
         aval((D, N), i16), aval((D, N), i16), aval((D, N), i16),
         aval((D, P), i16), aval((D, P), i16), aval((D, 4), jnp.int32),
     )
-    fn = ck.materialize_full_lean_device.__wrapped__
+    # a function of this test's own: jit's trace cache goes by the
+    # function, and the program's own jit of the lean kernel must not
+    # find, at this shape, the trace made here for a TPU (its Pallas
+    # call not interpreted) when a later test installs such a slab
+    def fn(*args, A, K):
+        return ck.materialize_full_lean_device.__wrapped__(*args, A=A, K=K)
+
     hlo = (
         jax.jit(fn, static_argnames=("A", "K"))
         .lower(*avals, A=4, K=16).compile().as_text()
@@ -440,3 +446,33 @@ def test_vmem_arm_compiles_at_a_slab_of_every_rung(
         .lower(table, table, table, forest).compile().as_text()
     )
     assert 'custom_call_target="tpu_custom_call"' in hlo
+
+
+@pytest.mark.parametrize("n_rows", [1024, 4096, 16384])
+def test_serve_advance_compiles_for_v5e_with_its_lanes_donated(
+    v5e_chip, n_rows
+):
+    """The read tier's `advance` program (ISSUE 38), one a row bucket,
+    at the rungs the docs of the yardstick store live at ([6, 4096]
+    once written) and the one above, compiled for a v5e: the output
+    takes the donated lanes' buffer (`input_output_alias`: 8 x n_rows
+    int32 on the chip, six lanes in a tile of eight rows), so an
+    advance allocates nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from hypermerge_tpu.serve import kernels
+
+    lanes = jax.ShapeDtypeStruct(
+        (kernels.N_LANES, n_rows), jnp.int32, sharding=v5e_chip
+    )
+    desc = jax.ShapeDtypeStruct(
+        (kernels.ADVANCE_OPS, kernels.DELTA_WIDTH), jnp.int32,
+        sharding=v5e_chip,
+    )
+    compiled = (
+        jax.jit(kernels._build_advance(), donate_argnums=(0,))
+        .lower(lanes, desc).compile()
+    )
+    assert "input_output_alias" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes >= 6 * n_rows * 4

@@ -205,26 +205,41 @@ def test_first_write_promotes_a_full_bucket_one_rung(tmp_path, monkeypatch):
         assert {k: serve(k) - v for k, v in c0.items()} == {
             "cold_reads": 1, "reinstalls": 1, "rung_promotions": 1,
             "fallbacks": 0, "install_host_kernel_docs": 0}
-        # a second write stays on the rung: a re-install, no promotion
+        # a second write finds a row free: the entry follows it in
+        # place, nothing is installed
+        a0 = serve("advances")
         repo.change(url, lambda d: d.__setitem__("k1", 7), "u2")
         assert repo.read(url, {"kind": "lookup", "path": ["k1"]}) == 7
+        assert repo.back.serve._cache._entries[doc_id] is entry
+        assert (entry.n, entry.bucket) == (258, 1024)
+        assert serve("advances") - a0 == 1
         assert serve("rung_promotions") - c0["rung_promotions"] == 1
-        assert serve("reinstalls") - c0["reinstalls"] == 2
+        assert serve("reinstalls") - c0["reinstalls"] == 1
         assert serve("resident_device_bytes") - b0 == 6 * 4 * (1024 - 256)
     finally:
         repo.close()
 
 
+@pytest.mark.parametrize("followed", [False, True])
 def test_read_between_clock_move_and_append_waits_for_the_writer(
-    tmp_path, monkeypatch
+    tmp_path, monkeypatch, followed
 ):
     """A local change moves the doc's clock, then appends its block. A
-    read flushed in between must not be answered by the host twin: the
-    flush waits for the writer to leave the doc's emission domain. Each
-    round holds a writer inside that window and flushes a read there."""
+    read flushed in between must not be answered by the host twin. Where
+    the write released the doc's entry, the flush waits for the writer
+    to leave the doc's emission domain and installs; where the entry
+    noted the change (`followed`) it is applied and the read answered,
+    writer or no writer. Each round holds a writer inside that window
+    and flushes a read there."""
     from hypermerge_tpu.backend.actor import Actor
+    from hypermerge_tpu.serve.tier import ServeTier
 
     pin_ladder(monkeypatch)
+    if not followed:  # every write releases the entry, as a remote's does
+        hook = ServeTier.note_clock_moved
+        monkeypatch.setattr(
+            ServeTier, "note_clock_moved",
+            lambda self, doc_id, event=None: hook(self, doc_id))
     job, urls = write_corpus(tmp_path, [group(2, 48)])
     repo = Repo(path=job.path)
     inside = threading.Event()
@@ -266,10 +281,84 @@ def test_read_between_clock_move_and_append_waits_for_the_writer(
             assert got == [n0 + i + 1]
             assert serve("fallbacks") == f0, (
                 i, repo.back.serve.residency_report())
-        assert serve("cold_reads") - w0 == rounds
+        # followed: cold only where the bucket was full (48 rows at
+        # rung 64, then at 256: a promotion each)
+        assert serve("cold_reads") - w0 == (2 if followed else rounds)
     finally:
         go.set()
         repo.close()
+
+
+def test_rehearsal_cell_follows_writes_in_place(tmp_path, monkeypatch):
+    """`rw.ycsb-a` at its rehearsal size through the harness's own
+    cell and driver (ISSUE 38): every check of the driver at 0, writes
+    followed in place (`serve.advances` > 0), no lane from the host
+    kernel, fewer re-installs than writes that met an entry; the new
+    metric file reads the counters' ratio, and nothing from a program
+    without them (the parent)."""
+    import argparse
+    from unittest import mock
+
+    from benchmark import harness
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    args = argparse.Namespace(
+        workload="rw.ycsb-a", seed=SEED, seconds=2.0, trace=0,
+        rehearse=True, control=False, mix=None,
+    )
+    host0 = serve("install_host_kernel_docs")  # the process's, so far
+    with mock.patch.dict(os.environ):
+        pin_ladder(monkeypatch)  # a CPU's p99 is no pressure
+        cell = harness.Cell(args, bench, time.perf_counter())
+        cell.work = str(tmp_path / "run")
+        harness.apply_env(cell)
+        driver = harness.load_module("drivers", cell.mix["driver"])
+        os.makedirs(cell.work)
+        early = driver.before_jax(cell)
+        cell.cache_watch = harness.CacheWatch()
+        state = driver.setup(cell, early)
+        try:
+            before = cell.counters()
+            win = driver.window(cell, state, float(args.seconds))
+            after = cell.counters()
+            checks = driver.verify(cell, state, win)
+        finally:
+            driver.teardown(cell, state)
+    assert win.failed == 0 and win.attempted > 0
+    # (the driver's `lanes_from_host_kernel` reads the process's total:
+    # other tests' installs are in it here)
+    assert [c.name for c in checks if not c.ok] in (
+        [], ["lanes_from_host_kernel"] if host0 else [])
+    assert serve("install_host_kernel_docs") == host0
+    moved = {k: after[k] - before.get(k, 0) for k in after
+             if k.startswith("serve.")}
+    assert moved["serve.advances"] > 0
+    assert moved["serve.advance_dispatches"] > 0
+    assert moved["serve.install_host_kernel_docs"] == 0
+    assert moved["serve.fallbacks"] == moved["serve.flush_errors"] == 0
+    met = moved["serve.advance_notes"] + moved["serve.advance_refusals"]
+    assert moved["serve.reinstalls"] < met
+    assert moved["serve.invalidations"] == moved["serve.advance_refusals"]
+    # the metric: data only, over a reader the benchmark had
+    name = "serve.advance_refusal_share"
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           name + ".json")) as fh:
+        spec = json.load(fh)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert bench["per_layer"][-1] is entry
+    assert spec["reader"] == "counter_ratio"
+    assert entry["workloads"] == spec["cells"] == ["rw.ycsb-a"]
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == spec[key]
+    cell.bench = dict(bench, per_layer=[entry])
+    obs = dict(win.obs, counters_before=before, counters_after=after)
+    got = harness.layer_metrics(cell, obs)
+    assert got[name]["value"] == (
+        moved["serve.advance_refusals"] / met)
+    older = {k: v for k, v in after.items() if "advance" not in k}
+    assert harness.layer_metrics(
+        cell, dict(obs, counters_before=older, counters_after=older)) == {}
 
 
 # -- the frontend's queue, with the backend's messages held ------------------

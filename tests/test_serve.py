@@ -141,10 +141,19 @@ def test_install_then_hits(repo):
 def test_write_invalidates_and_rebuilds(repo):
     url = _seed(repo)
     assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 41
-    inv0 = serve_counter("invalidations")
+    inv0, ins0 = serve_counter("invalidations"), serve_counter("installs")
+    # a write the entry cannot follow (a key its page never saw)
+    repo.change(url, lambda d: d.__setitem__("fresh", 42))
+    assert serve_counter("invalidations") == inv0 + 1
+    assert repo.read(url, {"kind": "lookup", "path": ["fresh"]}) == 42
+    assert serve_counter("installs") == ins0 + 1
+    # one it can: noted at the write, applied by the read, no install
+    adv0 = serve_counter("advances")
     repo.change(url, lambda d: d.__setitem__("n", 42))
     assert serve_counter("invalidations") == inv0 + 1
     assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 42
+    assert serve_counter("installs") == ins0 + 1
+    assert serve_counter("advances") == adv0 + 1
 
 
 def test_byte_budget_evicts_lru(repo, monkeypatch):
@@ -406,9 +415,15 @@ def test_write_releases_resident_bytes(repo):
     assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 41
     b0 = repo.back.serve._cache.resident_bytes
     assert b0 > 0
-    repo.change(url, lambda d: d.__setitem__("n", 99))
+    repo.change(url, lambda d: d.__setitem__("fresh", 99))
     assert repo.back.serve._cache.resident_bytes < b0
+    assert repo.read(url, {"kind": "lookup", "path": ["fresh"]}) == 99
+    # a write the entry follows keeps it, a row's bytes larger
+    b1 = repo.back.serve._cache.resident_bytes
+    repo.change(url, lambda d: d.__setitem__("n", 99))
+    assert repo.back.serve._cache.resident_bytes == b1
     assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 99
+    assert 0 < repo.back.serve._cache.resident_bytes - b1 < 1024
 
 
 # ---------------------------------------------------------------------------

@@ -114,7 +114,7 @@ def test_join_equals_the_per_row_decode(repo, case):
     assert repo.read(url, q) == want
     doc = repo.back.docs[validate_doc_url(url)]
     assert host_read(doc, q)["value"] == want
-    e = repo.back.serve._cache.get_fresh(doc.id, doc.clock)
+    e, _noted = repo.back.serve._cache.get_fresh(doc.id, doc.clock)
     order, n = resolve(e, path)
     assert _join_text(e, order[:n]) == oracle(e, order[:n]) == want
 
