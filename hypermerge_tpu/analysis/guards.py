@@ -138,7 +138,7 @@ GUARDS: Tuple[GuardedClass, ...] = (
     GuardedClass(
         "RepoBackend", "hypermerge_tpu.backend.repo_backend", "repo",
         guarded=("_bulk_deferred_syncs", "_bulk_feed_rows",
-                 "_writer_actors", "_pending_ready"),
+                 "_writer_actors", "_pending_ready", "_unheld"),
         atomic_read_ok=("docs", "actors"),
         init_only=(
             "path", "memory", "durability", "db", "clocks", "cursors",

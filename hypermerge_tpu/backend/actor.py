@@ -56,6 +56,9 @@ class Actor:
         # bulk cold open wants in its parallel prefetch, not in the
         # serial actor-creation loop.
         self._changes: Optional[List[Any]] = None
+        # index of the block write_change is appending right now (its
+        # _on_append has nothing to tell anyone), else -1
+        self._own_write = -1
         self._colcache: FeedColumnCache = feed.colcache or FeedColumnCache(
             MemoryColumnStorage(), writer=self.id
         )
@@ -124,6 +127,7 @@ class Actor:
                 )
                 return
             self.changes.append(change)
+            self._own_write = head  # _on_append runs inside feed.append
             try:
                 self.feed.append(blockmod.pack_change(change.to_json()))
             except BaseException:
@@ -137,6 +141,8 @@ class Actor:
                 if self.feed.length < len(self.changes):
                     self.changes.pop()
                 raise
+            finally:
+                self._own_write = -1
             if self._defer_cache is None:
                 self._sync_cache_locked()
         if self._defer_cache is not None:
@@ -146,6 +152,8 @@ class Actor:
     def _on_append(self, index: int, data: bytes) -> None:
         t0 = time.perf_counter()
         with self._lock:
+            if index == self._own_write:
+                return  # write_change recorded it; its doc applied it
             # the property sizes to the feed head, which already counts
             # this block; a callback racing ahead of a batch that
             # appended earlier indices (listeners fire outside the feed
@@ -153,9 +161,15 @@ class Actor:
             cs = self.changes
             if len(cs) <= index:
                 cs.extend([_UNSET] * (index + 1 - len(cs)))
-            if cs[index] is not _UNSET:
-                return  # our own write_change already recorded it
-            cs[index] = self._parse_block(data, index)
+            if cs[index] is _UNSET:
+                cs[index] = self._parse_block(data, index)
+            # (else a reader decoded the slot between the block's
+            # landing and this callback: the sidecar's deferred sync,
+            # an adoption's columns(), another block's window. None of
+            # them hands the change to a doc, so the block is still
+            # news: a return here lost the ActorSync of a feed's tail,
+            # and its docs stayed one change short until some other
+            # event synced the actor)
             if self._defer_cache is None:
                 self._sync_cache_locked()
             self._pending_dl[0] += len(data)

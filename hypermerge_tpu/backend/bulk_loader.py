@@ -176,7 +176,8 @@ class BulkLoader:
 
       calls   _doc_feed_spec, _get_or_create_actor, _init_bulk_doc,
               _doc_snapshot_fn, _gate_unknown_empty, _load_document,
-              _settle_store_rows, _begin_bulk_actors / _end_bulk_actors
+              _settle_store_rows, _note_unheld,
+              _begin_bulk_actors / _end_bulk_actors
               (the deferred feed rows and actor syncs live on the
               backend, whose actor plumbing fills them), _doc_notify
       stores  id, db, cursors, clocks, feeds, _col_slab, live, and
@@ -272,6 +273,7 @@ class BulkLoader:
             # not bulk-reload from the stale rows (same guard as
             # open/destroy)
             back._settle_store_rows({d.id for d in new_docs})
+            back._note_unheld([d.id for d in new_docs])
             with back.db.bulk():
                 back.cursors.add_actors(
                     back.id,

@@ -1184,10 +1184,10 @@ class LiveApplyEngine:
     # the tick
 
     def _on_tick(self, marked: Dict) -> None:
-        with telemetry.span("live.tick", cat="live"):
+        with telemetry.span("live.tick", cat="live") as sp:
             m = self._m
             kernel_docs: List[_LiveDoc] = []
-            ticked = 0
+            ticked = changes = 0
             for doc_id in list(marked):
                 # GIL-atomic table snapshot: the tick NEVER holds the
                 # engine lock while acquiring a doc's domain (and
@@ -1201,6 +1201,7 @@ class LiveApplyEngine:
                         if self._docs.get(doc_id) is not ld:
                             continue  # demoted/evicted before we got in
                         ld.last_use = self._bump_use()
+                    changes += len(ld.queued)
                     res = self._tick_doc_locked(ld)
                     if res:
                         ticked += 1
@@ -1222,6 +1223,11 @@ class LiveApplyEngine:
                     ).append(ld)
                 for bucket_n, lds in sorted(groups.items()):
                     self._run_group(bucket_n, lds)
+            sp.note(
+                docs=ticked, changes=changes,
+                inc_docs=ticked - len(kernel_docs),
+                kernel_docs=len(kernel_docs),
+            )
             self._enforce_budget()
 
     def _tick_doc_locked(self, ld: _LiveDoc) -> int:

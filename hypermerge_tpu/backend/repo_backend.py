@@ -18,7 +18,7 @@ from __future__ import annotations
 import functools
 import os
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Set
 
 from ..analysis.lockdep import make_rlock, maybe_install_racedep
 from .. import msgs
@@ -246,6 +246,9 @@ class RepoBackend:
         # queries); None outside one
         self._bulk_deferred_syncs: Optional[set] = None
         self._bulk_feed_rows: Optional[List] = None
+        # docs whose latest open found no cursor row of theirs in this
+        # store (_note_unheld; _doc_feed_spec opens them empty)
+        self._unheld: Set[str] = set()
         self.loader = BulkLoader(self)
         # cursor/clock gossip is a latest-state broadcast: debounce it
         # so a burst of local changes to one doc costs one frame
@@ -506,6 +509,7 @@ class RepoBackend:
             # not reload from the stale rows (load reads cursor/clock
             # directly)
             self._settle_store_rows(doc_id)
+            self._note_unheld([doc_id])
             self.cursors.add_actor(self.id, doc_id, root_actor_id(doc_id))
             if not self._load_document_fast(doc):
                 self._load_document(doc)
@@ -607,6 +611,21 @@ class RepoBackend:
             if actor is not None:
                 self._sync_changes(actor)
 
+    def _note_unheld(self, doc_ids: List[str]) -> None:
+        """An open's first step, single or bulk, BEFORE it gives the
+        docs their root's cursor row: remember which of them this store
+        holds no row of. Nothing of such a doc's feeds was ever applied
+        (a clone's docs), so whatever replication stores of them while
+        the load runs is NOT history: a feed's blocks taken for the
+        doc's clock by their count skip the check of each change's
+        deps, and a peer sends a doc's feeds one at a time. The one
+        place that acts on it is _doc_feed_spec (the per-change replay
+        of _load_document checks deps itself)."""
+        unheld = self.cursors.unheld(self.id, doc_ids)
+        with self._lock:
+            self._unheld.difference_update(doc_ids)
+            self._unheld |= unheld
+
     def _doc_feed_spec(
         self,
         doc_id: str,
@@ -619,9 +638,19 @@ class RepoBackend:
         feed's seqs are 1..n — gap-y feeds set ok=False and must take
         the safe per-op replay path). `contiguous` memoizes the per-feed
         verification across docs sharing an actor. Bulk callers pass the
-        pre-fetched `cursor` (one SELECT for the whole load)."""
+        pre-fetched `cursor` (one SELECT for the whole load). A doc the
+        store never held (_note_unheld) gets EMPTY windows: it opens
+        empty, and the sync of its actors after the load (a bulk load's
+        end, _load_document_fast's re-sync) admits what their feeds
+        hold by then change by change (backend/live.py _admit)."""
         if cursor is None:
             cursor = self.cursors.get(self.id, doc_id)
+        with self._lock:
+            unheld = doc_id in self._unheld
+            if unheld and self._bulk_deferred_syncs is not None:
+                self._bulk_deferred_syncs.update(cursor)
+        if unheld:
+            cursor = dict.fromkeys(cursor, 0)
         spec = []
         clock: Dict[str, int] = {}
         n_changes = 0
@@ -997,15 +1026,20 @@ class RepoBackend:
         """Feed caught new blocks: push the admissible window into every
         doc whose cursor includes this actor (reference syncChanges,
         src/RepoBackend.ts:506-531)."""
-        for doc_id in self.cursors.docs_with_actor(self.id, actor.id):
-            doc = self.docs.get(doc_id)
-            if doc is None or not doc.can_apply:
-                continue
-            start = doc.clock.get(actor.id, 0)
-            end = self.cursors.entry(self.id, doc_id, actor.id)
-            window = actor.changes_in_window(start, end)
-            if window:
-                doc.apply_remote_changes(window)
+        with telemetry.span("repo.sync_changes", "repo") as sp:
+            docs = changes = 0
+            for doc_id in self.cursors.docs_with_actor(self.id, actor.id):
+                doc = self.docs.get(doc_id)
+                if doc is None or not doc.can_apply:
+                    continue
+                start = doc.clock.get(actor.id, 0)
+                end = self.cursors.entry(self.id, doc_id, actor.id)
+                window = actor.changes_in_window(start, end)
+                if window:
+                    docs += 1
+                    changes += len(window)
+                    doc.apply_remote_changes(window)
+            sp.note(docs=docs, changes=changes)
 
     # ------------------------------------------------------------------
     # notifications from docs / actors

@@ -274,28 +274,41 @@ class DocFrontend:
         self._run_queue()
 
     def on_patch(self, patch_json: Dict, history: int) -> None:
-        with self._lock:
-            if self.mode == "pending":
-                # A patch can only precede this doc's Ready in the
-                # queue when the backend announced between emitting the
-                # patch and pushing the Ready — and that Ready snapshot
-                # (computed under the live-engine lock, AFTER every
-                # earlier emission) already contains the patch's
-                # effects. Applying it to the blank doc would corrupt
-                # the baseline and silently poison every later patch.
-                return
-            patch = Patch.from_json(patch_json)
-            self.front.apply_patch(patch)
-            self.history = history
-            if (
-                self._inflight is not None
-                and patch.actor == self.actor_id
-                and patch.seq == self._inflight
-            ):
-                self._inflight = None
-            empty = patch.is_empty
-        if not empty:
-            self._fan_out(self.front.materialize())  # «change final» echo
+        sp = telemetry.NOOP
+        try:
+            with self._lock:
+                if self.mode == "pending":
+                    # A patch can only precede this doc's Ready in
+                    # the queue when the backend announced between
+                    # emitting the patch and pushing the Ready — and
+                    # that Ready snapshot (computed under the
+                    # live-engine lock, AFTER every earlier emission)
+                    # already contains the patch's effects. Applying
+                    # it to the blank doc would corrupt the baseline
+                    # and silently poison every later patch.
+                    return
+                patch = Patch.from_json(patch_json)
+                if patch.actor is None:
+                    # no local change's echo: what a peer's changes
+                    # cost here, the handles' new value included
+                    sp = telemetry.begin(
+                        "frontend.remote_patch", "repo",
+                        diffs=len(patch.diffs),
+                    )
+                self.front.apply_patch(patch)
+                self.history = history
+                if (
+                    self._inflight is not None
+                    and patch.actor == self.actor_id
+                    and patch.seq == self._inflight
+                ):
+                    self._inflight = None
+                empty = patch.is_empty
+            if not empty:
+                # «change final» echo
+                self._fan_out(self.front.materialize())
+        finally:
+            sp.end()
         # the echo resumes the queue: the next change runs (one per
         # echo: committed state only advances by echoes), and the reads
         # that waited behind the one just echoed. A no-op change fn

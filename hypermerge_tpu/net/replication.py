@@ -13,7 +13,9 @@ public key BEFORE storage — storage/integrity.py, reference
 src/types/hypercore.d.ts:132-188):
 
   DiscoveryIds {ids}                      full/delta announcement
-  FeedLength   {id, length}               my block count for a shared feed
+  FeedLength   {id, length[, sweep]}      my block count for a shared feed
+                                          (`sweep`: an anti-entropy
+                                          sweep's re-announcement)
   Request      {id, from}                 send me blocks starting at `from`
   RequestRange {id, from, to}             sparse fetch: arbitrary range,
                                           out of order (hypercore's
@@ -88,6 +90,16 @@ def _flush_window_max_s() -> float:
     return float(os.environ.get("HM_REPL_FLUSH_MAX_MS", "25")) / 1e3
 
 
+def _note_frame(sp, msg: Dict) -> None:
+    """A live `net.repl.rx` / `net.repl.tx` span's tags: the Blocks
+    frame's blocks, and their bytes as framed (base64)."""
+    if sp is not telemetry.NOOP:
+        sp.note(
+            blocks=len(msg["blocks"]),
+            bytes=sum(map(len, msg["blocks"])),
+        )
+
+
 def _antientropy_s() -> float:
     """Anti-entropy sweep period (0 disables). The gap-driven protocol
     only recovers a LOST replication frame at the next tail flush or a
@@ -134,6 +146,17 @@ class ReplicationManager:
         # SparseBlocks push (even with valid proofs) must not grow
         # memory on a peer that never requested it
         self._sparse_wanted: Dict[str, Set[int]] = {}
+        # what each peer of this connection told us and what we asked
+        # of it, per feed: `_holds[peer][did]` = the most blocks the
+        # peer said it has (a FeedLength's length, a Blocks frame's
+        # total; a log only grows), so a tail flush skips the peers
+        # that hold the extension already (the one it came from,
+        # first); `_asked[peer][did]` = (from, swept) of our
+        # OUTSTANDING Request: no Blocks frame of the feed came from
+        # the peer since, and `swept` says one of its sweeps found it
+        # standing already (_ask)
+        self._holds: Dict[NetworkPeer, Dict[str, int]] = {}
+        self._asked: Dict[NetworkPeer, Dict[str, tuple]] = {}
         # churn accounting: a peer re-activating after a close is a
         # RESYNC (the supervised redial restored it); t_resync_ms sums
         # redial -> first post-reconnect replication data frame.
@@ -147,6 +170,7 @@ class ReplicationManager:
             for k in (
                 "resyncs", "t_resync_ms", "antientropy_sweeps",
                 "frames_tx", "frames_rx",
+                "blocks_rx", "bytes_rx", "feeds_synced", "unsigned_rx",
             )
         }
         self._seen_closed: Set[str] = set()
@@ -238,6 +262,8 @@ class ReplicationManager:
                 self._verified.remove(did, peer)
             self._challenge_local.pop(peer, None)
             self._challenge_remote.pop(peer, None)
+            self._holds.pop(peer, None)
+            self._asked.pop(peer, None)
 
     def announce(self, feed: Feed) -> None:
         """A newly created/opened feed: tell every connected peer
@@ -297,7 +323,8 @@ class ReplicationManager:
                 self._on_discovery_ids(peer, list(msg["ids"]))
             elif t == "FeedLength":
                 self._on_feed_length(
-                    peer, msg["id"], int(msg["length"]), msg.get("cap")
+                    peer, msg["id"], int(msg["length"]), msg.get("cap"),
+                    bool(msg.get("sweep")),
                 )
             elif t == "Request":
                 self._on_request(
@@ -322,15 +349,17 @@ class ReplicationManager:
                     list(msg["proofs"]),
                 )
             elif t == "Blocks":
-                self._on_blocks(
-                    peer,
-                    msg["id"],
-                    int(msg["from"]),
-                    list(msg["blocks"]),
-                    int(msg.get("len", -1)),
-                    msg.get("sig"),
-                    int(msg.get("total", -1)),
-                )
+                with telemetry.span("net.repl.rx", "net") as sp:
+                    _note_frame(sp, msg)
+                    self._on_blocks(
+                        peer,
+                        msg["id"],
+                        int(msg["from"]),
+                        list(msg["blocks"]),
+                        int(msg.get("len", -1)),
+                        msg.get("sig"),
+                        int(msg.get("total", -1)),
+                    )
         except (KeyError, TypeError, ValueError) as e:
             log("replication", f"malformed msg from {peer.id[:6]}: {e}")
 
@@ -382,6 +411,51 @@ class ReplicationManager:
                 feed.public_key, challenge, binding, we_are_client
             ),
         }
+
+    def _ask(
+        self, feed: Feed, peer: NetworkPeer, start: int,
+        sweep: bool = False,
+    ) -> None:
+        """Request `feed`'s blocks from `start` of `peer`, unless that
+        very Request is outstanding. A feed's length reaches us several
+        times (the capability reply, the peer's own announcement, its
+        answer to our proof, every anti-entropy sweep), and a Request
+        for each would cost the peer the whole chunk again. A Request
+        stands until a Blocks frame of the feed comes from the peer:
+        its reply, or a tail that overtook it (_on_blocks forgets it on
+        both, so a gap asks again at once, whatever else the peer is
+        sending). A reply that is lost with no tail behind it is the
+        sweep's to recover (`sweep`: the announcement is a sweep's):
+        the first sweep that finds the Request standing lets it stand,
+        since the reply may only be queued (a connection's frames come
+        in order, and a peer a whole store is asking of answers for
+        many seconds; repeating every queued Request at every sweep
+        made a clone's seconds depend on where the sweeps fell); the
+        second repeats it."""
+        did = feed.discovery_id
+        with self._lock:
+            asked = self._asked.setdefault(peer, {})
+            prev = asked.get(did)
+            if prev is not None and prev[0] == start and not (
+                sweep and prev[1]
+            ):
+                if sweep:
+                    asked[did] = (start, True)
+                return
+            asked[did] = (start, False)
+        msg = self._request_msg(feed, peer, start)
+        if msg is not None:
+            self._send(peer, msg)
+        else:
+            with self._lock:  # nothing went out: nothing to wait for
+                if asked.get(did) == (start, False):
+                    del asked[did]
+
+    def _note_holds(self, peer: NetworkPeer, did: str, n: int) -> None:
+        with self._lock:
+            holds = self._holds.setdefault(peer, {})
+            if n > holds.get(did, 0):
+                holds[did] = n
 
     def _check_cap(
         self, peer: NetworkPeer, feed: Feed, cap
@@ -440,21 +514,21 @@ class ReplicationManager:
                 self._send(peer, msg)
 
     def _on_feed_length(
-        self, peer: NetworkPeer, did: str, their_len: int, cap
+        self, peer: NetworkPeer, did: str, their_len: int, cap,
+        sweep: bool = False,
     ) -> None:
         feed = self.feeds.by_discovery_id(did)
         if feed is None:
             return
         if not self._check_cap(peer, feed, cap):
             return
+        self._note_holds(peer, did, their_len)
         if feed.length < their_len:
-            msg = self._request_msg(feed, peer, feed.length)
+            self._ask(feed, peer, feed.length, sweep)
         elif feed.length > their_len:
             msg = self._feed_length_msg(feed, peer)
-        else:
-            return
-        if msg is not None:
-            self._send(peer, msg)
+            if msg is not None:
+                self._send(peer, msg)
 
     def _pick_boundary(self, feed: Feed, start: int) -> int:
         """End of the next backfill chunk, bounded in BLOCKS and BYTES
@@ -531,8 +605,11 @@ class ReplicationManager:
             return  # no key knowledge proven: no data
         if start >= feed.length:
             return
-        end = self._pick_boundary(feed, start)
-        self._send(peer, self._blocks_msg(feed, did, start, end))
+        with telemetry.span("net.repl.tx", "net") as sp:
+            end = self._pick_boundary(feed, start)
+            msg = self._blocks_msg(feed, did, start, end)
+            _note_frame(sp, msg)
+            self._send(peer, msg)
 
     def _on_blocks(
         self,
@@ -553,14 +630,19 @@ class ReplicationManager:
         # _feed_length_msg deliberately conceals from peers that haven't
         # proven key knowledge
         verified = peer in self._verified.get(did)
+        with self._lock:
+            # our Request of this feed is answered, or overtaken by a
+            # tail: the next one goes out (_ask)
+            self._asked.get(peer, {}).pop(did, None)
+        if verified:
+            self._note_holds(peer, did, max(total, start + len(blocks)))
         if start > feed.length:
             # gap: re-request from our actual head
             if verified:
-                msg = self._request_msg(feed, peer, feed.length)
-                if msg is not None:
-                    self._send(peer, msg)
+                self._ask(feed, peer, feed.length)
             return
         raw = [base64.b64decode(b) for b in blocks]
+        had = feed.length
         if sig_b64 is not None and length >= 0:
             ok = feed.append_verified(
                 start, raw, length, base64.b64decode(sig_b64)
@@ -579,6 +661,7 @@ class ReplicationManager:
                 if index < feed.length:
                     continue  # duplicate
                 feed._append_raw(b)
+                self._m["unsigned_rx"].add(1)
         else:
             log(
                 "replication",
@@ -587,11 +670,15 @@ class ReplicationManager:
                 "to accept legacy feeds)",
             )
             return
+        if feed.length > had:
+            self._m["blocks_rx"].add(feed.length - had)
+            self._m["bytes_rx"].add(sum(map(len, raw[-(feed.length - had):])))
+            if feed.length >= total:
+                # the sender's head reached: this stream is done
+                self._m["feeds_synced"].add(1)
         if total > feed.length and verified:
             # ack-paced stream: pull the next chunk
-            msg = self._request_msg(feed, peer, feed.length)
-            if msg is not None:
-                self._send(peer, msg)
+            self._ask(feed, peer, feed.length)
 
     def request_range(
         self, discovery_id: str, start: int, end: int
@@ -767,14 +854,20 @@ class ReplicationManager:
 
     def _flush_feed(self, feed: Feed, start: int) -> None:
         did = feed.discovery_id
-        peers = self.peers_with_feed(did)
+        head = feed.length
+        with self._lock:
+            # not to a peer that told us it holds these blocks already
+            # (in a backfill: the one they came from)
+            peers = [
+                p for p in self.peers_with_feed(did)
+                if self._holds.get(p, {}).get(did, 0) < head
+            ]
         if self._sampler is not None:
             # bounded fanout: the tail rides to a sampled subset; the
             # rest converge via relay hops and the anti-entropy sweep
             peers = self._sampler.sample(did, peers)
         if not peers:
             return
-        head = feed.length
         while start < head:
             # _pick_boundary keeps each frame inside the chunk block +
             # byte budgets even when a window coalesced a huge range
@@ -796,9 +889,11 @@ class ReplicationManager:
                     if msg is not None:
                         self._send(peer, msg)
                 return
-            payload = self._blocks_msg(feed, did, start, end)
-            for peer in peers:
-                self._send(peer, payload)
+            with telemetry.span("net.repl.tx", "net") as sp:
+                payload = self._blocks_msg(feed, did, start, end)
+                _note_frame(sp, payload)
+                for peer in peers:
+                    self._send(peer, payload)
             start = end
 
     def flush_now(self, timeout: float = 5.0) -> bool:
@@ -833,7 +928,9 @@ class ReplicationManager:
         missed a SAMPLED cursor gossip (the bounded-fanout relay,
         net/discovery/gossip.py — a one-shot broadcast a peer wasn't
         sampled into would otherwise be lost forever) requests the gap
-        within one sweep period. Returns frames sent."""
+        within one sweep period; a peer whose Request for the gap is
+        standing (the Request or its reply was lost) repeats it at the
+        second sweep (_ask). Returns frames sent."""
         with self._lock:
             peers = list(self._peers)
         sent = 0
@@ -856,6 +953,7 @@ class ReplicationManager:
                     continue
                 msg = self._feed_length_msg(feed, peer)
                 if msg is not None:
+                    msg["sweep"] = True
                     self._send(peer, msg)
                     sent += 1
             if self.on_sweep is not None and pks:

@@ -704,17 +704,29 @@ class Feed:
             eff = blocks[have - start :]
             if have + len(eff) != length:
                 return False
-            res = self.integrity.verify_extension(
-                self, have, eff, length, sig
-            )
+            # signature + merkle, before anything is stored
+            with telemetry.span(
+                "net.repl.verify", "net", blocks=len(eff)
+            ):
+                res = self.integrity.verify_extension(
+                    self, have, eff, length, sig
+                )
             if res is None:
                 return False
             root, new_leaves = res
             indices = []
-            for b in eff:
-                self._storage.append(b)
-                indices.append(len(self._storage) - 1)
-            self.integrity.record_verified(length, root, sig, new_leaves)
+            # as a local append's span: the log writes, their `.len`,
+            # and the chain's record
+            with telemetry.span(
+                "storage.feed.append", "storage",
+                bytes=sum(map(len, eff)), blocks=len(eff),
+            ):
+                for b in eff:
+                    self._storage.append(b)
+                    indices.append(len(self._storage) - 1)
+                self.integrity.record_verified(
+                    length, root, sig, new_leaves
+                )
             self._prune_sparse_locked()
             listeners = list(self._append_listeners)
             extended = list(self._extend_listeners)

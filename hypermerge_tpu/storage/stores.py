@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from ..analysis.lockdep import make_rlock
 from ..crdt import clock as clockmod
@@ -388,6 +388,14 @@ class CursorStore:
         with self._lock:
             mem = self._repo(repo_id)
             return {d: dict(mem.get(d, {})) for d in ids}
+
+    def unheld(self, repo_id: str, doc_ids: Iterable[str]) -> Set[str]:
+        """Those of `doc_ids` this repo holds no cursor row of: docs it
+        never opened, so nothing of their feeds was ever applied."""
+        self._ensure_hydrated(repo_id)
+        with self._lock:
+            mem = self._repo(repo_id)
+            return {d for d in doc_ids if not mem.get(d)}
 
     def docs_with_actor(self, repo_id: str, actor_id: str) -> List[str]:
         self._ensure_hydrated(repo_id)

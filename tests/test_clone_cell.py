@@ -1,0 +1,741 @@
+"""The store cloned from a peer (ISSUE 40, `clone-1k3a` / `sync.clone`)
+at rehearsal size, on the CPU: the cell's rehearsal and its control; a
+cloned document held to `benchmark/reference/clone_plain.py` (which
+imports nothing of the program) and to the host OpSet whatever order
+its feeds arrive in; a lost `Blocks` frame and what recovers it; the
+clone reopened with no swarm; the spans and counters the cell's
+per-layer metrics read, and each new metric file on a hand-worked
+`obs`. Counts and states only; every wait has a limit of its own.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+from types import SimpleNamespace
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import harness  # noqa: E402
+from benchmark.corpora import multi_writer_rounds as mwr  # noqa: E402
+from benchmark.readers import span_tree  # noqa: E402
+from benchmark.reference import clone_plain  # noqa: E402
+from benchmark.reference.plainify import plain  # noqa: E402
+from hypermerge_tpu import telemetry  # noqa: E402
+from hypermerge_tpu.crdt.clock import INFINITY_SEQ  # noqa: E402
+from hypermerge_tpu.net import faults  # noqa: E402
+from hypermerge_tpu.net.tcp import TcpSwarm  # noqa: E402
+from hypermerge_tpu.repo import Repo  # noqa: E402
+
+SEEDS = (2147483659, 2147483693, 7)  # two over 2**31, as the driver's
+OPS, PER_CHANGE, DOCS = 192, 16, 4
+CHANGES = OPS // PER_CHANGE
+WAIT_S = 60.0
+
+
+def spec(docs=DOCS):
+    return {"writer": "multi_writer_rounds", "sign": True, "ops": OPS,
+            "ops_per_change": PER_CHANGE, "seq_frac": 0.85,
+            "del_frac": 0.1, "n_keys": 10, "seq_key": "t", "distinct": 2,
+            "classes": [{"writers": 3, "count": docs}]}
+
+
+class Store:
+    """Peer A: the corpus on disk, cold-opened, behind a TcpSwarm."""
+
+    def __init__(self, path, seed):
+        self.path = str(path)
+        self.job = mwr.CorpusJob(self.path, spec(), seed, 2)
+        try:
+            self.urls = self.job.start().finish()
+        except BaseException:
+            self.job.abort()
+            raise
+        self.repo = Repo(path=self.path)
+        self.swarm = TcpSwarm()
+        self.repo.set_swarm(self.swarm)
+        self.repo.open_many(self.urls)
+        self.repo.back.fetch_bulk_summaries()
+        self.keys = [[p.public_key for p in pairs]
+                     for pairs in self.job.pairs]
+        self._refs = {}
+
+    def ref(self, doc):
+        if doc not in self._refs:
+            self._refs[doc] = clone_plain.expect(
+                self.job.doc_changes(doc, {}), "t")
+        return self._refs[doc]
+
+    def opset_value(self, doc):
+        from hypermerge_tpu.crdt.change import Change
+        from hypermerge_tpu.crdt.opset import OpSet
+
+        opset = OpSet()
+        opset.apply_changes(
+            [Change.from_json(c) for c in self.job.doc_changes(doc, {})])
+        return plain(opset.materialize())
+
+    def feed(self, key):
+        """(blocks, {length: signature}) of one of A's feeds."""
+        feed = self.repo.back.feeds.open_feed(key)
+        blocks = feed.get_batch(0, feed.length)
+        sigs = {n: feed.integrity.record_for(feed, n)[2]
+                for n in range(1, len(blocks) + 1)}
+        return blocks, sigs
+
+    def close(self):
+        self.repo.close()
+
+
+@pytest.fixture(scope="module", params=SEEDS)
+def store(request, tmp_path_factory):
+    s = Store(tmp_path_factory.mktemp("a") / "repo", request.param)
+    yield s
+    s.close()
+
+
+def await_all(handles, changes=CHANGES, limit=WAIT_S):
+    """Subscribe every handle; True once each delivered `changes`."""
+    left = set(range(len(handles)))
+    lock, done = threading.Lock(), threading.Event()
+
+    def watch(i):
+        def on_value(_value, index):
+            if index >= changes:
+                with lock:
+                    left.discard(i)
+                    if not left:
+                        done.set()
+        return on_value
+
+    for i, h in enumerate(handles):
+        h.subscribe(watch(i))
+    return lambda limit=limit: done.wait(limit)
+
+
+def clone(path, store, swarm=None):
+    """B: a fresh repo on an empty directory, connected to A, every url
+    opened. -> (repo, handles, wait)."""
+    repo = Repo(path=str(path))
+    swarm = swarm or TcpSwarm()
+    repo.set_swarm(swarm)
+    swarm.connect(store.swarm.address)
+    handles = repo.open_many(store.urls)
+    return repo, handles, await_all(handles)
+
+
+# -- the cell's rehearsal and its control ------------------------------------
+
+
+def run_cell(*more):
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "sync.clone", "--seed", str(SEEDS[0]),
+         "--seconds", "2", "--trace", "0", "--rehearse", *more],
+        capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    try:
+        return json.loads(out.stdout.strip().splitlines()[-1])
+    except (IndexError, ValueError):
+        raise AssertionError(out.stderr[-2000:])
+
+
+@pytest.mark.parametrize("control", (False, True), ids=("sound", "control"))
+def test_rehearsal_of_the_cell(control):
+    """The sound rehearsal is `correct`; under the mix's control
+    (`HM_LIVE=0`) exactly `docs_not_live` fails."""
+    line = run_cell(*(["--control"] if control else []))
+    bad = [c["name"] for c in line["checks"] if c["value"] > c["limit"]]
+    assert line["metrics"] == {} and line["failed"] == 0
+    assert line["attempted"] % 24 == 0 and line["attempted"] >= 24
+    if control:
+        assert line["correct"] is False and bad == ["docs_not_live"]
+    else:
+        assert line["correct"] is True and bad == []
+        rounds = line["setup"]["rounds"]
+        assert rounds and all(r["live.adopted"] == 24 for r in rounds)
+        assert line["counts"]["compile"]["window"]["requests"] == 0
+
+
+# -- a cloned document, whatever order its feeds arrive in -------------------
+
+ORDERS = ("root_first", "root_last", "interleaved")
+
+
+def deliveries(order, lengths):
+    """[(feed, start, end)]: the extents of each feed in arrival
+    order. Feed 0 is the root actor's (change 0 makes the text)."""
+    whole = [(f, 0, n) for f, n in enumerate(lengths)]
+    if order == "root_first":
+        return whole
+    if order == "root_last":
+        return whole[::-1]
+    out, at = [], [0] * len(lengths)
+    f = 1
+    while any(a < n for a, n in zip(at, lengths)):
+        if at[f] < lengths[f]:
+            out.append((f, at[f], at[f] + 1))
+            at[f] += 1
+        f = (f + 1) % len(lengths)
+    return out
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_arrival_order_does_not_matter(store, tmp_path, order):
+    """B learns a doc's three actors (as A's cursor gossip tells it) and
+    receives their feeds' signed extents in the given order, each
+    verified before storage: the doc ends equal to `clone_plain` and to
+    the host OpSet, live-managed."""
+    repo = Repo(path=str(tmp_path / "b"))
+    try:
+        for doc, url in enumerate(store.urls):
+            keys = store.keys[doc]
+            handle = repo.open(url)
+            wait = await_all([handle])
+            feeds = [store.feed(k) for k in keys]
+            repo.back.on_cursor_message(
+                SimpleNamespace(id="peer-a"), keys[0],
+                dict.fromkeys(keys, INFINITY_SEQ),
+                {k: len(blocks) for k, (blocks, _s) in zip(keys, feeds)},
+            )
+            for f, start, end in deliveries(
+                order, [len(blocks) for blocks, _s in feeds]
+            ):
+                blocks, sigs = feeds[f]
+                feed = repo.back.feeds.open_feed(keys[f])
+                assert feed.append_verified(
+                    start, blocks[start:end], end, sigs[end])
+            assert wait(), f"doc {doc} short of its {CHANGES} changes"
+            got = plain(handle.value(timeout=WAIT_S))
+            assert got == store.ref(doc)["value"]
+            assert got == store.opset_value(doc)
+        stats = repo.back.live.stats
+        assert stats["adopted"] >= DOCS and stats["refused"] == 0
+    finally:
+        repo.close()
+
+
+@pytest.mark.parametrize("how", ("open_many", "open"))
+def test_blocks_that_land_while_the_doc_opens(
+    store, tmp_path, monkeypatch, how
+):
+    """The root's WHOLE feed is stored while B is still opening the doc
+    (a peer that answers before `open_many` is through; on the chip
+    machine the first docs of every round). Its later changes depend on
+    the other writers' and must wait for them: on the parent the open
+    took the feed's blocks for history by their count (clock
+    {root: every block}, no change's deps looked at), and the other
+    feeds' changes, applied one op at a time on that state, left the
+    doc's value wrong for good with the right clock."""
+    from hypermerge_tpu.backend import bulk_loader, repo_backend
+
+    repo = Repo(path=str(tmp_path / "b"))
+    feeds = [[store.feed(k) for k in keys] for keys in store.keys]
+
+    def deliver(doc, f, start, end):
+        blocks, sigs = feeds[doc][f]
+        feed = repo.back.feeds.open_feed(store.keys[doc][f])
+        assert feed.append_verified(start, blocks[start:end], end, sigs[end])
+
+    def roots_land():
+        for doc in range(DOCS if how == "open_many" else 1):
+            deliver(doc, 0, 0, len(feeds[doc][0][0]))
+
+    try:
+        if how == "open_many":
+            opened = bulk_loader.BulkLoader._open_feeds
+
+            def open_feeds(self, docs, cursor_map):
+                opened(self, docs, cursor_map)
+                roots_land()
+
+            monkeypatch.setattr(
+                bulk_loader.BulkLoader, "_open_feeds", open_feeds)
+            handles = repo.open_many(store.urls)
+        else:
+            spec_of = repo_backend.RepoBackend._doc_feed_spec
+
+            def feed_spec(self, doc_id, contiguous, cursor=None):
+                for actor_id in self.cursors.get(self.id, doc_id):
+                    self._get_or_create_actor(actor_id)
+                roots_land()
+                return spec_of(self, doc_id, contiguous, cursor)
+
+            monkeypatch.setattr(
+                repo_backend.RepoBackend, "_doc_feed_spec", feed_spec)
+            handles = [repo.open(store.urls[0])]
+        monkeypatch.undo()
+        wait = await_all(handles)
+        for doc in range(len(handles)):
+            keys = store.keys[doc]
+            repo.back.on_cursor_message(
+                SimpleNamespace(id="peer-a"), keys[0],
+                dict.fromkeys(keys, INFINITY_SEQ),
+                {k: len(b) for k, (b, _s) in zip(keys, feeds[doc])},
+            )
+            # the other two in thirds, a tick apart: small ticks, each
+            # applied one op at a time on the state the open left
+            n = len(feeds[doc][1][0])
+            for a, b in ((0, n // 3), (n // 3, 2 * n // 3), (2 * n // 3, n)):
+                deliver(doc, 1, a, b)
+                deliver(doc, 2, a, b)
+                time.sleep(0.1)
+        assert wait()
+        for doc, h in enumerate(handles):
+            assert plain(h.value(timeout=WAIT_S)) == store.ref(doc)["value"]
+        stats = repo.back.live.stats
+        assert stats["adopted"] >= len(handles) and stats["refused"] == 0
+    finally:
+        repo.close()
+
+
+def test_unverified_extension_stores_nothing(store, tmp_path):
+    """A block that is not the signed one is refused whole."""
+    repo = Repo(path=str(tmp_path / "b"))
+    try:
+        key = store.keys[0][1]
+        blocks, sigs = store.feed(key)
+        feed = repo.back.feeds.open_feed(key)
+        forged = [blocks[0][:-1] + bytes([blocks[0][-1] ^ 1])]
+        assert not feed.append_verified(0, forged, 1, sigs[1])
+        assert feed.length == 0
+        assert feed.append_verified(0, blocks[:1], 1, sigs[1])
+        assert feed.length == 1
+    finally:
+        repo.close()
+
+
+# -- over TCP: the clone, its disk, a lost frame -----------------------------
+
+
+def test_clone_over_tcp_and_reopen_without_a_swarm(store, tmp_path):
+    """Every doc of a clone over TCP equals the reference, its `len`
+    read too; closed, B's disk equals A's feed for feed, every stored
+    block under a signature record; a fresh repo with no swarm reads
+    the same values back."""
+    repo, handles, wait = clone(tmp_path / "b", store)
+    try:
+        assert wait()
+        for doc, (url, h) in enumerate(zip(store.urls, handles)):
+            assert plain(h.value(timeout=WAIT_S)) == store.ref(doc)["value"]
+            assert repo.read(url, {"kind": "len", "path": ["t"]},
+                             timeout=WAIT_S) == store.ref(doc)["len"]
+    finally:
+        repo.close()
+    disk = clone_plain.compare_stores(
+        store.path + "/feeds", str(tmp_path / "b" / "feeds"),
+        [k for keys in store.keys for k in keys])
+    assert disk["feeds"] == 3 * DOCS and disk["blocks"] == DOCS * CHANGES
+    assert (disk["short"], disk["differ"], disk["unsigned"]) == (0, 0, 0)
+    again = Repo(path=str(tmp_path / "b"))
+    try:
+        for doc, h in enumerate(again.open_many(store.urls)):
+            assert plain(h.value(timeout=WAIT_S)) == store.ref(doc)["value"]
+    finally:
+        again.close()
+
+
+def test_compare_stores_sees_a_short_and_an_unsigned_feed(store, tmp_path):
+    """The reference's disk check on hand-damaged copies."""
+    import shutil
+
+    keys = [k for ks in store.keys[:1] for k in ks]
+    root = tmp_path / "feeds"
+    shutil.copytree(store.path + "/feeds", root)
+    same = clone_plain.compare_stores(store.path + "/feeds", str(root), keys)
+    assert (same["short"], same["differ"], same["unsigned"]) == (0, 0, 0)
+    log = clone_plain.feed_path(str(root), keys[0])
+    n = len(clone_plain.feed_blocks(log))
+    with open(log, "r+b") as fh:  # the last block torn off
+        fh.truncate(os.path.getsize(log) - 1)
+    assert len(clone_plain.feed_blocks(log)) == n - 1
+    with open(clone_plain.feed_path(str(root), keys[1]) + ".sig", "r+b") as fh:
+        fh.truncate(os.path.getsize(fh.name) - 104)  # newest record gone
+    bad = clone_plain.compare_stores(store.path + "/feeds", str(root), keys)
+    assert bad["short"] == 1 and bad["unsigned"] == 2 and bad["differ"] == 0
+
+
+@pytest.mark.parametrize("lost,busy", ((1, False), (2, True)),
+                         ids=("one_reply-quiet_peer", "two_replies-busy_peer"))
+def test_a_lost_blocks_frame(store, tmp_path, monkeypatch, lost, busy):
+    """B's link loses the first `lost` replies (`Blocks` frames) of one
+    feed; the periodic timer is out of reach (HM_ANTIENTROPY_S an hour)
+    and A writes no tail behind them, so what a lost reply needs is the
+    timer's body, `sweep_now` on A: the first sweep finds B's Request
+    standing and lets it stand (the reply may only be queued), the
+    second makes B ask again (`ReplicationManager._ask`). `busy`: A
+    keeps writing ANOTHER document B holds all the while, so its tails
+    reach B every few milliseconds: B's repeated Request does not wait
+    for the peer to fall quiet."""
+    monkeypatch.setenv("HM_ANTIENTROPY_S", "3600")
+    from hypermerge_tpu.utils.keys import discovery_id
+
+    victim = discovery_id(store.keys[0][2])
+    dropped = []
+    base = faults.FaultDuplex
+
+    class Lossy(base):
+        def _on_rx(self, msg):
+            m = msg.get("m") if isinstance(msg, dict) else None
+            if (isinstance(m, dict) and m.get("type") == "Blocks"
+                    and m.get("id") == victim and len(dropped) < lost):
+                dropped.append(1)
+                return
+            base._on_rx(self, msg)
+
+    monkeypatch.setattr(faults, "FaultDuplex", Lossy)
+    repo, handles, wait = clone(
+        tmp_path / "b", store,
+        faults.FaultSwarm(TcpSwarm(), faults.FaultPlan()))
+    sweep = store.repo.back.network.replication.sweep_now
+    stop = threading.Event()
+    writer = None
+    try:
+        if busy:
+            other = store.repo.create({"n": 0})
+            theirs = repo.open(other)
+            assert theirs.value(timeout=WAIT_S) is not None
+
+            def write():
+                n = 0
+                while not stop.wait(0.01):
+                    n += 1
+                    store.repo.change(
+                        other, lambda d, n=n: d.__setitem__("n", n))
+
+            writer = threading.Thread(target=write, daemon=True)
+            writer.start()
+        assert not wait(1.5) and len(dropped) == 1  # nothing asks again
+        for n in range(1, lost + 1):
+            if busy:
+                seen = theirs.value()["n"]
+            assert sweep() > 0
+            assert not wait(0.5) and len(dropped) == n  # it stands once
+            assert sweep() > 0  # the second sweep: asked again
+            if n < lost:
+                assert not wait(0.5) and len(dropped) == n + 1
+            if busy:  # the other feed's tails kept coming all the while
+                assert theirs.value()["n"] > seen
+        assert wait(WAIT_S)
+        for doc, h in enumerate(handles):
+            assert plain(h.value(timeout=WAIT_S)) == store.ref(doc)["value"]
+    finally:
+        stop.set()
+        if writer is not None:
+            writer.join(timeout=WAIT_S)
+        repo.close()
+
+
+def test_a_program_without_the_counters_fails_at_once(tmp_path, monkeypatch):
+    """The checks `unsigned_accepted` and the feeds cloned are decided
+    by replication's counters: on a program that lacks one (the parent
+    of the PR that brought the cell) set-up exits 5 before A's cold
+    open, and leaves nothing open."""
+    from benchmark.drivers import clone_rounds
+
+    job = mwr.CorpusJob(str(tmp_path / "repo"), spec(2), SEEDS[2], 2).start()
+    monkeypatch.setattr(clone_rounds, "DECIDED_BY",
+                        clone_rounds.DECIDED_BY + ("net.repl.no_such",))
+    cell = SimpleNamespace(
+        work=str(tmp_path), seed=SEEDS[2], notes={}, name="sync.clone",
+        config={"corpus": spec(2)}, mix={"verify_sample_docs": 2},
+        counters=lambda: harness.Cell.counters(None))
+    with pytest.raises(SystemExit) as failed:
+        clone_rounds.setup(cell, job)
+    assert failed.value.code == 5 and "cold_open_s" not in cell.notes
+    Repo(path=str(tmp_path / "repo")).close()  # A's lock was given back
+
+
+# -- the spans and counters the cell's metrics read --------------------------
+
+SPANS = {
+    "net.repl.rx": ("blocks", "bytes"),
+    "net.repl.verify": ("blocks",),
+    "storage.feed.append": ("bytes", "blocks"),
+    "net.repl.tx": ("blocks", "bytes"),
+    "repo.sync_changes": ("docs", "changes"),
+    "live.tick": ("docs", "changes", "inc_docs", "kernel_docs"),
+    "live.adopt": ("outcome", "rows"),
+    "frontend.remote_patch": ("diffs",),
+}
+COUNTERS = ("net.repl.blocks_rx", "net.repl.bytes_rx",
+            "net.repl.feeds_synced", "live.tick_changes", "live.tick_docs")
+# what the read tier's metrics the cell joins read of a round's reads
+# (serve.batch_s, serve.dispatch_s, serve.reads_per_batch; the counters
+# of serve.host_answers move only where a read falls back)
+JOINED_SPANS = ("serve.batch", "serve.dispatch")
+JOINED_COUNTERS = ("serve.reads", "serve.batches")
+
+
+def counters():
+    return {k: v for k, v in telemetry.snapshot().items()
+            if isinstance(v, (int, float))}
+
+
+def by_name():
+    """The ring's spans by name."""
+    events = {}
+    for ev in telemetry.trace_events():
+        if ev[0] == "X":
+            events.setdefault(ev[1], []).append(ev)
+    return events
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One clone over TCP under the ring tracer and one read of every
+    document, then one change on A that reaches B as a RemotePatch:
+    (events by name, counter deltas, store)."""
+    s = Store(tmp_path_factory.mktemp("ta") / "repo", SEEDS[0])
+    telemetry.enable_tracing()
+    telemetry.reset_trace()
+    c0 = counters()
+    repo, handles, wait = clone(tmp_path_factory.mktemp("tb") / "b", s)
+    try:
+        assert wait()
+        repo.back.live.flush_now()  # the last tick's span is in the ring
+        answered = threading.Semaphore(0)
+        for url in s.urls:  # a round's read phase
+            repo.read(url, {"kind": "len", "path": ["t"]},
+                      lambda _answer: answered.release())
+        assert all(answered.acquire(timeout=WAIT_S) for _ in s.urls)
+        c1 = counters()
+        events = by_name()
+        # (A's first change of a bulk-opened doc mints it a fourth
+        # feed, which B then clones too: counted apart from the store)
+        patched = await_all([repo.open(s.urls[0])], changes=CHANGES + 1)
+        s.repo.change(s.urls[0], lambda d: d["t"].insert(0, "Z"))
+        assert patched()
+        events["frontend.remote_patch"] = by_name().get(
+            "frontend.remote_patch", [])
+    finally:
+        telemetry.disable_tracing()
+        telemetry.reset_trace()
+        repo.close()
+        s.close()
+    return events, {k: c1.get(k, 0) - c0.get(k, 0) for k in c1}, s
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+def test_span_is_recorded_with_its_tags(traced, name):
+    events, _moved, _s = traced
+    assert events.get(name), f"no {name} span in the ring"
+    for ev in events[name]:
+        args = ev[6] or {}
+        assert set(SPANS[name]) <= set(args), (name, args)
+
+
+def test_spans_add_up_to_the_counters(traced):
+    """One verify and one append a stored extension, inside a
+    `net.repl.rx` of its thread; their blocks are the counter's, the
+    ticks' changes the engine's."""
+    events, moved, s = traced
+    blocks = DOCS * CHANGES
+    appends = [e for e in events["storage.feed.append"]
+               if "blocks" in (e[6] or {})]
+    assert len(events["net.repl.verify"]) == len(appends)
+    assert sum(e[6]["blocks"] for e in appends) == blocks
+    assert moved["net.repl.blocks_rx"] == blocks
+    assert moved["net.repl.feeds_synced"] == 3 * DOCS
+    assert moved["net.repl.bytes_rx"] == sum(e[6]["bytes"] for e in appends)
+    for v in events["net.repl.verify"]:
+        assert any(r[5] == v[5] and r[3] <= v[3]
+                   and v[3] + v[4] <= r[3] + r[4] + 1
+                   for r in events["net.repl.rx"])
+    ticks = events["live.tick"]
+    # (a Ready snapshot's or a local change's catch-up applies queued
+    # changes outside any tick: the counter holds those too)
+    assert 0 < sum(e[6]["changes"] for e in ticks) <= moved[
+        "live.tick_changes"]
+    assert sum(e[6]["docs"] for e in ticks) == moved["live.tick_docs"]
+    assert all(e[6]["docs"] == e[6]["inc_docs"] + e[6]["kernel_docs"]
+               for e in ticks)
+    assert any(e[6]["diffs"] >= 1 for e in events["frontend.remote_patch"])
+
+
+def test_a_clone_asks_once_and_echoes_nothing(traced):
+    """A feed's length reaches B several times (the capability reply,
+    A's announcement, its answer to B's proof): ONE Request goes out
+    while the first is on its way, so every `Blocks` frame B receives
+    extends a feed, and B's tail flush sends nothing back to the peer
+    the blocks came from. (On the parent every frame crossed three
+    times, more when an anti-entropy sweep fell into the backfill, and
+    B echoed each extension to A.)"""
+    events, _moved, _s = traced
+    feeds = 3 * DOCS
+    assert len(events["net.repl.rx"]) == feeds
+    assert len(events["net.repl.verify"]) == feeds
+    assert len(events["net.repl.tx"]) == feeds
+    assert sum(e[6]["blocks"] for e in events["net.repl.tx"]) == (
+        DOCS * CHANGES)
+
+
+def test_a_request_stands_until_blocks_of_its_feed_or_a_second_sweep(
+    store, tmp_path, monkeypatch
+):
+    """`ReplicationManager._ask`, per peer and feed: the Request that
+    is outstanding is not repeated by one more announcement; another
+    `from` is another Request; a `Blocks` frame of the feed from the
+    peer (here a gap: a tail beyond our head) clears it, so every gap
+    asks again at once, as the parent did; of two sweeps' announcements
+    the second repeats it; a closed peer's Requests are forgotten."""
+    sent = []
+    repo = Repo(path=str(tmp_path / "b"))
+    try:
+        repo.set_swarm(TcpSwarm())
+        rm = repo.back.network.replication
+        feed = repo.back.feeds.open_feed(store.keys[0][0])
+        did = feed.discovery_id
+        peer = type("Peer", (), {"id": "peer-a"})()  # hashable
+        monkeypatch.setattr(
+            rm, "_request_msg", lambda f, p, start: {"from": start})
+        monkeypatch.setattr(rm, "_send", lambda p, msg: sent.append(msg))
+        rm._verified.add(did, peer)
+        rm._on_feed_length(peer, did, 12, None)
+        rm._on_feed_length(peer, did, 12, None)  # the first one stands
+        assert sent == [{"from": 0}]
+        rm._ask(feed, peer, 8)  # another Request
+        assert sent == [{"from": 0}, {"from": 8}]
+        del sent[:]
+        for _ in range(2):  # a tail beyond our head: a gap, each time
+            rm._on_blocks(peer, did, 5, ["AA=="], -1, None, 12)
+        assert sent == [{"from": 0}, {"from": 0}]
+        del sent[:]
+        rm._on_feed_length(peer, did, 12, None, sweep=True)
+        assert sent == []  # the first sweep lets it stand
+        rm._on_feed_length(peer, did, 12, None)
+        assert sent == []  # and no plain announcement repeats it
+        rm._on_feed_length(peer, did, 12, None, sweep=True)
+        assert sent == [{"from": 0}]  # the second sweep
+        rm._on_feed_length(peer, did, 12, None, sweep=True)
+        assert len(sent) == 1  # the repeated one stands a sweep again
+        rm.on_peer_closed(peer)
+        assert peer not in rm._asked
+    finally:
+        repo.close()
+
+
+@pytest.mark.parametrize("name", sorted(SPANS))
+def test_span_costs_nothing_with_no_sink(name):
+    assert not telemetry.tracing_enabled()
+    assert telemetry.span(name) is telemetry.NOOP
+
+
+@pytest.mark.parametrize("name", COUNTERS)
+def test_counter_moves_in_a_clone(traced, name):
+    _events, moved, _s = traced
+    assert moved[name] > 0
+
+
+@pytest.mark.parametrize("name", JOINED_SPANS + JOINED_COUNTERS)
+def test_the_read_phase_feeds_the_read_tiers_metrics(traced, name):
+    events, moved, _s = traced
+    assert events.get(name) if name in JOINED_SPANS else moved[name] > 0
+
+
+# -- each new metric file on a hand-worked obs -------------------------------
+
+
+def sp(name, t0, t1, cpu_us=None, line=(0, 0), **args):
+    if cpu_us is not None:
+        args["cpu_us"] = cpu_us
+    return span_tree.Span(name, t0, t1, line, args)
+
+
+HAND_SPANS = [
+    sp("net.repl.rx", 0.0, 2.0, 1_000_000, blocks=32, bytes=9000),
+    sp("net.repl.rx", 3.0, 4.0, 250_000, blocks=32, bytes=9000),
+    sp("net.repl.verify", 0.5, 1.0, 400_000, blocks=32),
+    sp("storage.feed.append", 1.0, 1.5, 100_000, blocks=32, bytes=9000),
+    sp("storage.feed.append", 3.25, 3.45, 100_000, blocks=32, bytes=9000),
+    sp("net.repl.tx", 0.0, 1.0, 400_000, line=(0, 1), blocks=32),
+    sp("net.repl.tx", 5.0, 6.0, None, line=(0, 1), blocks=32),
+    sp("repo.sync_changes", 2.0, 2.5, 300_000, line=(0, 2), docs=1),
+    sp("live.adopt", 0.0, 0.02, None, line=(0, 3), outcome="ok"),
+    sp("live.adopt", 1.0, 1.04, None, line=(0, 3), outcome="ok"),
+    sp("live.tick", 6.0, 7.0, 900_000, line=(0, 4), docs=1),
+    sp("host.gc", 8.0, 8.25, 250_000, line=(0, 5), gen=2),
+]
+HAND_OBS = {
+    "trace": {"busy_s": 0.5, "window_s": 10.0, "programs": {}},
+    "traced_s": 10.0, "round_s": 61.5, "converge_s": 50.25, "read_s": 9.5,
+    "adopts_per_doc": 1.0, "device_tick_pct": 0.0,
+    "counters_before": {
+        "live.tick_docs": 10, "live.ticks": 5, "net.repl.frames_rx": 100,
+        "net.repl.feeds_synced": 0, "net.repl.antientropy_sweeps": 0,
+        "live.adopted": 100, "live.t_adopt_lock_free": 2.5,
+        "live.t_adopt_lock_held": 0.25},
+    "counters_after": {
+        "live.tick_docs": 110, "live.ticks": 85, "net.repl.frames_rx": 1200,
+        "net.repl.feeds_synced": 100, "net.repl.antientropy_sweeps": 1,
+        "live.adopted": 200, "live.t_adopt_lock_free": 5.0,
+        "live.t_adopt_lock_held": 0.75},
+}
+HAND_WORKED = {
+    "sync.round_s": 61.5,
+    "sync.converge_s": 50.25,
+    "sync.read_s": 9.5,
+    "sync.rx_s": 0.3,  # (2 + 1) s over 10 traced
+    "sync.rx_cpu_s": 0.125,  # (1.0 + 0.25) s over 10
+    "sync.verify_s": 0.05,
+    "sync.append_s": 0.07,  # (0.5 + 0.2) over 10
+    "sync.tx_cpu_s": 0.04,  # the span without a CPU value counts for none
+    "sync.sync_changes_s": 0.05,
+    "sync.adopt_s": 0.03,  # (2.5 + 0.5) s of 100 adoptions
+    "sync.adopts_per_doc": 1.0,
+    "sync.tick_apply_s": 0.1,
+    "sync.device_tick_pct": 0.0,
+    "sync.docs_per_tick": 1.25,  # 100 docs in 80 ticks
+    "sync.frames_per_feed": 11.0,  # 1,100 frames for 100 feeds
+    "sync.patch_s": 0.0,  # program spans, none of this name
+    "sync.antientropy_sweeps": 1.0,
+    "host.gc_s.sync": 0.25,
+}
+
+
+def bench_json():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+@pytest.mark.parametrize("metric", sorted(HAND_WORKED))
+def test_metric_file_on_a_hand_worked_obs(monkeypatch, metric):
+    monkeypatch.setattr(span_tree, "newest_trace", lambda: "hand.pb")
+    monkeypatch.setattr(span_tree, "load", lambda _p: (HAND_SPANS, []))
+    spec_ = harness.load_json("layer_metrics", metric + ".json")
+    entry = next(m for m in bench_json()["per_layer"] if m["name"] == metric)
+    assert spec_["cells"] == entry["workloads"] == ["sync.clone"]
+    assert (spec_["moves"], entry["moves"]) == ("ops_per_s", "ops_per_s")
+    assert (spec_["layer"], spec_["unit"]) == (entry["layer"], entry["unit"])
+    reader = harness.load_module("readers", spec_["reader"])
+    got = reader.read(spec_.get("params") or {}, dict(HAND_OBS))
+    assert got == pytest.approx(HAND_WORKED[metric])
+    # the parent's program has none of these spans and counters: a
+    # trace without program spans, no counters, no driver numbers
+    monkeypatch.setattr(span_tree, "load", lambda _p: ([], []))
+    bare = {"trace": HAND_OBS["trace"], "counters_before": {},
+            "counters_after": {}}
+    assert reader.read(spec_.get("params") or {}, bare) is None
+
+
+def test_every_metric_of_the_cell_has_its_file():
+    bench = bench_json()
+    listed = [m["name"] for m in bench["per_layer"]
+              if "sync.clone" in m.get("workloads", ())]
+    assert set(HAND_WORKED) <= set(listed)
+    for name in listed:
+        spec_ = harness.load_json("layer_metrics", name + ".json")
+        harness.load_module("readers", spec_["reader"])
+    cell = next(w for w in bench["workloads"] if w["name"] == "sync.clone")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "clone-1k3a", "clone-rounds-tcp", 1)
+    assert "sync.clone" in next(
+        m for m in bench["end_to_end"] if m["name"] == "ops_per_s"
+    )["workloads"]

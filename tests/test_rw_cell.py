@@ -346,7 +346,8 @@ def test_rehearsal_cell_follows_writes_in_place(tmp_path, monkeypatch):
                            name + ".json")) as fh:
         spec = json.load(fh)
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
-    assert bench["per_layer"][-1] is entry
+    # appended by PR 38; what later PRs append follows it
+    assert bench["per_layer"].index(entry) == 81
     assert spec["reader"] == "counter_ratio"
     assert entry["workloads"] == spec["cells"] == ["rw.ycsb-a"]
     for key in ("unit", "better", "source", "layer", "moves"):
