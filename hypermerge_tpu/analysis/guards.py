@@ -161,8 +161,8 @@ GUARDS: Tuple[GuardedClass, ...] = (
     GuardedClass(
         "BulkLoader", "hypermerge_tpu.backend.bulk_loader",
         "repo.bulk",
-        guarded=("_pending_memo", "_bulk_t0", "_bulk_open", "_fetch_ctx",
-                 "_summary_memo_bytes"),
+        guarded=("_pending_memo", "_bulk_t0", "_bulk_planes0", "_bulk_open",
+                 "_fetch_ctx", "_summary_memo_bytes"),
         atomic_read_ok=("_summary_memo",),
         unguarded=("_pending_summaries",),
         doc="Bulk-load accumulators: one load at a time under "
@@ -557,6 +557,7 @@ REQUIRES: Dict[Tuple[str, str], str] = {
     ("ResidencyCache", "_note_evicted"): "serve.cache",
     ("FeedColumnCache", "_ensure_loaded"): "store.colcache",
     ("FeedColumnCache", "_set_loaded"): "store.colcache",
+    ("FeedColumnCache", "_snapshot"): "store.colcache",
     ("FeedColumnCache", "_intern"): "store.colcache",
     ("FeedColumnCache", "_take_pending"): "store.colcache",
     ("FeedColumnCache", "_total_rows"): "store.colcache",

@@ -40,6 +40,9 @@ _M_D2H = telemetry.counter("mesh.d2h_bytes")
 # feed by feed (_prefetch_columns)
 _M_COLS_BULK = telemetry.counter("loader.cols_bulk_feeds")
 _M_COLS_SINGLE = telemetry.counter("loader.cols_single_feeds")
+# of the former, those somebody read a plane of through numpy
+# (colcache._ImagePlanes adds)
+_M_PLANES_BUILT = telemetry.counter("loader.cols_planes_built")
 # feed heads of a bulk open by who answered: the store's head snapshot
 # (storage/feed.py HeadSnapshot) or a probe of the feed's files
 _M_HEADS_SNAP = telemetry.counter("loader.heads_snapshot_feeds")
@@ -124,6 +127,11 @@ _STATS0: Dict[str, Any] = {
     # (_prefetch_columns); cold feeds whose head the store's snapshot
     # answered / whose files were probed; the shares land after the load
     "cols_bulk_feeds": 0, "cols_single_feeds": 0, "cols_bulk_pct": 0.0,
+    # slab-granular feeds somebody read a plane of through numpy, from
+    # the load's start to its barrier (colcache._ImagePlanes; the
+    # process's count, so another load's feeds read meanwhile are in
+    # it): 0 where every pack is native
+    "cols_planes_built": 0, "cols_planes_built_pct": 0.0,
     "heads_snapshot_feeds": 0, "heads_probed_feeds": 0,
     "heads_snapshot_pct": 0.0,
     # feeds the open read; docs whose slab took the general
@@ -196,6 +204,7 @@ class BulkLoader:
         # the latest load's async fetch workers, joined by the barrier
         self._fetch_ctx: Optional[FetchContext] = None
         self._bulk_t0: Optional[float] = None
+        self._bulk_planes0 = 0.0  # _M_PLANES_BUILT as the latest load began
         self._bulk_open = 0  # request id of the latest load's spans
         # per-doc summary memo: doc_id -> last fetched summary row + the
         # clock it was fetched at. A later bulk load of a doc whose
@@ -252,6 +261,7 @@ class BulkLoader:
         self._settle_fetch("unfetched bulk load's fetch")
 
         self._bulk_t0 = time.perf_counter()
+        self._bulk_planes0 = _M_PLANES_BUILT.value()
 
         # -- phase 1: register docs + one bulk cursor upsert/select -----
         new_docs: List[DocBackend] = []
@@ -802,6 +812,7 @@ class BulkLoader:
             memo_pending = self._pending_memo
             fetch_ctx = self._fetch_ctx
             wall_t0 = self._bulk_t0
+            planes0 = self._bulk_planes0
             self._pending_summaries = []
             self._pending_memo = []
             self._fetch_ctx = None
@@ -822,8 +833,14 @@ class BulkLoader:
         with self._stats_lock:
             self.last_bulk_stats["t_fetch"] = round(barrier.dur, 3)
             if wall_t0 is not None:
-                self.last_bulk_stats["wall_critical_path"] = round(
+                stats = self.last_bulk_stats
+                stats["wall_critical_path"] = round(
                     time.perf_counter() - wall_t0, 3
+                )
+                built = int(_M_PLANES_BUILT.value() - planes0)
+                stats["cols_planes_built"] = built
+                stats["cols_planes_built_pct"] = _pct(
+                    built, stats["cols_bulk_feeds"]
                 )
         return out
 

@@ -51,13 +51,16 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import struct
 import threading
+from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..analysis.lockdep import make_rlock
 from ..crdt.change import HEAD, ROOT, Action, Change
 from .faults import io_fsync, io_open, io_remove, io_replace
@@ -101,6 +104,13 @@ class FeedColumns:
     read planes without ever widening to the row matrix, so a cold
     open builds none; per-op consumers upgrade transparently.
 
+    `planes` is a read-only mapping, PLANE_NAMES its keys in that
+    order: a dict of arrays from the per-feed loader, an _ImagePlanes
+    from the slab-granular one (load_slab_images), which makes a
+    plane's view of the slab's mapping when that name is first asked
+    for. The native pack entries read `plane_meta` and ask for none;
+    `n_rows` asks for none either.
+
     `preds` is [n_preds, 3] int32. `seq` is nondecreasing, so change
     windows slice via np.searchsorted. `ok_prefix_len` is the number of
     leading non-corrupt changes — the host OpSet can never apply past
@@ -119,7 +129,7 @@ class FeedColumns:
     # per-change cumulative row counts, len n_changes+1: change i (seq
     # i+1) owns rows [row_ends[i], row_ends[i+1])
     row_ends: np.ndarray
-    planes: Optional[Dict[str, np.ndarray]] = None
+    planes: Optional[Mapping] = None
     # (base_addr, offsets[len(PLANE_NAMES)] int64, dtype_codes uint8,
     # keep_alive) when every plane is a slice of ONE raw checkpoint
     # buffer: the native bulk pack derives all plane pointers from the
@@ -131,7 +141,7 @@ class FeedColumns:
     def n_rows(self) -> int:
         if self.rows is not None:
             return len(self.rows)
-        return len(self.planes["action"]) if self.planes else 0
+        return _plane_rows(self.planes)
 
     def plane(self, name: str) -> np.ndarray:
         """One column, narrow dtype when plane-backed."""
@@ -427,11 +437,64 @@ _V2_HDR = struct.Struct("<IIIB")
 _V3_MAGIC = b"HMc3"
 _V3_HDR = struct.Struct("<IIII")  # n_rows, n_changes, n_preds, tables_len
 _V3_DTYPES = (np.int8, np.int16, np.int32, np.uint8)
-_V3_ITEMSIZE = np.asarray(
-    [np.dtype(d).itemsize for d in _V3_DTYPES], np.int64
-)
+_V3_NP_DTYPES = tuple(np.dtype(d) for d in _V3_DTYPES)
+_V3_ITEMSIZE = np.asarray([d.itemsize for d in _V3_NP_DTYPES], np.int64)
+_PLANE_INDEX = {name: i for i, name in enumerate(PLANE_NAMES)}
 _NO_ROWS = np.zeros((0, ROW_FIELDS), np.int32)
 _NO_ROWS.flags.writeable = False
+
+# feeds of a slab-granular load of which somebody read a plane through
+# numpy (BulkLoader turns it into last_bulk_stats.cols_planes_built)
+_M_PLANES_BUILT = telemetry.counter("loader.cols_planes_built")
+
+
+class _ImagePlanes(Mapping):
+    """FeedColumns.planes of one v3 image that lies in a slab's mapping
+    (load_slab_images): PLANE_NAMES -> that plane's zero-copy,
+    non-writeable view, which keeps the mapping alive. A view is made
+    when its name is first asked for and kept, from the image's row of
+    the load's offset / dtype tables (`meta` is the feed's plane_meta,
+    `at` the image's start in the mapping). A cold open whose packs are
+    native asks for `seq` alone (seqs_contiguous, once a feed); a feed
+    is counted when another plane of it is first asked for."""
+
+    __slots__ = ("n_rows", "_meta", "_at", "_built", "_counted")
+
+    def __init__(self, meta, at: int, n_rows: int) -> None:
+        self.n_rows = n_rows
+        self._meta = meta
+        self._at = at
+        self._built: Dict[str, np.ndarray] = {}
+        self._counted = False
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        plane = self._built.get(name)
+        if plane is None:
+            pi = _PLANE_INDEX[name]
+            _addr, offs, dts, whole = self._meta
+            dt = _V3_NP_DTYPES[dts[pi]]
+            lo = self._at + int(offs[pi])
+            plane = self._built[name] = whole[
+                lo : lo + self.n_rows * dt.itemsize
+            ].view(dt)
+            if name != "seq" and not self._counted:
+                self._counted = True
+                _M_PLANES_BUILT.add(1)
+        return plane
+
+    def __iter__(self):
+        return iter(PLANE_NAMES)
+
+    def __len__(self) -> int:
+        return len(PLANE_NAMES)
+
+
+def _plane_rows(planes: Optional[Mapping]) -> int:
+    """Rows of a plane-backed feed, without making a view."""
+    if not planes:
+        return 0
+    n = getattr(planes, "n_rows", None)
+    return len(planes["action"]) if n is None else n
 
 
 def _narrow_plane(col: np.ndarray) -> np.ndarray:
@@ -1037,9 +1100,9 @@ class FeedColumnCache:
             # fresh cache: actor 0 is the writer (the table line flushes
             # with the first commit)
             self._intern("a", self._actors, self.writer)
-        self._base_planes: Optional[Dict[str, np.ndarray]] = planes
+        self._base_planes: Optional[Mapping] = planes
         self._base_meta = meta
-        self._base_rows = len(planes["action"]) if planes is not None else 0
+        self._base_rows = _plane_rows(planes)
         self._row_chunks: List[np.ndarray] = [rows] if len(rows) else []
         self._pred_chunks: List[np.ndarray] = [preds] if len(preds) else []
         self._n_rows_total = self._base_rows + len(rows)
@@ -1050,24 +1113,42 @@ class FeedColumnCache:
         self._commits_new: List[Tuple[int, int, int, int]] = []
         self._cached: Optional[FeedColumns] = None
 
-    def install_image(
-        self, kinds, planes, meta, preds, row_ends, flags
-    ) -> bool:
+    def install_image(self, kinds, planes, meta, preds, commits, row_ends,
+                      ok_prefix: int) -> bool:
         """The slab-granular loader's hand-over (load_slab_images): one
-        complete v3 image, parsed outside, becomes this cache's loaded
-        state and its FeedColumns is built, exactly as `_ensure_loaded`
-        + `columns()` would leave them. False, and nothing touched,
-        when the cache loaded itself meanwhile (an append raced the
-        bulk load)."""
+        complete v3 image becomes this cache's loaded state and its
+        FeedColumns, as `_ensure_loaded` + `columns()` would leave
+        them; `commits` [n, 4], `row_ends` [n + 1] and `ok_prefix` are
+        this feed's part of what the loader computed for its whole
+        chunk. False, and nothing touched, when the cache loaded itself
+        meanwhile (an append raced the bulk load)."""
         with self._lock:
             if self._loaded:
                 return False
-            self._set_loaded(
-                kinds, planes, meta, _NO_ROWS, preds,
-                _v3_commits(row_ends, flags, 0),
-            )
-            self.columns()
+            self._set_loaded(kinds, planes, meta, _NO_ROWS, preds, commits)
+            self._snapshot(None, preds, ok_prefix, row_ends, planes, meta)
             return True
+
+    def _snapshot(
+        self, rows, preds, ok_prefix, row_ends, planes, meta
+    ) -> FeedColumns:
+        """The FeedColumns of the loaded state, cached until the next
+        append."""
+        self._cached = FeedColumns(
+            rows=rows,
+            preds=preds,
+            actors=self._actors.snapshot(),
+            keys=self._keys.snapshot(),
+            strings=self._strings.snapshot(),
+            floats=self._floats.snapshot(),
+            bigints=self._bigints.snapshot(),
+            n_changes=len(self._commits_arr),
+            ok_prefix_len=ok_prefix,
+            row_ends=row_ends,
+            planes=planes,
+            plane_meta=meta,
+        )
+        return self._cached
 
     # -- table interning ----------------------------------------------
 
@@ -1299,21 +1380,9 @@ class FeedColumnCache:
             row_ends = np.zeros(n + 1, np.int64)
             if n:
                 row_ends[1:] = commits[:, 0]
-            self._cached = FeedColumns(
-                rows=rows,
-                preds=preds,
-                actors=self._actors.snapshot(),
-                keys=self._keys.snapshot(),
-                strings=self._strings.snapshot(),
-                floats=self._floats.snapshot(),
-                bigints=self._bigints.snapshot(),
-                n_changes=n,
-                ok_prefix_len=ok_prefix,
-                row_ends=row_ends,
-                planes=planes,
-                plane_meta=meta,
+            return self._snapshot(
+                rows, preds, ok_prefix, row_ends, planes, meta
             )
-            return self._cached
 
     def compact(self) -> None:
         """Fold the storage's whole committed state into one v3
@@ -1369,6 +1438,33 @@ class FeedColumnCache:
 # the slab-granular loader
 
 _ACTOR_LINE = b'{"t":"a","v":"'
+# leading actor lines in the writers' own form (_intern's json.dumps:
+# ASCII, every other character escaped), and what lies between two
+_CANON_ACTOR_LINES = re.compile(
+    rb'(?:\{"t":"a","v":"[ !#-\[\]-~]*"\}\n)*'
+)
+_ACTOR_SEAM = '"}\n' + _ACTOR_LINE.decode("ascii")
+
+
+def _lead_actors(blob: bytes) -> Tuple[List[str], int]:
+    """(the actors the blob's leading actor lines name, where those
+    lines end). Lines in the writers' form are sliced, not parsed; one
+    in any other (an escape, a byte outside ASCII, another spelling)
+    sends the feed's lead lines through _parse_tables, which reads or
+    refuses them as it always did."""
+    canon = cut = _CANON_ACTOR_LINES.match(blob).end()
+    while blob.startswith(_ACTOR_LINE, cut):
+        nl = blob.find(b"\n", cut)
+        if nl < 0:
+            break
+        cut = nl + 1
+    if cut != canon:
+        lines = blob[:cut].decode("utf-8").splitlines()
+        return _parse_tables(lines)["a"].items, cut
+    if not cut:
+        return [], cut
+    lead = blob[len(_ACTOR_LINE) : cut - 3].decode("ascii")
+    return lead.split(_ACTOR_SEAM), cut
 
 
 def _image_tables(blob: bytes, memo: Dict[bytes, Dict[str, _Interner]]):
@@ -1379,24 +1475,54 @@ def _image_tables(blob: bytes, memo: Dict[bytes, Dict[str, _Interner]]):
     that carry it, copied on the first write. Feeds that share nothing
     just parse everything: the split only moves work, each kind's items
     keep their order either way."""
-    cut = 0
-    while blob.startswith(_ACTOR_LINE, cut):
-        nl = blob.find(b"\n", cut)
-        if nl < 0:
-            break
-        cut = nl + 1
-    kinds = _parse_tables(blob[:cut].decode("utf-8").splitlines())
+    lead, cut = _lead_actors(blob)
     rest = blob[cut:]
     proto = memo.get(rest)
     if proto is None:
         proto = memo[rest] = _parse_tables(
             rest.decode("utf-8").splitlines()
         )
+    kinds = {t: proto[t].share() for t in _TABLE_KINDS[1:]}
+    actors = kinds["a"] = _Interner()
+    for actor in lead:
+        actors.add(actor)
     for actor in proto["a"].items:
-        kinds["a"].add(actor)
-    for t in _TABLE_KINDS[1:]:
-        kinds[t] = proto[t].share()
+        actors.add(actor)
     return kinds
+
+
+def _chunk_commits(whole, row_ends_at, flags_at, n_changes):
+    """The commit side of a chunk's images in one pass: feed g's
+    row_ends (8 unaligned bytes a change) lie at `row_ends_at[g]` of
+    the mapping, its flags at `flags_at[g]`, `n_changes[g]` of each.
+    Returns (starts, commits, row_ends, ok_prefix). `commits` is ONE
+    [sum n_changes, 4] array of [row_end, 0, 0, flag] rows, feed g's
+    are commits[starts[g]:starts[g + 1]]; `row_ends` ONE array of each
+    feed's [0, row_ends...], feed g's start at starts[g] + g; both are
+    read-only, the feeds slice them. ok_prefix[g] is the index of feed
+    g's first flagged change, n_changes[g] where it has none."""
+    G = len(n_changes)
+    starts = np.zeros(G + 1, np.int64)
+    np.cumsum(n_changes, out=starts[1:])
+    total = int(starts[-1])
+    feed = np.repeat(np.arange(G), n_changes)  # [total] change -> feed
+    k = np.arange(total) - starts[feed]  # its index inside the feed
+    # the mapping as the int64 at EVERY byte offset (stride 1): one
+    # indexed read takes all the unaligned row_ends
+    int64_at = np.ndarray((max(0, len(whole) - 7),), "<i8", whole, 0, (1,))
+    ends = int64_at[row_ends_at[feed] + 8 * k]
+    flags = whole[flags_at[feed] + k]
+    commits = np.zeros((total, COMMIT_FIELDS), np.int32)
+    commits[:, 0] = ends
+    commits[:, 3] = flags
+    row_ends = np.insert(ends, starts[:-1], 0)  # a 0 heads each feed
+    ok_prefix = n_changes.copy()
+    bad = np.nonzero(flags)[0]
+    if len(bad):
+        np.minimum.at(ok_prefix, feed[bad], k[bad])
+    commits.flags.writeable = False
+    row_ends.flags.writeable = False
+    return starts, commits, row_ends, ok_prefix
 
 
 def load_slab_images(slab, caches, heads) -> List[bool]:
@@ -1407,9 +1533,12 @@ def load_slab_images(slab, caches, heads) -> List[bool]:
     without a bytes copy or a per-feed parse. The extents come from the
     slab under its lock once; headers and plane dtype codes of all
     images are read from the mapping with vectorised numpy into
-    [F, planes] offset / dtype tables; planes, row_ends, flags and
-    preds are views of the mapping, which they keep alive
-    (CorpusSlab._drop_mapping).
+    [F, planes] offset / dtype tables, every image's row_ends and flags
+    into chunk-wide arrays the feeds slice (_chunk_commits). Per feed
+    only objects are made: the preds as a view of the mapping, which it
+    keeps alive (CorpusSlab._drop_mapping), the planes as an
+    _ImagePlanes that makes such views when asked, the tables with the
+    actor lines sliced and the rest shared (_image_tables).
 
     Returns, per cache, whether it was installed. The rest — a v2 tail
     (record segments, or records inside the image), a torn or short
@@ -1460,30 +1589,32 @@ def load_slab_images(slab, caches, heads) -> List[bool]:
     ok &= marks[:, 4] == at + ln
     ok &= n_changes == np.asarray(heads, np.int64)[cand]
 
-    base_addr = whole.__array_interface__["data"][0]
-    dtypes = [np.dtype(d) for d in _V3_DTYPES]
-    memo: Dict[bytes, Dict[str, _Interner]] = {}
     good = np.nonzero(ok)[0]
-    for j, i, a, nr, (ea, fa, pa, ta, end), offs_j, dts_j in zip(
-        good.tolist(), cand[good].tolist(), at[good].tolist(),
-        n_rows[good].tolist(), marks[good].tolist(),
-        plane_offs[good].tolist(), plane_dts[good].tolist(),
-    ):
+    marks = marks[good]
+    starts, commits, row_ends, ok_prefix = _chunk_commits(
+        whole, marks[:, 0], marks[:, 1], n_changes[good]
+    )
+    base_addr = whole.__array_interface__["data"][0]
+    memo: Dict[bytes, Dict[str, _Interner]] = {}
+    for g, (i, a, nr, npr, (_ea, _fa, pa, ta, end), s, e, okp, offs_g,
+            dts_g) in enumerate(zip(
+        cand[good].tolist(), at[good].tolist(), n_rows[good].tolist(),
+        n_preds[good].tolist(), marks.tolist(), starts[:-1].tolist(),
+        starts[1:].tolist(), ok_prefix.tolist(), plane_offs[good],
+        plane_dts[good],
+    )):
         try:
             kinds = _image_tables(whole[ta:end].tobytes(), memo)
         except (ValueError, KeyError, TypeError):
             continue  # the per-feed loader raises it where it always did
-        planes = {}
-        for name, off, code in zip(PLANE_NAMES, offs_j, dts_j):
-            dt = dtypes[code]
-            lo = a + off
-            planes[name] = whole[lo : lo + nr * dt.itemsize].view(dt)
+        meta = (base_addr + a, offs_g, dts_g, whole)
         done[i] = caches[i].install_image(
             kinds,
-            planes,
-            (base_addr + a, plane_offs[j], plane_dts[j], whole),
-            whole[pa:ta].view(np.int32).reshape(-1, PRED_FIELDS),
-            whole[ea:fa].view(np.int64),
-            whole[fa:pa],
+            _ImagePlanes(meta, a, nr),
+            meta,
+            np.ndarray((npr, PRED_FIELDS), np.int32, whole, pa),
+            commits[s:e],
+            row_ends[s + g : e + g + 1],
+            okp,
         )
     return done

@@ -12,6 +12,7 @@ outlives every append to, and the close of, the slab.
 """
 
 import ctypes
+import json
 import random
 import shutil
 
@@ -19,7 +20,8 @@ import numpy as np
 import pytest
 
 from helpers import Site, opset_replay_state, plainify, random_mutation
-from hypermerge_tpu.crdt.change import ROOT, Action, Change, Op
+from hypermerge_tpu import native, telemetry
+from hypermerge_tpu.crdt.change import ROOT, Action, Change, Op, OpId
 from hypermerge_tpu.ops.columnar import COLUMNS, pack_docs_columns
 from hypermerge_tpu.ops.corpus import make_corpus
 from hypermerge_tpu.repo import Repo
@@ -32,6 +34,8 @@ from hypermerge_tpu.storage.colcache import (
     file_column_storage_fn,
     load_slab_images,
     pack_v2_record,
+    pack_v3_checkpoint,
+    parse_v3_checkpoint,
 )
 from hypermerge_tpu.storage.slab import KIND_IMAGE, KIND_RECORD, CorpusSlab
 from hypermerge_tpu.utils.ids import root_actor_id, validate_doc_url
@@ -101,16 +105,93 @@ def _shared_tables(seed):
     return feeds
 
 
+def _empty_among_full(_seed):
+    return [
+        ("full0", "actor00", _history(3)), ("empty", "actor00", []),
+        ("full1", "actor00", _history(4)),
+    ]
+
+
+def _corrupt_among_many(seed):
+    # the flag in the middle of ONE feed, whole feeds on both sides
+    h = _history(seed)
+    feeds = [("g%d" % i, "actor00", _history(seed + i)) for i in range(4)]
+    feeds[2:2] = [("bad", "actor00", h[:2] + [None] + h[2:])]
+    return feeds
+
+
+def _lead_lines(n):
+    """A feed whose tables blob opens with `n` actor lines: its own and
+    those of n - 1 peers whose objects it writes into."""
+
+    def make(_seed):
+        w = "w00"
+        ops = (Op(action=Action.SET, obj=ROOT, key="t", value="x"),) + tuple(
+            Op(action=Action.SET, obj=OpId(1, "peer%02d" % i), key="k",
+               value=i)
+            for i in range(1, n)
+        )
+        first = Change(actor=w, seq=1, start_op=1, deps={}, ops=ops)
+        return [
+            ("lead%d" % n, w, [first, _set_change(w, 2, "n", n)]),
+            ("plain", "actor00", _history(4)),
+        ]
+
+    return make
+
+
+def _escaped_actor(_seed):
+    # writer ids json.dumps has to escape: no canonical actor line
+    return [
+        ("quote", 'we"ird\\actor', [_set_change('we"ird\\actor', 1, "k", 1)]),
+        ("umlaut", "act\u00f6r", [_set_change("act\u00f6r", 1, "k", 2)]),
+        ("plain", "actor00", _history(4)),
+    ]
+
+
+def _respelled_lead(_seed):
+    return [
+        (n, "actor00", _history(5 + i))
+        for i, n in enumerate(["trailing_space", "spaced", "plain"])
+    ]
+
+
+def _respell(slab):
+    """The same tables under another spelling of the lead actor line:
+    one that still opens like an actor line, one that does not."""
+    for name, line in (
+        ("trailing_space", '{"t":"a","v":"actor00" }'),
+        ("spaced", '{"t": "a", "v": "actor00"}'),
+    ):
+        planes, preds, row_ends, flags, tables, _end, _meta = (
+            parse_v3_checkpoint(slab.image_bytes(name))
+        )
+        assert tables[0] == '{"t":"a","v":"actor00"}'
+        blob = ("\n".join([line] + tables[1:]) + "\n").encode("utf-8")
+        slab.append(KIND_IMAGE, name, pack_v3_checkpoint(
+            planes, preds, row_ends, flags, blob
+        ))
+
+
 CASES = {
     "seeded": _seeded,
     "empty_feed": _empty_feed,
+    "empty_among_full": _empty_among_full,
     "corrupt_flag": _corrupt_flag,
+    "corrupt_among_many": _corrupt_among_many,
     "distinct_tables": _distinct_tables,
     "shared_tables": _shared_tables,
+    "lead_lines_1": _lead_lines(1),
+    "lead_lines_3": _lead_lines(3),
+    "lead_lines_32": _lead_lines(32),
+    "escaped_actor": _escaped_actor,
+    "respelled_lead": _respelled_lead,
 }
+# what a case does to its slab once the images are written
+REWRITES = {"respelled_lead": _respell}
 
 
-def _write_images(root, feeds):
+def _write_images(root, feeds, rewrite=None):
     """Each feed's history as ONE v3 image segment of a fresh slab."""
     fn = file_column_storage_fn(str(root))
     for name, writer, changes in feeds:
@@ -119,6 +200,8 @@ def _write_images(root, feeds):
             cc.append_change(c)
         cc.compact()
         cc.close()
+    if rewrite is not None:
+        rewrite(fn.slab)
     fn.slab.close()
 
 
@@ -169,7 +252,7 @@ def assert_same_columns(got, want, what=""):
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_bulk_load_equals_per_feed_load(tmp_path, case):
     feeds = CASES[case](11)
-    _write_images(tmp_path, feeds)
+    _write_images(tmp_path, feeds, REWRITES.get(case))
 
     slab_w, per_feed = _caches(tmp_path, feeds)
     want = [cc.columns() for cc in per_feed]
@@ -185,6 +268,149 @@ def test_bulk_load_equals_per_feed_load(tmp_path, case):
         # zero-copy: the planes are views of the slab's mapping
         assert not got.planes["action"].flags.writeable
         assert not got.planes["action"].flags.owndata
+    slab.close()
+    slab_w.close()
+
+
+def _bulk_loaded(root, feeds):
+    _write_images(root, feeds)
+    slab, caches = _caches(root, feeds)
+    assert all(load_slab_images(
+        slab, caches, [len(ch) for _n, _w, ch in feeds]
+    ))
+    return slab, caches
+
+
+_PLANES_BUILT = telemetry.counter("loader.cols_planes_built")
+
+_ACTOR_LINES = {
+    "one": ["actor00"],
+    "three": ["w00", "peer01", "peer02"],
+    "thirty_two": ["w%02d" % i for i in range(32)],
+    "repeated": ["w00", "w01", "w00"],
+    "none": [],
+    "escape": ['we"ird'],
+    "backslash": ["back\\slash"],
+    "control": ["tab\tbed"],
+    "not_ascii": ["act\u00f6r"],
+    "second_of_three_escaped": ["w00", 'we"ird', "w02"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ACTOR_LINES))
+def test_lead_actor_lines_are_sliced_where_canonical(case, monkeypatch):
+    """_lead_actors against the parse it replaces: the same actors and
+    the same cut whatever the lines hold, and no parse at all where
+    every lead line has the writers' own form."""
+    actors = _ACTOR_LINES[case]
+    lead = "".join(
+        json.dumps({"t": "a", "v": a}, separators=(",", ":")) + "\n"
+        for a in actors
+    )
+    rest = '{"t":"k","v":"title"}\n{"t":"s","v":"{\\"t\\":\\"a\\"}"}\n'
+    parsed = []
+    orig = colcache._parse_tables
+
+    def spy(lines):
+        parsed.append(lines)
+        return orig(lines)
+
+    monkeypatch.setattr(colcache, "_parse_tables", spy)
+    got, cut = colcache._lead_actors((lead + rest).encode("utf-8"))
+    assert cut == len(lead.encode("utf-8"))
+    assert list(dict.fromkeys(got)) == orig(lead.splitlines())["a"].items
+    canonical = all(
+        a.isascii() and a.isprintable() and not set(a) & set('"\\')
+        for a in actors
+    )
+    assert bool(parsed) == (not canonical)
+
+
+def test_lead_line_of_another_spelling_is_parsed():
+    blob = b'{"t":"a","v":"actor00" }\n{"t":"a","v":"peer"}\n{"t":"k","v":"x"}\n'
+    got, cut = colcache._lead_actors(blob)
+    assert got == ["actor00", "peer"] and blob[cut:] == b'{"t":"k","v":"x"}\n'
+    # a last actor line without its newline stays with the rest
+    got, cut = colcache._lead_actors(b'{"t":"a","v":"a"}\n{"t":"a","v":"b"}')
+    assert got == ["a"] and cut == 18
+
+
+def test_corrupt_flag_clamps_its_feed_alone(tmp_path):
+    feeds = _corrupt_among_many(11)
+    slab, caches = _bulk_loaded(tmp_path, feeds)
+    for (name, _w, changes), cc in zip(feeds, caches):
+        fc = cc.columns()
+        assert fc.n_changes == len(changes)
+        assert fc.ok_prefix_len == (2 if name == "bad" else len(changes))
+        assert len(fc.row_ends) == len(changes) + 1 and fc.row_ends[0] == 0
+    slab.close()
+
+
+def test_commit_slices_are_read_only_and_an_append_touches_no_sibling(
+    tmp_path,
+):
+    """The feeds of a chunk slice ONE commits array and ONE row_ends
+    array: nobody may write through a slice, and the feed that grows
+    gets arrays of its own."""
+    feeds = _seeded(7)
+    slab, caches = _bulk_loaded(tmp_path, feeds)
+    fcs = [cc.columns() for cc in caches]
+    for cc, fc in zip(caches, fcs):
+        assert not fc.row_ends.flags.writeable
+        assert not cc._commits_arr.flags.writeable
+        with pytest.raises(ValueError):
+            fc.row_ends[0] = 1
+        with pytest.raises(ValueError):
+            cc._commits_arr[0, 0] = 1
+    before = [
+        (fc.row_ends.copy(), cc._commits_arr.copy())
+        for cc, fc in zip(caches, fcs)
+    ]
+    n = fcs[1].n_changes
+    caches[1].append_change(_set_change("actor00", n + 1, "after", "load"))
+    grown = caches[1].columns()
+    assert grown.n_changes == n + 1 == grown.ok_prefix_len
+    assert np.array_equal(grown.row_ends[:-1], before[1][0])
+    assert grown.row_ends[-1] == before[1][0][-1] + 1
+    for cc, fc, (row_ends, commits) in zip(caches, fcs, before):
+        # the snapshots handed out before the append, the grown feed's
+        # included, and every sibling's cache
+        assert np.array_equal(fc.row_ends, row_ends)
+        if cc is not caches[1]:
+            assert np.array_equal(cc._commits_arr, commits)
+            assert cc.columns() is fc
+    slab.close()
+
+
+def test_row_counts_and_windows_build_no_plane(tmp_path):
+    feeds = _seeded(9)
+    slab, caches = _bulk_loaded(tmp_path, feeds)
+    slab_w, per_feed = _caches(tmp_path, feeds)
+    before = _PLANES_BUILT.value()
+    for cc, ref in zip(caches, per_feed):
+        fc, want = cc.columns(), ref.columns()
+        assert isinstance(fc.planes, colcache._ImagePlanes)
+        assert list(fc.planes) == list(PLANE_NAMES)
+        assert len(fc.planes) == len(PLANE_NAMES)
+        assert fc.n_rows == want.n_rows == cc._base_rows
+        assert cc._n_rows_total == want.n_rows
+        assert cc.n_changes == want.n_changes
+        assert fc.window(0, INF) == want.window(0, INF)
+        assert fc.window(2, 5) == want.window(2, 5)
+        assert fc.changes_in_window(1, INF) == want.changes_in_window(1, INF)
+        assert fc.planes._built == {}
+        assert fc.seqs_contiguous() and want.seqs_contiguous()
+        assert list(fc.planes._built) == ["seq"]
+    # the open's own check of `seq` is no numpy reader of the planes
+    assert _PLANES_BUILT.value() == before
+    fc = caches[0].columns()
+    ctr = fc.plane("ctr")
+    assert fc.plane("ctr") is ctr and fc.planes["ctr"] is ctr  # kept
+    assert set(fc.planes._built) == {"seq", "ctr"}
+    fc.plane("obj_a")
+    assert _PLANES_BUILT.value() == before + 1  # a feed counts once
+    with pytest.raises(KeyError):
+        fc.planes["no_such_plane"]
     slab.close()
     slab_w.close()
 
@@ -252,6 +478,36 @@ def test_only_whole_level_images_load_in_bulk(tmp_path):
     assert by_name["tail_inside"].columns().n_changes == heads["tail_inside"]
     assert by_name["short"].columns().n_changes == 0
     assert by_name["foreign"].columns().n_changes == 0
+    slab.close()
+
+
+def test_a_cache_that_loads_during_the_pass_is_skipped(tmp_path, monkeypatch):
+    """Between the extents (read under the slab's lock) and its
+    hand-over a cache may load itself (an append raced the open): the
+    pass leaves it as it is and says so."""
+    feeds = _seeded(13)
+    _write_images(tmp_path, feeds)
+    slab, caches = _caches(tmp_path, feeds)
+    extents = slab.image_extents
+
+    def racing(names):
+        out = extents(names)
+        caches[2].append_change(
+            _set_change("actor00", len(feeds[2][2]) + 1, "raced", 1)
+        )
+        return out
+
+    monkeypatch.setattr(slab, "image_extents", racing)
+    done = load_slab_images(
+        slab, caches, [len(ch) for _n, _w, ch in feeds]
+    )
+    assert done == [True, True, False, True, True]
+    raced = caches[2].columns()
+    assert raced.n_changes == len(feeds[2][2]) + 1
+    assert "raced" in raced.keys
+    assert raced.planes is None or not isinstance(
+        raced.planes, colcache._ImagePlanes
+    )
     slab.close()
 
 
@@ -404,6 +660,26 @@ def test_stale_sidecar_is_rebuilt_or_caught_up(tmp_path, which):
     repo.close()
 
 
+def test_a_feed_that_loads_during_the_pass_counts_as_single(
+    tmp_path, monkeypatch
+):
+    urls = make_corpus(str(tmp_path), 6, 64)
+    repo = Repo(path=str(tmp_path))
+    actors = _actors(repo.back, urls)
+    slab = repo.back._col_slab
+    extents = slab.image_extents
+
+    def racing(names):
+        out = extents(names)
+        actors[4].columns()
+        return out
+
+    monkeypatch.setattr(slab, "image_extents", racing)
+    assert repo.back.loader._prefetch_columns(actors) == (5, 1)
+    assert all(a.colcache.loaded for a in actors)
+    repo.close()
+
+
 def test_counters_and_span_tag(tmp_path):
     from hypermerge_tpu import telemetry
     from hypermerge_tpu.telemetry import trace as ttrace
@@ -430,8 +706,12 @@ def test_counters_and_span_tag(tmp_path):
     assert stats["cols_bulk_feeds"] == 10
     assert stats["cols_single_feeds"] == 0
     assert stats["cols_bulk_pct"] == 100.0
+    built = 0 if native.pack_lib() is not None else 10
+    assert stats["cols_planes_built"] == built
+    assert stats["cols_planes_built_pct"] == 10.0 * built
     for name, want in (
         ("loader.cols_bulk_feeds", 10), ("loader.cols_single_feeds", 0),
+        ("loader.cols_planes_built", built),
     ):
         assert after.get(name, 0) - before.get(name, 0) == want, name
     assert spans
@@ -536,11 +816,12 @@ def test_packs_bit_identical_with_counters(tmp_path, monkeypatch):
                 want, map_entries=None
             )
             assert value == want_value
-        out[pack] = (
-            states,
-            {k: stats[k] for k in stats if k.startswith("cols_")},
-            stats["fast"], stats["fallback"],
-        )
+        cols = {k: stats[k] for k in stats if k.startswith("cols_")}
+        # the slab's rows-backed feeds send either pack down the
+        # row-matrix path, which widens the five images' planes
+        assert cols.pop("cols_planes_built") == 5
+        assert cols.pop("cols_planes_built_pct") == 100.0
+        out[pack] = (states, cols, stats["fast"], stats["fallback"])
         assert stats["pipeline"] == 1
         repo.close()
     assert out["0"] == out["1"]

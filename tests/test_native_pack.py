@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from helpers import Site, random_mutation, sync
-from hypermerge_tpu import native
+from hypermerge_tpu import native, telemetry
 from hypermerge_tpu.models import Counter, Text
 from hypermerge_tpu.ops import columnar
 from hypermerge_tpu.ops.columnar import (
@@ -250,6 +250,23 @@ def _image_feeds(root, histories):
     return out
 
 
+def _per_feed_feeds(root, histories):
+    """The feeds _image_feeds wrote under `root`, each through the
+    per-feed loader: a bytes copy of its image, every plane an array
+    from the start (what every load gave before the slab-granular
+    pass handed out lazy planes)."""
+    fn = file_column_storage_fn(str(root))
+    return [
+        {
+            actor: FeedColumnCache(
+                fn(f"d{d}-{actor}"), writer=actor
+            ).columns()
+            for actor in _by_actor(history)
+        }
+        for d, history in enumerate(histories)
+    ]
+
+
 def _own_planes(fc):
     """The same feed plane-backed WITHOUT plane_meta: every plane an
     array of its own (not slices of one buffer)."""
@@ -463,6 +480,42 @@ def test_general_pack_native_gather_twin_and_per_op_pack(
 
 
 @needs_pack
+@pytest.mark.parametrize("path", ["prefix", "general"])
+def test_bulk_loaded_feeds_pack_natively_and_build_no_plane(
+    path, tmp_path, monkeypatch
+):
+    """A slab-granular load hands the pack planes nobody has made yet
+    (colcache._ImagePlanes). The native gate, the native prefix pack
+    and the native gather read the feeds' plane_meta and make none; the
+    batch is, bit for bit, the one the per-feed loader's columns give."""
+    hists = [
+        _single_writer_history(seed) if path == "prefix"
+        else _multi_history(seed)
+        for seed in (61, 62, 63, 64)
+    ]
+    bulk = _image_feeds(tmp_path, hists)
+    ref = _per_feed_feeds(tmp_path, hists)
+    calls = _spy_native(monkeypatch)
+    monkeypatch.setenv("HM_NATIVE_PACK", "1")
+    built = telemetry.counter("loader.cols_planes_built")
+    before = built.value()
+    got = pack_docs_columns([_whole(f) for f in bulk])
+    assert calls and all(calls), "native entry point was not used"
+    assert built.value() == before
+    assert all(
+        fc.planes._built == {} for feeds in bulk for fc in feeds.values()
+    )
+    want = pack_docs_columns([_whole(f) for f in ref])
+    _assert_batches_identical(got, want)
+    # the numpy twin reads them through the mapping: counted, a feed once
+    monkeypatch.setenv("HM_NATIVE_PACK", "0")
+    _assert_batches_identical(
+        pack_docs_columns([_whole(f) for f in bulk]), want
+    )
+    assert built.value() == before + sum(len(f) for f in bulk)
+
+
+@needs_pack
 def test_pack_releases_gil(tmp_path, monkeypatch):
     """The hm_pack_prefix binding must DROP the GIL (ctypes.CDLL
     foreign-call semantics) — the streaming slab pipeline's pack
@@ -475,7 +528,7 @@ def test_pack_releases_gil(tmp_path, monkeypatch):
     import threading
     import time
 
-    from hypermerge_tpu import native
+    from hypermerge_tpu import native, telemetry
     from hypermerge_tpu.ops.synth import synth_changes
 
     assert native.pack_drops_gil()
