@@ -481,7 +481,12 @@ GUARDS: Tuple[GuardedClass, ...] = (
             "domain + feed lock) and the WAL checkpoint thread's "
             "storage.sync() — every use, fsync, and drop serializes "
             "under store.feed_io, or interleaved seek/write could "
-            "tear the sidecar and a drop could close an fd mid-fsync.",
+            "tear the sidecar and a drop could close an fd mid-fsync. "
+            "append_many (and append, its one-block case) takes the "
+            "lock around _append_io_locked, which writes a whole run "
+            "of blocks and its one .len record under it; get_range / "
+            "block_sizes open the log read-only on a descriptor of "
+            "their own and touch none of these fields.",
     ),
     GuardedClass(
         "CursorStore", "hypermerge_tpu.storage.stores", "store.cursors",

@@ -82,6 +82,21 @@ def _chunk_bytes() -> int:
     return int(os.environ.get("HM_REPL_CHUNK_BYTES", str(8 * 1024 * 1024)))
 
 
+def _within_bytes(feed: Feed, start: int, end: int) -> int:
+    """The end of [start, end) shrunk until its blocks fit the byte
+    budget, at least one block: added up from the feed's index
+    (Feed.block_sizes), no block is read."""
+    budget = _chunk_bytes()
+    total = 0
+    count = 0
+    for size in feed.block_sizes(start, end):
+        total += size
+        if total > budget and count:
+            break
+        count += 1
+    return start + max(count, 1)
+
+
 def _flush_window_s() -> float:
     return float(os.environ.get("HM_REPL_FLUSH_MS", "2")) / 1e3
 
@@ -548,18 +563,10 @@ class ReplicationManager:
             ]
             if not lengths:
                 return have
-        # shrink the block budget until the byte budget holds
-        want = min(have, start + _chunk_blocks())
-        budget = _chunk_bytes()
-        total = 0
-        count = 0
-        for b in feed.get_batch(start, want):
-            total += len(b)
-            count += 1
-            if total > budget and count > 1:
-                count -= 1
-                break
-        want = start + max(count, 1)
+        # the block budget, shrunk until the byte budget holds
+        want = _within_bytes(
+            feed, start, min(have, start + _chunk_blocks())
+        )
         if writable:
             return want
         within = [l for l in lengths if l <= want]
@@ -738,16 +745,7 @@ class ReplicationManager:
         if start >= end:
             return
         # byte budget too: a frame must stay far below the transport cap
-        budget = _chunk_bytes()
-        total = 0
-        count = 0
-        for b in feed.get_batch(start, end):
-            total += len(b)
-            count += 1
-            if total > budget and count > 1:
-                count -= 1
-                break
-        end = start + max(count, 1)
+        end = _within_bytes(feed, start, end)
         served = feed.integrity.range_proofs(feed, start, end)
         if served is None:
             return  # no signed record covers the range

@@ -21,7 +21,7 @@ import os
 import struct
 import threading
 import zlib
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -34,6 +34,13 @@ from ..utils.queue import Queue
 from .durability import fsync_dir, fsync_tier
 from .faults import harness_gen, io_fsync, io_open, io_remove, io_replace
 
+# the block log's I/O by the run: a write of a block log and the blocks
+# it carried, an open of one to read blocks and the blocks it read
+_M_LOG_WRITES = telemetry.counter("storage.feed.log_writes")
+_M_BLOCKS_WRITTEN = telemetry.counter("storage.feed.blocks_written")
+_M_LOG_OPENS = telemetry.counter("storage.feed.log_opens")
+_M_BLOCKS_READ = telemetry.counter("storage.feed.blocks_read")
+
 
 class MemoryFeedStorage:
     def __init__(self) -> None:
@@ -42,8 +49,17 @@ class MemoryFeedStorage:
     def append(self, data: bytes) -> None:
         self.blocks.append(data)
 
+    def append_many(self, blocks: Sequence[bytes]) -> None:
+        self.blocks.extend(blocks)
+
     def get(self, index: int) -> bytes:
         return self.blocks[index]
+
+    def get_range(self, start: int, end: int) -> List[bytes]:
+        return self.blocks[start:end]
+
+    def block_sizes(self, start: int, end: int) -> List[int]:
+        return [len(b) for b in self.blocks[start:end]]
 
     def __len__(self) -> int:
         return len(self.blocks)
@@ -87,11 +103,27 @@ class FileFeedStorage:
     snapshot (`_tell_heads`), so a told feed is never answered from a
     sealed entry.
 
+    The log's I/O is by the RUN of blocks, whose length is what the
+    caller passed: `append_many` stores a run with one seek, ONE write
+    of every `header + data`, one truncate, one flush and ONE `.len`
+    record, and `get_range` reads one with one open, one seek and one
+    read, sliced by the index (`block_sizes` reads nothing). `append`
+    and `get` are the one-block cases of the same code. So a
+    replicated frame (Feed.append_verified on the way in, get_batch on
+    the way out) costs one write, one `.len` and one open however many
+    blocks it carries; a crash inside the write leaves a prefix of the
+    run's blocks and no `.len` over them, which the scan admits block
+    by whole block, as a crash between two appends did. Counters:
+    `storage.feed.log_writes` / `blocks_written`, `log_opens` /
+    `blocks_read` (the scan that builds the index counts in neither).
+
     Durability (storage/durability.py HM_FSYNC): tier 2 fsyncs the log
-    inside `append` BEFORE the `.len` sidecar describes it; tier 1
-    marks this storage dirty with the repo's DurabilityManager, whose
-    group flusher calls `sync()`. Tier 0 (default) never fsyncs —
-    crash-safe (torn tails heal), not crash-durable."""
+    inside `append_many` BEFORE the `.len` sidecar describes it (once
+    a run); with a journal every block of the run is journaled under
+    its own index instead; tier 1 marks this storage dirty with the
+    repo's DurabilityManager, whose group flusher calls `sync()`. Tier
+    0 (default) never fsyncs — crash-safe (torn tails heal), not
+    crash-durable."""
 
     _HDR = struct.Struct("<I")
     _LEN = struct.Struct("<QQ")  # block count, end offset
@@ -239,14 +271,26 @@ class FileFeedStorage:
         self._count = len(self._offsets)
 
     def append(self, data: bytes) -> None:
-        with self._io:
-            self._append_io_locked(data)
+        self.append_many((data,))
 
-    def _append_io_locked(self, data: bytes) -> None:
+    def append_many(self, blocks: Sequence[bytes]) -> None:
+        """Store a run of blocks in ONE log write and ONE `.len`
+        record: the bytes on disk are those of the same blocks appended
+        one by one. The run's length is what the caller passed (a local
+        change: one block; a replicated frame: all of its blocks,
+        Feed.append_verified)."""
+        if not blocks:
+            return
+        with self._io:
+            self._append_io_locked(blocks)
+
+    def _append_io_locked(self, blocks: Sequence[bytes]) -> None:
         # REQUIRES store.feed_io (analysis/guards.py)
         self._ensure_scan()
         self._tell_heads()  # before the write: a torn one counts too
         tier = fsync_tier()
+        pack = self._HDR.pack
+        raw = b"".join([p for b in blocks for p in (pack(len(b)), b)])
         # exception safety under mid-write ENOSPC/EIO: the in-memory
         # _offsets/_end/_count only advance AFTER every log byte landed
         # (and, at tier 2, fsynced) — a raise leaves memory pointing at
@@ -258,22 +302,25 @@ class FileFeedStorage:
         try:
             fh = self._write_handle()
             fh.seek(self._end)  # overwrite any torn tail...
-            fh.write(self._HDR.pack(len(data)))
-            fh.write(data)
+            fh.write(raw)
             fh.truncate()  # ...and drop stale bytes beyond it, so a later
             # scan can't misparse leftovers as a phantom block
             fh.flush()
-            # shared journal (storage/wal.py): at HM_FSYNC>=1 the
+            # shared journal (storage/wal.py): at HM_FSYNC>=1 a
             # block's durability is ONE sequential journal append +
             # the group-commit fsync — the log itself stays page-cache
-            # only until checkpoint. A raise here (journal fsync
+            # only until checkpoint. Every block of the run is handed
+            # over with its own index. A raise here (journal fsync
             # error) unwinds exactly like a torn write: memory never
             # advances, the on-disk tail heals on the next append.
-            journaled = False
-            if self._durability is not None:
-                journaled = self._durability.journal_append(
-                    self.path, len(self._offsets), data, self
-                )
+            journaled = self._durability is not None
+            if journaled:
+                index = len(self._offsets)
+                for i, b in enumerate(blocks):
+                    if not self._durability.journal_append(
+                        self.path, index + i, b, self
+                    ):
+                        journaled = False
             if tier >= 2 and not journaled:
                 # legacy: log durable BEFORE the .len sidecar
                 # describes it
@@ -281,16 +328,21 @@ class FileFeedStorage:
         except BaseException:
             self._drop_write_handles()
             raise
-        self._offsets.append(self._end + self._HDR.size)
-        self._sizes.append(len(data))
-        self._end += self._HDR.size + len(data)
+        end = self._end
+        for b in blocks:
+            self._offsets.append(end + self._HDR.size)
+            self._sizes.append(len(b))
+            end += self._HDR.size + len(b)
+        self._end = end
         self._count = len(self._offsets)
+        _M_LOG_WRITES.add(1)
+        _M_BLOCKS_WRITTEN.add(len(blocks))
         try:
             self._write_len()
         except OSError as e:
-            # the block is durable; the sidecar is advisory (a mismatch
-            # just costs the next open a rescan) — never fail the
-            # acked append over it
+            # the blocks are durable; the sidecar is advisory (a
+            # mismatch just costs the next open a rescan) — never fail
+            # the acked append over it
             log("storage:feed", f".len write failed {self.path}: {e}")
         if tier == 1 and not journaled and self._durability is not None:
             self._durability.mark_dirty(self)
@@ -392,9 +444,33 @@ class FileFeedStorage:
                 f"block {index} beyond scanned log end "
                 f"({len(self._offsets)} block(s))"
             )
+        return self.get_range(index, index + 1)[0]
+
+    def get_range(self, start: int, end: int) -> List[bytes]:
+        """Blocks [start, end) in ONE open, seek and read of the log,
+        sliced by the index. A log that ends before `end` (the `.len`
+        sidecar promised more than the scan could parse) gives the
+        blocks there are."""
+        self._ensure_scan()
+        offsets = self._offsets[start:end]
+        if not offsets:
+            return []
+        sizes = self._sizes[start:end]
+        base = offsets[0]
         with open(self.path, "rb") as fh:
-            fh.seek(self._offsets[index])
-            return fh.read(self._sizes[index])
+            fh.seek(base)
+            raw = fh.read(offsets[-1] + sizes[-1] - base)
+        _M_LOG_OPENS.add(1)
+        _M_BLOCKS_READ.add(len(offsets))
+        return [
+            raw[o - base : o - base + n] for o, n in zip(offsets, sizes)
+        ]
+
+    def block_sizes(self, start: int, end: int) -> List[int]:
+        """Byte lengths of blocks [start, end) from the index: no block
+        is read."""
+        self._ensure_scan()
+        return self._sizes[start:end]
 
     def __len__(self) -> int:
         self._ensure_count()
@@ -692,7 +768,12 @@ class Feed:
         over [0, length) BEFORE storing anything (the trust boundary —
         reference: hypercore verifies every replicated block against the
         feed key). Duplicate prefixes are tolerated; a gap or a bad
-        signature stores nothing and returns False."""
+        signature stores nothing and returns False. What is accepted
+        is stored whole: ONE write of the block log and one `.len`
+        record an extension (FileFeedStorage.append_many), however many
+        blocks the frame carried; the listeners then hear of every
+        block (`on_append`) and once of the extension
+        (`on_extended`)."""
         if self.integrity is None:
             return False
         with self._lock:
@@ -714,27 +795,24 @@ class Feed:
             if res is None:
                 return False
             root, new_leaves = res
-            indices = []
-            # as a local append's span: the log writes, their `.len`,
-            # and the chain's record
+            # as a local append's span: the log write, its `.len`, and
+            # the chain's record
             with telemetry.span(
                 "storage.feed.append", "storage",
                 bytes=sum(map(len, eff)), blocks=len(eff),
             ):
-                for b in eff:
-                    self._storage.append(b)
-                    indices.append(len(self._storage) - 1)
+                self._storage.append_many(eff)
                 self.integrity.record_verified(
                     length, root, sig, new_leaves
                 )
             self._prune_sparse_locked()
             listeners = list(self._append_listeners)
             extended = list(self._extend_listeners)
-        for i, b in zip(indices, eff):
+        for i, b in enumerate(eff, have):
             for cb in listeners:
                 cb(i, b)
         for cb in extended:
-            cb(indices[0], length)
+            cb(have, length)
         return True
 
     def seal(self) -> None:
@@ -856,19 +934,20 @@ class Feed:
             return self._storage.get(index)
 
     def get_batch(self, start: int, end: int) -> List[bytes]:
+        """Blocks [start, end), as far as the log holds them, in ONE
+        open and read of the block log (FileFeedStorage.get_range).
+        Where the count index ran ahead of what the block log can
+        actually parse (tampered/torn header) the caller gets the true
+        short log — the integrity audit turns the shortfall into
+        AUDIT_TAMPERED."""
         with self._lock:
-            end = min(end, len(self._storage))
-            out = []
-            for i in range(start, end):
-                try:
-                    out.append(self._storage.get(i))
-                except IndexError:
-                    # count index ran ahead of what the block log can
-                    # actually parse (tampered/torn header): hand the
-                    # caller the true short log — the integrity audit
-                    # turns the shortfall into AUDIT_TAMPERED
-                    break
-            return out
+            return self._storage.get_range(start, end)
+
+    def block_sizes(self, start: int, end: int) -> List[int]:
+        """Byte lengths of blocks [start, end), as far as the log holds
+        them, from the storage's index: nothing is read."""
+        with self._lock:
+            return self._storage.block_sizes(start, end)
 
     def read_all(self) -> List[bytes]:
         return self.get_batch(0, self.length)

@@ -577,6 +577,23 @@ def test_a_clone_asks_once_and_echoes_nothing(traced):
         DOCS * CHANGES)
 
 
+def test_a_frame_is_one_write_and_one_open_of_the_log(traced):
+    """ISSUE 42: B stores a verified frame with ONE write of the feed's
+    block log (`FileFeedStorage.append_many`), and A reads the frame it
+    sends with ONE open (`get_range`; the boundary is reckoned from the
+    index). Any other open is a single-block reader's
+    (`Actor._get_change`; how many there are goes by the threads'
+    timing: one in 13 opens here, 1,002 beside 768 in a round on the
+    chip machine)."""
+    _events, moved, _s = traced
+    feeds = 3 * DOCS
+    assert moved["storage.feed.log_writes"] == feeds
+    assert moved["storage.feed.blocks_written"] == DOCS * CHANGES
+    singles = moved["storage.feed.log_opens"] - feeds
+    assert singles >= 0
+    assert moved["storage.feed.blocks_read"] == DOCS * CHANGES + singles
+
+
 def test_a_request_stands_until_blocks_of_its_feed_or_a_second_sweep(
     store, tmp_path, monkeypatch
 ):
@@ -723,6 +740,45 @@ def test_metric_file_on_a_hand_worked_obs(monkeypatch, metric):
     bare = {"trace": HAND_OBS["trace"], "counters_before": {},
             "counters_after": {}}
     assert reader.read(spec_.get("params") or {}, bare) is None
+
+
+BLOCK_LOG_METRICS = {
+    # metric: (cells, counters before, after, reading)
+    "storage.blocks_per_log_write": (
+        ["sync.clone", "rw.ycsb-a"],
+        {"storage.feed.blocks_written": 64, "storage.feed.log_writes": 64},
+        {"storage.feed.blocks_written": 24640,
+         "storage.feed.log_writes": 832}, 32.0),
+    "storage.blocks_per_log_read": (
+        ["sync.clone"],
+        {"storage.feed.blocks_read": 10, "storage.feed.log_opens": 10},
+        {"storage.feed.blocks_read": 24580,
+         "storage.feed.log_opens": 790}, 31.5),
+}
+
+
+@pytest.mark.parametrize("metric", sorted(BLOCK_LOG_METRICS))
+def test_block_log_metric_file_on_a_hand_worked_obs(metric):
+    """ISSUE 42's two metrics are data: the benchmark's `counter_ratio`
+    over the window's counters; a program without them (the parent), or
+    a window that touched no block log, reads nothing."""
+    cells, before, after, reading = BLOCK_LOG_METRICS[metric]
+    spec_ = harness.load_json("layer_metrics", metric + ".json")
+    entry = next(m for m in bench_json()["per_layer"] if m["name"] == metric)
+    assert spec_["cells"] == entry["workloads"] == cells
+    assert (spec_["reader"], spec_["source"], entry["source"]) == (
+        "counter_ratio", "program_counter", "program_counter")
+    assert (spec_["layer"], entry["layer"]) == ("feed storage",) * 2
+    assert (spec_["moves"], entry["moves"]) == ("ops_per_s", "ops_per_s")
+    assert (spec_["unit"], spec_["better"]) == (
+        entry["unit"], entry["better"]) == ("blocks", "higher")
+    reader = harness.load_module("readers", spec_["reader"])
+    obs = {"counters_before": before, "counters_after": after}
+    assert reader.read(spec_["params"], obs) == pytest.approx(reading)
+    assert reader.read(spec_["params"], {
+        "counters_before": {}, "counters_after": {"serve.reads": 3}}) is None
+    assert reader.read(spec_["params"], {
+        "counters_before": after, "counters_after": after}) is None
 
 
 def test_every_metric_of_the_cell_has_its_file():

@@ -223,6 +223,41 @@ def test_feed_crash_matrix_intra_write_tears(tmp_path):
             ) == got + 1
 
 
+@pytest.mark.parametrize("cut", (None, 1, 5, 26, 27, 60),
+                         ids=lambda c: "boundary" if c is None else f"tear{c}")
+def test_feed_crash_matrix_of_a_run(tmp_path, cut):
+    """An extension stored as ONE write (append_many): a crash at any
+    boundary, or INSIDE the write, leaves a gapless prefix of the
+    acknowledged blocks plus at most a prefix of the run's own blocks,
+    each whole (a crash between two of the appends left the same), and
+    the next append heals the tail."""
+    work = tmp_path / "work"
+    rec = F.CrashRecorder(str(work))
+    acked = [b"solo-0", b"solo-1"]
+    run = [b"run-%d-%s" % (i, b"y" * (9 * i)) for i in range(5)]
+    with F.activate(recorder=rec):
+        s = FileFeedStorage(str(work / "ab" / "feed"))
+        for b in acked:
+            s.append(b)
+        before = rec.n_points - 1  # events until the run's own
+        s.append_many(run)
+    want = acked + run
+    for k in range(rec.n_points - (cut is not None)):
+        dst = str(tmp_path / f"r{k}")
+        rec.materialize(dst, k, partial_last=cut)
+        path = os.path.join(dst, "ab", "feed")
+        s2 = FileFeedStorage(path)
+        got = len(s2)  # reopen never raises
+        assert s2.get_range(0, got) == want[:got]
+        if k >= before:
+            assert got >= len(acked)  # nothing acknowledged is lost
+        if k == rec.n_points - 1:
+            assert got == len(want)
+        s2.append(b"heal")
+        s3 = FileFeedStorage(path)
+        assert len(s3) == got + 1 and s3.get(got) == b"heal"
+
+
 def test_slab_crash_matrix(tmp_path):
     from hypermerge_tpu.storage.slab import (
         CorpusSlab,

@@ -835,3 +835,122 @@ class TestProofServer:
             sig,
         )
         assert ok
+
+
+class TestFramesAsRanges:
+    """ISSUE 42: a frame crosses the block log as one range. What is
+    rejected never reaches it, and the boundary of the next frame is
+    reckoned from the index alone."""
+
+    COUNTERS = (
+        "storage.feed.log_writes", "storage.feed.blocks_written",
+        "storage.feed.log_opens", "storage.feed.blocks_read",
+    )
+
+    def _moved(self, before):
+        from hypermerge_tpu import telemetry
+
+        after = telemetry.snapshot()
+        return [after.get(k, 0) - before.get(k, 0) for k in self.COUNTERS]
+
+    def _file_feeds(self, root):
+        from hypermerge_tpu.storage.feed import file_storage_fn
+        from hypermerge_tpu.storage.integrity import file_sig_storage_fn
+
+        return FeedStore(
+            file_storage_fn(str(root)),
+            sig_fn=file_sig_storage_fn(str(root)),
+        )
+
+    def _writer(self, root, blocks):
+        feeds = self._file_feeds(root)
+        feed = feeds.create(keymod.create())
+        for b in blocks:
+            feed.append(b)
+        feed.seal()
+        return feeds, feed
+
+    @pytest.mark.parametrize(
+        "why", ("signature", "block", "gap", "length", "short")
+    )
+    def test_rejected_extension_writes_and_counts_nothing(
+        self, tmp_path, why
+    ):
+        from hypermerge_tpu import telemetry
+
+        blocks = [b"blk-%d" % i * 9 for i in range(8)]
+        feeds_a, fa = self._writer(tmp_path / "a", blocks)
+        sig = {n: fa.integrity.record_for(fa, n)[2] for n in (3, 8)}
+        feeds_b = self._file_feeds(tmp_path / "b")
+        fb = feeds_b.open_feed(fa.public_key)
+        assert fb.append_verified(0, blocks[:3], 3, sig[3])
+        path = fb._storage.path
+        was = [open(p, "rb").read() for p in (path, path + ".len")]
+        heard = []
+        fb.on_append(lambda i, b: heard.append(i))
+        fb.on_extended(lambda a, b: heard.append((a, b)))
+        before = telemetry.snapshot()
+        start, send, length, s = 3, list(blocks[3:]), 8, sig[8]
+        if why == "signature":
+            s = bytes([s[0] ^ 1]) + s[1:]
+        elif why == "block":
+            send[2] = b"forged"
+        elif why == "gap":
+            start, send = 4, send[1:]
+        elif why == "length":
+            length = 7
+        else:
+            send = send[:-1]
+        assert not fb.append_verified(start, send, length, s)
+        assert self._moved(before) == [0, 0, 0, 0]
+        assert heard == [] and fb.length == 3
+        assert was == [open(p, "rb").read() for p in (path, path + ".len")]
+        # and the true extension still lands, as one write
+        assert fb.append_verified(3, blocks[3:], 8, sig[8])
+        assert self._moved(before) == [1, 5, 0, 0]
+        assert heard == [3, 4, 5, 6, 7, (3, 8)]
+        assert fb.read_all() == blocks and fb.audit()
+        feeds_a.close()
+        feeds_b.close()
+
+    @pytest.mark.parametrize("writable", (True, False),
+                             ids=("writer", "reader"))
+    def test_pick_boundary_keeps_both_budgets_without_a_read(
+        self, tmp_path, monkeypatch, writable
+    ):
+        """HM_REPL_CHUNK blocks and HM_REPL_CHUNK_BYTES bytes bound the
+        next frame, added up from the log's index: no block is read. A
+        reader serves up to the largest signed record inside both
+        budgets, else the first one past `start`."""
+        from hypermerge_tpu import telemetry
+
+        blocks = [bytes([i]) * 1000 for i in range(12)]
+        feeds_a, fa = self._writer(tmp_path / "a", blocks)
+        feed, feeds = fa, feeds_a
+        if not writable:  # a clone holding records at 4, 8 and 12
+            feeds = self._file_feeds(tmp_path / "b")
+            feed = feeds.open_feed(fa.public_key)
+            for n in (4, 8, 12):
+                assert feed.append_verified(
+                    n - 4, blocks[n - 4:n], n,
+                    fa.integrity.record_for(fa, n)[2])
+        mgr = ReplicationManager(feeds, lambda pk, peer: None)
+        before = telemetry.snapshot()
+        monkeypatch.setenv("HM_REPL_CHUNK", "7")
+        assert mgr._pick_boundary(feed, 0) == (7 if writable else 4)
+        assert mgr._pick_boundary(feed, 8) == 12  # the head bounds it
+        monkeypatch.setenv("HM_REPL_CHUNK", "1024")
+        monkeypatch.setenv("HM_REPL_CHUNK_BYTES", "5500")
+        assert mgr._pick_boundary(feed, 2) == (7 if writable else 4)
+        monkeypatch.setenv("HM_REPL_CHUNK_BYTES", "2500")
+        assert mgr._pick_boundary(feed, 1) == (3 if writable else 4)
+        monkeypatch.setenv("HM_REPL_CHUNK_BYTES", "10")  # one block goes
+        assert mgr._pick_boundary(feed, 5) == (6 if writable else 8)
+        assert self._moved(before)[2:] == [0, 0]
+        # the frame itself is then ONE open of the log
+        msg = mgr._blocks_msg(feed, feed.discovery_id, 4, 8)
+        assert [base64.b64decode(b) for b in msg["blocks"]] == blocks[4:8]
+        assert self._moved(before)[2:] == [1, 4]
+        feeds.close()
+        if not writable:
+            feeds_a.close()

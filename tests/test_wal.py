@@ -837,6 +837,68 @@ def test_commit_ack_covers_unjournaled_legacy_appends(
 
 
 # ---------------------------------------------------------------------------
+# a run of blocks (FileFeedStorage.append_many, ISSUE 42): every block
+# journaled with its index, and durable before `.len` describes it
+
+
+@pytest.mark.parametrize("journal", (True, False), ids=("wal", "no-wal"))
+@pytest.mark.parametrize("tier", (1, 2))
+def test_append_many_is_durable_as_the_tier_has_it(
+    tmp_path, monkeypatch, tier, journal
+):
+    """One extension of five blocks onto a log of two. With a journal:
+    it holds every block under its own index, and at tier 2 its commit
+    fsync comes before the `.len` record (tier 1: the window's ONE
+    fsync, no per-feed fsync). Without one: tier 2 fsyncs the log ONCE,
+    before `.len`; tier 1 marks the storage dirty for the group
+    flush."""
+    monkeypatch.setenv("HM_FSYNC", str(tier))
+    monkeypatch.setenv("HM_FSYNC_MS", "10000")  # we drive the flush
+    work = tmp_path / "work"
+    blocks = [b"ext-%d" % i * (i + 1) for i in range(5)]
+    rec = F.CrashRecorder(str(work))
+    with F.activate(recorder=rec):
+        os.makedirs(str(work))
+        dm = DurabilityManager()
+        if journal:
+            dm.attach_wal(WriteAheadLog(str(work / "wal.log"), tier=tier))
+        st = FileFeedStorage(
+            str(work / "feeds" / "ab" / "feed0"), durability=dm
+        )
+        st.append(b"zero")
+        st.append(b"one")
+        mark = len(rec.events)
+        st.append_many(blocks)
+        events = rec.events[mark:]
+        if tier == 1:
+            assert dm.sync_now() >= 1
+        counts = _fsyncs(rec, mark)
+        journaled = read_journal(str(work / "wal.log"))  # ere the close
+        dm.close()
+    log, sidecar = "feeds/ab/feed0", "feeds/ab/feed0.len"
+    log_writes = [e for e in events if e[1] == log and e[0] == F.WRITE]
+    assert len(log_writes) == 1  # the run is ONE write of the log
+    assert len([e for e in events if e[1] == sidecar]) == 1
+    at = {(e[0], e[1]): i for i, e in enumerate(events)}
+    if journal:
+        _h, _dirty, records, torn = journaled
+        assert not torn
+        assert [r for r in records if r[1] >= 2] == [
+            ("feed0", 2 + i, b) for i, b in enumerate(blocks)
+        ]
+        assert log not in counts and counts["wal.log"] >= 1
+        if tier == 2:
+            assert at[F.FSYNC, "wal.log"] < at[F.WRITE, sidecar]
+    elif tier == 2:
+        assert counts == {log: 1}
+        assert at[F.WRITE, log] < at[F.FSYNC, log] < at[F.WRITE, sidecar]
+    else:
+        assert counts.get(log) == 1  # the group flush found it dirty
+    assert FileFeedStorage(str(work / log)).get_range(0, 9) == [
+        b"zero", b"one"] + blocks
+
+
+# ---------------------------------------------------------------------------
 # the crash matrix crossed with the sharded write plane: kill -9 a
 # worker PROCESS mid-burst and hold the same gate — acked_lost=0
 
