@@ -233,7 +233,6 @@ def plan(n_docs: int, n_ops: int, seed: int, env: dict) -> dict:
     n_merge = max(5, math.ceil((min_cells // 2 + 1) / n_ops) + 1)
     merge = rng.sample(slabs[0], n_merge)
     return {
-        "n_slabs": len(slabs),
         "tail": slabs[-1],
         "sample": sample,
         "edit": edit,
@@ -242,7 +241,7 @@ def plan(n_docs: int, n_ops: int, seed: int, env: dict) -> dict:
     }
 
 
-def _open_corpus(path, urls, n_slabs, expect_platform, n_devices):
+def _open_corpus(path, urls, expect_platform, n_devices):
     """Repo(path) -> open_many -> summary barrier, with the checks every
     cold open of the smoke must pass. Returns (repo, handles, summaries,
     stats, wall seconds)."""
@@ -259,15 +258,16 @@ def _open_corpus(path, urls, n_slabs, expect_platform, n_devices):
     log(f"cold open {n} docs in {wall:.2f}s: {stats}")
     check(stats["docs"] == stats["fast"] == n, "docs == fast == n", brief)
     check(stats["fallback"] == 0, "fallback == 0", brief)
-    check(stats["pipeline"] == 1, "pipeline == 1", brief)
     check(stats["host_slabs"] == 0, "host_slabs == 0", brief)
-    check(stats["device_slabs"] == n_slabs, "every slab on device", brief)
+    check(stats["device_slabs"] == stats["slabs"], "every slab on device",
+          brief)
     check(stats["platform"] == expect_platform, "slab platform", brief)
     check(len(summ.doc_ids) == n, "summaries cover the corpus")
     if n_devices > 1:
         check(stats.get("rr_devices") == n_devices, "rr_devices", brief)
         check(
-            sum(stats["slabs_per_chip"]) == stats["rr_slabs"] == n_slabs,
+            sum(stats["slabs_per_chip"]) == stats["rr_slabs"]
+            == stats["device_slabs"],
             "slabs_per_chip sums to rr_slabs", brief,
         )
     return repo, handles, summ, stats, wall
@@ -613,51 +613,6 @@ def _hbm_peak():
     return out
 
 
-def _mesh_checks(n_ops: int) -> dict:
-    """More than one chip: the collective reductions of the mesh
-    scheduler, once, against the per-slab host fetch / host merge."""
-    import numpy as np
-
-    from hypermerge_tpu.ops.columnar import pack_docs_columns
-    from hypermerge_tpu.ops.crdt_kernels import bucket_doc_actors
-    from hypermerge_tpu.ops.warmup import template_specs
-    from hypermerge_tpu.parallel.mesh import make_mesh
-    from hypermerge_tpu.parallel.sharded import (
-        MeshBulkScheduler,
-        remote_copy_capable,
-    )
-
-    specs = template_specs(n_ops, OPS_PER_CHANGE)
-    mesh = make_mesh()
-    sch = MeshBulkScheduler(mesh, track_resident=True)
-    outs = []
-    for k in range(6):
-        b = pack_docs_columns(
-            [specs[(k + j) % 8] for j in range(64)], n_docs=64
-        )
-        out, wire = sch.dispatch(b, lean=False)
-        outs.append((b, out, wire))
-    n_actors = max(len(b.actors) for b, _o, _w in outs)
-    want = np.zeros(n_actors, np.int32)
-    for b, out, _w in outs:
-        da, _A, _K = bucket_doc_actors(b)
-        clock = np.asarray(out.clock)
-        ok = da >= 0
-        np.maximum.at(want, da[ok], clock[ok])
-    got = sch.collective_clock_union(n_actors)
-    check(np.array_equal(got, want), "collective_clock_union == host merge")
-    gathered = sch.gather_summaries()
-    check([g[0] for g in gathered] == list(range(len(outs))),
-          "gather_summaries in dispatch order")
-    for (_seq, _n, host_wire), (_b, _o, wire) in zip(gathered, outs):
-        check(np.array_equal(host_wire, np.asarray(wire)),
-              "gathered wire == per-slab host fetch")
-    return {
-        "pallas_gather": bool(remote_copy_capable(mesh)),
-        "slabs_per_chip": list(sch.slabs_per_chip),
-    }
-
-
 def child_first(args, state: dict) -> dict:
     """Stage 1: holds the chip for the whole main path."""
     import jax
@@ -674,13 +629,13 @@ def child_first(args, state: dict) -> dict:
     out = {"n_devices": n_devices}
 
     repo, handles, summ, stats, wall = _open_corpus(
-        args.repo, urls, p["n_slabs"], expect, n_devices
+        args.repo, urls, expect, n_devices
     )
     out["cold_open_s"] = round(wall, 3)
     out["setup_s"] = round(time.perf_counter() - t_start, 3)
     out["bulk_stats"] = {
         k: stats.get(k) for k in (
-            "docs", "fast", "fallback", "pipeline", "device_slabs",
+            "docs", "fast", "fallback", "device_slabs",
             "host_slabs", "platform", "pack_workers", "rr_devices",
             "rr_slabs", "slabs_per_chip", "wall_critical_path",
         ) if k in stats
@@ -705,10 +660,9 @@ def child_first(args, state: dict) -> dict:
         repo, summ, urls, p["merge"]
     )
     if n_devices > 1:
-        out["mesh"] = _mesh_checks(args.ops)
         # where the long-lived device state sits (a finding for the
         # one-chip-per-worker work, not a check)
-        out["mesh"]["state_devices"] = {
+        out["mesh"] = {"state_devices": {
             "serve": sorted({
                 d.id for e in repo.back.serve._cache._entries.values()
                 for d in e.dev.devices()
@@ -716,7 +670,7 @@ def child_first(args, state: dict) -> dict:
             "clock_mirror": sorted(
                 d.id for d in repo.back.clocks.mirror._matrix.devices()
             ),
-        }
+        }}
         mesh0_summaries = {
             i: _summary_rows(summ, validate_doc_url(urls[i]))
             for i in p["sample"]
@@ -729,7 +683,7 @@ def child_first(args, state: dict) -> dict:
 
     # reopen in the same process: the edits are there
     repo, handles, summ, stats, wall = _open_corpus(
-        args.repo, urls, p["n_slabs"], expect, n_devices
+        args.repo, urls, expect, n_devices
     )
     out["reopen_s"] = round(wall, 3)
     for i in p["edit"]:
@@ -790,7 +744,7 @@ def child_second(args, state: dict) -> dict:
     check(jax.default_backend() == expect, "jax.default_backend()")
     urls, p = state["urls"], state["plan"]
     repo, handles, summ, stats, wall = _open_corpus(
-        args.repo, urls, p["n_slabs"], expect, len(jax.devices())
+        args.repo, urls, expect, len(jax.devices())
     )
     out = {
         "cold_open_s": round(wall, 3),
@@ -835,7 +789,7 @@ def child_recover(args, state: dict) -> dict:
     expect = "cpu" if args.rehearse else "tpu"
     urls, p = state["urls"], state["plan"]
     repo, handles, summ, stats, wall = _open_corpus(
-        args.repo, urls, p["n_slabs"], expect, len(jax.devices())
+        args.repo, urls, expect, len(jax.devices())
     )
     rep = repo.back.recovery_report
     check(rep is not None, "crash recovery ran on reopen")
@@ -1144,7 +1098,6 @@ def main() -> int:
         "corpus_s": round(corpus_s, 3),
         "host_slabs": first["bulk_stats"]["host_slabs"],
         "fallback": first["bulk_stats"]["fallback"],
-        "pipeline": first["bulk_stats"]["pipeline"],
         "serve": first["serve"],
         "live": first["live"]["live"],
         "acked_lost": recover["acked_lost"],

@@ -6,7 +6,8 @@ CRDT with hypercore-style signed append-only feeds) built TPU-first:
 
 - The CRDT compute path — vector-clock algebra, LWW map resolution, RGA list
   ordering, whole-document materialization — runs as batched JAX/XLA programs
-  (`vmap` across documents, `pjit`/`shard_map` across chips of a Mesh).
+  (`vmap` across documents; whole slabs dealt round-robin across the
+  visible chips, `parallel/sharded.SlabRoundRobin`).
 - The runtime around it — repo orchestration, per-actor append-only signed
   feeds, replication, storage — is host-side Python/C++ mirroring the
   reference's layer map (see SURVEY.md §1).

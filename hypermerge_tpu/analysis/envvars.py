@@ -1,7 +1,7 @@
 """The HM_* environment-variable registry.
 
 Every `os.environ` read of an `HM_`-prefixed name anywhere in the
-package (plus tools/, scripts/, bench.py, __graft_entry__.py) must be
+package (plus tools/, scripts/, __graft_entry__.py) must be
 declared here exactly once — the `env-registry` lint rule
 (analysis/linter.py) fails tier-1 on an undeclared read, on a registry
 entry nothing reads (stale), and on a registry entry missing from the
@@ -54,13 +54,10 @@ REGISTRY: Tuple[EnvVar, ...] = (
            "summary rows; clean docs skip pack+dispatch+fetch "
            "(0 = disabled)."),
     # -- mesh / multi-chip ---------------------------------------------
-    EnvVar("HM_MESH", "1", "Multi-device mesh programs (0 = single "
-           "device)."),
+    EnvVar("HM_MESH", "1", "Bulk-load slabs round-robin across the "
+           "visible devices (0 = single device)."),
     EnvVar("HM_RR_LEAST_LOADED", "0", "Shortest-queue-first slab "
            "placement instead of strict round-robin."),
-    EnvVar("HM_ICI_PALLAS", "1", "Pallas async remote-copy gather "
-           "for collective gathers on real ICI (0 = lax.all_gather "
-           "twin)."),
     # -- storage --------------------------------------------------------
     EnvVar("HM_SLAB", "1", "Columnar sidecars in one mmap'd corpus slab "
            "file (0 = per-feed .cols2 files)."),

@@ -2,7 +2,7 @@
 repo used to enforce by comment.
 
 One AST pass over the whole tree (the package, tools/, scripts/,
-bench.py, __graft_entry__.py), driven by the declared rule data in
+__graft_entry__.py), driven by the declared rule data in
 `analysis/hierarchy.py` and `analysis/envvars.py`:
 
 - **lock-order** — nested `with` acquisitions must follow the declared
@@ -141,10 +141,9 @@ def default_files(root: Optional[str] = None) -> List[str]:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     out.append(os.path.join(dirpath, fn))
-    for fn in ("bench.py", "__graft_entry__.py"):
-        p = os.path.join(root, fn)
-        if os.path.exists(p):
-            out.append(p)
+    p = os.path.join(root, "__graft_entry__.py")
+    if os.path.exists(p):
+        out.append(p)
     return out
 
 

@@ -106,7 +106,7 @@ def device_min_cells() -> int:
 FETCH_WORKERS = 4
 
 # the seconds of a dispatch's child spans (recorded where the work
-# happens: ops/crdt_kernels.py, parallel/sharded.py) -> stats keys
+# happens: ops/crdt_kernels.py) -> stats keys
 _DISPATCH_KIDS = (
     ("pipeline.narrow", "t_narrow"),
     ("pipeline.upload", "t_upload"),
@@ -117,7 +117,7 @@ _STAGE_KEYS = (
 )
 # last_bulk_stats as a load begins (docs and t_sql are the load's own)
 _STATS0: Dict[str, Any] = {
-    "fast": 0, "memo": 0, "fallback": 0, "pipeline": 1,
+    "fast": 0, "memo": 0, "fallback": 0,
     # which kernel ran each slab: the device program or the numpy twin
     # below HM_DEVICE_MIN_CELLS — and on which platform the device
     # slabs ran (None: none did)
@@ -298,7 +298,7 @@ class BulkLoader:
         # materialization barrier runs.
         # rebinding the stats dict holds repo.stats (guard manifest,
         # analysis/guards.py): stage threads _stat_add concurrently
-        # once the load streams, and bench/tools read the dict after
+        # once the load streams, and tools read the dict after
         with self._stats_lock:
             self.last_bulk_stats = dict(
                 _STATS0, docs=len(new_docs), t_sql=round(register.dur, 3)
@@ -373,11 +373,6 @@ class BulkLoader:
             with telemetry.span("pipeline.actors_flush", "pipeline"):
                 back._end_bulk_actors()
         with self._stats_lock:
-            # busy aliases: the names bench's JSON reads (ROADMAP D1)
-            for k in _STAGE_KEYS:
-                self.last_bulk_stats[k + "_busy"] = (
-                    self.last_bulk_stats.get(k, 0.0)
-                )
             # provisional: the barrier extends this through the fetch
             self.last_bulk_stats["wall_critical_path"] = round(
                 time.perf_counter() - self._bulk_t0, 3
@@ -508,7 +503,7 @@ class BulkLoader:
         with self._stats_lock:
             # pool shape + per-worker busy lanes: sum(busy) can exceed
             # the wall once packs overlap — a trace draws one lane per
-            # worker and bench computes speedup = sum(busy)/wall
+            # worker (speedup = sum(busy)/wall)
             stats["pack_workers"] = pipe.pack_workers
             stats["t_pack_busy_per_worker"] = [
                 round(b, 6) for b in pipe.pack_busy
@@ -772,14 +767,10 @@ class BulkLoader:
         # not "one device"
         if len(jax.devices()) < 2:
             return None
-        from ..parallel.mesh import make_mesh
-        from ..parallel.sharded import MeshBulkScheduler
+        from ..parallel.sharded import SlabRoundRobin
 
-        # whole slabs per chip, same kernels. Resident tracking OFF: the
-        # barrier fetches per slab on the overlapped fetch workers, so
-        # the collective-reduction refs would pin every slab's device
-        # wire with no consumer.
-        return MeshBulkScheduler(make_mesh(), track_resident=False)
+        # whole slabs per chip, same kernels
+        return SlabRoundRobin(jax.devices())
 
     # ------------------------------------------------------------------
     # the barrier and the summary memo

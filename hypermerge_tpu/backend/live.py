@@ -84,8 +84,8 @@ ROOT_ID = "0@_root"
 
 # engine stats series (telemetry registry, labeled per engine). The
 # key lists drive both the handle table and the `stats` property, so
-# the dict shape bench.py/tests read stays exactly the pre-telemetry
-# one: event counts first, then the resident gauges, then seconds.
+# the dict shape the tests read stays the pre-telemetry one: event
+# counts first, then the resident gauges, then seconds.
 _LIVE_COUNTS = (
     "adopted", "refused", "ticks", "tick_docs", "tick_changes",
     "inc_changes", "kernel_runs", "device_dispatches",
@@ -675,7 +675,7 @@ class LiveApplyEngine:
         # labeled series per engine so concurrent repos stay exact,
         # per-thread sharded adds so no bump needs the engine lock,
         # and the `stats` property rebuilds the historical dict shape
-        # bench.py and the tests read.
+        # the tests read.
         inst = str(telemetry.next_instance())
         reg = telemetry.REGISTRY
         self._m: Dict[str, Any] = {

@@ -71,7 +71,8 @@ from ..ops.columnar import round_up_pow2
 # busy seconds + slab counts across every bulk load, and live queue
 # depth gauges — the "what is the cold open doing RIGHT NOW" view
 # tools/top.py renders. last_bulk_stats stays the per-load truth
-# bench.py scrapes; these are the daemon-lifetime aggregate.
+# (the benchmark's bulk_stats reader); these are the daemon-lifetime
+# aggregate.
 _M_SLABS = telemetry.counter("pipeline.slabs")
 _M_BUSY = {
     stage: telemetry.counter(f"pipeline.{stage}_busy_s")

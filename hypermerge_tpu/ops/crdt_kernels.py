@@ -475,9 +475,8 @@ def _widen(flags, slot, ctr, seq, obj, key, ref, value, psrc, ptgt):
 
 
 def batched_kernel(A: int, K: int):
-    """Batched (vmapped) kernel over narrow wire args — the function the
-    single-device jits and the mesh-sharded path (parallel/sharded.py)
-    both compile, so both lower to the same program."""
+    """Batched (vmapped) kernel over narrow wire args — the function
+    every slab program compiles, whichever device it is pinned to."""
 
     def fn(flags, slot, ctr, seq, obj, key, ref, value, psrc, ptgt,
            doc_actors):
@@ -805,9 +804,9 @@ def actor_bucket(batch: ColumnarBatch) -> int:
 
 def bucket_doc_actors(batch: ColumnarBatch):
     """(doc_actors padded to the A_loc bucket, A_loc, K): the pow2 bucket
-    shape (A_loc >= 4, K >= 16) shared by the single-device and sharded
-    paths so batches of different composition land in the same compiled
-    program — a bulk load's slabs all reuse one executable."""
+    shape (A_loc >= 4, K >= 16), so batches of different composition
+    land in the same compiled program — a bulk load's slabs all reuse
+    one executable."""
     import numpy as np
 
     da = ensure_doc_actors(batch)

@@ -1,1 +1,1 @@
-"""Multi-chip scale-out: mesh construction + sharded batched programs."""
+"""Multi-chip scale-out: the slab round-robin and the program table."""

@@ -1,70 +1,12 @@
-"""Device mesh construction for doc-sharded CRDT compute.
-
-The framework's scale axes (BASELINE.json north star: pmap/pjit doc shards
-on a v5e-8):
-
-- `dp` — document parallelism: the embarrassingly-parallel axis; every
-  per-doc kernel (ops/crdt_kernels.py) shards here with zero collectives.
-- `sp` — state parallelism: the actor/op axis of clock matrices and
-  reduction kernels; XLA inserts the max/sum collectives over ICI when a
-  reduction crosses this axis (clock unions, dominated-set queries).
-
-The mesh maps dp to the longer physical axis so doc traffic never needs
-ICI; sp collectives ride the short axis.
-"""
+"""What the process can see of its devices."""
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
-
-import jax
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def make_mesh(
-    n_devices: Optional[int] = None,
-    sp: int = 1,
-) -> Mesh:
-    """A (dp, sp) mesh over the first n_devices devices."""
-    devices = jax.devices()
-    n = n_devices if n_devices is not None else len(devices)
-    if n > len(devices):
-        raise ValueError(f"asked for {n} devices, have {len(devices)}")
-    if n % sp != 0:
-        raise ValueError(f"n_devices {n} not divisible by sp {sp}")
-    grid = np.array(devices[:n]).reshape(n // sp, sp)
-    return Mesh(grid, axis_names=("dp", "sp"))
-
-
-def doc_sharding(mesh: Mesh) -> NamedSharding:
-    """[D, ...] arrays sharded across docs only."""
-    return NamedSharding(mesh, P("dp"))
-
-
-def doc_actor_sharding(mesh: Mesh) -> NamedSharding:
-    """[D, A] clock matrices: docs over dp, actor axis over sp."""
-    return NamedSharding(mesh, P("dp", "sp"))
-
-
-def replicated(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())
-
-
-def pad_to_multiple(n: int, multiple: int) -> int:
-    return ((n + multiple - 1) // multiple) * multiple
-
 
 def device_topology() -> dict:
-    """Visible-device + mesh topology summary — the audit context a
-    multichip bench number needs to be interpretable on its own (the
-    bench embeds it in the JSON line; `tools/meta.py --devices` prints
-    it standalone). Includes whether the Pallas ICI remote-copy path is
-    live (`remote_copy_capable`) — CPU host-platform meshes always run
-    the lax-collective twin."""
+    """Visible-device summary: the Telemetry reply's `device` and what
+    `tools/meta.py --devices` prints standalone."""
     import jax
-
-    from .sharded import remote_copy_capable
 
     devs = jax.devices()
     return {
@@ -72,7 +14,5 @@ def device_topology() -> dict:
         "platform": devs[0].platform if devs else None,
         "device_kind": devs[0].device_kind if devs else None,
         "default_backend": jax.default_backend(),
-        "mesh": {"dp": len(devs), "sp": 1},
-        "ici_remote_copy": remote_copy_capable(),
         "process_count": jax.process_count(),
     }

@@ -35,7 +35,6 @@ Consumers:
 - tools read ``telemetry.snapshot()`` / ``prometheus_text()``
 - the backend answers a ``{"type": "Telemetry"}`` query over the
   IPC/serve seam with ``query_payload()`` (tools/top.py's feed)
-- bench.py embeds ``snapshot()`` as the JSON line's ``telemetry`` block
 """
 
 from __future__ import annotations

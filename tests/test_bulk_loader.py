@@ -74,7 +74,6 @@ def test_host_without_native_pack_runs_the_pipeline(tmp_path, monkeypatch):
     monkeypatch.delenv("HM_PACK_WORKERS", raising=False)
     monkeypatch.setenv("HM_DEVICE_MIN_CELLS", "1")
     stats, got, want = _load(tmp_path, ids, slab=3)
-    assert stats["pipeline"] == 1
     assert stats["pack_workers"] == 1
     assert len(stats["t_pack_busy_per_worker"]) == 1
     assert (stats["device_slabs"], stats["fast"]) == (3, 7)
@@ -112,7 +111,6 @@ def test_bulk_stats_hold_what_the_driver_checks(small_load_stats):
     for key, kind in DRIVER_KEYS.items():
         assert isinstance(small_load_stats[key], kind), key
     assert small_load_stats["platform"] == "cpu"
-    assert small_load_stats["pipeline"] == 1
 
 
 def test_gate_native_pct_is_data_only_and_lists_the_single_writer_cells():

@@ -52,7 +52,6 @@ def test_rehearsal_runs_every_stage():
     assert '"platform": "tpu"' not in p.stdout
     assert (out["docs"], out["ops_per_doc"]) == (48, 128)
     assert out["host_slabs"] == 0 and out["fallback"] == 0
-    assert out["pipeline"] == 1
     assert out["serve"]["fallbacks"] == 0
     assert out["serve"]["flush_errors"] == 0
     assert out["live"]["device_dispatches"] >= 1

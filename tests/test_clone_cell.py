@@ -506,6 +506,12 @@ def traced(tmp_path_factory):
             repo.read(url, {"kind": "len", "path": ["t"]},
                       lambda _answer: answered.release())
         assert all(answered.acquire(timeout=WAIT_S) for _ in s.urls)
+        # the callbacks run INSIDE the flusher's serve.batch span: the
+        # batch that answered the last read ends a moment after it
+        deadline = time.monotonic() + WAIT_S
+        while "serve.batch" not in by_name():
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
         c1 = counters()
         events = by_name()
         # (A's first change of a bulk-opened doc mints it a fourth

@@ -822,7 +822,6 @@ def test_packs_bit_identical_with_counters(tmp_path, monkeypatch):
         assert cols.pop("cols_planes_built") == 5
         assert cols.pop("cols_planes_built_pct") == 100.0
         out[pack] = (states, cols, stats["fast"], stats["fallback"])
-        assert stats["pipeline"] == 1
         repo.close()
     assert out["0"] == out["1"]
     assert out["1"][1] == {

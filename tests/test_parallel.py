@@ -1,81 +1,23 @@
-"""Sharded execution over the 8-device virtual CPU mesh + graft entries."""
+"""The slab round-robin over the 8-device virtual CPU backend, the
+program table, the device summary + graft entries."""
 
-import subprocess
 import sys
 
 import jax
 import numpy as np
 import pytest
 
-from hypermerge_tpu.ops.crdt_kernels import run_batch
+from hypermerge_tpu.ops import crdt_kernels as ck
+from hypermerge_tpu.ops.materialize import fetch_summary
 from hypermerge_tpu.ops.synth import synth_batch, synth_changes
-from hypermerge_tpu.parallel.mesh import make_mesh
 from hypermerge_tpu.parallel import sharded as sharded_mod
-from hypermerge_tpu.parallel.sharded import (
-    MeshBulkScheduler,
-    SlabRoundRobin,
-    local_clock_union,
-    sharded_clock_union,
-    sharded_dominated,
-    sharded_full,
-    sharded_materialize,
-    step,
-)
+from hypermerge_tpu.parallel.sharded import SlabRoundRobin
 
-# mesh tests need the 8-device virtual CPU backend conftest.py sets up
+# the round-robin tests need the 8-device virtual CPU backend
+# conftest.py sets up
 pytestmark = pytest.mark.skipif(
-    len(jax.devices()) < 8, reason="needs >= 8 devices (virtual mesh)"
+    len(jax.devices()) < 8, reason="needs >= 8 (virtual) devices"
 )
-
-
-def test_mesh_shapes():
-    mesh = make_mesh(8, sp=2)
-    assert dict(mesh.shape) == {"dp": 4, "sp": 2}
-    mesh1 = make_mesh(4)
-    assert dict(mesh1.shape) == {"dp": 4, "sp": 1}
-    with pytest.raises(ValueError):
-        make_mesh(1000)
-
-
-def test_sharded_materialize_matches_single_device():
-    batch = synth_batch(n_docs=16, n_ops=128)
-    single = run_batch(batch)
-    mesh = make_mesh(8, sp=1)
-    sharded = sharded_materialize(batch, mesh)
-    for field in ("visible", "map_winner", "elem_live", "rank", "clock"):
-        a = np.asarray(getattr(single, field))
-        b = np.asarray(getattr(sharded, field))[: batch.n_docs]
-        np.testing.assert_array_equal(a, b, err_msg=field)
-
-
-def test_sharded_materialize_pads_ragged_doc_axis():
-    batch = synth_batch(n_docs=13, n_ops=64)  # not divisible by dp
-    mesh = make_mesh(8, sp=1)
-    out = sharded_materialize(batch, mesh)
-    assert out.rank.shape[0] == 16  # padded to dp multiple
-    single = run_batch(batch)
-    np.testing.assert_array_equal(
-        np.asarray(single.rank), np.asarray(out.rank)[:13]
-    )
-
-
-def test_sharded_clock_union_and_dominated():
-    mesh = make_mesh(8, sp=2)
-    rng = np.random.default_rng(0)
-    clocks = rng.integers(0, 100, (64, 16)).astype(np.int32)
-    union = np.asarray(sharded_clock_union(clocks, mesh))
-    np.testing.assert_array_equal(union, clocks.max(axis=0))
-
-    query = clocks[7]
-    dom = np.asarray(sharded_dominated(clocks, query, mesh))
-    np.testing.assert_array_equal(dom, np.all(clocks <= query, axis=-1))
-
-
-def test_full_step():
-    batch = synth_batch(n_docs=8, n_ops=64)
-    mesh = make_mesh(8, sp=2)
-    out, union = step(batch, mesh)
-    assert union.shape[-1] == len(batch.actors)
 
 
 def test_synth_changes_replay_host():
@@ -110,110 +52,69 @@ def test_synth_columns_equal_synth_changes_on_device():
 
 
 def _run(batch):
-    return batch, run_batch(batch)
+    return batch, ck.run_batch(batch)
 
 
-# -- mesh shapes the fuzz matrix pins: (dp, sp) ------------------------
-_MESH_SHAPES = [(8, 1), (4, 2), (2, 2), (1, 1)]
+# five slabs of unequal [D, N]: not a multiple of 2, 4 or 8 devices
+_SLAB_SHAPES = [(7, 96), (3, 48), (12, 96), (5, 200), (9, 48)]
 
 
-def _mesh_for(dp, sp):
-    return make_mesh(dp * sp, sp=sp)
+def _slabs():
+    return [
+        synth_batch(n_docs=d, n_ops=n, seed=11 * i)
+        for i, (d, n) in enumerate(_SLAB_SHAPES)
+    ]
 
 
-def _host_local_union(clock, doc_actors, n_actors):
-    """Numpy twin of the collective local clock union."""
-    want = np.zeros(n_actors + 1, np.int64)
-    c = np.asarray(clock)
-    da = np.asarray(doc_actors)
-    np.maximum.at(
-        want,
-        np.where(da >= 0, da, n_actors).ravel(),
-        np.where(da >= 0, c, 0).ravel(),
+def _jit_cache_sizes():
+    return (
+        ck.materialize_full_device._cache_size(),
+        ck.materialize_full_lean_device._cache_size(),
     )
-    return want[:n_actors].astype(np.int32)
 
 
-def test_mesh_reductions_fuzz_bit_identical_across_shapes():
-    """sharded_clock_union / sharded_dominated match the numpy twin on
-    every mesh shape, including ragged (non-multiple) doc and actor
-    counts that force padding on both axes."""
-    rng = np.random.default_rng(7)
-    for dp, sp in _MESH_SHAPES:
-        mesh = _mesh_for(dp, sp)
-        for D, A in [(13, 5), (32, 16), (7, 11), (1, 1), (64, 3)]:
-            clocks = rng.integers(0, 1000, (D, A)).astype(np.int32)
-            union = np.asarray(sharded_clock_union(clocks, mesh))
-            np.testing.assert_array_equal(
-                union, clocks.max(axis=0), err_msg=f"{dp}x{sp} {D}x{A}"
-            )
-            query = clocks[rng.integers(0, D)]
-            dom = np.asarray(sharded_dominated(clocks, query, mesh))
-            np.testing.assert_array_equal(
-                dom,
-                np.all(clocks <= query, axis=-1),
-                err_msg=f"{dp}x{sp} {D}x{A}",
-            )
+@pytest.mark.parametrize("lean", [False, True], ids=["full", "lean"])
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_round_robin_bit_identical_to_one_device(k, lean):
+    """Several devices give bit for bit what one device gives, on
+    ragged slab counts and shapes: every slab's decoded summary equals
+    run_batch_full's on the default device, array by array."""
+    slabs = _slabs()
+    if lean:
+        assert all(ck.batch_is_lean(b) for b in slabs)
+    rr = SlabRoundRobin(jax.devices()[:k])
+    wires = [rr.dispatch(b, lean=lean)[1] for b in slabs]
+    assert sum(rr.slabs_per_chip) == len(slabs)
+    assert max(rr.slabs_per_chip) - min(rr.slabs_per_chip) <= 1
+    for i, (b, wire) in enumerate(zip(slabs, wires)):
+        assert next(iter(wire.devices())) == rr.devices[i % k]
+        got = fetch_summary(wire, b, lean)
+        _out, one = ck.run_batch_full(b, lean=lean)
+        want = fetch_summary(one, b, lean)
+        assert got.keys() == want.keys()
+        for key in want:
+            np.testing.assert_array_equal(got[key], want[key], err_msg=key)
 
 
-def test_step_fuzz_bit_identical_to_single_device_across_shapes():
-    """The one-program collective merge step (materialize + clock
-    union) matches the single-device twin on every mesh shape, ragged
-    doc counts included."""
-    from hypermerge_tpu.ops.crdt_kernels import bucket_doc_actors
-
-    for seed, (dp, sp) in enumerate(_MESH_SHAPES):
-        mesh = _mesh_for(dp, sp)
-        for n_docs in (13, 8):
-            batch = synth_batch(n_docs=n_docs, n_ops=96, seed=seed)
-            single = run_batch(batch)
-            da, _A, _K = bucket_doc_actors(batch)
-            n_actors = len(batch.actors)
-            out, union = step(batch, mesh)
-            for field in (
-                "visible", "map_winner", "elem_live", "rank", "clock",
-            ):
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(single, field)),
-                    np.asarray(getattr(out, field))[:n_docs],
-                    err_msg=f"{dp}x{sp} D={n_docs} {field}",
-                )
-            np.testing.assert_array_equal(
-                np.asarray(union),
-                _host_local_union(single.clock, da, n_actors),
-                err_msg=f"{dp}x{sp} D={n_docs} union",
-            )
-
-
-def test_mesh_programs_cached_no_retrace():
-    """Repeated same-shape calls reuse ONE traced program: the program
-    table (not a fresh jit closure per call) serves local_clock_union,
-    sharded_full, and step — the r5 per-call retrace regression."""
-    mesh = make_mesh(8, sp=1)
-    batch = synth_batch(n_docs=16, n_ops=64, seed=1)
-    n_actors = max(1, len(batch.actors))
-
-    out, da = sharded_mod._materialize_on_mesh(batch, mesh)
-    local_clock_union(out.clock, da, n_actors, mesh)
-    sharded_full(batch, mesh, lean=False)
-    step(batch, mesh)
-    sharded_clock_union(
-        np.ones((16, 8), np.int32), mesh
-    )
-    snapshot = dict(sharded_mod.trace_counts)
-    assert snapshot, "trace counter never engaged"
-
-    for _ in range(3):
-        out, da = sharded_mod._materialize_on_mesh(batch, mesh)
-        local_clock_union(out.clock, da, n_actors, mesh)
-        sharded_full(batch, mesh, lean=False)
-        step(batch, mesh)
-        sharded_clock_union(np.ones((16, 8), np.int32), mesh)
-    assert dict(sharded_mod.trace_counts) == snapshot, (
-        "a mesh program retraced on a repeated same-shape call",
-        snapshot,
-        sharded_mod.trace_counts,
-    )
+def test_round_robin_second_pass_traces_nothing():
+    """The same shapes over the same devices a second time compile and
+    trace nothing: the jit caches keep their sizes and the program
+    table's tallies stand."""
+    devices = jax.devices()[:4]
+    for lean in (False, True):
+        rr = SlabRoundRobin(devices)
+        for b in _slabs() + _slabs()[:3]:  # every shape on every device
+            rr.dispatch(b, lean=lean)
+        rr.drain()
+    sizes = _jit_cache_sizes()
+    counts = dict(sharded_mod.trace_counts)
+    for lean in (False, True):
+        rr = SlabRoundRobin(devices)
+        for b in _slabs() + _slabs()[:3]:
+            rr.dispatch(b, lean=lean)
+        rr.drain()
+    assert _jit_cache_sizes() == sizes
+    assert dict(sharded_mod.trace_counts) == counts
 
 
 class _Saturator:
@@ -257,63 +158,59 @@ def test_least_loaded_env_gate(monkeypatch):
     assert not SlabRoundRobin(jax.devices()).least_loaded
 
 
-def test_mesh_scheduler_collective_union_and_gather():
-    """MeshBulkScheduler: streaming whole-slab dispatch stays
-    bit-identical to per-slab fetch, while the cross-doc reductions
-    (clock union, summary gather) run as collective programs whose
-    results equal the host-side merge they replace."""
-    from hypermerge_tpu.ops.columnar import pack_docs
-    from hypermerge_tpu.ops.crdt_kernels import bucket_doc_actors
-    from hypermerge_tpu.ops.materialize import fetch_summary
-
-    mesh = make_mesh(8, sp=2)
-    sch = MeshBulkScheduler(mesh, depth=2)
-    batches = [
-        pack_docs(
-            [synth_changes(48, n_actors=2, ops_per_change=8, seed=s)]
-        )
-        for s in range(5)
-    ]
-    outs = []
-    for b in batches:
-        out, wire = sch.dispatch(b, lean=False)
-        outs.append((b, out, wire))
-    n_actors = max(len(b.actors) for b in batches)
-    want = np.zeros(n_actors, np.int32)
-    for b, out, _w in outs:
-        da, _A, _K = bucket_doc_actors(b)
-        want = np.maximum(
-            want, _host_local_union(out.clock, da, n_actors)
-        )
-    np.testing.assert_array_equal(
-        sch.collective_clock_union(n_actors), want
-    )
-    gathered = sch.gather_summaries()
-    assert [g[0] for g in gathered] == list(range(len(batches)))
-    for (_seq, _n, host_wire), (b, _out, wire) in zip(gathered, outs):
-        np.testing.assert_array_equal(host_wire, np.asarray(wire))
-        a = fetch_summary(host_wire, b, lean=False)
-        bsl = fetch_summary(wire, b, lean=False)
-        for k in a:
-            np.testing.assert_array_equal(a[k], bsl[k], err_msg=k)
-    # per-chip accounting: every dispatched slab is attributed
-    assert sum(sch.slabs_per_chip) == len(batches)
-    sch.drain()
-    sch.release()
-    sch.reset_resident()
-    assert sch.gather_summaries() == []
+@pytest.mark.parametrize("case", ["release", "device_index"])
+def test_round_robin_release_and_device_index(case):
+    devices = jax.devices()
+    rr = SlabRoundRobin(devices[:2])
+    if case == "device_index":
+        assert rr.device_index(devices[1]) == 1
+        assert rr.device_index(devices[2]) is None
+        return
+    # release() empties the in-flight queues without blocking on what
+    # is in them, and a summary the caller holds still decodes
+    batch = _slabs()[0]
+    _out, wire = rr.dispatch(batch)
+    rr._inflight[1] = [_Saturator()]
+    rr.release()
+    assert all(not q for q in rr._inflight.values())
+    assert fetch_summary(wire, batch)["n_map_entries"].shape == (7,)
 
 
-def test_remote_copy_capability_gate(monkeypatch):
-    """CPU host-platform meshes never select the Pallas ICI path; the
-    env escape hatch forces it off everywhere."""
-    from hypermerge_tpu.parallel.sharded import remote_copy_capable
+@pytest.mark.parametrize("case", ["HM_MESH=0", "one visible device", "eight"])
+def test_loader_takes_round_robin_only_with_devices_to_share(
+    case, monkeypatch
+):
+    """BulkLoader._rr: None under HM_MESH=0 and with one device to be
+    seen, else the round-robin over jax.devices() in order."""
+    from hypermerge_tpu.backend.bulk_loader import BulkLoader
 
-    mesh = make_mesh(8, sp=1)
-    assert remote_copy_capable(mesh) is False  # cpu devices
-    assert remote_copy_capable() is False
-    monkeypatch.setenv("HM_ICI_PALLAS", "0")
-    assert remote_copy_capable(mesh) is False
+    devices = jax.devices()
+    if case == "HM_MESH=0":
+        monkeypatch.setenv("HM_MESH", "0")
+    else:
+        monkeypatch.delenv("HM_MESH", raising=False)
+    if case == "one visible device":
+        monkeypatch.setattr(jax, "devices", lambda: devices[:1])
+    rr = BulkLoader(backend=None)._rr
+    if case != "eight":
+        assert rr is None
+        return
+    assert type(rr) is SlabRoundRobin
+    assert rr.devices == devices and rr.depth == sharded_mod.RR_DEPTH
+
+
+def test_device_topology_keys():
+    """What `tools/meta.py --devices` prints and the Telemetry reply
+    carries as `device`."""
+    from hypermerge_tpu.parallel.mesh import device_topology
+
+    assert device_topology() == {
+        "n_devices": 8,
+        "platform": "cpu",
+        "device_kind": jax.devices()[0].device_kind,
+        "default_backend": "cpu",
+        "process_count": 1,
+    }
 
 
 def test_graft_entry_single_chip():

@@ -386,13 +386,8 @@ def test_pipeline_per_chip_stats(tmp_path, monkeypatch):
     ) == sum(1 for s in stats["slabs_per_chip"] if s > 0)
     assert len(stats.get("t_fetch_chips", [])) == n, stats
     assert sum(stats["t_fetch_chips"]) > 0
-    # the PRODUCT scheduler never tracks collective-reduction refs:
     # nothing may pin slab wires beyond the barrier
-    rr = repo.back.loader._rr
-    if hasattr(rr, "track_resident"):
-        assert rr.track_resident is False
-        assert all(not q for q in rr._resident_wires.values())
-        assert all(not q for q in rr._resident_clocks.values())
+    assert all(not q for q in repo.back.loader._rr._inflight.values())
     for u in urls:
         assert plainify(repo.doc(u)) == want[u]
     repo.close()
@@ -429,7 +424,6 @@ def test_pipeline_pack_worker_matrix(tmp_path, monkeypatch):
         copy = tmp_path / f"m{i}"
         shutil.copytree(src, copy)
         out, stats = _load_once(copy, ids, monkeypatch, 4, workers)
-        assert stats["pipeline"] == 1
         want_pool = pack_worker_count()  # env still set from _load_once
         assert stats["pack_workers"] == want_pool
         if workers != "0":
@@ -477,8 +471,7 @@ def test_pipeline_stats_report_busy_and_critical_path(tmp_path, monkeypatch):
     repo.back.load_documents_bulk(ids, slab=2)
     repo.back.fetch_bulk_summaries()
     stats = repo.back.last_bulk_stats
-    assert stats["pipeline"] == 1
-    for k in ("t_io_busy", "t_pack_busy", "t_dispatch_busy"):
+    for k in ("t_io", "t_pack", "t_dispatch"):
         assert k in stats
     assert stats["wall_critical_path"] >= 0.0
     assert "t_fetch" in stats and "t_fetch_busy" in stats
@@ -537,7 +530,6 @@ def test_bulk_stats_say_how_the_columns_loaded(tmp_path, monkeypatch, pack):
     repo.back.load_documents_bulk(ids, slab=4)
     repo.back.fetch_bulk_summaries()
     st = dict(repo.back.last_bulk_stats)
-    assert st["pipeline"] == 1
     assert cols(st) == (10, 0, 100.0)
     for d in ids[:3]:
         repo.back.docs.pop(d)  # forget three docs; their actors stay

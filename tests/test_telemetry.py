@@ -335,9 +335,23 @@ def test_timed_reads_the_clock_with_tracing_off():
 
 
 def _spin(seconds: float) -> None:
-    t0 = time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
-        pass
+    """Busy for `seconds`, ahead of the suite's other workers where the
+    box lets this thread be (SCHED_FIFO, root): under `-n 6` a plain
+    spin shares its core and reads 35-72% CPU through no fault of the
+    span's clock (ROADMAP D14). Where it may not, a plain spin."""
+    try:
+        os.sched_setscheduler(0, os.SCHED_FIFO, os.sched_param(1))
+    except (AttributeError, OSError):
+        back = False
+    else:
+        back = True
+    try:
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < seconds:
+            pass
+    finally:
+        if back:
+            os.sched_setscheduler(0, os.SCHED_OTHER, os.sched_param(0))
 
 
 def test_span_cpu_tells_work_from_waiting(tracer):

@@ -241,8 +241,7 @@ def test_dispatch_stats_come_from_its_child_spans(traced_open):
     _, stats = traced_open
     for key in ("t_sql", "t_io", "t_spec", "t_pack", "t_narrow",
                 "t_upload", "t_dispatch", "t_fetch_busy", "t_fetch",
-                "t_pack_wall", "wall_critical_path", "t_io_busy",
-                "t_dispatch_busy"):
+                "t_pack_wall", "wall_critical_path"):
         assert key in stats, key
     for key in ("t_io", "t_pack", "t_narrow", "t_upload", "t_dispatch",
                 "t_fetch_busy"):

@@ -12,11 +12,9 @@ Output is one JSON object. Documents are opened first (metadata queries
 answer from the open doc's backend state); unknown urls print null and
 exit non-zero.
 
-`--devices` prints the visible-device/mesh topology instead (no repo
-needed): device count, platform/kind, (dp, sp) mesh shape, and whether
-the Pallas ICI remote-copy path is live — the same object the bench
-embeds as `multichip_topology`, so a bench JSON line is auditable
-against the box it ran on.
+`--devices` prints the visible-device topology instead (no repo
+needed): device count, platform/kind, default backend and process
+count — the same object the Telemetry reply carries as `device`.
 
 `--dht` probes a running DHT fleet from outside: boots an EPHEMERAL
 node (net/discovery/dht.py), bootstraps it from `--bootstrap` or
@@ -28,8 +26,7 @@ size after the walk; an empty table means no bootstrap answered.
 `--stats` opens the repo (and its docs) and prints the process-wide
 telemetry snapshot JSON — the registry every subsystem now reports
 into (hypermerge_tpu/telemetry/) instead of the per-object stats
-dicts it replaced. Same counter names as bench.py's `telemetry`
-block and tools/top.py.
+dicts it replaced. Same counter names as tools/top.py.
 """
 
 import argparse
