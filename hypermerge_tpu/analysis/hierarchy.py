@@ -293,6 +293,11 @@ LOCK_CLASSES: Tuple[LockClass, ...] = (
     LockClass("front.repo", None, "RepoFrontend._lock."),
     LockClass("front.doc", None, "DocFrontend._lock."),
     LockClass(
+        "front.handle", None,
+        "Handle._lock — a push's stored state and the subscriber it "
+        "finds, against subscribe(); callbacks run outside it.",
+    ),
+    LockClass(
         "ops.clock_mirror", None,
         "DeviceClockMirror._lock — host-buffered device clock table.",
     ),

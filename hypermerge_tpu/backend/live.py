@@ -90,6 +90,10 @@ _LIVE_COUNTS = (
     "adopted", "refused", "ticks", "tick_docs", "tick_changes",
     "inc_changes", "kernel_runs", "device_dispatches",
     "local_changes", "adopt_retries", "demoted", "readopted",
+    # adoptions of a doc that had history: the exact-size host kernel
+    # over rows the doc already held (a peer catching up, a bulk-opened
+    # doc's first write), not over an empty clone
+    "adopt_held",
 )
 _LIVE_GAUGES = ("live_bytes", "live_docs")
 _LIVE_TIMES = (
@@ -898,9 +902,13 @@ class LiveApplyEngine:
                 outcome = status
                 break
         finally:
+            had_rows = int(ld is not None and ld.cols.n > 0)
             sp.end(
-                outcome=outcome, rows=0 if ld is None else ld.cols.n
+                outcome=outcome, rows=0 if ld is None else ld.cols.n,
+                held=had_rows,
             )
+            if had_rows and outcome == "ok":
+                self._m["adopt_held"].add(1)
             with self._lock:
                 self._adopting.pop(doc.id, None)
                 gate.outcome = outcome

@@ -20,6 +20,9 @@ from ..utils.debug import log
 from ..utils.ids import to_doc_url
 from .handle import Handle
 
+# patches no local change made, applied to a frontend's document
+_M_REMOTE_PATCHES = telemetry.counter("frontend.remote_patches")
+
 
 class DocFrontend:
     def __init__(self, repo_frontend, doc_id: str,
@@ -289,6 +292,7 @@ class DocFrontend:
                     return
                 patch = Patch.from_json(patch_json)
                 if patch.actor is None:
+                    _M_REMOTE_PATCHES.add(1)
                     # no local change's echo: what a peer's changes
                     # cost here, the handles' new value included
                     sp = telemetry.begin(

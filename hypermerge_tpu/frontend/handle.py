@@ -10,6 +10,8 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Generic, List, Optional, TypeVar
 
+from ..analysis.lockdep import make_lock
+
 T = TypeVar("T")
 
 
@@ -24,6 +26,10 @@ class Handle(Generic[T]):
         self._state: Optional[T] = None
         self._index = 0
         self._have_state = threading.Event()
+        # a leaf lock: what a push stores and which subscriber it finds
+        # are one step, as are what a new subscriber is owed and its
+        # becoming the subscriber (callbacks run outside it)
+        self._lock = make_lock("front.handle")
         self._closed = False
 
     # -- pushes from DocFrontend ---------------------------------------
@@ -31,11 +37,13 @@ class Handle(Generic[T]):
     def push(self, state: T, index: int) -> None:
         if self._closed:
             return
-        self._state = state
-        self._index = index
-        self._have_state.set()
-        if self.value_fn is not None:
-            self.value_fn(state, index)
+        with self._lock:
+            self._state = state
+            self._index = index
+            self._have_state.set()
+            fn = self.value_fn
+        if fn is not None:
+            fn(state, index)
 
     def push_progress(self, progress: dict) -> None:
         if not self._closed and self.progress_fn is not None:
@@ -48,12 +56,21 @@ class Handle(Generic[T]):
     # -- subscription api ----------------------------------------------
 
     def subscribe(self, fn: Callable[[T, int], None]) -> "Handle[T]":
-        if self.value_fn is not None:
-            raise RuntimeError("handle already has a value subscriber")
-        self.value_fn = fn
-        self._df.poke()  # resolve a lazy-ready (bulk-opened) doc
-        if self._have_state.is_set():
-            fn(self._state, self._index)
+        # the state this handle already had is the subscriber's first
+        # delivery; a lazy-ready (bulk-opened) doc the poke resolves
+        # delivers its state through push(), once. A push from another
+        # thread lands wholly before this step (its state is what is
+        # owed here) or wholly after it (it finds `fn` itself): no
+        # state is delivered twice and none is lost
+        with self._lock:
+            if self.value_fn is not None:
+                raise RuntimeError("handle already has a value subscriber")
+            had_state = self._have_state.is_set()
+            state, index = self._state, self._index
+            self.value_fn = fn
+        self._df.poke()
+        if had_state:
+            fn(state, index)
         return self
 
     def once(self, fn: Callable[[T, int], None]) -> "Handle[T]":

@@ -186,6 +186,10 @@ class ReplicationManager:
                 "resyncs", "t_resync_ms", "antientropy_sweeps",
                 "frames_tx", "frames_rx",
                 "blocks_rx", "bytes_rx", "feeds_synced", "unsigned_rx",
+                # a received block the feed already held (it crossed
+                # for nothing), and Requests that started at the feed's
+                # own length and not at 0 (a peer that held a prefix)
+                "blocks_dup_rx", "requests_from_len",
             )
         }
         self._seen_closed: Set[str] = set()
@@ -460,6 +464,8 @@ class ReplicationManager:
             asked[did] = (start, False)
         msg = self._request_msg(feed, peer, start)
         if msg is not None:
+            if start > 0:
+                self._m["requests_from_len"].add(1)
             self._send(peer, msg)
         else:
             with self._lock:  # nothing went out: nothing to wait for
@@ -650,6 +656,8 @@ class ReplicationManager:
             return
         raw = [base64.b64decode(b) for b in blocks]
         had = feed.length
+        if start < had and raw:
+            self._m["blocks_dup_rx"].add(min(had - start, len(raw)))
         if sig_b64 is not None and length >= 0:
             ok = feed.append_verified(
                 start, raw, length, base64.b64decode(sig_b64)

@@ -801,10 +801,22 @@ class TcpSwarm(Swarm):
         with self._dlock:
             self._destroyed = True  # _track closes later arrivals
         self.supervisor.stop()  # no redial races the teardown below
+        # close() alone leaves a thread that is already inside accept()
+        # blocked there for good (Linux): the accepter would outlive
+        # the swarm and keep it, its `_cb` and through that the whole
+        # closed repo reachable. shutdown() wakes it with an error
+        # (where the platform refuses that on a listening socket,
+        # close() wakes it)
+        try:
+            self._server.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._server.close()
         except OSError:
             pass
+        if self._accepter is not threading.current_thread():
+            self._accepter.join(timeout=2.0)
         if not self._async:
             # wake parked handshake workers (they see _destroyed and
             # exit) and refuse the sockets still queued behind them

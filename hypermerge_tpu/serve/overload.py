@@ -306,6 +306,12 @@ class OverloadController:
             self._thread = None
         if t is not None:
             t.join(timeout=2.0)
+        # as every component that opens and closes with its repo: the
+        # counters fold into the process totals and the gauges go. A
+        # closed ladder's last `service.state` left in the table would
+        # be summed into every later snapshot of the process, which is
+        # what a benchmark driver's wait for a HEALTHY ladder reads
+        telemetry.REGISTRY.retire(*self._m.values())
 
     def _run(self) -> None:
         while True:
@@ -330,6 +336,10 @@ class OverloadController:
             float(sig.get("debt_frac", 0.0)),
         )
         with self._lock:
+            if self._closed:
+                # a ticker that outlived close()'s join publishes
+                # nothing: the series are retired
+                return self._state
             self._last = dict(sig)
             self._pressure = pressure
             prev = self._state

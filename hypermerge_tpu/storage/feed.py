@@ -40,6 +40,9 @@ _M_LOG_WRITES = telemetry.counter("storage.feed.log_writes")
 _M_BLOCKS_WRITTEN = telemetry.counter("storage.feed.blocks_written")
 _M_LOG_OPENS = telemetry.counter("storage.feed.log_opens")
 _M_BLOCKS_READ = telemetry.counter("storage.feed.blocks_read")
+# first change of length of a feed the sealed snapshot of heads vouched
+# for (HeadSnapshot.tell): from then on its entry is not trusted
+_M_EXTENDED_SEALED = telemetry.counter("storage.feed.extended_sealed")
 
 
 class MemoryFeedStorage:
@@ -598,7 +601,10 @@ class HeadSnapshot:
             self.state = "absent"
 
     def tell(self, storage: "FileFeedStorage") -> None:
-        self._fresh[os.path.basename(storage.path)] = storage
+        name = os.path.basename(storage.path)
+        if name not in self._fresh and name in self._sealed:
+            _M_EXTENDED_SEALED.add(1)  # the sealed entry's last moment
+        self._fresh[name] = storage
 
     def resolve(self, storage: "FileFeedStorage") -> bool:
         """The batched question, one feed of it (the caller holds the

@@ -734,7 +734,8 @@ def test_metric_file_on_a_hand_worked_obs(monkeypatch, metric):
     monkeypatch.setattr(span_tree, "load", lambda _p: (HAND_SPANS, []))
     spec_ = harness.load_json("layer_metrics", metric + ".json")
     entry = next(m for m in bench_json()["per_layer"] if m["name"] == metric)
-    assert spec_["cells"] == entry["workloads"] == ["sync.clone"]
+    # (the cells a later PR joined to the metric follow its own)
+    assert spec_["cells"] == entry["workloads"][:1] == ["sync.clone"]
     assert (spec_["moves"], entry["moves"]) == ("ops_per_s", "ops_per_s")
     assert (spec_["layer"], spec_["unit"]) == (entry["layer"], entry["unit"])
     reader = harness.load_module("readers", spec_["reader"])
@@ -771,7 +772,7 @@ def test_block_log_metric_file_on_a_hand_worked_obs(metric):
     cells, before, after, reading = BLOCK_LOG_METRICS[metric]
     spec_ = harness.load_json("layer_metrics", metric + ".json")
     entry = next(m for m in bench_json()["per_layer"] if m["name"] == metric)
-    assert spec_["cells"] == entry["workloads"] == cells
+    assert spec_["cells"] == entry["workloads"][:len(cells)] == cells
     assert (spec_["reader"], spec_["source"], entry["source"]) == (
         "counter_ratio", "program_counter", "program_counter")
     assert (spec_["layer"], entry["layer"]) == ("feed storage",) * 2

@@ -454,6 +454,7 @@ def test_rehearsal_of_the_cell(control):
                     if m["name"] == spec["name"]]
     assert spec["reader"] == "bulk_stats"
     assert spec["params"] == {"key": "pack_gather_native_pct"}
-    assert entry["workloads"] == spec["cells"] == [line["workload"]]
+    # (the cells a later PR joined to the metric follow its own)
+    assert entry["workloads"][:1] == spec["cells"] == [line["workload"]]
     assert (entry["unit"], entry["better"], entry["layer"],
             entry["moves"]) == ("%", "higher", "pack", "ops_per_s")

@@ -368,7 +368,9 @@ def test_the_cell_and_its_metrics_are_listed():
         (m,) = [m for m in bench["per_layer"] if m["name"] == name]
         assert spec["reader"] == reader
         assert params is None or spec["params"] == params
-        assert m["workloads"] == spec["cells"] and cell in spec["cells"]
+        # (the cells a later PR joined to the metric follow its own)
+        assert m["workloads"][:len(spec["cells"])] == spec["cells"]
+        assert cell in spec["cells"]
         assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
             spec["unit"], spec["better"], spec["layer"], spec["moves"])
 

@@ -79,6 +79,22 @@ def pin_ladder(monkeypatch):
     monkeypatch.setenv("HM_SERVICE_FORCE", "healthy")
 
 
+def forget_other_ladders():
+    """The driver's wait for a HEALTHY ladder (`ycsb_read_loop.
+    _await_healthy`) reads the PROCESS's `service.state`: the sum over
+    every ladder in the registry, not this cell's own. A worker runs
+    other files' tests before this one, and a ladder one of them left
+    above HEALTHY (a controller a test ticked up and never closed; on
+    the parent a closed repo's too, which kept its gauge) holds that
+    sum over 0 for good, whatever this test pins: the wait then timed
+    out beside busy workers and never alone."""
+    from hypermerge_tpu import telemetry
+
+    for m in telemetry.REGISTRY.series():
+        if m.name == "service.state":
+            m.set(0)
+
+
 # -- the program against the reference ---------------------------------------
 
 
@@ -310,6 +326,7 @@ def test_rehearsal_cell_follows_writes_in_place(tmp_path, monkeypatch):
     host0 = serve("install_host_kernel_docs")  # the process's, so far
     with mock.patch.dict(os.environ):
         pin_ladder(monkeypatch)  # a CPU's p99 is no pressure
+        forget_other_ladders()  # nor is another test's ladder
         cell = harness.Cell(args, bench, time.perf_counter())
         cell.work = str(tmp_path / "run")
         harness.apply_env(cell)

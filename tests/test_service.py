@@ -328,6 +328,29 @@ def test_brownout_serves_cold_reads_from_host(monkeypatch):
         repo.close()
 
 
+def test_a_closed_ladder_leaves_the_process_snapshot(monkeypatch):
+    """A repo closed with its ladder above HEALTHY takes its gauges
+    out of the registry (its counters fold into the totals): on the
+    parent its last `service.state` stayed in every later snapshot of
+    the process, and a benchmark driver's wait for `service.state` 0
+    could never end (tests/test_rw_cell.py under busy workers)."""
+    others = snap().get("service.state", 0)
+    monkeypatch.setenv("HM_SERVICE_FORCE", "brownout")
+    repo = Repo(memory=True)
+    try:
+        assert snap()["service.state"] == others + BROWNOUT
+        url = repo.create({"n": 5})
+        assert repo.read(url, {"kind": "lookup", "path": ["n"]}) == 5
+        counts = {k: v for k, v in snap().items() if k in (
+            "service.brownout_reads", "service.admitted_reads",
+            "service.transitions", "service.shed_reads")}
+        assert counts["service.brownout_reads"] > 0
+    finally:
+        repo.close()
+    assert snap().get("service.state", 0) == others
+    assert {k: snap().get(k, 0) for k in counts} == counts  # totals stay
+
+
 def test_healthy_repo_never_touches_the_ladder():
     repo = Repo(memory=True)
     try:

@@ -604,6 +604,7 @@ def test_live_engine_stats_keys_unchanged():
             "adopted", "refused", "ticks", "tick_docs", "tick_changes",
             "inc_changes", "kernel_runs", "device_dispatches",
             "local_changes", "adopt_retries", "demoted", "readopted",
+            "adopt_held",  # PR 44: adoptions of a doc that had history
             "live_bytes", "live_docs",
             "t_live_append", "t_live_apply", "t_live_kernel",
             "t_live_decode", "t_live_diff",
