@@ -58,7 +58,7 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from itertools import repeat
+from itertools import compress, repeat
 from typing import Any, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
@@ -94,6 +94,10 @@ _LIVE_COUNTS = (
     # over rows the doc already held (a peer catching up, a bulk-opened
     # doc's first write), not over an empty clone
     "adopt_held",
+    # ops `_tick_doc_locked` applied one at a time (inc_changes counts
+    # their changes), and ops that went through `_apply_seq_state`,
+    # remote or local: each asks the liveness vector for one index
+    "inc_ops", "seq_ops",
 )
 _LIVE_GAUGES = ("live_bytes", "live_docs")
 _LIVE_TIMES = (
@@ -139,7 +143,16 @@ class _Val(NamedTuple):
 
 
 class _Obj:
-    __slots__ = ("type", "fields", "order")
+    """One object of the decoded state. A list / text carries its
+    elements' liveness as a byte vector beside `order`: `alive[i]` is 1
+    where `fields[order[i]]` is non-empty and 0 for a tombstone, so an
+    element's live index is `alive.count(1, 0, i)`, a C-speed count,
+    and not a Python walk of `order`. The vector is part of the state,
+    not a cache: whatever writes `order` or empties / revives a cell
+    (`_decode_state`, `_apply_seq_state`) writes it in the same step,
+    and nothing rebuilds it lazily."""
+
+    __slots__ = ("type", "fields", "order", "alive")
 
     def __init__(self, type_: str) -> None:
         self.type = type_
@@ -149,13 +162,14 @@ class _Obj:
         # inserts may reference it and the skip-scan walks it)
         self.fields: Dict[Any, Dict[OpId, _Val]] = {}
         self.order: List[OpId] = []  # ALL elems in RGA order
+        self.alive = bytearray()  # one byte an elem of `order`
 
     @property
     def is_sequence(self) -> bool:
         return self.type in ("list", "text")
 
     def live(self) -> List[OpId]:
-        return [e for e in self.order if self.fields.get(e)]
+        return list(compress(self.order, self.alive))
 
 
 class _DocState:
@@ -262,7 +276,14 @@ def _decode_state(lv: LiveColumns, lanes) -> _DocState:
     if len(ins_rows):
         o_ins = obj_col[ins_rows]
         order = np.lexsort((-rank[ins_rows], o_ins))
-        sorted_rows = ins_rows[order].tolist()
+        sorted_idx = ins_rows[order]
+        sorted_rows = sorted_idx.tolist()
+        # the kernel's flag an insert row (its element holds a visible
+        # value: the cells the visible-row pass below fills), in the
+        # containers' element order: each object's liveness vector
+        live_sorted = np.asarray(lanes.elem_live)[sorted_idx].astype(
+            np.uint8
+        )
         o_sorted = o_ins[order]
         bounds = np.nonzero(o_sorted[1:] != o_sorted[:-1])[0] + 1
         starts = np.concatenate(([0], bounds)).tolist()
@@ -273,6 +294,7 @@ def _decode_state(lv: LiveColumns, lanes) -> _DocState:
             obj = objs[ROOT] if o < 0 else objs[opids[o]]
             elems = [opids[r] for r in sorted_rows[s:e]]
             obj.order = elems
+            obj.alive = bytearray(live_sorted[s:e].tobytes())
             fields = obj.fields
             if fields:
                 for el in elems:
@@ -418,10 +440,9 @@ def _compute_reachable(state: _DocState) -> None:
     while stack:
         obj = objs[stack.pop()]
         if obj.is_sequence:
-            fields = obj.fields
-            cells = [
-                c_ for c_ in (fields.get(e) for e in obj.order) if c_
-            ]
+            cells = list(
+                map(obj.fields.__getitem__, compress(obj.order, obj.alive))
+            )
         else:
             cells = [c_ for c_ in obj.fields.values() if c_]
         for cell in cells:
@@ -1271,6 +1292,7 @@ class LiveApplyEngine:
                 for i, op in enumerate(c.ops):
                     self._apply_op_state(ld.state, c.op_id(i), op, diffs)
             m["inc_changes"].add(len(changes))
+            m["inc_ops"].add(n_ops)
             m["t_live_apply"].add(now() - t1)
             self._emit_tick(ld, diffs)
             return 1
@@ -1556,15 +1578,15 @@ class LiveApplyEngine:
         return ROOT_ID if op.obj == ROOT else str(op.obj)
 
     @staticmethod
-    def _live_index(obj: _Obj, elem: OpId) -> int:
-        """Index among LIVE elems (OpSet._live_index twin)."""
-        idx = 0
-        for e in obj.order:
-            if e == elem:
-                return idx
-            if obj.fields.get(e):
-                idx += 1
-        return idx
+    def _live_index(obj: _Obj, pos: int) -> int:
+        """Index among LIVE elems of the elem at `order[pos]`: the live
+        elems before it, counted over the liveness vector (the elem's
+        own byte is not among them, so a delete reads the index the
+        elem HAD whether or not its byte is flipped yet). Same answer
+        as `OpSet._live_index`, whose Python walk of `order` is the
+        host twin and the `HM_LIVE=0` reference: the two deliberately
+        share no code."""
+        return obj.alive.count(1, 0, pos)
 
     def _apply_map_state(self, state, obj, opid, op, val, diffs) -> None:
         key = op.key
@@ -1611,7 +1633,16 @@ class LiveApplyEngine:
         )
 
     def _apply_seq_state(self, state, obj, opid, op, val, diffs) -> None:
+        """One op on a list / text (OpSet's sequence branch, twin in
+        behaviour and not in code). Keeps `obj.alive` in step with
+        `order` and `fields`: an insert adds its byte where it adds
+        the elem; a set / delete finds the elem with ONE `order.index`
+        (a C scan), writes whether its cell still holds a value, and
+        counts its live index from the same position. No Python loop
+        runs over the sequence's elems."""
+        self._m["seq_ops"].add(1)
         oid = self._obj_str(op)
+        order = obj.order
         if op.insert:
             # RGA insert-after with descending-OpId skip scan (OpSet's
             # algorithm verbatim; `order` includes tombstones)
@@ -1619,12 +1650,13 @@ class LiveApplyEngine:
                 pos = 0
             else:
                 try:
-                    pos = obj.order.index(op.ref) + 1
+                    pos = order.index(op.ref) + 1
                 except ValueError:
                     return  # unknown predecessor
-            while pos < len(obj.order) and obj.order[pos] > opid:
+            while pos < len(order) and order[pos] > opid:
                 pos += 1
-            obj.order.insert(pos, opid)
+            order.insert(pos, opid)
+            obj.alive.insert(pos, 1)
             obj.fields[opid] = {opid: val}
             value, link, datatype = _op_value(state, opid, val)
             diffs.append(
@@ -1632,7 +1664,7 @@ class LiveApplyEngine:
                     action="insert",
                     obj=oid,
                     obj_type=obj.type,
-                    index=self._live_index(obj, opid),
+                    index=self._live_index(obj, pos),
                     elem_id=str(opid),
                     value=value,
                     link=link,
@@ -1655,6 +1687,17 @@ class LiveApplyEngine:
                     state.inc.pop(p, None)
             if op.action == Action.SET or op.action.makes_object:
                 visible[opid] = val
+        if not visible and not had:
+            return  # a tombstone stays one: nothing to tell, no index
+        try:
+            pos = order.index(elem)
+        except ValueError:
+            # a cell the decode keeps for a SET whose ref is no elem of
+            # this sequence (a malformed remote op): not in `order`, so
+            # it has no index and the frontend never held it
+            return
+        obj.alive[pos] = 1 if visible else 0
+        index = self._live_index(obj, pos)
         if visible:
             winner, value, link, datatype, conflicts = _display(
                 state, visible
@@ -1666,7 +1709,7 @@ class LiveApplyEngine:
                     action="set" if had else "insert",
                     obj=oid,
                     obj_type=obj.type,
-                    index=self._live_index(obj, elem),
+                    index=index,
                     elem_id=str(elem),
                     value=value,
                     link=link,
@@ -1674,7 +1717,7 @@ class LiveApplyEngine:
                     conflicts=conflicts,
                 )
             )
-        elif had:
+        else:
             # tombstone RETAINED in order/fields (OpSet keeps it: later
             # remote inserts may reference this elem)
             diffs.append(
@@ -1682,7 +1725,7 @@ class LiveApplyEngine:
                     action="remove",
                     obj=oid,
                     obj_type=obj.type,
-                    index=self._live_index(obj, elem),
+                    index=index,
                     elem_id=str(elem),
                 )
             )

@@ -606,3 +606,376 @@ def test_live_tick_batches_multiple_docs(tmp_path, live_env):
     for did in ids:
         assert repo2.back.docs[did].opset is None
     repo2.close()
+
+
+# ---------------------------------------------------------------------------
+# the liveness vector (ISSUE 45): a sequence object's `alive` bytes
+# beside `order`, and every live index a count over them
+
+
+def _naive_live_index(obj, elem):
+    """The live index as the engine computed it before ISSUE 45 (and
+    as `OpSet._live_index` still does): a walk of `order`. The oracle
+    the vector's count is held to."""
+    idx = 0
+    for e in obj.order:
+        if e == elem:
+            return idx
+        if obj.fields.get(e):
+            idx += 1
+    return idx
+
+
+def _live_vectors(repo, doc):
+    """`_assert_vectors` over a live doc's state, under its emission
+    domain (the state's declared guard)."""
+    with doc.emission:
+        with repo.back.live._lock:
+            ld = repo.back.live._docs[doc.id]
+        return _assert_vectors(ld.state)
+
+
+def _assert_vectors(state):
+    """Every sequence object's vector is its elems' liveness, byte for
+    byte, and `live()` is the walk's answer. Returns the sequences."""
+    seqs = [o for o in state.objs.values() if o.is_sequence]
+    for obj in seqs:
+        assert len(obj.alive) == len(obj.order)
+        assert bytes(obj.alive) == bytes(
+            1 if obj.fields[e] else 0 for e in obj.order
+        )
+        assert obj.live() == [e for e in obj.order if obj.fields.get(e)]
+    return seqs
+
+
+def _drop(seq, i):
+    """Delete position i of a list or a text proxy."""
+    if hasattr(seq, "delete"):
+        seq.delete(i)
+    else:
+        del seq[i]
+
+
+def _seq_mutation(site, r):
+    """One random change, sequence ops mostly: inserts at random
+    positions of a list and a text, deletes, element sets, nested
+    lists / texts made mid-stream and written into."""
+
+    def fn(d):
+        lst, txt = d["l"], d["t"]
+        choice = r.random()
+        if choice < 0.25:
+            lst.insert(r.randint(0, len(lst)), r.randint(0, 99))
+        elif choice < 0.45:
+            txt.insert(r.randint(0, len(txt)), r.choice("xyz"))
+        elif choice < 0.6:
+            seq = r.choice((lst, txt))
+            if len(seq) > 0:
+                _drop(seq, r.randint(0, len(seq) - 1))
+        elif choice < 0.75:
+            if len(lst) > 0:
+                lst[r.randint(0, len(lst) - 1)] = r.randint(100, 199)
+        elif choice < 0.85:
+            made = r.choice(([r.randint(0, 9)], Text("ab")))
+            lst.insert(r.randint(0, len(lst)), made)
+        else:
+            nested = [x for x in lst if hasattr(x, "insert")]
+            if nested:
+                seq = r.choice(nested)
+                if len(seq) > 0 and r.random() < 0.4:
+                    _drop(seq, r.randint(0, len(seq) - 1))
+                else:
+                    seq.insert(r.randint(0, len(seq)), "n")
+            else:
+                d["k"] = r.randint(0, 9)
+
+    site.change(fn)
+
+
+def _seq_script(stored, seed, n_rounds=12):
+    """Change batches from two peers that extend `stored`, in an order
+    that is causal: random sequence edits, and in rounds 3 and 7 a
+    CONCURRENT set and delete of one list element, once delivered set
+    first (the delete then finds its pred gone: the elem stays) and
+    once delete first (the set then revives a tombstone)."""
+    r = random.Random(seed)
+    peers = [Site(f"peer{i:1d}0000000000001") for i in range(2)]
+    for p in peers:
+        p.receive(stored)
+    script = []
+
+    def step(idx, fn=None):
+        site = peers[idx]
+        before = len(site.opset.history)
+        if fn is None:
+            _seq_mutation(site, r)
+        else:
+            site.change(fn)
+        batch = site.opset.history[before:]
+        if batch:
+            script.append((idx, list(batch)))
+
+    for rnd in range(n_rounds):
+        if rnd in (3, 7):
+            sync(*peers)
+            scalars = [
+                i for i, x in enumerate(peers[0].doc["l"])
+                if not isinstance(x, (list, Text))
+            ]
+            i = r.choice(scalars)
+            fns = [
+                lambda d, i=i: d["l"].__setitem__(i, 555),
+                lambda d, i=i: d["l"].__delitem__(i),
+            ]
+            if rnd == 7:
+                fns.reverse()  # the delete is delivered first
+            step(0, fns[0])
+            step(1, fns[1])
+            continue
+        idx = r.randrange(2)
+        for _ in range(r.randint(1, 3)):
+            step(idx)
+        if rnd % 3 == 2:
+            sync(*peers)
+    return script
+
+
+def _seq_seed_dir(tmp):
+    """A stored doc on disk whose history holds a list with a
+    tombstone and a nested list, and a text."""
+    repo = Repo(path=tmp)
+    url = repo.create({"l": [1, 2, 3, 4, 5, 6], "t": Text("hello")})
+    repo.change(url, lambda d: d["l"].__delitem__(2))
+    repo.change(url, lambda d: d["l"].insert(1, [7, 8]))
+    repo.change(url, lambda d: d["t"].insert(2, "!"))
+    doc_id = validate_doc_url(url)
+    stored = list(repo.back.docs[doc_id].opset.history)
+    repo.close()
+    return url, doc_id, stored
+
+
+@pytest.mark.parametrize("seed", [11, 2147483659, 77])
+@pytest.mark.parametrize("way", ["adopt", "tick", "kernel", "local"])
+def test_liveness_vector_is_the_elems_liveness(
+    tmp_path, monkeypatch, way, seed
+):
+    """The four ways a decoded state comes to be or changes keep every
+    sequence object's `alive` equal to its elems' liveness, and every
+    index the engine emits equal to the naive walk's: `adopt` (the
+    adoption's decode of a packed history), `tick` (`_tick_doc_locked`
+    one op at a time), `kernel` (`_decode_install_locked`: the kernel
+    path's decode and state diff), `local` (local changes with list
+    intents between the remote batches). The patches equal the host
+    OpSet's: diff for diff where ops are applied one at a time, by the
+    frontend's state where ticks are decoded and diffed."""
+    from hypermerge_tpu.backend import live as live_mod
+    from hypermerge_tpu.crdt.opset import OpSet
+
+    url, doc_id, stored = _seq_seed_dir(str(tmp_path))
+    script = _seq_script(stored, seed)
+
+    if way == "adopt":
+        from hypermerge_tpu.ops.columnar import (
+            LiveColumns,
+            causal_sort,
+            pack_docs,
+        )
+
+        changes = causal_sort(
+            stored + [c for _i, batch in script for c in batch]
+        )
+        lv = LiveColumns.from_batch(pack_docs([changes]), 0)
+        state = live_mod._decode_state(
+            lv, live_mod.LiveApplyEngine._host_lanes(lv)
+        )
+        seqs = _assert_vectors(state)
+        assert len(seqs) >= 3  # the list, the text, a nested one
+        assert any(0 in o.alive for o in seqs)  # tombstones decoded
+        opset = OpSet()
+        opset.apply_changes(changes)
+        got = live_mod._diff_states(live_mod._DocState(), state)
+        assert [d.to_json() for d in got] == [
+            d.to_json() for d in opset.snapshot_patch().diffs
+        ]
+        # ...and the state-walk reachability reads the same vector
+        walked = live_mod._decode_state(
+            lv, live_mod.LiveApplyEngine._host_lanes(lv)
+        )
+        live_mod._compute_reachable(walked)
+        assert walked.reachable == state.reachable
+        return
+
+    monkeypatch.setenv("HM_LIVE", "1")
+    if way == "kernel":
+        # every tick of more than 8 ops goes to the kernel group
+        monkeypatch.setenv("HM_LIVE_INC_BUDGET", "0")
+        merged, script, batch = script, [], []
+        for _i, b in merged:
+            batch.extend(b)
+            if sum(len(c.ops) for c in batch) > 8:
+                script.append((0, batch))
+                batch = []
+        if batch:
+            script.append((0, batch))
+
+    # every op through _apply_seq_state: the vector after it, and each
+    # index it emitted against the naive walk (errors are collected:
+    # an assert on the tick thread would only be logged)
+    faults, kinds = [], set()
+    real = live_mod.LiveApplyEngine._apply_seq_state
+
+    def checked(self, state, obj, opid, op, val, diffs):
+        n = len(diffs)
+        real(self, state, obj, opid, op, val, diffs)
+        try:
+            _assert_vectors(state)
+            elem = opid if op.insert else op.ref
+            for d in diffs[n:]:
+                kinds.add(
+                    "revive" if d.action == "insert" and not op.insert
+                    else d.action
+                )
+                assert d.index == _naive_live_index(obj, elem), d
+        except AssertionError as e:
+            faults.append(e)
+
+    monkeypatch.setattr(
+        live_mod.LiveApplyEngine, "_apply_seq_state", checked
+    )
+
+    repo = Repo(path=str(tmp_path))
+    try:
+        got_diffs = []
+        orig_push = repo.back.to_frontend.push
+
+        def record(msg):
+            if msg.get("type") == "Patch":
+                got_diffs.extend(msg["patch"]["diffs"])
+            orig_push(msg)
+
+        repo.back.to_frontend.push = record
+        h = repo.open(url)
+        assert h.value(timeout=20) is not None
+        doc = repo.back.docs[doc_id]
+        eng = repo.back.live
+        oracle = OpSet()
+        oracle.apply_changes(_local_changes(repo, doc_id))
+        want_diffs = []
+        r = random.Random(seed + 1)
+
+        def local_edit(d):
+            seq = d[r.choice("lt")]
+            kind = r.random()
+            if kind < 0.5 or len(seq) == 0:
+                seq.insert(r.randint(0, len(seq)), "L")
+            elif kind < 0.75 or hasattr(seq, "delete"):
+                _drop(seq, r.randint(0, len(seq) - 1))
+            else:
+                seq[r.randint(0, len(seq) - 1)] = "S"
+
+        for _idx, batch in script:
+            want_diffs.extend(
+                d.to_json() for d in oracle.apply_changes(batch).diffs
+            )
+            doc.apply_remote_changes(list(batch))
+            wait_until(lambda: dict(doc.clock) == dict(oracle.clock))
+            eng.flush_now()
+            _live_vectors(repo, doc)
+            if way == "local":
+                repo.change(url, local_edit)
+                me = doc.actor_id
+                mine = repo.back._get_or_create_actor(
+                    me
+                ).changes_in_window(
+                    oracle.clock.get(me, 0), doc.clock[me]
+                )
+                want_diffs.extend(
+                    d.to_json()
+                    for d in oracle.apply_changes(mine).diffs
+                )
+                _live_vectors(repo, doc)
+        assert not faults, faults[:3]
+        assert doc.opset is None  # live-managed to the end
+        stats = eng.stats
+        if way == "kernel":
+            assert stats["kernel_runs"] > 0
+        else:
+            # one op at a time all the way: the same diffs, in order
+            assert stats["kernel_runs"] == 0
+            assert got_diffs == want_diffs
+            assert {"insert", "remove", "set", "revive"} <= kinds
+            assert stats["seq_ops"] > 0
+        wait_until(
+            lambda: plainify(h.value())
+            == plainify(oracle.materialize())
+        )
+        seqs = _live_vectors(repo, doc)
+        assert any(0 in o.alive for o in seqs)
+    finally:
+        repo.close()
+
+
+def test_caught_up_tick_counts_its_ops_and_walks_no_elems(
+    tmp_path, monkeypatch
+):
+    """The catch-up cell's document shape: a held three-writer doc of
+    1,152 ops takes its missing 24 changes (384 ops) in ONE tick, one
+    op at a time: `live.inc_ops` moves by 384 and `live.seq_ops` by
+    the ops on its text, and the tick asks no object for `live()` (the
+    one O(elems) Python pass a sequence op could still reach)."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.corpora import multi_writer_rounds_behind as mwrb
+    from hypermerge_tpu.backend import live as live_mod
+    from hypermerge_tpu.crdt.change import ROOT, Change
+
+    monkeypatch.setenv("HM_LIVE", "1")
+    corpus = {
+        "writer": "multi_writer_rounds_behind", "sign": True,
+        "ops": 1536, "ops_per_change": 16, "blocks_held": 24,
+        "seq_frac": 0.85, "del_frac": 0.1, "n_keys": 10,
+        "seq_key": "t", "distinct": 1,
+        "classes": [{"writers": 3, "count": 1}],
+    }
+    job = mwrb.CorpusJob(str(tmp_path / "a"), corpus, 2147483659, 1)
+    try:
+        urls = job.start().finish()
+    except BaseException:
+        job.abort()
+        raise
+    tail = [Change.from_json(c) for c in job.doc_changes(0, {})[72:]]
+    assert sum(len(c.ops) for c in tail) == 384
+    seq_ops = sum(op.obj != ROOT for c in tail for op in c.ops)
+    assert seq_ops > 250  # seq_frac 0.85 of 384
+
+    calls = []
+    real_live = live_mod._Obj.live
+    monkeypatch.setattr(
+        live_mod._Obj, "live",
+        lambda self: calls.append(1) or real_live(self),
+    )
+    repo = Repo(path=job.behind_path)
+    try:
+        repo.open_many(urls)
+        repo.back.fetch_bulk_summaries()
+        doc = repo.back.docs[validate_doc_url(urls[0])]
+        eng = repo.back.live
+        before = eng.stats
+        doc.apply_remote_changes(tail)
+        wait_until(lambda: sum(doc.clock.values()) == 96, timeout=60)
+        eng.flush_now()
+        after = eng.stats
+        moved = {k: after[k] - before[k] for k in after}
+        assert moved["adopt_held"] == moved["adopted"] == 1
+        assert moved["tick_changes"] == moved["inc_changes"] == 24
+        assert moved["inc_ops"] == 384
+        assert moved["seq_ops"] == seq_ops
+        assert moved["kernel_runs"] == 0
+        assert calls == []
+        text = [o for o in _live_vectors(repo, doc) if o.type == "text"]
+        assert len(text) == 1 and len(text[0].order) > 1000
+    finally:
+        repo.close()

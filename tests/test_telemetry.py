@@ -605,6 +605,8 @@ def test_live_engine_stats_keys_unchanged():
             "inc_changes", "kernel_runs", "device_dispatches",
             "local_changes", "adopt_retries", "demoted", "readopted",
             "adopt_held",  # PR 44: adoptions of a doc that had history
+            "inc_ops", "seq_ops",  # PR 45: ops a tick applied one at a
+            # time; ops through _apply_seq_state (the liveness vector)
             "live_bytes", "live_docs",
             "t_live_append", "t_live_apply", "t_live_kernel",
             "t_live_decode", "t_live_diff",
