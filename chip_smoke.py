@@ -465,20 +465,14 @@ def _warm_buckets(repo) -> list:
 
     entries = list(repo.back.serve._cache._entries.values())
     check(len(entries) >= READ_THREADS, "resident entries to warm with")
-    calls = {
-        "map_lookup": lambda es: kernels.map_lookup(
-            es, [-1] * len(es), [0] * len(es)),
-        "seq_order": lambda es: kernels.seq_order(es, [-1] * len(es)),
-        "counts": lambda es: kernels.counts(es, [-1] * len(es)),
-    }
     added = []
     for n_rows in sorted({e.bucket for e in entries}):
         es = [e for e in entries if e.bucket == n_rows]
         B = 1
         while B <= READ_THREADS and B <= len(es):
-            for kind, call in calls.items():
+            for kind in kernels.KINDS:
                 if ("serve", kind, B, n_rows) not in sharded.trace_counts:
-                    call(es[:B])
+                    getattr(kernels, kind)(es[:B], [-1] * B, [0] * B)
                     added.append([kind, B, n_rows])
             B *= 2
     return added

@@ -38,7 +38,7 @@ class ReadRequest:
 
     __slots__ = (
         "doc_id", "query", "cb", "t0", "span",
-        "entry", "obj_row", "steps", "done", "cold",
+        "entry", "obj_row", "steps", "qkey", "ask", "done", "cold",
     )
 
     def __init__(self, doc_id: str, query: Dict, cb: Callable) -> None:
@@ -50,6 +50,8 @@ class ReadRequest:
         self.entry: Any = None
         self.obj_row = -1
         self.steps: List = []
+        self.qkey = -1  # this round's string step as a key index
+        self.ask: Any = None  # and what lies behind it (tier._ask)
         self.done = False
         self.cold = False  # its flush installed its doc for it
 

@@ -1,11 +1,14 @@
 """Batched device query kernels for the read-serving tier.
 
 One read of a resident doc never materializes anything host-side: the
-structural queries — element order of a text/list object, winner row of
-a (map, key) pair, live-entry counts — run as jitted programs over the
-stacked summary lanes of EVERY read in the batch, so a thousand
-concurrent reads cost one dispatch per (query kind, shape bucket)
-instead of a thousand host summary parses.
+structural queries — winner row of a (map, key) pair, live-entry
+counts, element order of a text/list object — run as jitted programs
+over the stacked summary lanes of EVERY read in the batch, so a
+thousand concurrent reads cost one dispatch per shape bucket instead
+of a thousand host summary parses. Each program answers what the one
+before it does and one thing more, about the container a read names
+as (object row, key): the key's winner is resolved on the device, so
+no read waits for a row to come back before it asks about it.
 
 The programs live in the PR-7 cached program table
 (parallel/sharded._PROGRAMS): one trace per ("serve", kind, B, N) key
@@ -26,7 +29,7 @@ array per resident doc — a single host->device transfer per install):
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Sequence
 
 import numpy as np
 
@@ -126,52 +129,60 @@ def _pad_q(vals: List[int], B: int, fill: int) -> np.ndarray:
     return out
 
 
+def _container(stacked, qobj, qkey):
+    """The container each read asks about, and (row, found) of the key
+    it named: `qkey` < 0 (no key: a keyless read, a pad row) leaves
+    the container `qobj` itself; otherwise it is the winner row of
+    (`qobj`, `qkey`), and NO_OBJ where nothing won (the argmax of an
+    empty mask is 0, a real row). A winner that is a scalar owns no
+    rows: a count of 0 either way, and the host decides from `found`
+    and the row's type."""
+    jnp = _jnp()
+    mask = (
+        (stacked[:, L_MAPWIN] != 0)
+        & (stacked[:, L_KEY] == qkey[:, None])
+        & (stacked[:, L_OBJ] == qobj[:, None])
+    )
+    row = jnp.argmax(mask, axis=1).astype(jnp.int32)
+    found = mask.any(axis=1)
+    return jnp.where(qkey < 0, qobj, jnp.where(found, row, NO_OBJ)), row, found
+
+
+def _count(stacked, obj):
+    """(the live element rows of `obj`, how many, its live map keys)."""
+    at_obj = stacked[:, L_OBJ] == obj[:, None]
+    elems = (stacked[:, L_LIVE] != 0) & at_obj & (stacked[:, L_INSERT] == 1)
+    n_map = ((stacked[:, L_MAPWIN] != 0) & at_obj).sum(axis=1)
+    return elems, elems.sum(axis=1).astype("int32"), n_map.astype("int32")
+
+
 def _build_map_lookup():
     def fn(arrs, qobj, qkey):
-        jnp = _jnp()
-        stacked = jnp.stack(arrs)
-        mask = (
-            (stacked[:, L_MAPWIN] != 0)
-            & (stacked[:, L_KEY] == qkey[:, None])
-            & (stacked[:, L_OBJ] == qobj[:, None])
-        )
-        row = jnp.argmax(mask, axis=1).astype(jnp.int32)
-        return row, mask.any(axis=1)
-
-    return fn
-
-
-def _build_seq_order():
-    def fn(arrs, qobj):
-        jnp = _jnp()
-        stacked = jnp.stack(arrs)
-        mask = (
-            (stacked[:, L_LIVE] != 0)
-            & (stacked[:, L_OBJ] == qobj[:, None])
-            & (stacked[:, L_INSERT] == 1)
-        )
-        # descending rank, ties in row order — the decode_patch element
-        # order (jnp.argsort is stable)
-        key = jnp.where(mask, -stacked[:, L_RANK], _INT32_MAX)
-        order = jnp.argsort(key, axis=1).astype(jnp.int32)
-        return order, mask.sum(axis=1).astype(jnp.int32)
+        return _container(_jnp().stack(arrs), qobj, qkey)[1:]
 
     return fn
 
 
 def _build_counts():
-    def fn(arrs, qobj):
+    def fn(arrs, qobj, qkey):
         stacked = _jnp().stack(arrs)
-        at_obj = stacked[:, L_OBJ] == qobj[:, None]
-        n_elems = (
-            ((stacked[:, L_LIVE] != 0) & at_obj & (stacked[:, L_INSERT] == 1))
-            .sum(axis=1)
-            .astype("int32")
-        )
-        n_map = (
-            ((stacked[:, L_MAPWIN] != 0) & at_obj).sum(axis=1).astype("int32")
-        )
-        return n_elems, n_map
+        obj, row, found = _container(stacked, qobj, qkey)
+        return (row, found, *_count(stacked, obj)[1:])
+
+    return fn
+
+
+def _build_seq_order():
+    def fn(arrs, qobj, qkey):
+        jnp = _jnp()
+        stacked = jnp.stack(arrs)
+        obj, row, found = _container(stacked, qobj, qkey)
+        elems, n_elems, n_map = _count(stacked, obj)
+        # descending rank, ties in row order — the decode_patch element
+        # order (jnp.argsort is stable)
+        key = jnp.where(elems, -stacked[:, L_RANK], _INT32_MAX)
+        order = jnp.argsort(key, axis=1).astype(jnp.int32)
+        return row, found, n_elems, n_map, order
 
     return fn
 
@@ -291,17 +302,25 @@ def advance(dev, desc: np.ndarray):
     return dev
 
 
-def _query(kind: str, build, entries: Sequence, *qs) -> tuple:
+# a query program's outputs, narrowest answer first: a kind returns
+# its own and those of the kinds before it (map_lookup the first two,
+# counts four, seq_order all), each about the container (`qobj`,
+# `qkey`) names
+ROW, FOUND, N_ELEMS, N_MAP, ORDER = range(5)
+KINDS = ("map_lookup", "counts", "seq_order")
+
+
+def _query(kind: str, build, entries: Sequence, qobjs, qkeys, skip) -> tuple:
     """One query dispatch over the group's resident lanes: the stack of
     the entries, the program call, the device->host fetch of its
-    outputs as numpy arrays. `qs` = (per-read values, pad fill) a query
-    argument. The `serve.dispatch{kind,B,N}` span is the host's whole
-    cost of the dispatch, its three children follow one another:
-    `serve.dispatch.stack` (the program's look-up and its arguments:
-    the entries' arrays, the padded query as device arrays),
+    outputs as numpy arrays (None for those `skip` names: no read of
+    the group uses them). The `serve.dispatch{kind,B,N}` span is the
+    host's whole cost of the dispatch, its three children follow one
+    another: `serve.dispatch.stack` (the program's look-up and its
+    arguments: the entries' arrays, the padded query as device arrays),
     `serve.dispatch.call` (the program call, until it returns) and
-    `serve.dispatch.fetch` (the wait for the program and the transfer
-    of what it returned)."""
+    `serve.dispatch.fetch` (the wait for the program and the transfers
+    of what it returned, started together)."""
     jnp = _jnp()
     B, N = batch_bucket(len(entries)), entries[0].dev.shape[1]
     with telemetry.span("serve.dispatch", "serve", kind=kind, B=B, N=N):
@@ -309,35 +328,47 @@ def _query(kind: str, build, entries: Sequence, *qs) -> tuple:
             fn = _program(kind, B, N, build)
             args = (
                 stack_entries(entries),
-                *(jnp.asarray(_pad_q(vals, B, fill)) for vals, fill in qs),
+                jnp.asarray(_pad_q(qobjs, B, NO_OBJ)),
+                jnp.asarray(_pad_q(qkeys, B, -1)),
             )
         with telemetry.span("serve.dispatch.call", "serve"):
             out = fn(*args)
         with telemetry.span("serve.dispatch.fetch", "serve"):
-            return tuple(np.asarray(o) for o in out)
+            # every wanted output's copy is under way before the first
+            # is waited for: one blocking wait a dispatch, where an
+            # `np.asarray` pass alone is a round trip an output
+            want = [None if i in skip else o for i, o in enumerate(out)]
+            for o in want:
+                if o is not None:
+                    o.copy_to_host_async()
+            return tuple(None if o is None else np.asarray(o) for o in want)
 
 
 def map_lookup(
-    entries: Sequence, qobjs: List[int], qkeys: List[int]
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Winner value row per (doc, container, key): [B] rows + [B] found
-    mask. One dispatch for the whole group."""
+    entries: Sequence, qobjs: List[int], qkeys: List[int], skip=()
+) -> tuple:
+    """(ROW, FOUND): the winner value row per (doc, container, key),
+    [B] rows + [B] found mask. One dispatch for the whole group."""
     return _query(
-        "map_lookup", _build_map_lookup, entries,
-        (qobjs, NO_OBJ), (qkeys, -1),
+        "map_lookup", _build_map_lookup, entries, qobjs, qkeys, skip
     )
 
 
-def seq_order(
-    entries: Sequence, qobjs: List[int]
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Element order (live INS rows, descending rank) per (doc,
-    container): [B, N] row order + [B] live counts."""
-    return _query("seq_order", _build_seq_order, entries, (qobjs, NO_OBJ))
-
-
 def counts(
-    entries: Sequence, qobjs: List[int]
-) -> Tuple[np.ndarray, np.ndarray]:
-    """([B] live element counts, [B] map entry counts) per container."""
-    return _query("counts", _build_counts, entries, (qobjs, NO_OBJ))
+    entries: Sequence, qobjs: List[int], qkeys: List[int], skip=()
+) -> tuple:
+    """`map_lookup`'s pair, then (N_ELEMS, N_MAP): [B] live element
+    counts and [B] map entry counts of the container: `qobj` itself
+    where `qkey` is -1, else the winner of (`qobj`, `qkey`)."""
+    return _query("counts", _build_counts, entries, qobjs, qkeys, skip)
+
+
+def seq_order(
+    entries: Sequence, qobjs: List[int], qkeys: List[int], skip=()
+) -> tuple:
+    """`counts`' four, then ORDER: the container's element order (live
+    INS rows, descending rank), [B, N] rows of which the first N_ELEMS
+    count."""
+    return _query(
+        "seq_order", _build_seq_order, entries, qobjs, qkeys, skip
+    )

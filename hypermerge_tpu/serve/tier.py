@@ -26,11 +26,15 @@ the entry and the next read installs the doc again.
 A flush is one `serve.batch{reads,cold}` span; below it, also when
 nothing is cold: `serve.batch.attach{docs}` (the residency check, and
 under it a `serve.advance` for each entry that had changes noted),
-one `serve.dispatch{kind,B,N}` a query dispatch with its blocking
-`serve.dispatch.fetch` (serve/kernels.py), one `serve.decode{reads,
-rows}` a seq_order group (its text joins and index / path steps; the
-answers go out after it). Counters `serve.text_reads` / `serve.text_rows`
-give the joins' work.
+then ONE `serve.dispatch{kind,B,N}` a row bucket and round of the path
+walk, with its blocking `serve.dispatch.fetch` (serve/kernels.py): the
+query programs take their container as (object row, key), so a read's
+string step and the question behind it leave together (`_resolve`;
+counter `serve.fused_steps`), in the program of the widest answer the
+group's reads ask for. Behind a seq_order, one `serve.decode{reads,
+rows}` (the group's text joins and index / path steps; the answers go
+out after it). Counters `serve.text_reads` / `serve.text_rows` give the
+joins' work.
 
 Read queries (all JSON-safe; `path` is map keys (str) / sequence
 indices (int) from the root):
@@ -82,6 +86,12 @@ from .resident import REFUSALS, ResidencyCache, build_group, rung_of
 READ_KINDS = ("lookup", "index", "text", "len", "clock", "history")
 
 _MAX_PATH_ROUNDS = 64  # path depth bound (per-level batched dispatches)
+
+# what a read asks of a round's dispatch (`ServeTier._ask`), narrowest
+# answer first: kernels.KINDS[ask] is the program that gives it, and
+# every wider one gives it too
+_STEP, _COUNT, _ORDER = range(3)
+_FINAL = {"lookup": _STEP, "len": _COUNT, "text": _ORDER, "index": _ORDER}
 
 # the longest a flush waits for a writer to leave a cold doc's emission
 # domain (clock moved, block not appended yet) before it lets the host
@@ -257,6 +267,9 @@ class ServeTier:
                 "fallbacks", "evictions", "evictions_pressure",
                 "batches", "memo_hits", "host_memo_hits", "dispatches",
                 "overload_shed", "flush_errors",
+                # reads whose string step and the question behind it
+                # left in one dispatch
+                "fused_steps",
                 # installs by group (one pack, one upload) and by who
                 # computed the docs' kernel lanes where the summary memo
                 # did not hold them: the slab program on the device, or
@@ -688,58 +701,23 @@ class ServeTier:
     # batched path resolution + query dispatch
 
     def _resolve(self, reqs: List[ReadRequest]) -> None:
+        """Walk the reads' paths in rounds. A round's reads of one row
+        bucket leave in ONE dispatch: it takes each read's next string
+        step and, where nothing but a question (or an int step) lies
+        behind that, answers the question about the container the step
+        led to, which the device resolves itself (`_ask`)."""
         from . import kernels
 
         live = [r for r in reqs if not r.done]
         for _round in range(_MAX_PATH_ROUNDS):
             if not live:
                 return
-            lookups: List[ReadRequest] = []
-            orders: List[ReadRequest] = []
-            fin_text: List[ReadRequest] = []
-            fin_len: List[ReadRequest] = []
-            fin_index: List[ReadRequest] = []
             for r in live:
-                if r.steps:
-                    s = r.steps[0]
-                    if isinstance(s, str):
-                        # a key the doc never saw resolves host-side
-                        if s not in r.entry.key_index:
-                            self._finish(r, None)
-                        else:
-                            lookups.append(r)
-                    elif isinstance(s, int):
-                        otype = r.entry.obj_type(r.obj_row)
-                        if otype in ("list", "text"):
-                            orders.append(r)
-                        else:
-                            self._finish(r, None)
-                    else:
-                        self._finish(r, None)
-                    continue
-                kind = r.query.get("kind")
-                if kind == "text":
-                    if r.entry.obj_type(r.obj_row) == "text":
-                        fin_text.append(r)
-                    else:
-                        self._finish(r, None)
-                elif kind == "index":
-                    i = r.query.get("index")
-                    if isinstance(i, int) and r.entry.obj_type(
-                        r.obj_row
-                    ) in ("list", "text"):
-                        fin_index.append(r)
-                    else:
-                        self._finish(r, None)
-                elif kind == "len":
-                    fin_len.append(r)
-                else:  # lookup with an exhausted path
+                r.ask = self._ask(r)
+                if r.ask is None:
                     self._finish(r, None)
-            self._dispatch_lookups(kernels, lookups)
-            self._dispatch_orders(
-                kernels, orders + fin_index + fin_text
-            )
-            self._dispatch_counts(kernels, fin_len)
+            for group in self._by_bucket([r for r in live if not r.done]):
+                self._dispatch(kernels, group)
             # every round either finishes a request or consumes one of
             # its path steps, so this converges in <= depth rounds
             live = [r for r in reqs if not r.done]
@@ -753,6 +731,48 @@ class ServeTier:
             else:
                 self._m["fallbacks"].add(1)
                 self._fallback(r, doc)
+
+    def _ask(self, r: ReadRequest) -> Optional[int]:
+        """What `r` asks of this round, as the index of the narrowest
+        program that answers it (kernels.KINDS): _STEP (a string step
+        with more path behind it, or a final `lookup`: the winner's
+        row), _COUNT (a final `len`) or _ORDER (a final `text` /
+        `index`, or an int step); the last two about `r.obj_row`
+        (`r.qkey` -1) or about what one string step from it leads to
+        (`r.qkey` the step's key index; the step leaves `r.steps`
+        here). None: the host can tell that the path breaks."""
+        r.qkey = -1
+        if r.steps and isinstance(r.steps[0], str):
+            # a key the doc never saw resolves host-side
+            r.qkey = r.entry.key_index.get(r.steps.pop(0), -1)
+            if r.qkey < 0:
+                return None
+        if not r.steps:
+            ask = _FINAL.get(r.query.get("kind"))
+            if ask == _STEP and r.qkey < 0:
+                return None  # lookup with an exhausted path
+        elif isinstance(r.steps[0], str):
+            return _STEP
+        else:
+            ask = _ORDER if isinstance(r.steps[0], int) else None
+        if ask is None or (r.qkey < 0 and not self._sound(r, r.obj_row)):
+            return None
+        return ask
+
+    @staticmethod
+    def _sound(r: ReadRequest, row: int) -> bool:
+        """Whether the container at `row` is one of which `r` can ask
+        what it asks next: an int step or an `index` a sequence, a
+        `text` a text, a `len` any."""
+        otype = r.entry.obj_type(row)
+        kind = r.query.get("kind")
+        if r.steps:
+            return otype in ("list", "text")
+        if kind == "index":
+            return isinstance(r.query.get("index"), int) and otype in (
+                "list", "text"
+            )
+        return kind != "text" or otype == "text"
 
     @staticmethod
     def _by_bucket(rs: List[ReadRequest]) -> List[List[ReadRequest]]:
@@ -769,85 +789,91 @@ class ServeTier:
             for at in range(0, len(group), MAX_BATCH)
         ]
 
-    def _dispatch_lookups(self, kernels, rs: List[ReadRequest]) -> None:
-        """One map_lookup dispatch per shape bucket: resolve the next
-        (string) path step of every request in the group."""
-        for group in self._by_bucket(rs):
-            keys = [r.steps[0] for r in group]
-            rows, found = kernels.map_lookup(
-                [r.entry for r in group],
-                [r.obj_row for r in group],
-                [r.entry.key_index[k] for r, k in zip(group, keys)],
-            )
-            self._m["dispatches"].add(1)
+    def _dispatch(self, kernels, group: List[ReadRequest]) -> None:
+        """One dispatch answers the group, through the program of the
+        widest answer any of its reads asks for (`lookup`s alone run
+        map_lookup, a `len` among them makes it counts, a `text` or an
+        int step seq_order); each read takes from its outputs what it
+        asked for, and only the outputs some read uses are fetched.
+        The group's host half runs to its end before any of its
+        answers goes out; behind a seq_order it is one `serve.decode`
+        span, which so holds no reader's callback."""
+        widest = max(r.ask for r in group)
+        keyed = any(r.qkey >= 0 for r in group)
+        skip = () if keyed else (kernels.ROW, kernels.FOUND)
+        if all(r.ask != _COUNT for r in group):
+            skip += (kernels.N_MAP,)
+        out = getattr(kernels, kernels.KINDS[widest])(
+            [r.entry for r in group], [r.obj_row for r in group],
+            [r.qkey for r in group], skip,
+        )
+        self._m["dispatches"].add(1)
+        self._m["fused_steps"].add(
+            sum(r.qkey >= 0 and r.ask != _STEP for r in group)
+        )
+        answers: List = []  # (request, value) this group finished
+        texts = rows = 0
+        with telemetry.span(
+            "serve.decode", "serve",
+            reads=sum(r.ask == _ORDER for r in group),
+        ) if widest == _ORDER else telemetry.NOOP as sp:
             for i, r in enumerate(group):
-                r.steps.pop(0)
-                if not found[i]:
-                    self._finish(r, None)
+                e = r.entry
+                if r.qkey >= 0 and not self._take_step(
+                    r, int(out[kernels.ROW][i]), out[kernels.FOUND][i],
+                    answers,
+                ):
                     continue
-                w = int(rows[i])
-                if not r.steps and r.query.get("kind") == "lookup":
-                    self._finish(r, self._row_leaf(r.entry, w))
-                elif r.entry.obj_type(w) is not None:
-                    r.obj_row = w  # descend into the linked object
+                if r.ask == _STEP:
+                    continue  # the next round goes on from r.obj_row
+                n = int(out[kernels.N_ELEMS][i])
+                if r.ask == _COUNT:
+                    if e.obj_type(r.obj_row) not in ("list", "text"):
+                        n = int(out[kernels.N_MAP][i])
+                    answers.append((r, n))
+                    continue
+                order = out[kernels.ORDER][i]
+                if not r.steps and r.query.get("kind") == "text":
+                    answers.append((r, _join_text(e, order[:n])))
+                    texts += 1
+                    rows += n
+                    continue
+                if r.steps:  # int path step: descend through it
+                    idx, descend = r.steps.pop(0), True
+                else:  # final "index" query on the resolved sequence
+                    idx, descend = r.query.get("index"), False
+                if not isinstance(idx, int) or not 0 <= idx < n:
+                    answers.append((r, None))
+                    continue
+                w = int(e.elem_val[int(order[idx])])
+                if not descend:
+                    answers.append((r, self._row_leaf(e, w)))
+                elif e.obj_type(w) is not None:
+                    r.obj_row = w
                 else:
-                    self._finish(r, None)  # scalar mid-path
+                    answers.append((r, None))  # scalar mid-path
+            sp.note(rows=rows)
+        self._m["text_reads"].add(texts)
+        self._m["text_rows"].add(rows)
+        for r, value in answers:
+            self._finish(r, value)
 
-    def _dispatch_orders(self, kernels, rs: List[ReadRequest]) -> None:
-        """One seq_order dispatch per bucket serves int path steps,
-        final index lookups, and text joins together. The group's host
-        half (`serve.decode`) runs to its end before any of its
-        answers goes out, so the span holds no reader's callback."""
-        for group in self._by_bucket(rs):
-            order, count = kernels.seq_order(
-                [r.entry for r in group], [r.obj_row for r in group]
-            )
-            self._m["dispatches"].add(1)
-            answers: List = []  # (request, value) this group finished
-            texts = rows = 0
-            with telemetry.span(
-                "serve.decode", "serve", reads=len(group)
-            ) as sp:
-                for i, r in enumerate(group):
-                    e = r.entry
-                    n = int(count[i])
-                    if not r.steps and r.query.get("kind") == "text":
-                        answers.append((r, _join_text(e, order[i, :n])))
-                        texts += 1
-                        rows += n
-                        continue
-                    if r.steps:  # int path step: descend through it
-                        idx, descend = r.steps.pop(0), True
-                    else:  # final "index" query on the resolved sequence
-                        idx, descend = r.query.get("index"), False
-                    if not isinstance(idx, int) or not 0 <= idx < n:
-                        answers.append((r, None))
-                        continue
-                    w = int(e.elem_val[int(order[i][idx])])
-                    if not descend:
-                        answers.append((r, self._row_leaf(e, w)))
-                    elif e.obj_type(w) is not None:
-                        r.obj_row = w
-                    else:
-                        answers.append((r, None))  # scalar mid-path
-                sp.note(rows=rows)
-            self._m["text_reads"].add(texts)
-            self._m["text_rows"].add(rows)
-            for r, value in answers:
-                self._finish(r, value)
-
-    def _dispatch_counts(self, kernels, rs: List[ReadRequest]) -> None:
-        for group in self._by_bucket(rs):
-            n_elems, n_map = kernels.counts(
-                [r.entry for r in group], [r.obj_row for r in group]
-            )
-            self._m["dispatches"].add(1)
-            for i, r in enumerate(group):
-                otype = r.entry.obj_type(r.obj_row)
-                if otype in ("list", "text"):
-                    self._finish(r, int(n_elems[i]))
-                else:
-                    self._finish(r, int(n_map[i]))
+    def _take_step(self, r: ReadRequest, w: int, found, answers) -> bool:
+        """The string step the dispatch took for `r`, which led to row
+        `w`: answered here (into `answers`), or True: `r` goes on from
+        the container `w`, of which its question can be asked."""
+        if not found:
+            answers.append((r, None))
+        elif not r.steps and r.query.get("kind") == "lookup":
+            answers.append((r, self._row_leaf(r.entry, w)))
+        elif r.entry.obj_type(w) is None:
+            answers.append((r, None))  # scalar mid-path
+        elif r.ask != _STEP and not self._sound(r, w):
+            answers.append((r, None))
+        else:
+            r.obj_row = w  # descend into the linked object
+            return True
+        return False
 
     # ------------------------------------------------------------------
     # host-side row decode (the host half of a device-served read)

@@ -476,3 +476,34 @@ def test_serve_advance_compiles_for_v5e_with_its_lanes_donated(
     )
     assert "input_output_alias" in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes >= 6 * n_rows * 4
+
+
+@pytest.mark.parametrize("kind,batch,n_rows", [
+    ("seq_order", 16, 1024), ("seq_order", 4, 1024),
+    ("counts", 256, 4096), ("counts", 16, 1024),
+    ("map_lookup", 16, 1024),
+])
+def test_keyed_query_programs_compile_for_v5e(v5e_chip, kind, batch, n_rows):
+    """The read tier's query programs (ISSUE 46: the container named by
+    (object row, key) and resolved on the device) at the shapes the
+    benchmark's cells dispatch them at (`reads.resident`'s mixed flush:
+    seq_order at B 16 and 4 over [6, 1024] lanes; the sync cells' 256
+    `len` reads: one keyed counts over [6, 4096]), compiled for a v5e:
+    each returns the outputs of the kinds before it and its own."""
+    import jax
+    import jax.numpy as jnp
+
+    from hypermerge_tpu.serve import kernels
+
+    lanes = jax.ShapeDtypeStruct(
+        (kernels.N_LANES, n_rows), jnp.int32, sharding=v5e_chip
+    )
+    q = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=v5e_chip)
+    build = getattr(kernels, "_build_" + kind)
+    compiled = jax.jit(build()).lower((lanes,) * batch, q, q).compile()
+    shapes = [(o.shape, str(o.dtype)) for o in compiled.out_info]
+    want = [((batch,), "int32"), ((batch,), "bool"),
+            ((batch,), "int32"), ((batch,), "int32"),
+            ((batch, n_rows), "int32")]
+    assert shapes == want[:{"map_lookup": 2, "counts": 4, "seq_order": 5}[kind]]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -217,14 +217,15 @@ def test_a_group_is_built_a_page_at_a_time(corpus, monkeypatch):
         d = moved(c0)
         assert d["install_groups"] == 2 and d["installs"] == N_DOCS
         assert d["install_device_docs"] == N_DOCS
-        # 7 docs of 64 rows and 6 of 256: 2 + 2 dispatches a program
-        assert telemetry.snapshot()["serve.dispatches"] - d0 == 8
+        # 7 docs of 64 rows and 6 of 256: 2 + 2 dispatches, each the
+        # keyed counts program (the `len` behind its string step)
+        assert telemetry.snapshot()["serve.dispatches"] - d0 == 4
         shapes = {(k[1], k[2]) for k in sharded.trace_counts
                   if k[0] == "serve"}
         assert shapes == {
             ("install_split", 4), ("install_split", 2),
             ("install_merge", 4), ("install_merge", 2),
-            ("map_lookup", 4), ("counts", 4),
+            ("counts", 4),
         }
         check_every_read(repo, corpus)
     sharded.clear_program_cache()
@@ -502,7 +503,18 @@ def test_counter_and_obs_readers():
     assert obs_value.read({"key": "absent"}, obs) is None
 
 
-def test_serve_roofline_counts_each_dispatch_at_its_shape(monkeypatch):
+@pytest.mark.parametrize("modules,shapes,took_us", [
+    # a flush of lookup round + seq_order group (before ISSUE 46)
+    ([("jit_serve_seq_order_b16_n1024(7)", 0, 100),
+      ("jit_serve_seq_order_b4_n1024(8)", 200, 50),
+      ("jit_serve_map_lookup_b16_n1024(9)", 300, 10)],
+     [(16, 1024), (4, 1024)], 150),
+    # a flush that is ONE keyed seq_order and never runs map_lookup
+    ([("jit_serve_seq_order_b16_n1024(7)", 0, 100)], [(16, 1024)], 100),
+], ids=["lookup-then-order", "keyed-order-alone"])
+def test_serve_roofline_counts_each_dispatch_at_its_shape(
+    monkeypatch, modules, shapes, took_us
+):
     from benchmark import trace_reduce
     from benchmark.counts import serve_query
     from benchmark.readers import serve_roofline, span_tree
@@ -510,10 +522,7 @@ def test_serve_roofline_counts_each_dispatch_at_its_shape(monkeypatch):
     us = 1e3  # ns
     planes = [
         ("/device:TPU:0", [("XLA Modules", [
-            ("jit_serve_seq_order_b16_n1024(7)", 0, 100 * us),
-            ("jit_serve_seq_order_b4_n1024(8)", 200 * us, 50 * us),
-            ("jit_serve_map_lookup_b16_n1024(9)", 300 * us, 10 * us),
-        ])]),
+            (name, at * us, dur * us) for name, at, dur in modules])]),
         ("/device:TPU:1", [("XLA Modules", [
             ("jit_serve_seq_order_b64_n1024(7)", 0, 100 * us)])]),
         ("/host:CPU", [("python", [("bench.serve.read_loop", 0, 400 * us)])]),
@@ -522,12 +531,11 @@ def test_serve_roofline_counts_each_dispatch_at_its_shape(monkeypatch):
     monkeypatch.setattr(trace_reduce, "load", lambda path: planes)
     obs = {"trace": {"busy_s": 1}, "device_kind": "TPU v5 lite",
            "peaks": {"TPU v5 lite": {"hbm_bytes_per_s": 819e9}}}
-    work = (serve_query.bytes_moved("seq_order", 16, 1024)
-            + serve_query.bytes_moved("seq_order", 4, 1024))
+    work = sum(serve_query.bytes_moved("seq_order", b, n) for b, n in shapes)
     got = serve_roofline.read(
         {"kind": "seq_order", "peak": "hbm_bytes_per_s"}, obs
     )
-    assert got == pytest.approx(100 * (work / 819e9) / 150e-6)
+    assert got == pytest.approx(100 * (work / 819e9) / (took_us * 1e-6))
     assert 0 < got < 100
     assert serve_roofline.read(
         {"kind": "counts", "peak": "hbm_bytes_per_s"}, obs

@@ -53,11 +53,11 @@ def resolve(e, path):
             assert found[0]
             obj = int(rows[0])
         else:
-            order, count = kernels.seq_order([e], [obj])
-            assert 0 <= step < int(count[0])
-            obj = int(e.elem_val[int(order[0][step])])
-    order, count = kernels.seq_order([e], [obj])
-    return order[0], int(count[0])
+            out = kernels.seq_order([e], [obj], [-1])
+            assert 0 <= step < int(out[kernels.N_ELEMS][0])
+            obj = int(e.elem_val[int(out[kernels.ORDER][0][step])])
+    out = kernels.seq_order([e], [obj], [-1])
+    return out[kernels.ORDER][0], int(out[kernels.N_ELEMS][0])
 
 
 @pytest.fixture(scope="module")
@@ -242,10 +242,11 @@ def _flush(repo, reads):
 def test_a_warm_flush_has_attach_dispatch_and_decode_spans(repo):
     """Two text reads of 5 and 12 characters, one index read and one
     lookup, nothing cold: under the flush's `serve.batch` one
-    `serve.batch.attach{docs=2}`, a `serve.dispatch{kind,B,N}` (with
-    its `serve.dispatch.fetch`) for the lookup round and one for the
-    seq_order group, one `serve.decode{reads=3,rows=17}`; and the
-    counters moved by 2 reads and 17 rows."""
+    `serve.batch.attach{docs=2}`, ONE `serve.dispatch{kind,B,N}` (with
+    its `serve.dispatch.fetch`): the seq_order program takes every
+    read's string step and answers the three sequence reads behind it;
+    one `serve.decode{reads=3,rows=17}`; and the counters moved by 2
+    text reads, 17 rows and 3 fused steps."""
     a = repo.create()
     repo.change(a, lambda d: d.__setitem__("t", Text("hello")))
     b = repo.create()
@@ -287,15 +288,14 @@ def test_a_warm_flush_has_attach_dispatch_and_decode_spans(repo):
     for e in inside:
         by_name.setdefault(e[1], []).append(e[6])
     assert by_name["serve.batch.attach"] == [{"docs": 2}]
-    # every read's first step is a string key: one map_lookup round,
-    # then the three sequence reads share one seq_order dispatch
+    # every read's first step is a string key, and the widest answer
+    # asked for behind it is an order: the one dispatch is seq_order's
     assert by_name["serve.dispatch"] == [
-        {"kind": "map_lookup", "B": 4, "N": 64},
         {"kind": "seq_order", "B": 4, "N": 64},
     ]
-    assert len(by_name["serve.dispatch.fetch"]) == 2
+    assert len(by_name["serve.dispatch.fetch"]) == 1
     assert by_name["serve.decode"] == [{"reads": 3, "rows": 17}]
-    assert moved("dispatches") == 2
+    assert (moved("dispatches"), moved("fused_steps")) == (1, 3)
     # ISSUE 34: a dispatch is stack -> call -> fetch, one after the
     # other and nothing else; a read's callback is a span of its own,
     # on the flusher's thread; every one has its thread's CPU seconds
@@ -410,3 +410,262 @@ def test_metric_files_read_a_rehearsal_size_traced_run(tmp_path, monkeypatch):
          for s in real_load(p)[0]
          if s.name in ("serve.batch", "serve.read")], []))
     assert harness.layer_metrics(cell, obs_parent) == {}
+
+
+# -- one dispatch a row bucket (ISSUE 46) ------------------------------------
+# `seq_order` and `counts` take their container as (object row, key
+# index) and resolve it on the device; a round's reads of one row
+# bucket leave in one dispatch, by the widest answer they ask for
+
+
+def _nested(d):
+    d["s"] = 3
+    d["t"] = Text("outer text")
+    d["e"] = Text("")
+    d["el"] = []
+    d["l"] = [1, {"x": Text("in a list"), "n": 9}, [7, 8, 9], "str"]
+    d["m"] = {"n": {"t": Text("deep text"), "l": [4, 5], "k": 5, "o": {}}}
+    d["gone"] = Text("deleted")
+    del d["gone"]
+
+
+@pytest.fixture(scope="module")
+def nested(repo):
+    """(url, doc, resident entry) of a doc with maps, lists and texts
+    inside one another, an empty text, an empty list, a deleted key."""
+    url = repo.create()
+    repo.change(url, _nested)
+    assert repo.read(url, {"kind": "len", "path": []}) == 6
+    doc_id = validate_doc_url(url)
+    return url, repo.back.docs[doc_id], repo.back.serve._cache._entries[doc_id]
+
+
+def _containers(e):
+    return [-1] + [r for r in range(e.n) if e.obj_type(r) is not None]
+
+
+@pytest.mark.parametrize("kind", ["seq_order", "counts"])
+def test_a_keyed_program_equals_the_lookup_then_the_unkeyed(nested, kind):
+    """For every (container, key) of the doc, and a key index the doc
+    never had: the program asked by (object, key) gives map_lookup's
+    row and found, and for the rest what it gives when asked about
+    that row itself; nothing found (never set, deleted) counts 0, and
+    a scalar winner owns no rows; the pad rows of the batch too."""
+    _url, _doc, e = nested
+    program = getattr(kernels, kind)
+    keys = sorted(e.key_index.values()) + [len(e.key_index) + 5]
+    pairs = [(obj, k) for obj in _containers(e) for k in keys]
+    seen = {"seq": 0, "empty": 0, "scalar": 0, "missing": 0}
+    for at in range(0, len(pairs), 60):
+        part = pairs[at:at + 60]  # 60 of a batch of 64: four pad rows
+        es = [e] * len(part)
+        qobj, qkey = [p[0] for p in part], [p[1] for p in part]
+        row, found = kernels.map_lookup(es, qobj, qkey)
+        keyed = program(es, qobj, qkey)
+        assert (keyed[kernels.ROW] == row).all()
+        assert (keyed[kernels.FOUND] == found).all()
+        plain = program(
+            es, [int(r) if f else kernels.NO_OBJ
+                 for r, f in zip(row, found)], [-1] * len(part),
+        )
+        for out in range(kernels.N_ELEMS, len(keyed)):
+            assert (keyed[out] == plain[out]).all(), out
+        assert not found[len(part):].any()
+        for i, (f, r) in enumerate(zip(found, row)):
+            n, n_map = (int(keyed[o][i])
+                        for o in (kernels.N_ELEMS, kernels.N_MAP))
+            if i >= len(part) or not f:
+                assert (n, n_map) == (0, 0)
+                seen["missing"] += 1
+            elif e.obj_type(int(r)) is None:
+                assert (n, n_map) == (0, 0)
+                seen["scalar"] += 1
+            elif e.obj_type(int(r)) in ("list", "text"):
+                seen["seq" if n else "empty"] += 1
+    assert all(seen.values()), seen
+
+
+QUERIES = {
+    "text-1": {"kind": "text", "path": ["t"]},
+    "text-3": {"kind": "text", "path": ["m", "n", "t"]},
+    "text-behind-int": {"kind": "text", "path": ["l", 1, "x"]},
+    "text-empty": {"kind": "text", "path": ["e"]},
+    "text-of-list": {"kind": "text", "path": ["l"]},
+    "text-of-scalar": {"kind": "text", "path": ["s"]},
+    "text-of-root": {"kind": "text", "path": []},
+    "text-deleted": {"kind": "text", "path": ["gone"]},
+    "text-never-seen": {"kind": "text", "path": ["nope"]},
+    "lookup-1": {"kind": "lookup", "path": ["s"]},
+    "lookup-1-object": {"kind": "lookup", "path": ["m"]},
+    "lookup-3": {"kind": "lookup", "path": ["m", "n", "k"]},
+    "lookup-behind-int": {"kind": "lookup", "path": ["l", 1, "n"]},
+    "lookup-int-last": {"kind": "lookup", "path": ["l", 0]},
+    "lookup-scalar-mid-path": {"kind": "lookup", "path": ["s", "x"]},
+    "lookup-no-path": {"kind": "lookup", "path": []},
+    "len-0": {"kind": "len", "path": []},
+    "len-1-text": {"kind": "len", "path": ["t"]},
+    "len-1-list": {"kind": "len", "path": ["l"]},
+    "len-1-map": {"kind": "len", "path": ["m"]},
+    "len-1-empty": {"kind": "len", "path": ["el"]},
+    "len-3-list": {"kind": "len", "path": ["m", "n", "l"]},
+    "len-3-empty-map": {"kind": "len", "path": ["m", "n", "o"]},
+    "len-behind-int": {"kind": "len", "path": ["l", 2]},
+    "len-of-scalar": {"kind": "len", "path": ["s"]},
+    "len-of-element": {"kind": "len", "path": ["l", 0]},
+    "index-1": {"kind": "index", "path": ["t"], "index": 4},
+    "index-1-object": {"kind": "index", "path": ["l"], "index": 1},
+    "index-3": {"kind": "index", "path": ["m", "n", "l"], "index": 1},
+    "index-behind-int": {"kind": "index", "path": ["l", 2], "index": 2},
+    "index-past-end": {"kind": "index", "path": ["l"], "index": 4},
+    "index-of-empty": {"kind": "index", "path": ["el"], "index": 0},
+    "index-of-map": {"kind": "index", "path": ["m"], "index": 0},
+    "index-not-int": {"kind": "index", "path": ["l"], "index": "1"},
+    "int-step-on-map": {"kind": "len", "path": ["m", 0]},
+    "int-step-past-end": {"kind": "text", "path": ["l", 9, "x"]},
+    "odd-step": {"kind": "len", "path": ["m", 1.5]},
+}
+
+
+@pytest.fixture(scope="module")
+def together(repo, nested):
+    """Every query above in ONE flush (a `lookup` and a `text` of one
+    doc among them, of course): {name: answer}."""
+    url = nested[0]
+    return dict(zip(QUERIES, _flush(repo, [(url, q) for q in
+                                           QUERIES.values()])))
+
+
+@pytest.mark.parametrize("name", sorted(QUERIES))
+def test_the_tier_answers_as_its_host_twin(repo, nested, together, name):
+    """A read flushed alone and the same read flushed among all the
+    others (another program, other outputs fetched) both give what the
+    per-request host path gives."""
+    url, doc, _e = nested
+    want = host_read(doc, QUERIES[name])["value"]
+    assert _flush(repo, [(url, QUERIES[name])]) == [want]
+    assert together[name] == want
+    if name.endswith(("-1", "-3", "-0")) or "behind" in name:
+        assert want is not None
+
+
+def _programs_of(repo, reads, monkeypatch):
+    """[(program kind, B, outputs skipped)] of ONE warm flush of
+    `reads`, with its answers and the counters it moved."""
+    calls = []
+    for kind in kernels.KINDS:
+        def spy(es, qobj, qkey, skip=(), kind=kind,
+                real=getattr(kernels, kind)):
+            calls.append((kind, kernels.batch_bucket(len(es)),
+                          tuple(skip)))
+            return real(es, qobj, qkey, skip)
+        monkeypatch.setattr(kernels, kind, spy)
+    before = telemetry.snapshot()
+    answers = _flush(repo, reads)
+    after = telemetry.snapshot()
+    moved = {k: after["serve." + k] - before.get("serve." + k, 0)
+             for k in ("dispatches", "fused_steps", "batches",
+                       "host_memo_hits", "fallbacks")}
+    return calls, answers, moved
+
+
+ROWS, NO_MAP = (kernels.ROW, kernels.FOUND), (kernels.N_MAP,)
+TEXT = {"kind": "text", "path": ["t"]}
+LEN = {"kind": "len", "path": ["t"]}
+KEY = {"kind": "lookup", "path": ["s"]}
+FLUSHES = {
+    # the cell's mix on one bucket
+    "mix": ([TEXT, TEXT, TEXT, KEY, LEN],
+            [("seq_order", 16, ())], 4),
+    "texts": ([TEXT, TEXT], [("seq_order", 4, NO_MAP)], 2),
+    "text-and-lookup-of-one-doc": (
+        [TEXT, KEY], [("seq_order", 4, NO_MAP)], 1),
+    "lens": ([LEN] * 5, [("counts", 16, ())], 5),
+    "len-and-lookup": ([LEN, KEY], [("counts", 4, ())], 1),
+    "lookups": ([KEY] * 3, [("map_lookup", 4, NO_MAP)], 0),
+    "root-len": ([{"kind": "len", "path": []}],
+                 [("counts", 1, ROWS)], 0),
+    "root-len-and-lookup": (
+        [{"kind": "len", "path": []}, KEY], [("counts", 4, ())], 0),
+    # a path of three string steps: a round a step, the last one fused
+    "three-steps": (
+        [{"kind": "text", "path": ["m", "n", "t"]}, KEY],
+        [("map_lookup", 4, NO_MAP), ("map_lookup", 1, NO_MAP),
+         ("seq_order", 1, NO_MAP)], 1),
+    # an int step behind a string step leaves with it; what lies
+    # behind the int step takes the next round
+    "int-behind-string": (
+        [{"kind": "len", "path": ["l", 2]}],
+        [("seq_order", 1, NO_MAP), ("counts", 1, ROWS)], 1),
+    "text-behind-int": (
+        [{"kind": "text", "path": ["l", 1, "x"]}],
+        [("seq_order", 1, NO_MAP), ("seq_order", 1, NO_MAP)], 2),
+    # what the host can tell goes nowhere
+    "never-seen": ([{"kind": "text", "path": ["nope"]},
+                    {"kind": "text", "path": []}], [], 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLUSHES))
+def test_a_flush_makes_one_dispatch_a_round(repo, nested, monkeypatch, name):
+    """The programs one flush runs, their batch, and the outputs it
+    left on the device: one dispatch a round of the longest path, of
+    the widest kind its reads ask for."""
+    url, doc, _e = nested
+    queries, programs, fused = FLUSHES[name]
+    calls, answers, moved = _programs_of(
+        repo, [(url, q) for q in queries], monkeypatch
+    )
+    assert calls == programs
+    assert answers == [host_read(doc, q)["value"] for q in queries]
+    assert moved == {
+        "dispatches": len(programs), "fused_steps": fused, "batches": 1,
+        "host_memo_hits": 0, "fallbacks": 0,
+    }
+
+
+def test_two_row_buckets_take_a_dispatch_each(repo, nested, monkeypatch):
+    """The mix over a short doc and a long one: one seq_order a row
+    bucket, whatever the reads of a bucket ask for."""
+    url = nested[0]
+    long = repo.create()
+    repo.change(long, lambda d: d.__setitem__("t", Text("x" * 300)))
+    assert repo.read(long, LEN) == 300
+    reads = [(url, TEXT), (long, KEY), (long, LEN), (url, KEY), (long, TEXT)]
+    calls, answers, moved = _programs_of(repo, reads, monkeypatch)
+    assert sorted(calls) == [("seq_order", 4, ()), ("seq_order", 4, NO_MAP)]
+    assert answers == ["outer text", None, 300, 3, "x" * 300]
+    assert (moved["dispatches"], moved["fused_steps"]) == (2, 3)
+
+
+def test_dispatches_per_batch_reads_one_for_the_mix(repo, nested):
+    """The metric the issue adds is a data file for the `counter_ratio`
+    reader: its entry in BENCHMARK.json agrees with it, two flushes of
+    the cell's mix read 1.0, and a program without the counters (none
+    is new: the parent has both) would read nothing and not raise."""
+    from benchmark.readers import counter_ratio
+
+    name = "serve.dispatches_per_batch"
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           name + ".json")) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    entry = bench["per_layer"][-1]
+    assert entry["name"] == spec["name"] == name
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == spec[key], key
+    assert entry["workloads"] == spec["cells"] == [
+        "reads.resident", "rw.ycsb-a"]
+    cells = {w["name"] for w in bench["workloads"]}
+    assert set(entry["workloads"]) <= cells
+    url = nested[0]
+    mix = [(url, q) for q in (TEXT, TEXT, TEXT, KEY, LEN)]
+    before = telemetry.snapshot()
+    _flush(repo, mix)
+    _flush(repo, mix[2:])
+    obs = {"counters_before": before,
+           "counters_after": telemetry.snapshot()}
+    assert counter_ratio.read(spec["params"], obs) == 1.0
+    assert counter_ratio.read(spec["params"], {
+        "counters_before": {}, "counters_after": {"serve.batches": 2},
+    }) is None

@@ -145,7 +145,7 @@ class Entry:
 import jax.numpy as jnp
 e = Entry()
 e.dev = jnp.asarray(np.zeros((sk.N_LANES, 64), np.int32))
-sk.counts([e], [-1])
+sk.counts([e], [-1], [-1])
 print("OK")
 """
 
@@ -207,7 +207,7 @@ def _compile_three_and_check(monkeypatch, tmp_path):
     class Entry:
         dev = jax.numpy.zeros((sk.N_LANES, 64), jax.numpy.int32)
 
-    sk.counts([Entry()], [-1])  # serve
+    sk.counts([Entry()], [-1], [-1])  # serve
     z = np.zeros((1, 64), np.int32)
     jax.block_until_ready(ck.materialize_live_device(  # live
         np.full((1, 64), 7, np.uint8), z, z, z - 1, z - 1, z - 3, z,
